@@ -8,8 +8,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import fedml_tpu  # noqa: F401  (honors FEDML_TPU_FORCE_CPU before jax use)
-
 import numpy as np
 
 from fedml_tpu.fa import FASimulator, run_fa_cross_silo

@@ -13,8 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import fedml_tpu  # noqa: F401  (honors FEDML_TPU_FORCE_CPU before jax use)
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -12,8 +12,6 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import fedml_tpu  # noqa: F401  (honors FEDML_TPU_FORCE_CPU before jax use)
-
 import fedml_tpu.api as api  # noqa: E402
 
 

@@ -11,9 +11,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import fedml_tpu  # noqa: F401  (honors FEDML_TPU_FORCE_CPU before jax use)
-
-
 import fedml_tpu
 
 if len(sys.argv) > 1:

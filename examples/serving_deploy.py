@@ -9,8 +9,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import fedml_tpu  # noqa: F401  (honors FEDML_TPU_FORCE_CPU before jax use)
-
 import json
 import tempfile
 import urllib.request

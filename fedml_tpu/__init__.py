@@ -13,19 +13,9 @@ data/data_loader.py:234 data.load, model/model_hub.py:19 model.create).
 from __future__ import annotations
 
 import logging
-import os as _os
 import random
 
 import numpy as np
-
-# FEDML_TPU_FORCE_CPU=1 pins jax to CPU (the examples smoke suite / CI knob:
-# some TPU plugins override the JAX_PLATFORMS env var, so the config flag
-# must be set in-process). Guarded import keeps the package's normal
-# no-jax-at-import laziness.
-if _os.environ.get("FEDML_TPU_FORCE_CPU"):
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 from . import config as _config
 from .config import Config, load_config

@@ -243,7 +243,7 @@ def render_json(findings: list[Finding], stats: dict) -> str:
 
 # ------------------------------------------------------- shared AST helpers
 def dotted_name(node: ast.AST) -> Optional[str]:
-    """'jax.experimental.shard_map.shard_map' for nested Attribute/Name
+    """'jax.lax.scan' for nested Attribute/Name
     chains; None for anything dynamic."""
     parts: list[str] = []
     while isinstance(node, ast.Attribute):

@@ -10,9 +10,9 @@ from typing import Iterable, Optional
 from .core import Finding, LintContext, Rule, dotted_name
 
 # Spellings that construct a compiled program. Matched on the dotted call
-# chain's suffix so aliased module imports (`import jax.experimental.
-# shard_map as shmap`) still register via the bare-name import map.
-_JIT_SUFFIXES = ("jax.jit", "jax.pmap")
+# chain's suffix; bare names (`from jax import shard_map`) register via
+# the import map.
+_JIT_SUFFIXES = ("jax.jit", "jax.pmap", "jax.shard_map")
 _BARE_JITTERS = {"jit", "pmap", "shard_map", "track_jit"}
 
 # Tracing entry points that take a function OPERAND (not a decorator):
@@ -22,14 +22,14 @@ _TRACE_OPERANDS: dict[str, tuple[int, ...]] = {
     "jax.value_and_grad": (0,), "jax.checkpoint": (0,), "jax.remat": (0,),
     "lax.scan": (0,), "lax.map": (0,), "lax.fori_loop": (2,),
     "lax.while_loop": (0, 1), "lax.cond": (1, 2), "lax.associative_scan": (0,),
-    "shard_map.shard_map": (0,), "shard_map": (0,), "track_jit": (0,),
+    "shard_map": (0,), "track_jit": (0,),
 }
 
 
 def _bare_jit_names(tree: ast.AST) -> set[str]:
     """Names this module imported that construct compiled programs
-    (`from jax import jit`, `from jax.experimental.shard_map import
-    shard_map`, `from ..utils.metrics import track_jit`)."""
+    (`from jax import jit`, `from jax import shard_map`,
+    `from ..utils.metrics import track_jit`)."""
     names: set[str] = set()
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
@@ -45,8 +45,7 @@ def _is_jit_ctor(call: ast.Call, bare: set[str]) -> bool:
         return False
     if any(d == s or d.endswith("." + s) for s in _JIT_SUFFIXES):
         return True
-    return d in bare or (("." in d) and d.rsplit(".", 1)[1] in
-                         {"shard_map"} and "shard_map" in d)
+    return d in bare
 
 
 def _donate_argnums(call: ast.Call) -> Optional[tuple[int, ...]]:

@@ -47,13 +47,11 @@ class CentralizedTrainer:
     def __init__(self, cfg: Config, dataset: Optional[FedDataset] = None,
                  model=None):
         from ..data import loader as data_loader
-        from ..utils import maybe_enable_compilation_cache
+        from ..utils import enable_compilation_cache
 
         self.cfg = cfg
         t = cfg.train_args
-        # before the first trace: repeated runs reuse on-disk compiled
-        # programs when common_args.extra.compilation_cache_dir is set
-        maybe_enable_compilation_cache(cfg)
+        enable_compilation_cache()   # before the first trace
         # opt-in live /metrics endpoint (common_args.extra.metrics_port)
         from ..utils.prometheus import maybe_start_metrics_server
 

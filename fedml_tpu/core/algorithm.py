@@ -38,10 +38,6 @@ Pytree = Any
 LINEAR = "linear"   # update aggregates as a sample-count-weighted mean (psum)
 FULL = "full"       # aggregator needs the full stacked update set (all_gather)
 
-# jax<=0.4.x needs local_sgd's batches gathered before the scan (see there)
-_PREGATHER_BATCHES = tuple(
-    int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-
 
 @struct.dataclass
 class ServerState:
@@ -274,22 +270,10 @@ def local_sgd(
         nonempty = (cnt > 0).astype(jnp.float32)
         return (p, s), (loss * cnt, correct, cnt, nonempty)
 
-    if _PREGATHER_BATCHES:
-        # jax<=0.4.x: a dynamic row-gather inside the scan body miscompiles
-        # under shard_map (the SPMD partitioner feeds devices >0 skewed rows
-        # inside the while loop — caught by test_sp_and_xla_backends_agree);
-        # gathering every batch BEFORE the scan produces a leading batch
-        # axis that partitions correctly, at the cost of materializing
-        # ~epochs× the shard inside the program — so it is gated to the jax
-        # versions that need it
-        xs = {k: v[batch_idx] for k, v in shard.items()}
-        scan_step = step
-    else:
-        xs = batch_idx
-        scan_step = lambda carry, idx: step(
-            carry, {k: v[idx] for k, v in shard.items()})
     (params, opt_state), (losses, corrects, counts, steps) = jax.lax.scan(
-        scan_step, (params, opt_state), xs
+        lambda carry, idx: step(
+            carry, {k: v[idx] for k, v in shard.items()}),
+        (params, opt_state), batch_idx,
     )
     metrics = ClientMetrics(losses.sum(), corrects.sum(), counts.sum())
     if return_opt_state:

@@ -25,6 +25,7 @@ Sequence-parallel data layout: {"x": [N, S, T], "y": [N, S, T],
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
@@ -32,10 +33,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover — jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..algorithms.builtin import make_fedavg
 from ..config import TrainArgs
@@ -63,9 +61,18 @@ def federated_lora(model: TransformerLM, base_params: Pytree, t: TrainArgs,
     build_round_fn / cross-silo managers — the round payload is the adapter
     tree only (reference parity: peft exchanges only adapter state_dicts).
 
-    NOTE: the round engines donate their input server state; if you need the
-    initial adapters after a round has run (e.g. to seed a second runtime),
-    copy them first: jax.tree.map(jnp.array, adapters)."""
+    The frozen base rides in `ServerState.extra`: `alg.server_init` puts it
+    there and every client reads it off the broadcast, so the round program
+    takes the base as an ARGUMENT. A base closed over by the apply fn is
+    baked into the program as a literal instead — at 1.2B parameters that
+    is 2.4 GB of HLO text to lower, a second copy of the base in device
+    memory, and minutes of compile.
+
+    NOTE: the round engines DONATE their input server state, so after the
+    first round both the adapters and the base arrays handed in here are
+    consumed — the live ones are `out.server_state.params` / `.extra`
+    (copy first, `jax.tree.map(jnp.array, tree)`, to keep a second
+    handle)."""
     from ..models.hub import mixed_precision_apply
 
     adapters = lora_init(rng, base_params, rank=rank, targets=targets)
@@ -73,9 +80,21 @@ def federated_lora(model: TransformerLM, base_params: Pytree, t: TrainArgs,
     # (simulator.py): bf16 runs the merged matmuls on the MXU while the
     # adapters/optimizer stay f32
     base_apply = mixed_precision_apply(model.apply, t.compute_dtype)
-    apply_fn = lora_apply_fn(base_apply, base_params, alpha)
-    alg = make_fedavg(apply_fn, t)
-    return alg, adapters
+
+    def fedavg_over(base):
+        return make_fedavg(lora_apply_fn(base_apply, base, alpha), t)
+
+    avg = fedavg_over(base_params)   # its server side never calls the apply
+
+    def server_init(params, cfg=None):
+        return avg.server_init(params, cfg).replace(extra=base_params)
+
+    def client_update(bcast, shard, client_state, rng):
+        return fedavg_over(bcast["extra"]).client_update(
+            {**bcast, "extra": None}, shard, client_state, rng)
+
+    return dataclasses.replace(avg, server_init=server_init,
+                               client_update=client_update), adapters
 
 
 def make_fedllm_seq_round(

@@ -475,10 +475,6 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         attn_fused = paged_attention
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
-            try:  # newer jax exports shard_map at the top level
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
 
             # heads are independent in attention, so the mp split of the
             # pool (partition.paged_kv_cache_spec) reaches the kernel
@@ -486,27 +482,13 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             # table/positions replicated — no resharding, no collective
             # (the int8 scales split the same heads axis:
             # partition.paged_kv_scale_spec)
+            heads = P(None, None, "mp", None)
+            in_specs = (heads, heads, heads, P(None, None), P(None))
             if quant:
-                attn_fused = shard_map(
-                    lambda q, kp, vp, pg, po, ksc, vsc: paged_attention(
-                        q, kp, vp, pg, po, ksc, vsc),
-                    mesh=mesh,
-                    in_specs=(P(None, None, "mp", None),
-                              P(None, None, "mp", None),
-                              P(None, None, "mp", None),
-                              P(None, None), P(None),
-                              P(None, "mp"), P(None, "mp")),
-                    out_specs=P(None, None, "mp", None), check_rep=False)
-            else:
-                attn_fused = shard_map(
-                    lambda q, kp, vp, pg, po: paged_attention(
-                        q, kp, vp, pg, po),
-                    mesh=mesh,
-                    in_specs=(P(None, None, "mp", None),
-                              P(None, None, "mp", None),
-                              P(None, None, "mp", None),
-                              P(None, None), P(None)),
-                    out_specs=P(None, None, "mp", None), check_rep=False)
+                in_specs += (P(None, "mp"), P(None, "mp"))
+            attn_fused = jax.shard_map(
+                paged_attention, mesh=mesh, in_specs=in_specs,
+                out_specs=heads, check_vma=False)
 
     def verify(params, adapters, cache, pages, pos, tokens, active):
         """C tokens per slot through one forward (C = tokens.shape[1];

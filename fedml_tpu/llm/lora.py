@@ -90,8 +90,11 @@ def lora_merge(base_params: Pytree, adapters: dict, alpha: float = 16.0,
 def lora_apply_fn(apply_fn: Callable, base_params: Pytree,
                   alpha: float = 16.0) -> Callable:
     """Wrap a flax apply into the (adapters -> logits) view the FL engine
-    trains: variables = {"params": adapters}; base weights are closure
-    constants (replicated device arrays under jit)."""
+    trains: variables = {"params": adapters}. `base_params` may be traced
+    values (llm.federated_lora binds the base off the round's broadcast so
+    it stays a program argument); concrete arrays closed over here become
+    literals of whatever jit traces the result — fine at test sizes, not
+    at a billion parameters."""
 
     def wrapped(variables, x, *args, **kwargs):
         merged = lora_merge(base_params, variables["params"], alpha)

@@ -40,7 +40,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.seq import ring_attention
@@ -74,7 +74,7 @@ def make_ring_attn_fn(mesh: Mesh, seq_axis: str = "seq",
     ring = shard_map(
         functools.partial(ring_attention, axis_name=seq_axis),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def attn(q, k, v):
         return ring(q, k, v)

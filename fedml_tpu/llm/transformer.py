@@ -99,10 +99,9 @@ class TransformerLM(nn.Module):
     remat: bool = False
     # scan-over-layers: compile ONE block and lax.scan it, with block params
     # stacked on a leading [n_layers] axis (`blocks/...: [L, ...]`). The HLO
-    # is O(1) in depth instead of O(L) — a 32-layer d4096 model unrolled is
-    # too big for some compile services (observed: the remote-compile helper
-    # 500s on unrolled LLaMA-7B-shape while L=4 compiles fine), and compile
-    # time drops ~L-fold. Combines with `remat` (checkpoint per scanned
+    # is O(1) in depth instead of O(L), and compile time drops ~L-fold
+    # (the 16-layer 1.2B round program compiles for a v5e in ~11 s:
+    # PERF.md). Combines with `remat` (checkpoint per scanned
     # step = the flax remat_scan pattern). llm/lora.py and llm/quant.py
     # both understand the stacked [L, din, dout] kernel layout.
     scan_layers: bool = False

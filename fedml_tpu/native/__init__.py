@@ -7,13 +7,18 @@ a jax-free edge trainer, and a wire-integrity checksum; see
 fedml_native.cpp's header for the inventory.)
 
 The .so builds lazily with g++ (baked into the image; pybind11 is not, so
-bindings are plain ctypes over an extern-C ABI). Every caller has a numpy
-fallback: `available()` is False and everything still works when no
-compiler is present.
+bindings are plain ctypes over an extern-C ABI) and is NAMED by a hash of
+fedml_native.cpp: a binary built from other source — a stale one, or a
+stray one that rode in with a copy of the tree — has another name and is
+never loaded (file times say nothing once a tree has been copied). Every
+caller has a numpy fallback: `available()` is False and everything still
+works when no compiler is present.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,17 +31,22 @@ log = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "fedml_native.cpp")
-_SO = os.path.join(_HERE, "libfedml_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libfedml_native.{digest}.so")
+
+
+def _build(so: str) -> bool:
     # compile to a per-pid temp path, then atomically rename: concurrent
     # processes racing on the shared .so would otherwise dlopen a
     # half-written file (or SIGBUS on truncated mapped pages)
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
@@ -47,7 +57,14 @@ def _build() -> bool:
         log.warning("native build failed; using numpy fallbacks:\n%s",
                     r.stderr[-2000:])
         return False
-    os.replace(tmp, _SO)
+    os.replace(tmp, so)
+    # binaries of other source versions are dead weight from here on
+    for old in glob.glob(os.path.join(_HERE, "libfedml_native*.so")):
+        if old != so:
+            try:
+                os.remove(old)
+            except OSError:
+                pass
     return True
 
 
@@ -57,14 +74,13 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = (not os.path.exists(_SO)
-                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
-            log.warning("could not load %s: %s", _SO, e)
+            log.warning("could not load %s: %s", so, e)
             return None
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
         f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")

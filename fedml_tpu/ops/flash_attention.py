@@ -135,6 +135,7 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, interpret: bool):
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum l
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -289,6 +290,7 @@ def _pallas_bwd(q, k, v, o, lse_q, do, block_q: int, block_k: int,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse_q, dlt_q)
 
     # dK/dV grid: (bh, k-block, q-block) — q streams, k/v accumulate
@@ -307,6 +309,7 @@ def _pallas_bwd(q, k, v, o, lse_q, do, block_q: int, block_k: int,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse_q, dlt_q)
     return dq, dk, dv
 

@@ -30,11 +30,15 @@ layer):
     ->     [S, C, H, Dh]
 
 With an int8 pool (`kv_quant: int8`), the per-(page, head) f32 scales
-[P, H] ride as two further operands whose BlockSpec index maps read the
-SAME scalar-prefetched page-table entry as the K/V slabs: each grid
-step DMAs its page's (1, H) scale rows alongside the (page_size, H, Dh)
-int8 slab and dequantizes in VMEM — the pool crosses HBM at one byte
-per element, which is the whole point.
+[P, H] are gathered through the page table OUTSIDE the kernel into
+[S, H, max_pages] (S * max_pages * H floats — noise next to one slab)
+and ride as two further operands blocked per slot: a (1, H) row of the
+[P, H] array is not a legal TPU block (the last two block dims must be
+(8, 128)-tiled or full), a whole (H, max_pages) slot view is. Each grid
+step picks its page's [H, 1] column with a lane mask — heads already on
+sublanes, which is where the (page_size, H, Dh) slab wants them — and
+dequantizes the int8 slab in VMEM: the pool crosses HBM at one byte per
+element, which is the whole point.
 
 Semantics match the gather path exactly: query i of slot s attends
 virtual positions <= pos[s] + i of the slot's page-table view (the
@@ -84,9 +88,8 @@ def _dot(a, b, contract, batch):
 def _kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             page_size: int, scale: float, quant: bool):
     if quant:
-        # int8 pool: the per-(page, head) scales ride as two extra
-        # operands whose index map follows the SAME page-table entry as
-        # the K/V slabs — each grid step sees exactly its page's scales
+        # int8 pool: this slot's per-(head, page) scales [1, H, max_pages],
+        # page-table-gathered by the caller
         ks_ref, vs_ref, o_ref, o_acc, m_acc, l_acc = rest
     else:
         o_ref, o_acc, m_acc, l_acc = rest
@@ -111,11 +114,16 @@ def _kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         if quant:
             # in-place dequant of the DMA'd slab: the pool stays int8 in
             # HBM and on the wire; f32 rows exist only in VMEM, cast to
-            # the query dtype so the MXU contract matches the bf16 path
-            kb = (kb.astype(jnp.float32)
-                  * ks_ref[0][None, :, None]).astype(q.dtype)
-            vb = (vb.astype(jnp.float32)
-                  * vs_ref[0][None, :, None]).astype(q.dtype)
+            # the query dtype so the MXU contract matches the bf16 path.
+            # This page's scale column comes out of the slot's
+            # [H, max_pages] view by lane mask (a dynamic lane index is
+            # not a Mosaic load; one nonzero term keeps the sum exact).
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, ks_ref.shape[1:], 1) == pj
+            ksc = jnp.where(here, ks_ref[0], 0.0).sum(axis=1, keepdims=True)
+            vsc = jnp.where(here, vs_ref[0], 0.0).sum(axis=1, keepdims=True)
+            kb = (kb.astype(jnp.float32) * ksc[None]).astype(q.dtype)
+            vb = (vb.astype(jnp.float32) * vsc[None]).astype(q.dtype)
         # scores per head: batch H, contract Dh -> [H, C, ps]
         s = _dot(q, kb, ((2,), (2,)), ((1,), (1,))) * scale
         qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
@@ -161,11 +169,12 @@ def _call(q, k_pool, v_pool, pages, pos, scales, interpret: bool):
     ]
     operands = [pages, pos, q, k_pool, v_pool]
     if quant:
-        # per-(page, head) f32 scales [P, H], page-table-indexed like
-        # the slabs they dequantize
-        in_specs += [pl.BlockSpec((1, h), lambda s, p, pt, ps_: (pt[s, p], 0)),
-                     pl.BlockSpec((1, h), lambda s, p, pt, ps_: (pt[s, p], 0))]
-        operands += [scales[0], scales[1]]
+        # per-(page, head) f32 scales [P, H] -> this call's page-table
+        # view [S, H, max_pages], one whole (H, max_pages) block per slot
+        spec = pl.BlockSpec((1, h, max_pages),
+                            lambda s, p, pt, ps_: (s, 0, 0))
+        in_specs += [spec, spec]
+        operands += [jnp.swapaxes(sc[pages], 1, 2) for sc in scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,     # pages + pos steer the index maps
         grid=(s_, max_pages),
@@ -184,6 +193,7 @@ def _call(q, k_pool, v_pool, pages, pos, scales, interpret: bool):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, c, h, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*operands)
 
 

@@ -34,10 +34,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover — jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.algorithm import FedAlgorithm, ServerState, make_batch_indices
 from ..ops import tree as tu

@@ -30,10 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover — jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.algorithm import FULL, ClientMetrics, FedAlgorithm, ServerState
 from ..ops import tree as tu
@@ -45,13 +42,9 @@ Pytree = Any
 def _localize(tree: Pytree, axis: str) -> Pytree:
     """Convert replicated values to device-varying inside a shard_map body,
     so gradients w.r.t. them stay per-device instead of auto-psum'd."""
-    if hasattr(jax.lax, "pcast"):  # jax >= 0.9
-        cast = lambda x: jax.lax.pcast(x, (axis,), to="varying")
-    elif hasattr(jax.lax, "pvary"):  # pragma: no cover
-        cast = lambda x: jax.lax.pvary(x, (axis,))
-    else:  # pragma: no cover — jax <= 0.4.x: no replication casting; body-
-        return tree  # level grads are already per-device under shard_map
-    return jax.tree.map(lambda x: cast(x) if hasattr(x, "dtype") else x, tree)
+    return jax.tree.map(
+        lambda x: (jax.lax.pcast(x, (axis,), to="varying")
+                   if hasattr(x, "dtype") else x), tree)
 
 
 class RoundOutput(NamedTuple):
@@ -442,7 +435,7 @@ def make_round_parts(
                 # Mark the replicated broadcast as device-varying before any
                 # differentiation: shard_map treats grads w.r.t. replicated
                 # values as global (auto-psum across the mesh), but local SGD
-                # needs per-client gradients. pcast/pvary localizes the copy.
+                # needs per-client gradients. pcast localizes the copy.
                 bc = _localize(bc, axis)
                 o = _localize(o, axis)
                 return run_chunk(bc, sh, rg, w, kp, a, bf, o)

@@ -34,14 +34,6 @@ import jax.numpy as jnp
 _NEG = -1e9  # finite "-inf": keeps exp() NaN-free for fully-masked rows
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a shard_map mesh axis. jax <= 0.4.x has no
-    lax.axis_size; psum of the literal 1 folds to the same static int."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            q_offset=0, k_offset=0) -> jax.Array:
     """Reference causal attention. q/k/v: [B, T, H, D] -> [B, T, H, D].
@@ -93,7 +85,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     of comparing global positions, so fully-future blocks contribute nothing
     (their work is wasted MXU cycles — acceptable; a skew-schedule variant
     can skip them later)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, t, h, d = q.shape
     scale = d ** -0.5
@@ -131,7 +123,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     [B, T, H/n, D], dense causal attention runs on full sequences per head
     group, and the output all_to_alls back to seq-sharded. Requires
     H % axis_size == 0."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(
             f"ulysses needs heads ({q.shape[2]}) divisible by the "

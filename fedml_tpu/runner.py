@@ -42,7 +42,10 @@ class FedMLRunner:
     def __init__(self, cfg: Config, dataset=None, model=None,
                  role: str = "server", rank: int = 0,
                  transport: Optional[str] = None, **kw):
+        from .utils import enable_compilation_cache
+
         self.cfg = cfg
+        enable_compilation_cache()   # before any runtime's first trace
         tt = cfg.common_args.training_type
         fa_task = cfg.train_args.extra.get("fa_task")
         if fa_task:

@@ -157,6 +157,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import enable_compilation_cache
 from ..utils import metrics as _mx
 from ..utils import xla_ledger as _ledger
 from ..utils.events import recorder
@@ -437,6 +438,7 @@ class DecodeEngine:
 
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1; got {n_slots}")
+        enable_compilation_cache()   # before the first trace
         self.model = model
         self.max_len = int(max_len)
         self.n_slots = int(n_slots)
@@ -1191,7 +1193,6 @@ class DecodeEngine:
         In paged mode "admit" is the chunk program (<= log2(prefill_chunk)
         + 1 buckets: chunks are prefill_chunk-sized except a final
         pow2-bucketed remainder)."""
-        out = {}
         pairs = [("step", self._step_jit), ("admit", self._admit_jit)]
         if self._spec_jit is not None:
             # spec mode replaces the step dispatch with ONE verify-window
@@ -1201,12 +1202,7 @@ class DecodeEngine:
             # admit_batch > 1 replaces the per-admission chunk dispatch:
             # bounded by chunk buckets x pow2 batch buckets
             pairs.append(("admit_batch", self._admit_many_jit))
-        for name, fn in pairs:
-            try:
-                out[name] = fn._cache_size()
-            except Exception:  # jax without the introspection hook
-                out[name] = None
-        return out
+        return {name: fn._cache_size() for name, fn in pairs}
 
     # ------------------------------------------------------------ engine loop
     def _loop(self) -> None:

@@ -74,6 +74,7 @@ class FedMLInferenceRunner:
         self._chaos = chaos
         self._chaos_rank = int(chaos_rank)
         self._chaos_tokens = 0
+        self._chaos_fired = False
         self._chaos_lock = threading.Lock()
         runner = self
 
@@ -352,11 +353,19 @@ class FedMLInferenceRunner:
         with self._chaos_lock:
             self._chaos_tokens += 1
             n = self._chaos_tokens
-        if self._chaos.replica_killed(self._chaos_rank, n):
+            due = self._chaos.replica_killed(self._chaos_rank, n)
+            # ONE kill per schedule entry: concurrent streams all tick
+            # past the threshold, and every one of them is severed (the
+            # process is dead), but only the first IS the kill — the
+            # counter counts kills, not cut connections
+            first = due and not self._chaos_fired
+            self._chaos_fired = self._chaos_fired or due
+        if first:
             _mx.inc("fed.chaos.replica_kills")
             with recorder.span("serving.chaos.replica_kill",
                                rank=self._chaos_rank, tokens=n):
                 self.kill()
+        if due:
             raise ConnectionError(
                 f"chaos: replica {self._chaos_rank} killed after "
                 f"{n} streamed tokens")

@@ -144,9 +144,11 @@ def start_replica(spec: dict):
     import jax.numpy as jnp
 
     from ..models import hub as model_hub
+    from ..utils import enable_compilation_cache
     from .inference_runner import FedMLInferenceRunner
     from .predictor import JaxPredictor
 
+    enable_compilation_cache()   # before any predictor's first trace
     chaos = None
     if spec.get("chaos"):
         from ..comm.chaos import FaultSpec

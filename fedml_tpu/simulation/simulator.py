@@ -30,7 +30,7 @@ from ..ops import tree as tu
 from ..parallel.mesh import make_mesh
 from .. import schedule as lpt_sched
 from ..parallel.round import build_block_fn, build_round_fn, shard_fed_data
-from ..utils import maybe_enable_compilation_cache
+from ..utils import enable_compilation_cache
 from ..utils.events import recorder
 
 
@@ -72,9 +72,7 @@ class Simulator:
                  model=None, mesh=None):
         self.cfg = cfg
         t = cfg.train_args
-        # before the first trace: repeated runs reuse on-disk compiled
-        # programs when common_args.extra.compilation_cache_dir is set
-        maybe_enable_compilation_cache(cfg)
+        enable_compilation_cache()   # before the first trace
         self.dataset = dataset if dataset is not None else data_loader.load(cfg)
         self.num_classes = self.dataset.num_classes
 
@@ -240,10 +238,11 @@ class Simulator:
             )
         else:
             self.client_states = jnp.zeros((self.dataset.num_clients,))
-        if self._cohort_chunk and self.mesh is not None:
-            # pin replicated layouts up front: the chunk/finalize jit caches
-            # key on input shardings, and uncommitted first-round state
-            # would buy one throwaway compile per program before settling
+        if self.mesh is not None:
+            # pin replicated layouts up front: the round/chunk/finalize jit
+            # caches key on input shardings, and uncommitted first-round
+            # state would buy one throwaway compile per program before
+            # settling on the layouts the programs themselves return
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             rep = NamedSharding(self.mesh, P())

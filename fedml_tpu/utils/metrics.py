@@ -365,10 +365,7 @@ class _TrackedJit:
 
     def __call__(self, *args, **kwargs):
         out = self._fn(*args, **kwargs)
-        try:
-            size = self._fn._cache_size()
-        except Exception:  # jax version without the introspection hook
-            return out
+        size = self._fn._cache_size()
         from . import xla_ledger
 
         xla_ledger.note_call(self._name)
@@ -387,6 +384,5 @@ class _TrackedJit:
 
 def track_jit(fn, name: str):
     """Wrap a jitted entry point with compile/retrace accounting (see
-    `_TrackedJit`). Safe on non-jit callables — tracking degrades to a
-    no-op when `_cache_size` is absent."""
+    `_TrackedJit`)."""
     return _TrackedJit(fn, name)

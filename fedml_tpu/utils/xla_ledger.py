@@ -5,12 +5,15 @@ The metrics plane (ISSUE 2) counts compiles and retraces; this module
 (round/block/chunk/finalize/eval, the serving engine's admit/step/spec
 programs) reports its program's `cost_analysis()` FLOPs and
 bytes-accessed plus its HBM argument/output footprint, published as
-`xla.program.*` gauges keyed by program name. Capture is AOT and
-COMPILE-FREE: on a compile-cache growth the wrapper hands this module the
-call's abstract signature (ShapeDtypeStructs — donated buffers are never
-touched), `jitted.lower(...)` answers `cost_analysis()` from the lowering
-(milliseconds, no XLA optimization pass), and argument/output bytes come
-from the avals; steady-state calls pay one counter bump. The deeper
+`xla.program.*` gauges keyed by program name. Capture is AOT and, where
+the backend allows it, COMPILE-FREE: on a compile-cache growth the wrapper
+hands this module the call's abstract signature (ShapeDtypeStructs —
+donated buffers are never touched), `jitted.lower(...)` answers
+`cost_analysis()` from the lowering (milliseconds, no XLA optimization
+pass; XLA:TPU answers only for a compiled program, so there the capture
+compiles — a load from the persistent cache the entry points keep on), and
+argument/output bytes come from the avals; steady-state calls pay one
+counter bump. The deeper
 `memory_analysis()` stats (temp + generated-code bytes) require a real
 compile — a full DUPLICATE of XLA's optimization work per program, which
 once cost tier-1 ~50% extra on engine-heavy modules — so they ride only
@@ -30,14 +33,16 @@ Two more ledgers ride along:
   (`xla.program.mfu.*`) only where a spec peak is known — on the CPU
   interpret lanes `tpu_spec_peak_tflops` is None and no MFU is claimed.
 
-Everything here degrades to a no-op on failure: a jax version without the
-AOT introspection hooks, a backend without memory stats, or a disabled
-ledger (`set_enabled(False)` — the bench overhead row's off-switch) must
-never take a training step down.
+A failed capture never takes a training step down: it logs a WARNING and
+leaves the program out of `programs()` — which is what chip_smoke.py
+checks, so the gap is seen on the chip instead of reading as a zero.
+`set_enabled(False)` is the bench overhead row's off-switch.
 """
 from __future__ import annotations
 
+import io
 import logging
+import re
 import threading
 from typing import Optional
 
@@ -57,6 +62,9 @@ _MEM_ATTRS = (("argument_size_in_bytes", "hbm_args"),
               ("output_size_in_bytes", "hbm_out"),
               ("temp_size_in_bytes", "hbm_temp"),
               ("generated_code_size_in_bytes", "hbm_code"))
+
+# the attribute a Pallas TPU call carries in the lowered module
+_KERNEL_NAME = re.compile(r'kernel_name = "([^"]+)"')
 
 # program name -> recorder span name whose wall time measures it. Multiple
 # training programs share the "train" span (per-round vs blocked vs chunked
@@ -86,7 +94,8 @@ def reset() -> None:
 
 
 def programs() -> dict:
-    """{program name: {flops, bytes, hbm_*, calls}} — a deep copy."""
+    """{program name: {flops, bytes, hbm_*, calls, kernels}} — a copy;
+    `kernels` lists the Mosaic (Pallas TPU) kernel names in the program."""
     with _lock:
         return {k: dict(v) for k, v in _programs.items()}
 
@@ -97,23 +106,50 @@ def buffers() -> dict:
         return dict(_buffers)
 
 
-def _abstract_signature(args: tuple, kwargs: dict, shardings: bool = True):
+def _abstract_signature(args: tuple, kwargs: dict):
     """The call's shapes/dtypes as ShapeDtypeStructs — valid `lower()`
     input even after the concrete (possibly donated) buffers are gone:
-    aval metadata survives buffer deletion."""
+    aval metadata survives buffer deletion. A sharding rides only where
+    the array was COMMITTED to it, which is exactly what the call itself
+    honoured: jit moves an uncommitted array (a fresh `jnp.asarray`) to
+    wherever the committed ones live, while pinning it to its default
+    device here would make a mesh program's lower() refuse the mixed
+    device set — and would make even a single-device lowering a different
+    program to the compilation cache than the one the call just stored."""
     import jax
 
     def spec(x):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
-            sharding = getattr(x, "sharding", None) if shardings else None
-            try:
-                return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                            sharding=sharding)
-            except Exception:  # noqa: BLE001 — e.g. numpy input, no sharding
-                return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            sharding = (getattr(x, "sharding", None)
+                        if getattr(x, "committed", False) else None)
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
         return x
 
     return jax.tree_util.tree_map(spec, (args, kwargs))
+
+
+def _mosaic_kernels(lowered) -> list:
+    """Names of the Pallas kernels the program hands to Mosaic, read off the
+    lowered module itself: an interpret-mode kernel lowers to plain HLO and
+    leaves no tpu_custom_call, so chip_smoke.py can assert the compiled path
+    from the program, not from a flag. Printing a module costs ~6 ms per
+    100 KB; the bytecode probe keeps the usual answer — none, for every
+    program of a CPU run — at ~1 ms."""
+    buf = io.BytesIO()
+    lowered.compiler_ir().operation.write_bytecode(file=buf)
+    if b"tpu_custom_call" not in buf.getvalue():
+        return []
+    return sorted(set(_KERNEL_NAME.findall(lowered.as_text())))
+
+
+def _cost_fields(cost) -> dict:
+    """{flops, bytes} out of whatever shape cost_analysis() answered in
+    (a dict, a one-element list of dicts, or None)."""
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0] if cost else None
+    cost = cost or {}
+    return {field: float(cost[key]) for key, field in _COST_KEYS
+            if cost.get(key) is not None}
 
 
 def note_call(name: str) -> None:
@@ -155,31 +191,23 @@ def capture(name: str, jitted, args: tuple, kwargs: dict) -> None:
         import jax
 
         spec_args, spec_kwargs = _abstract_signature(args, kwargs)
-        try:
-            lowered = jitted.lower(*spec_args, **spec_kwargs)
-        except ValueError:
-            # Mixed device sets (a mesh-sharded arg next to a
-            # single-device one) are legal in the real call — jit moves
-            # the uncommitted array — but sharding-annotated avals make
-            # lower() refuse. Strip the shardings: total cost is layout-
-            # independent.
-            spec_args, spec_kwargs = _abstract_signature(
-                args, kwargs, shardings=False)
-            lowered = jitted.lower(*spec_args, **spec_kwargs)
-        cost = lowered.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        ent: dict = {}
-        for key, field in _COST_KEYS:
-            v = cost.get(key) if hasattr(cost, "get") else None
-            if v is not None:
-                ent[field] = float(v)
+        lowered = jitted.lower(*spec_args, **spec_kwargs)
+        ent = _cost_fields(lowered.cost_analysis())
+        compiled = None
+        if "flops" not in ent:
+            # XLA:TPU answers cost_analysis for a COMPILED program only
+            # (the lowering's is None there). Every entry point keeps the
+            # persistent compilation cache on, so this compile is a load
+            # of the executable the call has just built and stored.
+            compiled = lowered.compile()
+            ent = _cost_fields(compiled.cost_analysis())
         ent["hbm_args"] = _aval_bytes((spec_args, spec_kwargs))
         ent["hbm_out"] = _aval_bytes(
             jax.eval_shape(jitted, *spec_args, **spec_kwargs))
         ent["hbm_peak"] = ent["hbm_args"] + ent["hbm_out"]
+        kernels = _mosaic_kernels(lowered)
         if os.environ.get("FEDML_TPU_XLA_DEEP") == "1":
-            mem = lowered.compile().memory_analysis()
+            mem = (compiled or lowered.compile()).memory_analysis()
             for attr, field in _MEM_ATTRS:
                 v = getattr(mem, attr, None)
                 if v is not None:
@@ -187,11 +215,11 @@ def capture(name: str, jitted, args: tuple, kwargs: dict) -> None:
             ent["hbm_peak"] = (ent["hbm_args"] + ent["hbm_out"]
                                + ent.get("hbm_temp", 0))
     except Exception as e:  # noqa: BLE001 — ledger must never break a step
-        log.debug("xla ledger: capture failed for %s: %s: %s",
-                  name, type(e).__name__, e)
+        log.warning("xla ledger: capture failed for %s: %s: %s",
+                    name, type(e).__name__, e)
         return
     with _lock:
-        _programs.setdefault(name, {}).update(ent)
+        _programs.setdefault(name, {}).update(ent, kernels=kernels)
     for field, v in ent.items():
         _mx.set_gauge(f"xla.program.{field}.{name}", v)
 

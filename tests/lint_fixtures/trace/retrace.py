@@ -1,7 +1,7 @@
 """graftlint fixture: retrace-hazard (positive + negative + suppressed).
 Never imported — parsed by the linter only."""
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bad_loop(fns, xs):

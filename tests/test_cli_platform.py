@@ -97,3 +97,22 @@ def test_cli_diagnosis(capsys):
     # grpc/native may legitimately fail in minimal images, but must report
     assert "grpc_transport" in report["checks"]
     assert "native_lib" in report["checks"]
+
+
+def test_bench_verb_parent_stays_jax_free():
+    """`python -m fedml_tpu bench` starts bench.py as a CHILD, and a chip
+    belongs to one process: the child gets it only while the parent never
+    touched jax. Pin the laziness that makes that true — importing the
+    package and the CLI module must not import jax."""
+    import os
+    import subprocess
+
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, fedml_tpu, fedml_tpu.__main__; "
+         "sys.exit(int('jax' in sys.modules))"],
+        capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert r.returncode == 0, (
+        "import fedml_tpu / fedml_tpu.__main__ pulled in jax — the bench "
+        f"verb's child could no longer get the chip\n{r.stderr[-1000:]}")

@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover — jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fedml_tpu.config import TrainArgs
@@ -144,7 +141,10 @@ def test_federated_lora_flat_trains_adapters_only():
     assert losses[-1] < losses[0] * 0.8, losses
     # the trained state is adapters-shaped, not base-shaped
     assert set(st.params.keys()) == set(
-        lora_init(jax.random.key(1), base, rank=4).keys())
+        lora_init(jax.random.key(1), st.extra, rank=4).keys())
+    # the frozen base rode the server state as a program ARGUMENT (never
+    # a closure literal) and came back untouched
+    assert jax.tree.structure(st.extra) == jax.tree.structure(base)
 
 
 @pytest.mark.slow
@@ -167,6 +167,8 @@ def test_fedllm_seq_round_matches_flat():
                           {k: jnp.asarray(v) for k, v in data.items()},
                           ids, weights, rng, None)
 
+    # the flat round consumed (donated) `base` with its server state
+    base = flat_out.server_state.extra
     mesh = make_mesh({"silos": 2, "seq": 4})
     seq_round = make_fedllm_seq_round(model, base, t, mesh)
     st_seq = ServerState(jax.tree.map(jnp.array, adapters), None,

@@ -1,0 +1,113 @@
+"""Every Pallas kernel variant the trainer and the engine can request must
+LOWER FOR THE TPU — checked here, on the CPU, in seconds.
+
+tier-1 runs the kernels under `interpret=True`, which never meets the TPU
+lowering rules: the int8 paged kernel shipped with a (1, H) block over a
+[P, H] array — illegal on a TPU (the last two block dims must be (8, 128)-
+tiled or full) — and nothing failed until someone looked. Two nets:
+
+- `jax.export` for `platforms=["tpu"]` with `interpret=False` runs the Pallas
+  TPU lowering (block-shape rules, unsupported primitives) and must leave a
+  `tpu_custom_call` per kernel in the module;
+- where libtpu is installed, the same programs are COMPILED for a v5e
+  through a device-less topology — the real Mosaic compiler, no chip. (It
+  cannot run them: numerics on the chip are `chip_smoke.py`'s job.)
+
+Shapes are chip_smoke.py's: flash at [B*H, T, Dh] = [64, 2048, 128]; paged
+at S=8 slots, H=16, Dh=128, page 16, 2048-token tables.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fedml_tpu.ops.flash_attention import flash_attention
+from fedml_tpu.ops.paged_attention import paged_attention
+
+S = jax.ShapeDtypeStruct
+BH, T, DH = 64, 2048, 128
+SLOTS, HEADS, PAGE, MAX_PAGES = 8, 16, 16, 128
+N_PAGES = SLOTS * MAX_PAGES + 1
+
+
+def _flash_loss(q, k, v):
+    o = flash_attention(q, k, v, interpret=False)
+    return jnp.sum(o.astype(jnp.float32) ** 2)
+
+
+def _flash_case(kind, dtype):
+    x = S((BH, T, DH), dtype)
+    if kind == "fwd":
+        return (lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                (x, x, x), {"flash_fwd"})
+    grad = jax.grad(_flash_loss, argnums=(0, 1, 2))
+    if kind == "fwd_bwd":
+        return grad, (x, x, x), {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    xv = S((2,) + x.shape, dtype)        # the round engine's client vmap
+    return (jax.vmap(grad), (xv, xv, xv),
+            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
+
+
+def _paged_case(c, dtype, quant):
+    q = S((SLOTS, c, HEADS, DH), dtype)
+    pool = S((N_PAGES, PAGE, HEADS, DH), jnp.int8 if quant else dtype)
+    pages, pos = S((SLOTS, MAX_PAGES), jnp.int32), S((SLOTS,), jnp.int32)
+    if not quant:
+        return (lambda q, k, v, pg, po: paged_attention(
+            q, k, v, pg, po, interpret=False),
+            (q, pool, pool, pages, pos), {"paged_attention"})
+    sc = S((N_PAGES, HEADS), jnp.float32)
+    return (lambda q, k, v, pg, po, ks, vs: paged_attention(
+        q, k, v, pg, po, ks, vs, interpret=False),
+        (q, pool, pool, pages, pos, sc, sc), {"paged_attention"})
+
+
+CASES = {
+    "flash_fwd_bf16": lambda: _flash_case("fwd", jnp.bfloat16),
+    "flash_fwd_bwd_bf16": lambda: _flash_case("fwd_bwd", jnp.bfloat16),
+    "flash_fwd_bwd_f32": lambda: _flash_case("fwd_bwd", jnp.float32),
+    "flash_vmap_fwd_bwd_bf16": lambda: _flash_case("vmap", jnp.bfloat16),
+    "paged_c1_bf16": lambda: _paged_case(1, jnp.bfloat16, False),
+    "paged_c4_bf16": lambda: _paged_case(4, jnp.bfloat16, False),
+    "paged_c1_f32": lambda: _paged_case(1, jnp.float32, False),
+    "paged_c4_f32": lambda: _paged_case(4, jnp.float32, False),
+    "paged_c1_int8": lambda: _paged_case(1, jnp.bfloat16, True),
+    "paged_c4_int8": lambda: _paged_case(4, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_lowers_for_tpu(name):
+    fn, args, kernels = CASES[name]()
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *args).mlir_module()
+    assert "tpu_custom_call" in text
+    # the stable kernel names the XLA ledger / chip_smoke.py read
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == kernels
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a device-less v5e topology (libtpu's compile-only
+    client); skips where libtpu is not installed."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip(f"no TPU compile-only topology: {type(e).__name__}: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name", [
+    n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16", "paged_c4_int8")
+    else pytest.param(n, marks=pytest.mark.slow)    # tier-1 is at its cap
+    for n in sorted(CASES)])
+def test_kernel_compiles_with_mosaic(name, v5e):
+    fn, args, _kernels = CASES[name]()
+    compiled = jax.jit(
+        fn, in_shardings=jax.tree.map(lambda _: v5e, args)).lower(
+            *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

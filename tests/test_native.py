@@ -218,3 +218,33 @@ def test_native_cnn_rejects_bad_shapes():
     x = np.zeros((4, 8, 8, 1), np.float32)
     with pytest.raises(ValueError, match="labels"):
         native.NativeCNNTrainer(x, np.full(4, 9, np.int32), 3)
+
+
+def test_build_is_keyed_on_source_hash_not_file_times(tmp_path, monkeypatch):
+    """A binary built from OTHER source must never be loaded, however new
+    its file time says it is (a copied tree scrambles times): the loaded
+    library's NAME carries the hash of fedml_native.cpp, so a foreign or
+    stale .so — here garbage under the old fixed name and under another
+    hash — is simply not the file that gets opened."""
+    import hashlib
+    import os
+
+    src = open(native._SRC, "rb").read()
+    so = native._so_path()
+    assert hashlib.sha256(src).hexdigest()[:16] in os.path.basename(so)
+    assert os.path.exists(so)          # available() above built or found it
+    strays = [os.path.join(native._HERE, "libfedml_native.so"),
+              os.path.join(native._HERE, "libfedml_native.0123456789abcdef.so")]
+    try:
+        for s in strays:
+            with open(s, "wb") as f:
+                f.write(b"not an ELF")
+            os.utime(s, (2e9, 2e9))    # "newer" than everything
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        assert native.available()
+        assert native.crc32c(b"123456789") == 0xE3069283
+    finally:
+        for s in strays:
+            if os.path.exists(s):
+                os.remove(s)

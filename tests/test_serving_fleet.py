@@ -642,6 +642,31 @@ def test_chaos_replica_kill_spec():
         FaultSpec(replica_kill=[3])
 
 
+def test_chaos_replica_kill_counts_once_under_concurrent_streams():
+    """Two streams on the doomed replica both tick past the threshold
+    (the soak's `fed.chaos.replica_kills == 1` pin once read 2): every
+    one of them is severed, but ONE kill is executed and counted."""
+    from fedml_tpu.comm.chaos import FaultSpec
+
+    class _Pred:
+        def predict(self, input_json):
+            return {}
+
+    runner = FedMLInferenceRunner(
+        _Pred(), port=0, chaos=FaultSpec(replica_kill={0: 2}), chaos_rank=0)
+    try:
+        c0 = _mx.snapshot()["counters"].get("fed.chaos.replica_kills", 0)
+        runner._chaos_tick()                      # token 1: below threshold
+        for _ in range(3):                        # tokens 2, 3, 4: all due
+            with pytest.raises(ConnectionError, match="killed"):
+                runner._chaos_tick()
+        assert runner._killed
+        c1 = _mx.snapshot()["counters"].get("fed.chaos.replica_kills", 0)
+        assert c1 - c0 == 1
+    finally:
+        runner.stop()
+
+
 def test_fleet_serve_knob_validation_and_mapping():
     from fedml_tpu.config import Config
     from fedml_tpu.serving.scheduler import fleet_knobs

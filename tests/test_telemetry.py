@@ -340,9 +340,12 @@ def test_tracked_run_exports_valid_chrome_trace(tmp_path):
     assert "counters" in report[-1]["report"]["metrics"]
 
 
-def test_retrace_metric_round_fn():
+@pytest.mark.parametrize("backend,clients", [("sp", 2), ("xla", 8)])
+def test_retrace_metric_round_fn(backend, clients):
     """PR 1's retrace guard as an always-on metric: a warm simulator shows
-    exactly one compiled round program and zero retraces."""
+    exactly one compiled round program and zero retraces — on the mesh
+    path too, where un-pinned first-round state once bought a second
+    compile (the program's own outputs come back mesh-committed)."""
     mx.reset()
     try:
         cfg = fedml_tpu.init(config={
@@ -350,12 +353,12 @@ def test_retrace_metric_round_fn():
                           "extra": {"synthetic_samples_per_client": 16}},
             "model_args": {"model": "lr"},
             "train_args": {"federated_optimizer": "FedAvg",
-                           "client_num_in_total": 2,
-                           "client_num_per_round": 2, "comm_round": 3,
+                           "client_num_in_total": clients,
+                           "client_num_per_round": clients, "comm_round": 3,
                            "epochs": 1, "batch_size": 8,
                            "learning_rate": 0.3},
             "validation_args": {"frequency_of_the_test": 0},
-            "comm_args": {"backend": "sp"},
+            "comm_args": {"backend": backend},
         })
         from fedml_tpu.simulation.simulator import Simulator
 
