@@ -58,6 +58,10 @@ _EMIT_METHODS = {"inc": "counter", "counter": "counter",
                  "set_gauge": "gauge", "gauge": "gauge"}
 
 
+# span-like names: host spans of the recorder, named scopes of a program
+_SPAN_METHODS = ("span", "record_span", "named_scope")
+
+
 def _sanitize(name: str) -> str:
     s = _INVALID.sub("_", name)
     return ("_" + s) if s and s[0].isdigit() else (s or "_")
@@ -134,12 +138,14 @@ class MetricRegistryRule(Rule):
                                 and parts[-2] in receivers)):
                         kind = _EMIT_METHODS[parts[-1]]
                         arg = node.args[0]
-                    elif parts[-1] == "span" and len(parts) > 1 \
+                    elif parts[-1] in _SPAN_METHODS and len(parts) > 1 \
                             and node.args:
-                        # recorder.span("name") — a Chrome-trace span, not
-                        # a /metrics series; collected so README span
-                        # claims resolve, excluded from scrape-surface
-                        # matching and typo checks
+                        # recorder.span("name") / .record_span("name", ..)
+                        # — a Chrome-trace span, not a /metrics series —
+                        # and jax.named_scope("name"), a layer's name
+                        # inside a device program; collected so README
+                        # span and scope claims resolve, excluded from
+                        # scrape-surface matching and typo checks
                         kind, arg = "span", node.args[0]
                     elif parts[-1] == "AtomicCounter":
                         for kw in node.keywords:

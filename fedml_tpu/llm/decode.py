@@ -43,6 +43,16 @@ from .quant import (
 Pytree = Any
 
 
+def layer_scope(part: str):
+    """`decode.<part>` as a `jax.named_scope`: the names a device trace
+    shows for the decode programs' layers (kv_write, attn, mlp, head; the
+    engine adds sample). What a layer scan does to carry the KV pool (the
+    slices in, the restack out) is left outside every scope on purpose: it
+    reads as the program's time under no layer (PERF.md section 3). One
+    place for the names, so a single block body inherits them."""
+    return jax.named_scope(f"decode.{part}")
+
+
 def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     """Convert an UNROLLED TransformerLM param tree (block_0..block_{L-1})
     to the stacked scan-layers layout ({"blocks": [L, ...]}) the decode
@@ -434,39 +444,46 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 bl, ad_l, ck, cv, ks, vs = layer
             else:
                 bl, ad_l, ck, cv = layer                  # ck/cv [P,ps,H,Dh]
-            h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-            q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-            q = _rope_rows(q, posr[None, :])
-            k = _rope_rows(k, posr[None, :])
-            if quant:
-                ck, ks = _kv_quant_write(ck, ks, wpage, woff, k[0])
-                cv, vs = _kv_quant_write(cv, vs, wpage, woff, v[0])
-                kk = dq_pages(ck, ks, pages_row)
-                vv = dq_pages(cv, vs, pages_row)
-            else:
-                ck = ck.at[wpage, woff].set(k[0])
-                cv = cv.at[wpage, woff].set(v[0])
-                kk, vv = ck[pages_row], cv[pages_row]
-            # gather AFTER the write so the chunk attends to itself;
-            # page-table order makes the gathered view contiguous virtual
-            # positions 0..n_virt-1
-            kk = kk.reshape((n_virt,) + ck.shape[2:])
-            vv = vv.reshape((n_virt,) + cv.shape[2:])
-            scale = q.shape[-1] ** -0.5
-            s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
-            live = jnp.arange(n_virt)[None, :] <= posr[:, None]  # [C, T]
-            s = jnp.where(live[None, None, :, :], s, _NEG)
-            o = jnp.einsum("bhqk,khd->bqhd", jax.nn.softmax(s, -1), vv)
-            x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
-                bl, ad_l, "wo", rank_scale)
-            x = mlp(bl, ad_l, rank_scale, x)
+            with layer_scope("attn"):
+                h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
+                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
+                q = _rope_rows(q, posr[None, :])
+                k = _rope_rows(k, posr[None, :])
+            with layer_scope("kv_write"):
+                if quant:
+                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k[0])
+                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v[0])
+                else:
+                    ck = ck.at[wpage, woff].set(k[0])
+                    cv = cv.at[wpage, woff].set(v[0])
+            with layer_scope("attn"):
+                # gather AFTER the write so the chunk attends to itself;
+                # page-table order makes the gathered view contiguous
+                # virtual positions 0..n_virt-1
+                if quant:
+                    kk = dq_pages(ck, ks, pages_row)
+                    vv = dq_pages(cv, vs, pages_row)
+                else:
+                    kk, vv = ck[pages_row], cv[pages_row]
+                kk = kk.reshape((n_virt,) + ck.shape[2:])
+                vv = vv.reshape((n_virt,) + cv.shape[2:])
+                scale = q.shape[-1] ** -0.5
+                s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
+                live = jnp.arange(n_virt)[None, :] <= posr[:, None]  # [C, T]
+                s = jnp.where(live[None, None, :, :], s, _NEG)
+                o = jnp.einsum("bhqk,khd->bqhd", jax.nn.softmax(s, -1), vv)
+                x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
+                    bl, ad_l, "wo", rank_scale)
+            with layer_scope("mlp"):
+                x = mlp(bl, ad_l, rank_scale, x)
             return x, ((ck, cv, ks, vs) if quant else (ck, cv))
 
         x, cc = jax.lax.scan(
             body, x, (params["blocks"], blk_ads) + cxs(cache))
-        last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
-                                            keepdims=False)
-        logits = head(params, top_ads, rank_scale, last[None, None])
+        with layer_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
+                                                keepdims=False)
+            logits = head(params, top_ads, rank_scale, last[None, None])
         return cout(cc), logits[:, 0]
 
     if kernel:
@@ -520,45 +537,51 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 bl, ad_l, ck, cv, ks, vs = layer
             else:
                 bl, ad_l, ck, cv = layer
-            h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-            q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-            q = _rope_rows(q, posr)
-            k = _rope_rows(k, posr)
-            if quant:
-                ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
-                cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
-            else:
-                ck = ck.at[wpage, woff].set(k)
-                cv = cv.at[wpage, woff].set(v)
-            if kernel:
-                # fused path: pages read in place by the Pallas kernel —
-                # no virtually-contiguous copy materializes (int8 pools
-                # ride in as-is; the kernel dequants each slab in VMEM)
-                o = (attn_fused(q, ck, cv, pages, pos, ks, vs)
-                     if quant else attn_fused(q, ck, cv, pages, pos))
-            else:
+            with layer_scope("attn"):
+                h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
+                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
+                q = _rope_rows(q, posr)
+                k = _rope_rows(k, posr)
+            with layer_scope("kv_write"):
                 if quant:
-                    kk = dq_pages(ck, ks, pages)
-                    vv = dq_pages(cv, vs, pages)
+                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
+                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
                 else:
-                    kk, vv = ck[pages], cv[pages]
-                kk = kk.reshape((s_, n_virt) + ck.shape[2:])
-                vv = vv.reshape((s_, n_virt) + cv.shape[2:])
-                scale = q.shape[-1] ** -0.5
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-                live = (jnp.arange(n_virt)[None, None, :]
-                        <= posr[:, :, None])                 # [S, C, T]
-                s = jnp.where(live[:, None, :, :], s, _NEG)
-                o = jnp.einsum("bhqk,bkhd->bqhd",
-                               jax.nn.softmax(s, -1), vv)
-            x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
-                bl, ad_l, "wo", rank_scale)
-            x = mlp(bl, ad_l, rank_scale, x)
+                    ck = ck.at[wpage, woff].set(k)
+                    cv = cv.at[wpage, woff].set(v)
+            with layer_scope("attn"):
+                if kernel:
+                    # fused path: pages read in place by the Pallas kernel
+                    # — no virtually-contiguous copy materializes (int8
+                    # pools ride in as-is; the kernel dequants each slab
+                    # in VMEM)
+                    o = (attn_fused(q, ck, cv, pages, pos, ks, vs)
+                         if quant else attn_fused(q, ck, cv, pages, pos))
+                else:
+                    if quant:
+                        kk = dq_pages(ck, ks, pages)
+                        vv = dq_pages(cv, vs, pages)
+                    else:
+                        kk, vv = ck[pages], cv[pages]
+                    kk = kk.reshape((s_, n_virt) + ck.shape[2:])
+                    vv = vv.reshape((s_, n_virt) + cv.shape[2:])
+                    scale = q.shape[-1] ** -0.5
+                    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+                    live = (jnp.arange(n_virt)[None, None, :]
+                            <= posr[:, :, None])             # [S, C, T]
+                    s = jnp.where(live[:, None, :, :], s, _NEG)
+                    o = jnp.einsum("bhqk,bkhd->bqhd",
+                                   jax.nn.softmax(s, -1), vv)
+                x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
+                    bl, ad_l, "wo", rank_scale)
+            with layer_scope("mlp"):
+                x = mlp(bl, ad_l, rank_scale, x)
             return x, ((ck, cv, ks, vs) if quant else (ck, cv))
 
         x, cc = jax.lax.scan(
             body, x, (params["blocks"], blk_ads) + cxs(cache))
-        logits = head(params, top_ads, rank_scale, x)
+        with layer_scope("head"):
+            logits = head(params, top_ads, rank_scale, x)
         return cout(cc), logits
 
     def step(params, adapters, cache, pages, pos, token, active):
@@ -593,39 +616,47 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 bl, ad_l, ck, cv, ks, vs = layer
             else:
                 bl, ad_l, ck, cv = layer
-            h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-            q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-            q = _rope_rows(q, posr)
-            k = _rope_rows(k, posr)
-            if quant:
-                ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
-                cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
-                kk = dq_pages(ck, ks, pages)
-                vv = dq_pages(cv, vs, pages)
-            else:
-                ck = ck.at[wpage, woff].set(k)
-                cv = cv.at[wpage, woff].set(v)
-                kk, vv = ck[pages], cv[pages]
-            kk = kk.reshape((b_, n_virt) + ck.shape[2:])
-            vv = vv.reshape((b_, n_virt) + cv.shape[2:])
-            scale = q.shape[-1] ** -0.5
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-            live = (jnp.arange(n_virt)[None, None, :]
-                    <= posr[:, :, None])                     # [B, C, T]
-            s = jnp.where(live[:, None, :, :], s, _NEG)
-            o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
-            x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
-                bl, ad_l, "wo", rank_scale)
-            x = mlp(bl, ad_l, rank_scale, x)
+            with layer_scope("attn"):
+                h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
+                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
+                q = _rope_rows(q, posr)
+                k = _rope_rows(k, posr)
+            with layer_scope("kv_write"):
+                if quant:
+                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
+                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
+                else:
+                    ck = ck.at[wpage, woff].set(k)
+                    cv = cv.at[wpage, woff].set(v)
+            with layer_scope("attn"):
+                if quant:
+                    kk = dq_pages(ck, ks, pages)
+                    vv = dq_pages(cv, vs, pages)
+                else:
+                    kk, vv = ck[pages], cv[pages]
+                kk = kk.reshape((b_, n_virt) + ck.shape[2:])
+                vv = vv.reshape((b_, n_virt) + cv.shape[2:])
+                scale = q.shape[-1] ** -0.5
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+                live = (jnp.arange(n_virt)[None, None, :]
+                        <= posr[:, :, None])                 # [B, C, T]
+                s = jnp.where(live[:, None, :, :], s, _NEG)
+                o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+                x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
+                    bl, ad_l, "wo", rank_scale)
+            with layer_scope("mlp"):
+                x = mlp(bl, ad_l, rank_scale, x)
             return x, ((ck, cv, ks, vs) if quant else (ck, cv))
 
         x, cc = jax.lax.scan(
             body, x, (params["blocks"], blk_ads) + cxs(cache))
         # per-row last live position (PAD rows clamp to 0 — garbage the
         # engine discards alongside their dropped scatters)
-        last = jax.vmap(lambda xr, n: jax.lax.dynamic_index_in_dim(
-            xr, jnp.maximum(n, 1) - 1, axis=0, keepdims=False))(x, lengths)
-        logits = head(params, top_ads, rank_scale, last[:, None])
+        with layer_scope("head"):
+            last = jax.vmap(lambda xr, n: jax.lax.dynamic_index_in_dim(
+                xr, jnp.maximum(n, 1) - 1, axis=0, keepdims=False))(
+                    x, lengths)
+            logits = head(params, top_ads, rank_scale, last[:, None])
         return cout(cc), logits[:, 0]
 
     return chunk, step, verify, chunk_batch

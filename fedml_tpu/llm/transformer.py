@@ -60,25 +60,31 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, pos):
+        # the layer names a device trace shows (PERF.md section 3): jax
+        # writes jvp(...), transpose(jvp(...)) and the checkpoint's
+        # rematted_computation around them, so forward, backward and the
+        # recomputed forward of each part can be told apart by name
         d_model = x.shape[-1]
         dh = d_model // self.n_heads
-        h = RMSNorm()(x)
-        q = nn.Dense(d_model, use_bias=False, name="wq")(h)
-        k = nn.Dense(d_model, use_bias=False, name="wk")(h)
-        v = nn.Dense(d_model, use_bias=False, name="wv")(h)
-        split = lambda a: a.reshape(a.shape[:2] + (self.n_heads, dh))
-        q, k, v = split(q), split(k), split(v)
-        q, k = rope(q, pos), rope(k, pos)
-        attn = self.attn_fn or dense_causal_attention
-        o = attn(q, k, v)
-        o = o.reshape(o.shape[:2] + (d_model,))
-        x = x + nn.Dense(d_model, use_bias=False, name="wo")(o)
+        with jax.named_scope("lm.attn"):
+            h = RMSNorm()(x)
+            q = nn.Dense(d_model, use_bias=False, name="wq")(h)
+            k = nn.Dense(d_model, use_bias=False, name="wk")(h)
+            v = nn.Dense(d_model, use_bias=False, name="wv")(h)
+            split = lambda a: a.reshape(a.shape[:2] + (self.n_heads, dh))
+            q, k, v = split(q), split(k), split(v)
+            q, k = rope(q, pos), rope(k, pos)
+            attn = self.attn_fn or dense_causal_attention
+            o = attn(q, k, v)
+            o = o.reshape(o.shape[:2] + (d_model,))
+            x = x + nn.Dense(d_model, use_bias=False, name="wo")(o)
 
-        h = RMSNorm()(x)
-        gate = nn.Dense(self.d_ff, use_bias=False, name="w_gate")(h)
-        up = nn.Dense(self.d_ff, use_bias=False, name="w_up")(h)
-        x = x + nn.Dense(d_model, use_bias=False, name="w_down")(
-            nn.silu(gate) * up)
+        with jax.named_scope("lm.mlp"):
+            h = RMSNorm()(x)
+            gate = nn.Dense(self.d_ff, use_bias=False, name="w_gate")(h)
+            up = nn.Dense(self.d_ff, use_bias=False, name="w_up")(h)
+            x = x + nn.Dense(d_model, use_bias=False, name="w_down")(
+                nn.silu(gate) * up)
         return x
 
 
@@ -109,7 +115,8 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, pos_offset=0):
         pos = pos_offset + jnp.arange(tokens.shape[1])
-        x = nn.Embed(self.vocab_size, self.d_model, name="embed")(tokens)
+        with jax.named_scope("lm.embed"):
+            x = nn.Embed(self.vocab_size, self.d_model, name="embed")(tokens)
         if self.scan_layers:
             block = Block
             if self.remat:
@@ -126,5 +133,7 @@ class TransformerLM(nn.Module):
             for i in range(self.n_layers):
                 x = block_cls(self.n_heads, self.d_ff, self.attn_fn,
                               name=f"block_{i}")(x, pos)
-        x = RMSNorm(name="final_norm")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head")(x)
+        with jax.named_scope("lm.head"):
+            x = RMSNorm(name="final_norm")(x)
+            return nn.Dense(self.vocab_size, use_bias=False,
+                            name="lm_head")(x)

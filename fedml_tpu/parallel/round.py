@@ -199,9 +199,10 @@ def make_round_parts(
     dv = int(mesh.devices.size) if mesh is not None else 1
 
     def one_client(bcast, shard, cstate, rng, weight):
-        upd, new_state, met = alg.client_update(bcast, shard, cstate, rng)
-        if postprocess_update is not None:
-            upd = postprocess_update(upd, rng)
+        with jax.named_scope("fed.local_sgd"):
+            upd, new_state, met = alg.client_update(bcast, shard, cstate, rng)
+            if postprocess_update is not None:
+                upd = postprocess_update(upd, rng)
         return upd, new_state, met
 
     def client_structs(server_state, full_cstates, shards):
@@ -306,22 +307,24 @@ def make_round_parts(
             )
             # zero-weight clients are mesh-padding duplicates (simulator
             # _pad_ids); keep them out of the reported training metrics
-            met = jax.tree.map(lambda a: a * (w > 0).astype(a.dtype), met)
             num, den, ms = car
-            if not use_full:
-                # weight-premultiplied group sum folded into the carry — the
-                # NCCL-sim reduce (common.py:197-207) restructured as a
-                # sequential accumulation so a chunk boundary (ISSUE 8)
-                # cannot change the addition order
-                num = jax.tree.map(
-                    lambda n, u: n + jnp.sum(
-                        u * w.reshape((-1,) + (1,) * (u.ndim - 1)).astype(
-                            u.dtype),
-                        axis=0)[None],
-                    num, upd)
-                den = den + jnp.sum(w)[None]
-            ms = jax.tree.map(
-                lambda a, b: a + jnp.sum(b, axis=0)[None], ms, met)
+            with jax.named_scope("fed.accumulate"):
+                met = jax.tree.map(
+                    lambda a: a * (w > 0).astype(a.dtype), met)
+                if not use_full:
+                    # weight-premultiplied group sum folded into the carry
+                    # — the NCCL-sim reduce (common.py:197-207) restructured
+                    # as a sequential accumulation so a chunk boundary
+                    # (ISSUE 8) cannot change the addition order
+                    num = jax.tree.map(
+                        lambda n, u: n + jnp.sum(
+                            u * w.reshape((-1,) + (1,) * (u.ndim - 1)).astype(
+                                u.dtype),
+                            axis=0)[None],
+                        num, upd)
+                    den = den + jnp.sum(w)[None]
+                ms = jax.tree.map(
+                    lambda a, b: a + jnp.sum(b, axis=0)[None], ms, met)
             ys = {"ns": ns}
             if collect_upds:
                 ys["u"] = upd
@@ -333,18 +336,27 @@ def make_round_parts(
             lambda a: a.reshape((n_groups, g) + a.shape[1:]),
             (shards, cstates, rngs, weights),
         )
-        acc, ys = jax.lax.scan(body, acc, grouped)
+        # a scope is an operation's INNERMOST name (PERF.md section 3): the
+        # body's parts keep their own, and what stays `fed.collect` is the
+        # loop's own work, slicing each group's inputs and stacking its
+        # per-client updates, states and metrics as scan outputs, and below
+        # their way into the round's buffers
+        with jax.named_scope("fed.collect"):
+            acc, ys = jax.lax.scan(body, acc, grouped)
         ungroup = lambda t: jax.tree.map(
             lambda a: a.reshape((m_local,) + a.shape[2:]), t)
-        nstates = ungroup(ys["ns"])
-        if collect_upds:
-            bufs = {**bufs, "u": jax.tree.map(
-                lambda b, u: jax.lax.dynamic_update_slice_in_dim(b, u, off, 0),
-                bufs["u"], ungroup(ys["u"]))}
-        if collect_cmets:
-            bufs = {**bufs, "m": jax.tree.map(
-                lambda b, u: jax.lax.dynamic_update_slice_in_dim(b, u, off, 0),
-                bufs["m"], ungroup(ys["m"]))}
+        with jax.named_scope("fed.collect"):
+            nstates = ungroup(ys["ns"])
+            if collect_upds:
+                bufs = {**bufs, "u": jax.tree.map(
+                    lambda b, u: jax.lax.dynamic_update_slice_in_dim(
+                        b, u, off, 0),
+                    bufs["u"], ungroup(ys["u"]))}
+            if collect_cmets:
+                bufs = {**bufs, "m": jax.tree.map(
+                    lambda b, u: jax.lax.dynamic_update_slice_in_dim(
+                        b, u, off, 0),
+                    bufs["m"], ungroup(ys["m"]))}
         return acc, nstates, bufs
 
     def fault_masks(rng, ids):
@@ -379,7 +391,8 @@ def make_round_parts(
         gather (carry bufs "cs") and new states buffered into "ns" — never
         scattered mid-round, so a pad duplicate in a later chunk cannot
         observe (and corrupt) its source's already-updated state."""
-        bcast = alg.broadcast(server_state)
+        with jax.named_scope("fed.broadcast"):
+            bcast = alg.broadcast(server_state)
         rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(ids)
         keep = jnp.ones(ids.shape, bool)
         if chaos_on:
@@ -451,6 +464,11 @@ def make_round_parts(
         """Close the round: ONE cross-device reduction of the accumulated
         per-device partials, the FULL-mode hook over the collected stack,
         post-processing, the server step, and the metrics row."""
+        with jax.named_scope("fed.finalize"):
+            return _finalize(server_state, carry, ids, weights, rng,
+                             hook_state)
+
+    def _finalize(server_state, carry, ids, weights, rng, hook_state):
         agg_rng = jax.random.fold_in(rng, 0x5EC)
         faults = None
         keep = None
@@ -493,9 +511,10 @@ def make_round_parts(
         summed = jax.tree.map(lambda a: jnp.sum(a, axis=0), carry["msum"])
         health = None
         if health_stats:
-            health = _client_health(
-                carry["bufs"]["u"], agg,
-                _per_client_loss(carry["bufs"]["m"]), summed)
+            with jax.named_scope("fed.health"):
+                health = _client_health(
+                    carry["bufs"]["u"], agg,
+                    _per_client_loss(carry["bufs"]["m"]), summed)
         if postprocess_agg is not None:
             agg = postprocess_agg(agg, ctx)
         new_server = alg.server_update(server_state, agg)

@@ -140,9 +140,14 @@ replica death; the engine carries the first and last:
 
 Telemetry rides the existing planes: `serving.ttft` / `serving.tbt`
 histograms, `serving.slots_active` gauge, `serving.tokens_total` counter,
-`serving.engine.*` counters, and `serving.engine.admit` / `.fetch` spans
-on the Chrome trace — all visible in `/metrics` and `python -m fedml_tpu
-top`.
+`serving.engine.*` counters (`steps` / `slot_steps`: drained step frames
+and the live slots in them, whose ratio is the slot occupancy), and
+`serving.engine.admit` / `.fetch` spans on the Chrome trace — all visible
+in `/metrics` and `python -m fedml_tpu top`. Each request also leaves three
+contiguous spans in its caller's trace once its consumer has the first
+token (`Ticket._record_spans`): `serving.engine.queue`, `.prefill`,
+`.first_fetch`; the device programs carry `decode.*` named scopes
+(llm/decode.py `layer_scope`).
 """
 from __future__ import annotations
 
@@ -160,7 +165,7 @@ import numpy as np
 from ..utils import enable_compilation_cache
 from ..utils import metrics as _mx
 from ..utils import xla_ledger as _ledger
-from ..utils.events import recorder
+from ..utils.events import current_trace, recorder
 from .predictor import InvalidRequest, _bucket
 
 log = logging.getLogger(__name__)
@@ -221,17 +226,53 @@ class Ticket:
     `stream()` can relay them while the request still decodes (the SSE
     serving surface); `result()` keeps the block-until-done contract."""
 
-    __slots__ = ("_cv", "_done", "_tokens", "_error", "t_submit", "t_first",
-                 "t_done")
+    __slots__ = ("_cv", "_done", "_tokens", "_error", "trace", "t_submit",
+                 "t_slot", "t_prefilled", "t_first", "t_done", "prefill",
+                 "_spanned")
 
-    def __init__(self):
+    def __init__(self, prompt: int = 0):
         self._cv = threading.Condition()
         self._done = threading.Event()
         self._tokens: list[int] = []
         self._error: Optional[BaseException] = None
+        # created by submit() on the caller's thread (the HTTP handler's,
+        # inside its `serving.request` span): the spans of this request's
+        # life inside the engine join that trace under that span
+        self.trace = current_trace()
         self.t_submit = time.perf_counter()
+        self.t_slot: Optional[float] = None        # a slot was claimed
+        self.t_prefilled: Optional[float] = None   # final chunk dispatched
         self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
+        # what its prefill took: chunks dispatched, prompt pages the prefix
+        # cache already held (the engine counts, the spans carry them)
+        self.prefill = {"chunks": 0, "prompt": prompt, "hit_pages": 0}
+        self._spanned = False
+
+    def _record_spans(self) -> None:
+        """The request's life inside the engine up to its first token, as
+        three contiguous spans of its trace: `queue` (submit to the slot
+        claimed), `prefill` (to the final chunk DISPATCHED) and
+        `first_fetch` (to the token pushed: the chunk's own device time
+        plus the frames in flight ahead of it); they add up to `t_first -
+        t_submit`. The engine thread only stamps; the CONSUMER's thread
+        records, once, after it has its first token (`stream`) or its
+        result: the loop that paces every request stays as lean as it was."""
+        if self._spanned or None in (self.t_slot, self.t_prefilled,
+                                     self.t_first):
+            return
+        self._spanned = True
+        trace_id, parent = self.trace
+        s = recorder.record_span(
+            "serving.engine.queue", self.t_submit, self.t_slot,
+            trace_id=trace_id, parent_id=parent)
+        # a ticket submitted outside any span starts a trace of its own
+        recorder.record_span(
+            "serving.engine.prefill", self.t_slot, self.t_prefilled,
+            trace_id=s.trace_id, parent_id=parent, **self.prefill)
+        recorder.record_span(
+            "serving.engine.first_fetch", self.t_prefilled, self.t_first,
+            trace_id=s.trace_id, parent_id=parent)
 
     # engine-thread side -------------------------------------------------
     def _push(self, tok: int) -> None:
@@ -253,6 +294,7 @@ class Ticket:
         if not self._done.wait(timeout):
             raise TimeoutError("decode engine ticket not done "
                                f"after {timeout}s")
+        self._record_spans()
         if self._error is not None:
             raise self._error
         with self._cv:
@@ -279,9 +321,25 @@ class Ticket:
                 tok = self._tokens[i]
             yield tok           # outside the lock: the consumer may block
             i += 1
+            self._record_spans()    # the consumer has its first token
 
     def done(self) -> bool:
         return self._done.is_set()
+
+
+# the ticket the calling thread's last submit() returned. The HTTP handler
+# never sees a streamed request's ticket (the predictor's generator owns it)
+# and needs its stamps to time its own two ends of the time to first token.
+_submitted = threading.local()
+
+
+def submitted_ticket(trace_id: Optional[str]) -> Optional[Ticket]:
+    """The ticket this thread submitted inside trace `trace_id`, if any (a
+    request served by the per-request fallback submitted none)."""
+    tk = getattr(_submitted, "ticket", None)
+    if tk is None or not trace_id or tk.trace[0] != trace_id:
+        return None
+    return tk
 
 
 class _Request:
@@ -292,7 +350,7 @@ class _Request:
         self.max_new = max_new
         self.temperature = temperature
         self.seed = seed
-        self.ticket = Ticket()
+        self.ticket = Ticket(len(tokens))
 
 
 class _Swap:
@@ -368,6 +426,7 @@ class _SlotState:
 
     def __init__(self, req: _Request):
         self.req = req
+        req.ticket.t_slot = time.perf_counter()   # the queue wait ends here
         self.out: list[int] = []
         self.t_first: Optional[float] = None
         self.entries: list[_PrefixEntry] = []
@@ -432,8 +491,8 @@ class DecodeEngine:
                  spec_k: int = 4, kv_quant: str = "off",
                  admit_batch: int = 1):
         from ..llm.decode import (
-            make_kv_decode, make_paged_kv_decode, ngram_propose,
-            stack_adapter_blocks, stack_blocks,
+            layer_scope, make_kv_decode, make_paged_kv_decode,
+            ngram_propose, stack_adapter_blocks, stack_blocks,
         )
 
         if n_slots < 1:
@@ -598,16 +657,18 @@ class DecodeEngine:
             covers both): softmax sampling computes alongside and a where
             picks — the greedy lane is bit-identical to the per-request
             path's argmax."""
-            greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-            l = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[
-                ..., None]
-            if logits.ndim == 1:
-                sampled = jax.random.categorical(key, l, -1)
-            else:
-                sampled = jax.vmap(
-                    lambda k, row: jax.random.categorical(k, row, -1))(
-                        key, l)
-            return jnp.where(temp > 0.0, sampled.astype(jnp.int32), greedy)
+            with layer_scope("sample"):
+                greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+                l = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[
+                    ..., None]
+                if logits.ndim == 1:
+                    sampled = jax.random.categorical(key, l, -1)
+                else:
+                    sampled = jax.vmap(
+                        lambda k, row: jax.random.categorical(k, row, -1))(
+                            key, l)
+                return jnp.where(temp > 0.0, sampled.astype(jnp.int32),
+                                 greedy)
 
         def _decode_tail(carry, cache, logits, extra=None):
             """Shared post-forward step logic: sample/argmax the next
@@ -1123,6 +1184,7 @@ class DecodeEngine:
             _mx.set_gauge("serving.engine.queue", len(self._waiting))
             self._cond.notify_all()
         _mx.inc("serving.engine.requests")
+        _submitted.ticket = req.ticket
         return req.ticket
 
     # -------------------------------------------------------------- capacity
@@ -1293,8 +1355,8 @@ class DecodeEngine:
                     jnp.asarray(buf), jnp.int32(len(req.tokens)),
                     jnp.int32(slot), jnp.float32(req.temperature),
                     jnp.uint32(req.seed), jnp.int32(limit))
+            self._prefilled(req.ticket)
             pending.append(("admit", slot, first))
-            _mx.inc("serving.engine.admissions")
 
     # ----------------------------------------------- paged admission plane
     # All of the page machinery below runs on the ENGINE THREAD only
@@ -1415,14 +1477,13 @@ class DecodeEngine:
             row = np.zeros(self._max_pages, np.int32)
             row[:len(hits)] = [e.page for e in hits]
             row[len(hits):total] = fresh
+            req.ticket.prefill["hit_pages"] = len(hits)
             if hits:
                 _mx.inc("serving.prefix_hits")
-                _mx.inc("serving.prefix_hit_pages", len(hits))
             elif self._prefix_on:
                 _mx.inc("serving.prefix_misses")
             self._admissions.append(_Admission(
                 req, slot, row, len(hits) * ps, keys, len(hits), total))
-            _mx.inc("serving.engine.admissions")
 
     def _advance_admissions(self, pending: deque) -> None:
         """ONE prefill chunk per engine iteration, round-robin across
@@ -1460,6 +1521,7 @@ class DecodeEngine:
                 jnp.float32(req.temperature), jnp.uint32(req.seed),
                 jnp.int32(limit), jnp.bool_(final), jnp.int32(plen))
         _mx.inc("serving.engine.prefill_chunks")
+        self._prefilled(req.ticket, final)
         if final:
             self._register_prefix(adm)
             pending.append(("admit", adm.slot, first))
@@ -1530,12 +1592,22 @@ class DecodeEngine:
         _mx.inc("serving.engine.prefill_chunks", b)
         _mx.observe("serving.engine.admit_batch", b)
         for i, adm in enumerate(group):
+            self._prefilled(adm.req.ticket, bool(finals[i]))
             if finals[i]:
                 self._register_prefix(adm)
                 pending.append(("admit", adm.slot, firsts[i]))
             else:
                 adm.t0 += int(clens[i])
                 self._admissions.append(adm)
+
+    @staticmethod
+    def _prefilled(ticket: Ticket, final: bool = True) -> None:
+        """One prefill chunk of a request was dispatched; the FINAL one's
+        dispatch ends its `serving.engine.prefill` span (what follows, to
+        the first token, is `serving.engine.first_fetch`)."""
+        ticket.prefill["chunks"] += 1
+        if final:
+            ticket.t_prefilled = time.perf_counter()
 
     def _register_prefix(self, adm: _Admission) -> None:
         """Publish the request's full prompt pages into the prefix map AT
@@ -1573,10 +1645,7 @@ class DecodeEngine:
             with recorder.span("serving.engine.fetch", kind="admit"):
                 tok = int(np.asarray(first))
             self._deliver(slot, tok, first=True)
-            _mx.set_gauge("serving.slots_active",
-                          sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
-            return
-        if frame[0] == "spec":
+        elif frame[0] == "spec":
             # one verify window's yield: toks [S, spec_k+1] target picks,
             # counts [S] accepted lengths (0 = slot was inert)
             _kind, toks_dev, counts_dev = frame
@@ -1584,6 +1653,7 @@ class DecodeEngine:
                 toks = np.asarray(toks_dev)
                 counts = np.asarray(counts_dev)
             live = counts > 0
+            self._count_step(int(live.sum()))
             if live.any():
                 # every live slot consumed spec_k drafts and banked
                 # count - 1 beyond the guaranteed token — the accept
@@ -1595,21 +1665,28 @@ class DecodeEngine:
             for slot in np.nonzero(live)[0]:
                 for t in toks[slot, :counts[slot]]:
                     self._deliver(int(slot), int(t), first=False)
-            _mx.set_gauge("serving.slots_active",
-                          sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
-            return
-        _kind, toks_dev, mask_dev = frame
-        with recorder.span("serving.engine.fetch", kind="step"):
-            toks = np.asarray(toks_dev)
-            mask = np.asarray(mask_dev)
-        for slot in np.nonzero(mask)[0]:
-            self._deliver(int(slot), int(toks[slot]), first=False)
+        else:
+            _kind, toks_dev, mask_dev = frame
+            with recorder.span("serving.engine.fetch", kind="step"):
+                toks = np.asarray(toks_dev)
+                mask = np.asarray(mask_dev)
+            self._count_step(int(mask.sum()))
+            for slot in np.nonzero(mask)[0]:
+                self._deliver(int(slot), int(toks[slot]), first=False)
         # publish the POST-delivery host occupancy, not the frame's entry
         # mask: with fetch_chunk=1 the final completing frame's entry mask
         # is >= 1 and no trailing all-inactive frame is ever dispatched —
         # an entry-mask gauge would read busy forever at idle
         _mx.set_gauge("serving.slots_active",
                       sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+
+    @staticmethod
+    def _count_step(live_slots: int) -> None:
+        """One drained step (or verify) frame: the program ran over every
+        slot, `live_slots` of them were doing a request's work. Their
+        ratio over a run is the engine's slot occupancy."""
+        _mx.inc("serving.engine.steps")
+        _mx.inc("serving.engine.slot_steps", live_slots)
 
     def _deliver(self, slot: int, tok: int, first: bool) -> None:
         st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)

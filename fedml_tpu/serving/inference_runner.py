@@ -50,7 +50,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from ..utils import metrics as _mx
-from ..utils.events import recorder
+from ..utils.events import current_trace, recorder
 from .predictor import Predictor
 
 log = logging.getLogger(__name__)
@@ -266,11 +266,11 @@ class FedMLInferenceRunner:
                 for k, v in (self._residency_headers() or {}).items():
                     self.send_header(k, v)
                 self.end_headers()
-                _mx.inc("serving.stream_responses")
                 _mx.observe("serving.stream_ttft",
                             time.perf_counter() - t0)
                 try:
                     self._emit(first)
+                    self._http_spans(t0)
                     for chunk in gen:
                         self._emit(chunk)
                 except (BrokenPipeError, ConnectionError):
@@ -289,6 +289,26 @@ class FedMLInferenceRunner:
                              "code": code if code == 409 else 503}
                         ).encode() + b"\n\n")
                     self.wfile.flush()
+
+            @staticmethod
+            def _http_spans(t0: float) -> None:
+                """The handler's two ends of a streamed request's time to
+                first token, in the request's trace: `serving.http.in`
+                (handler entry to the engine's submit) and
+                `serving.http.out` (first token pushed to the ticket, to
+                its SSE bytes written: the wake-up of this thread, the
+                headers, the write). With the engine's queue, prefill and
+                first_fetch spans they add up to the replica's own time
+                to first token."""
+                written = time.perf_counter()
+                from .engine import submitted_ticket
+
+                tk = submitted_ticket(current_trace()[0])
+                if tk is None or tk.t_first is None:
+                    return      # served by the per-request fallback
+                recorder.record_span("serving.http.in", t0, tk.t_submit)
+                recorder.record_span("serving.http.out", tk.t_first,
+                                     written)
 
             def _emit(self, chunk: dict) -> None:
                 # concurrent streams on a killed replica die at their next

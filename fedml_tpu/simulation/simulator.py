@@ -441,17 +441,24 @@ class Simulator:
     def run_round(self, round_idx: int) -> dict:
         if self._cohort_chunk:
             return self._run_round_chunked(round_idx)
-        ids, weights = self._pad_ids(self.sample_clients(round_idx))
-        rng = jax.random.fold_in(
-            jax.random.key(self.cfg.common_args.random_seed), round_idx
-        )
+        # the host's share of a round, by part (PERF.md section 3): sample,
+        # dispatch and observe are the host's own time, fetch is the one
+        # wait on the device
+        with recorder.span("fed.round.sample", round=round_idx):
+            ids, weights = self._pad_ids(self.sample_clients(round_idx))
+            rng = jax.random.fold_in(
+                jax.random.key(self.cfg.common_args.random_seed), round_idx
+            )
+            ids_dev, weights_dev = jnp.asarray(ids), jnp.asarray(weights)
         t0 = time.perf_counter()
         with recorder.span("train", round=round_idx):
-            out = self.round_fn(
-                self.server_state, self.client_states, self.data,
-                jnp.asarray(ids), jnp.asarray(weights), rng, self.hook_state,
-            )
-            fetched = jax.device_get(out.metrics)
+            with recorder.span("fed.round.dispatch", round=round_idx):
+                out = self.round_fn(
+                    self.server_state, self.client_states, self.data,
+                    ids_dev, weights_dev, rng, self.hook_state,
+                )
+            with recorder.span("fed.round.fetch", round=round_idx):
+                fetched = jax.device_get(out.metrics)
         # the per-client health arrays rode the SAME transfer as the scalar
         # metrics; peel them off before the history row is float-mapped
         health = fetched.pop("health", None)
@@ -461,14 +468,22 @@ class Simulator:
         self.client_states = out.client_states
         self.hook_state = out.hook_state
         dur = time.perf_counter() - t0
-        self.health.observe_round(round_idx, ids, weights, health,
-                                  duration_s=dur, faults=faults)
-        self._record_dispatch(ids, weights, dur)
-        self._cold_dispatch = False
-        self.dp.step_round()
-        if self.dp.enabled and self.dp.accountant is not None:
-            metrics["dp_epsilon"] = self.dp.get_epsilon()
+        self._observe_round(round_idx, ids, weights, health, faults, dur,
+                            metrics)
         return metrics
+
+    def _observe_round(self, round_idx: int, ids, weights, health, faults,
+                       dur: float, metrics: dict) -> None:
+        """What the host does with a finished round before the next one:
+        health accounting, the cost model, the DP accountant."""
+        with recorder.span("fed.round.observe", round=round_idx):
+            self.health.observe_round(round_idx, ids, weights, health,
+                                      duration_s=dur, faults=faults)
+            self._record_dispatch(ids, weights, dur)
+            self._cold_dispatch = False
+            self.dp.step_round()
+            if self.dp.enabled and self.dp.accountant is not None:
+                metrics["dp_epsilon"] = self.dp.get_epsilon()
 
     # ------------------------------------------- chunked cohort execution
     def _chunk_plan(self, ids: np.ndarray, weights: np.ndarray):
@@ -505,24 +520,27 @@ class Simulator:
         device: chunk k+1's gather+transfer overlaps chunk k's compute
         (IngestPipeline), the partial aggregate rides the donated carry,
         and finalize closes the round. Returns (ids, weights, RoundOutput)."""
-        ids, weights = self._pad_ids(self.sample_clients(round_idx))
-        rng = jax.random.fold_in(
-            jax.random.key(self.cfg.common_args.random_seed), round_idx)
-        plan, c_local = self._chunk_plan(ids, weights)
-        chunk_struct = {
-            k: jax.ShapeDtypeStruct((len(plan[0][1]),) + v.shape[1:], v.dtype)
-            for k, v in self._host_data.items()}
-        carry = self._make_carry(self.server_state, self.client_states,
-                                 ids, chunk_struct)
-        thunks = [self._chunk_thunk(cids, cw) for _, cids, cw in plan]
-        for (j, _, _), (cdata, cids_dev, cw_dev) in zip(
-                plan, self._ingest.stream(thunks)):
-            carry = self.chunk_fn(
-                carry, self.server_state, cdata, cids_dev, cw_dev, rng,
-                jnp.asarray(j * c_local, jnp.int32))
-        out = self.finalize_fn(
-            self.server_state, carry, jnp.asarray(ids),
-            jnp.asarray(weights), rng, self.hook_state)
+        with recorder.span("fed.round.sample", round=round_idx):
+            ids, weights = self._pad_ids(self.sample_clients(round_idx))
+            rng = jax.random.fold_in(
+                jax.random.key(self.cfg.common_args.random_seed), round_idx)
+            plan, c_local = self._chunk_plan(ids, weights)
+        with recorder.span("fed.round.dispatch", round=round_idx):
+            chunk_struct = {
+                k: jax.ShapeDtypeStruct(
+                    (len(plan[0][1]),) + v.shape[1:], v.dtype)
+                for k, v in self._host_data.items()}
+            carry = self._make_carry(self.server_state, self.client_states,
+                                     ids, chunk_struct)
+            thunks = [self._chunk_thunk(cids, cw) for _, cids, cw in plan]
+            for (j, _, _), (cdata, cids_dev, cw_dev) in zip(
+                    plan, self._ingest.stream(thunks)):
+                carry = self.chunk_fn(
+                    carry, self.server_state, cdata, cids_dev, cw_dev, rng,
+                    jnp.asarray(j * c_local, jnp.int32))
+            out = self.finalize_fn(
+                self.server_state, carry, jnp.asarray(ids),
+                jnp.asarray(weights), rng, self.hook_state)
         self.server_state = out.server_state
         self.client_states = out.client_states
         self.hook_state = out.hook_state
@@ -533,19 +551,15 @@ class Simulator:
         with recorder.span("train", round=round_idx) as sp:
             ids, weights, out = self._dispatch_chunked(round_idx)
             sp.meta["chunks"] = len(ids) // self._cohort_chunk
-            fetched = jax.device_get(out.metrics)
+            with recorder.span("fed.round.fetch", round=round_idx):
+                fetched = jax.device_get(out.metrics)
         faults = fetched.pop("faults", None)
         metrics = jax.tree.map(float, fetched)
         dur = time.perf_counter() - t0
         # chunked rounds run the in-jit health stats off (see __init__);
         # participation/straggler accounting still observes every round
-        self.health.observe_round(round_idx, ids, weights, None,
-                                  duration_s=dur, faults=faults)
-        self._record_dispatch(ids, weights, dur)
-        self._cold_dispatch = False
-        self.dp.step_round()
-        if self.dp.enabled and self.dp.accountant is not None:
-            metrics["dp_epsilon"] = self.dp.get_epsilon()
+        self._observe_round(round_idx, ids, weights, None, faults, dur,
+                            metrics)
         return metrics
 
     def _eval_dispatch(self):

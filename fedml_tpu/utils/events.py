@@ -166,7 +166,7 @@ class EventRecorder:
         # time across processes
         self._epoch = time.time() - time.perf_counter()
 
-    # span_id/parent bookkeeping shared by span() and log_block_span()
+    # span_id/parent bookkeeping shared by span() and record_span()
     def _open_trace(self) -> tuple[str, str, str, bool]:
         parent = getattr(_tl, "span_id", None) or ""
         trace_id = getattr(_tl, "trace_id", None)
@@ -224,9 +224,40 @@ class EventRecorder:
             if fresh:
                 _tl.trace_id = None
             self._record(s)
+            self._to_sinks(s)
+
+    def _to_sinks(self, s: Span) -> None:
+        if self.sinks:
             payload = self._sink_payload(s)
             for sink in self.sinks:
                 sink("span", payload)
+
+    def _closed(self, name: str, start: float, end: float,
+                trace_id: Optional[str], parent_id: Optional[str],
+                meta: dict) -> Span:
+        """Ids, ring and aggregate of a span whose two ends are given."""
+        if trace_id is None:
+            trace_id, span_id, parent, _fresh = self._open_trace()
+        else:
+            span_id, parent = _new_id(), parent_id or ""
+        s = Span(name, start, end, meta=meta, trace_id=trace_id,
+                 span_id=span_id, parent_id=parent)
+        self._record(s)
+        return s
+
+    def record_span(self, name: str, start: float, end: float,
+                    trace_id: Optional[str] = None,
+                    parent_id: Optional[str] = None, **meta) -> Span:
+        """Record an interval that was not a `with` block on one thread: it
+        crossed threads or engine iterations, and the caller timed both
+        ends itself with `perf_counter` (the clock `span` uses). With
+        `trace_id` the span joins that trace under `parent_id` (a request's
+        life inside the engine hangs under its `serving.request`); without,
+        it inherits the calling thread's open span like `span` does. Ring,
+        aggregate and sinks see it exactly as they see a `with` span."""
+        s = self._closed(name, start, end, trace_id, parent_id, meta)
+        self._to_sinks(s)
+        return s
 
     def log_block_span(self, name: str, rounds, duration: float, **meta):
         """Record a span over a round BLOCK (round-block execution runs K
@@ -241,18 +272,16 @@ class EventRecorder:
         so summing them can exceed wall time."""
         rounds = list(rounds)
         end = time.perf_counter()
-        trace_id, span_id, parent, _fresh = self._open_trace()
-        s = Span(name, end - duration, end,
-                 meta={"rounds": [rounds[0], rounds[-1]], **meta}
-                 if rounds else dict(meta),
-                 trace_id=trace_id, span_id=span_id, parent_id=parent)
-        self._record(s)
+        # record_span's ring and aggregate; the sink rows are per ROUND
+        s = self._closed(name, end - duration, end, None, None,
+                         {"rounds": [rounds[0], rounds[-1]], **meta}
+                         if rounds else dict(meta))
         per_round = duration / max(len(rounds), 1)
         for sink in self.sinks:
             for r in rounds:
                 sink("span", {"name": name, "duration": per_round,
                               "round": r, "block": True,
-                              "trace_id": trace_id, "span_id": span_id,
+                              "trace_id": s.trace_id, "span_id": s.span_id,
                               **meta})
 
     def log(self, metrics: dict):
@@ -301,7 +330,7 @@ class EventRecorder:
         if name.startswith("serving"):
             return "serving"
         if name.startswith(("train", "eval", "round", "block", "agg",
-                            "local_", "fit")):
+                            "local_", "fit", "fed.round")):
             return "round"
         return "other"
 

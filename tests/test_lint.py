@@ -103,11 +103,11 @@ def test_lock_discipline_survives_subset_scans():
     pkg = os.path.join(os.path.dirname(__file__), "..", "fedml_tpu")
     findings, stats = run_lint([os.path.join(pkg, "serving")],
                                rules=["lock-discipline"], extra_docs={})
-    assert findings == [] and stats["suppressed"] >= 8
+    assert findings == [] and stats["suppressed"] >= 6
     findings, stats = run_lint(
         [os.path.join(pkg, "serving", "engine.py")],
         rules=["lock-discipline"], extra_docs={})
-    assert findings == [] and stats["suppressed"] >= 8
+    assert findings == [] and stats["suppressed"] >= 6
 
 
 def test_missing_scan_path_is_loud():
@@ -277,7 +277,8 @@ def test_tree_zero_findings():
     assert stats["files"] > 100    # really scanned the package
     # the engine's documented thread-ownership suppressions exist; a
     # wholesale deletion of the comments (or of the rule) would show here
-    assert stats["suppressed"] >= 8
+    # (6 since PR 26 made _drain's three copies of one gauge update one)
+    assert stats["suppressed"] >= 6
 
 
 def test_rule_catalog_and_unknown_rule():
