@@ -46,10 +46,11 @@ Pytree = Any
 def layer_scope(part: str):
     """`decode.<part>` as a `jax.named_scope`: the names a device trace
     shows for the decode programs' layers (kv_write, attn, mlp, head; the
-    engine adds sample). What a layer scan does to carry the KV pool (the
-    slices in, the restack out) is left outside every scope on purpose: it
-    reads as the program's time under no layer (PERF.md section 3). One
-    place for the names, so a single block body inherits them."""
+    engine adds sample). What a layer scan itself does (slicing the
+    stacked weights; until PR 27 also moving the KV pool in and out) is
+    left outside every scope on purpose: it reads as the program's time
+    under no layer (PERF.md section 3). One place for the names, so a
+    single block body inherits them."""
     return jax.named_scope(f"decode.{part}")
 
 
@@ -369,14 +370,24 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     ceiling) for a <1pt greedy-token quality delta; `quant=False` is
     byte-identical to the pre-quant layout.
 
-    Page 0 is the null/trash page by contract: never allocated to a
-    request, it absorbs padded-position and inactive-slot writes; reads
-    of it only ever surface at virtual positions beyond a slot's `pos`,
-    which the live mask discards. Attention gathers each slot's pages
-    into a virtually-contiguous [max_pages * page_size] sequence, so the
-    math (and, pinned in tests, the greedy tokens) matches the contiguous
-    cache — the gather is the XLA-level cost of paging; the win is that
-    the PERSISTENT pool holds only `n_pages * page_size` rows.
+    How the pool is carried: the cache the engine holds keeps its
+    layout `[L, P, page, H, Dh]` (scales `[L, P, H]`), and every program
+    threads it through ONE layer scan (`scan_layers`) as part of the
+    CARRY, viewed flat `[L * P, ...]`. Layer l writes its new rows at
+    pages `l * P + id` and reads (gather or kernel) only the pages it
+    attends to, so the donated pool is updated in place and a program
+    moves the rows it touches, never the pool. The layer index rides the
+    scan's xs beside the stacked weights.
+
+    Page 0 is the null/trash page by contract, per layer (flat page
+    `l * P`): never allocated to a request, it absorbs padded-position
+    and inactive-slot writes; reads of it only ever surface at virtual
+    positions beyond a slot's `pos`, which the live mask discards.
+    Attention gathers each slot's pages into a virtually-contiguous
+    [max_pages * page_size] sequence, so the math (and, pinned in tests,
+    the greedy tokens) matches the contiguous cache — the gather is the
+    XLA-level cost of paging; the win is that the PERSISTENT pool holds
+    only `n_pages * page_size` rows.
 
     `kernel=True` swaps step/verify's gather-then-attend for the fused
     Pallas paged-attention kernel (ops/paged_attention.py) that reads
@@ -409,22 +420,55 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     def head(params, top_ads, rank_scale, x):
         return lm_head_logits(params, top_ads, rank_scale, x, dtype, eps)
 
-    def cxs(cache):
-        """Cache leaves in scan-xs order (scales ride when quantized)."""
-        base = (cache["k"], cache["v"])
-        return base + ((cache["ks"], cache["vs"]) if quant else ())
+    def scan_layers(layer, x, params, blk_ads, cache):
+        """THE layer scan of all four programs. The pool leaves ride the
+        CARRY, each viewed flat as [L * P, ...] (a bitcast of the
+        contiguous [L, P, ...] array), and every layer addresses its own
+        pages at `base = l * P`: `layer(x, pool, base, bl, ad_l) ->
+        (x, pool)` writes its new rows into the whole pool in place and
+        reads only the pages it attends to. As scan `xs`/`ys` the pool
+        was sliced out and restacked layer by layer, and the donated
+        input copied once more to close the loop: three pool-sized moves
+        a program for rows nobody read (PERF.md section 6, PR 27)."""
+        n_layers, n_pages = cache["k"].shape[:2]
+        pool = {name: leaf.reshape((n_layers * n_pages,) + leaf.shape[2:])
+                for name, leaf in cache.items()}
 
-    def cout(cc):
-        out = {"k": cc[0], "v": cc[1]}
-        if quant:
-            out["ks"], out["vs"] = cc[2], cc[3]
-        return out
+        def body(carry, xs):
+            x, pool = carry
+            bl, ad_l, l = xs
+            return layer(x, pool, l * n_pages, bl, ad_l), None
 
-    def dq_pages(pool, scales, idx):
-        """Gather pages + in-place dequant: scales[idx] [..., H]
-        broadcast over the (page_size, Dh) axes of pool[idx]."""
-        g = pool[idx].astype(jnp.float32)
-        return (g * scales[idx][..., None, :, None]).astype(dtype)
+        (x, pool), _ = jax.lax.scan(
+            body, (x, pool),
+            (params["blocks"], blk_ads, jnp.arange(n_layers, dtype=jnp.int32)))
+        return x, {name: leaf.reshape(cache[name].shape)
+                   for name, leaf in pool.items()}
+
+    def kv_write(pool, wpage, woff, k, v):
+        """New K/V rows into the flat pool at (wpage, woff); `wpage`
+        already carries the layer's base, so the null page of layer l is
+        base + 0 and absorbs that layer's redirected writes."""
+        with layer_scope("kv_write"):
+            if quant:
+                pk, ks = _kv_quant_write(pool["k"], pool["ks"], wpage, woff, k)
+                pv, vs = _kv_quant_write(pool["v"], pool["vs"], wpage, woff, v)
+                return {"k": pk, "v": pv, "ks": ks, "vs": vs}
+            return {"k": pool["k"].at[wpage, woff].set(k),
+                    "v": pool["v"].at[wpage, woff].set(v)}
+
+    def kv_pages(pool, idx):
+        """Gather the pages `idx` (base included) of the flat pool as a
+        virtually-contiguous [..., len(idx) * page_size, H, Dh] K and V
+        (int8 pools dequantize in place: scales[idx] [..., H] broadcast
+        over the (page_size, Dh) axes)."""
+        def one(leaf, scales):
+            g = pool[leaf][idx]
+            if quant:
+                g = (g.astype(jnp.float32)
+                     * pool[scales][idx][..., None, :, None]).astype(dtype)
+            return g.reshape(idx.shape[:-1] + (-1,) + g.shape[-2:])
+        return one("k", "ks"), one("v", "vs")
 
     def chunk(params, adapters, cache, pages_row, tokens, t0, length):
         blk_ads, top_ads, rank_scale = split_adapters(adapters, alpha)
@@ -439,34 +483,18 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         woff = posr % ps
         n_virt = pages_row.shape[0] * ps
 
-        def body(x, layer):
-            if quant:
-                bl, ad_l, ck, cv, ks, vs = layer
-            else:
-                bl, ad_l, ck, cv = layer                  # ck/cv [P,ps,H,Dh]
+        def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
                 q = _rope_rows(q, posr[None, :])
                 k = _rope_rows(k, posr[None, :])
-            with layer_scope("kv_write"):
-                if quant:
-                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k[0])
-                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v[0])
-                else:
-                    ck = ck.at[wpage, woff].set(k[0])
-                    cv = cv.at[wpage, woff].set(v[0])
+            pool = kv_write(pool, base + wpage, woff, k[0], v[0])
             with layer_scope("attn"):
                 # gather AFTER the write so the chunk attends to itself;
                 # page-table order makes the gathered view contiguous
                 # virtual positions 0..n_virt-1
-                if quant:
-                    kk = dq_pages(ck, ks, pages_row)
-                    vv = dq_pages(cv, vs, pages_row)
-                else:
-                    kk, vv = ck[pages_row], cv[pages_row]
-                kk = kk.reshape((n_virt,) + ck.shape[2:])
-                vv = vv.reshape((n_virt,) + cv.shape[2:])
+                kk, vv = kv_pages(pool, base + pages_row)
                 scale = q.shape[-1] ** -0.5
                 s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
                 live = jnp.arange(n_virt)[None, :] <= posr[:, None]  # [C, T]
@@ -476,15 +504,14 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     bl, ad_l, "wo", rank_scale)
             with layer_scope("mlp"):
                 x = mlp(bl, ad_l, rank_scale, x)
-            return x, ((ck, cv, ks, vs) if quant else (ck, cv))
+            return x, pool
 
-        x, cc = jax.lax.scan(
-            body, x, (params["blocks"], blk_ads) + cxs(cache))
+        x, cache = scan_layers(layer, x, params, blk_ads, cache)
         with layer_scope("head"):
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                                 keepdims=False)
             logits = head(params, top_ads, rank_scale, last[None, None])
-        return cout(cc), logits[:, 0]
+        return cache, logits[:, 0]
 
     if kernel:
         from ..ops.paged_attention import paged_attention
@@ -532,39 +559,24 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         woff = posr % ps
         n_virt = max_pages * ps
 
-        def body(x, layer):
-            if quant:
-                bl, ad_l, ck, cv, ks, vs = layer
-            else:
-                bl, ad_l, ck, cv = layer
+        def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
                 q = _rope_rows(q, posr)
                 k = _rope_rows(k, posr)
-            with layer_scope("kv_write"):
-                if quant:
-                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
-                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
-                else:
-                    ck = ck.at[wpage, woff].set(k)
-                    cv = cv.at[wpage, woff].set(v)
+            pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
                 if kernel:
-                    # fused path: pages read in place by the Pallas kernel
-                    # — no virtually-contiguous copy materializes (int8
-                    # pools ride in as-is; the kernel dequants each slab
-                    # in VMEM)
-                    o = (attn_fused(q, ck, cv, pages, pos, ks, vs)
-                         if quant else attn_fused(q, ck, cv, pages, pos))
+                    # fused path: this layer's pages read in place by the
+                    # Pallas kernel, straight out of the carried pool — no
+                    # virtually-contiguous copy materializes (int8 pools
+                    # ride in as-is; the kernel dequants each slab in VMEM)
+                    scales = (pool["ks"], pool["vs"]) if quant else ()
+                    o = attn_fused(q, pool["k"], pool["v"], base + pages,
+                                   pos, *scales)
                 else:
-                    if quant:
-                        kk = dq_pages(ck, ks, pages)
-                        vv = dq_pages(cv, vs, pages)
-                    else:
-                        kk, vv = ck[pages], cv[pages]
-                    kk = kk.reshape((s_, n_virt) + ck.shape[2:])
-                    vv = vv.reshape((s_, n_virt) + cv.shape[2:])
+                    kk, vv = kv_pages(pool, base + pages)
                     scale = q.shape[-1] ** -0.5
                     s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
                     live = (jnp.arange(n_virt)[None, None, :]
@@ -576,13 +588,12 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     bl, ad_l, "wo", rank_scale)
             with layer_scope("mlp"):
                 x = mlp(bl, ad_l, rank_scale, x)
-            return x, ((ck, cv, ks, vs) if quant else (ck, cv))
+            return x, pool
 
-        x, cc = jax.lax.scan(
-            body, x, (params["blocks"], blk_ads) + cxs(cache))
+        x, cache = scan_layers(layer, x, params, blk_ads, cache)
         with layer_scope("head"):
             logits = head(params, top_ads, rank_scale, x)
-        return cout(cc), logits
+        return cache, logits
 
     def step(params, adapters, cache, pages, pos, token, active):
         cache, logits = verify(params, adapters, cache, pages, pos,
@@ -611,31 +622,15 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         woff = posr % ps
         n_virt = max_pages * ps
 
-        def body(x, layer):
-            if quant:
-                bl, ad_l, ck, cv, ks, vs = layer
-            else:
-                bl, ad_l, ck, cv = layer
+        def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
                 q = _rope_rows(q, posr)
                 k = _rope_rows(k, posr)
-            with layer_scope("kv_write"):
-                if quant:
-                    ck, ks = _kv_quant_write(ck, ks, wpage, woff, k)
-                    cv, vs = _kv_quant_write(cv, vs, wpage, woff, v)
-                else:
-                    ck = ck.at[wpage, woff].set(k)
-                    cv = cv.at[wpage, woff].set(v)
+            pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
-                if quant:
-                    kk = dq_pages(ck, ks, pages)
-                    vv = dq_pages(cv, vs, pages)
-                else:
-                    kk, vv = ck[pages], cv[pages]
-                kk = kk.reshape((b_, n_virt) + ck.shape[2:])
-                vv = vv.reshape((b_, n_virt) + cv.shape[2:])
+                kk, vv = kv_pages(pool, base + pages)
                 scale = q.shape[-1] ** -0.5
                 s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
                 live = (jnp.arange(n_virt)[None, None, :]
@@ -646,10 +641,9 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     bl, ad_l, "wo", rank_scale)
             with layer_scope("mlp"):
                 x = mlp(bl, ad_l, rank_scale, x)
-            return x, ((ck, cv, ks, vs) if quant else (ck, cv))
+            return x, pool
 
-        x, cc = jax.lax.scan(
-            body, x, (params["blocks"], blk_ads) + cxs(cache))
+        x, cache = scan_layers(layer, x, params, blk_ads, cache)
         # per-row last live position (PAD rows clamp to 0 — garbage the
         # engine discards alongside their dropped scatters)
         with layer_scope("head"):
@@ -657,7 +651,7 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 xr, jnp.maximum(n, 1) - 1, axis=0, keepdims=False))(
                     x, lengths)
             logits = head(params, top_ads, rank_scale, last[:, None])
-        return cout(cc), logits[:, 0]
+        return cache, logits[:, 0]
 
     return chunk, step, verify, chunk_batch
 

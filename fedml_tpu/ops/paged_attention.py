@@ -16,8 +16,10 @@ HBM traffic drops from O(context copied + context read) to O(context
 read), and the transient gather buffer disappears from the memory
 high-water mark.
 
-Shape contract (one transformer layer; the decode scan calls it per
-layer):
+Shape contract (one transformer layer's pages; the decode layer scan
+carries the whole pool flat as `[L * P, page_size, H, Dh]` and calls this
+per layer with page ids offset by `l * P`, so "the pool" here is every
+layer's and the page table picks this layer's slabs out of it in place):
 
     q      [S, C, H, Dh]   C queries per slot at global positions
                            pos[s] .. pos[s] + C - 1 (C == 1 is the plain

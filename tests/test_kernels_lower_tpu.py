@@ -16,6 +16,7 @@ tiled or full) — and nothing failed until someone looked. Two nets:
 Shapes are chip_smoke.py's: flash at [B*H, T, Dh] = [64, 2048, 128]; paged
 at S=8 slots, H=16, Dh=128, page 16, 2048-token tables.
 """
+import math
 import re
 
 import jax
@@ -111,3 +112,39 @@ def test_kernel_compiles_with_mosaic(name, v5e):
         fn, in_shardings=jax.tree.map(lambda _: v5e, args)).lower(
             *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_step_carries_the_pool_in_place(v5e):
+    """The decode step at chip_smoke.py's engine shape (8 slots x 2,048,
+    page 16, heads 16 x 128), cache donated, compiled for the v5e: the
+    program's temporaries stay under a quarter of the pool's bytes. With
+    the pool as the layer scan's xs/ys they were 1.1 pools — the restacked
+    copy — and each step moved the pool three times (ISSUE 27)."""
+    from fedml_tpu.llm.decode import make_paged_kv_decode
+    from fedml_tpu.llm.transformer import TransformerLM
+    from fedml_tpu.ops import paged_attention as pa
+
+    layers, d_model = 4, HEADS * DH
+    model = TransformerLM(vocab_size=512, d_model=d_model, n_layers=layers,
+                          n_heads=HEADS, d_ff=512, scan_layers=True)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), shapes)
+    pool = S((layers, N_PAGES, PAGE, HEADS, DH), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
+    vec = S((SLOTS,), jnp.int32)
+    args = (params, cache, S((SLOTS, MAX_PAGES), jnp.int32), vec, vec,
+            S((SLOTS,), jnp.bool_))
+    _chunk, step, _verify, _cb = make_paged_kv_decode(
+        HEADS, PAGE, dtype=jnp.bfloat16, kernel=True)
+    auto, pa._auto_interpret = pa._auto_interpret, lambda: False
+    try:        # the backend here is the CPU; the program is the chip's
+        compiled = jax.jit(
+            lambda p, c, *a: step(p, None, c, *a), donate_argnums=(1,),
+            in_shardings=jax.tree.map(lambda _: v5e, args)).lower(
+                *args).compile()
+    finally:
+        pa._auto_interpret = auto
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = 2 * math.prod(pool.shape) * pool.dtype.itemsize   # K and V
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4

@@ -300,7 +300,9 @@ def test_engine_telemetry_contract(eng_shared):
 def test_http_concurrency_through_engine_runner(setup):
     """8 concurrent HTTP requests through FedMLInferenceRunner on an
     engine-backed predictor: every request gets exactly one response,
-    more than one slot is concurrently active at some point, and the
+    some device step advanced more than one request (the engine's drained
+    step frames held more live slots than there were frames — counted, not
+    caught by polling a gauge at the right two milliseconds), and the
     in-flight gauge returns to zero (atomic counter satellite)."""
     from fedml_tpu.serving.inference_runner import FedMLInferenceRunner
 
@@ -310,30 +312,30 @@ def test_http_concurrency_through_engine_runner(setup):
     runner = FedMLInferenceRunner(pred, port=0).start()
     url = f"http://127.0.0.1:{runner.port}/predict"
     prompts = _prompts((6, 10, 8, 5, 7, 9, 4, 11), seed=3)
-    want = [pred.predict({"tokens": p, "max_new_tokens": 6})
+    # long enough outputs, sent at one moment, that a loaded box cannot
+    # serialize the requests past each other's whole decode
+    new = MAXLEN - max(len(p) for p in prompts)
+    go = threading.Barrier(len(prompts))
+    want = [pred.predict({"tokens": p, "max_new_tokens": new})
             ["generated_tokens"] for p in prompts]
 
-    max_active = [0]
-    stop_poll = threading.Event()
+    def steps():
+        c = _mx.snapshot()["counters"]
+        return (c.get("serving.engine.steps", 0),
+                c.get("serving.engine.slot_steps", 0))
 
-    def poll():
-        g = _mx.registry.gauge("serving.slots_active")
-        while not stop_poll.is_set():
-            max_active[0] = max(max_active[0], int(g.value()))
-            time.sleep(0.002)
-
+    steps0, slot_steps0 = steps()       # after the serial reference above
     results: list = [None] * len(prompts)
 
     def hit(i):
         body = json.dumps({"tokens": prompts[i],
-                           "max_new_tokens": 6}).encode()
+                           "max_new_tokens": new}).encode()
         req = urllib.request.Request(
             url, data=body, headers={"Content-Type": "application/json"})
+        go.wait(timeout=60)
         with urllib.request.urlopen(req, timeout=120) as r:
             results[i] = json.loads(r.read())["generated_tokens"]
 
-    poller = threading.Thread(target=poll, daemon=True)
-    poller.start()
     threads = [threading.Thread(target=hit, args=(i,))
                for i in range(len(prompts))]
     try:
@@ -342,11 +344,11 @@ def test_http_concurrency_through_engine_runner(setup):
         for t in threads:
             t.join(timeout=120)
     finally:
-        stop_poll.set()
-        poller.join(timeout=5)
         runner.stop()
     assert results == want
-    assert max_active[0] > 1, "requests never shared a device step"
+    steps1, slot_steps1 = steps()
+    assert slot_steps1 - slot_steps0 > steps1 - steps0 > 0, (
+        "requests never shared a device step")
     assert _mx.snapshot()["gauges"]["serving.queue_depth"] == 0
 
 
