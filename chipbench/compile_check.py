@@ -8,9 +8,10 @@ Nothing runs: the TPU compiler is given a `v5e:2x2` topology that is
 described and not attached, so what it refuses (a kernel, a program that
 does not fit) costs no chip time. It counts ONE program at a time, not what
 else the process keeps on the device, and a compile that passes is not a
-chip run. The `serve` kind's programs are private to the engine
-(`DecodeEngine._step_jit`, `_admit_jit`); they are not covered here, and
-the chip run's own `memory_peak_bytes` is what is reported for that cell.
+chip run. It covers the kinds that `build()` their program apart from
+running it; the `serve` kind's programs are private to a running engine
+(`DecodeEngine._step_jit`, `_admit_jit`) and are not covered here: the chip
+run's own `memory_peak_bytes` is what is reported for that cell.
 """
 from __future__ import annotations
 
@@ -39,14 +40,12 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench import drivers
-
     jax.config.update("jax_enable_compilation_cache", False)
     cell = manifest.Cell(manifest.load_manifest(), args.workload)
-    driver = drivers.load(cell.driver)(cell, 1, False)
-    if not hasattr(driver, "programs"):
-        print(f"compile_check: the {cell.driver!r} kind exposes no program "
-              "to compile here (see this file's docstring)")
+    driver = manifest.find("drivers", cell.driver)(cell, 1, False)
+    if not hasattr(driver, "build"):
+        print(f"compile_check: the {cell.driver!r} kind builds no program "
+              "apart from running it (see this file's docstring)")
         return 0
     # the kernels choose interpret mode from the backend they find (the
     # CPU, here): steer them to the Mosaic path for this compile only

@@ -28,10 +28,8 @@ from chipbench import manifest  # noqa: E402
 def read(workload: str, seed: int, rehearse: bool, cases=None) -> dict:
     """{case: numbers compared} for one seed: the control and each fault
     against the float32 reference of the same inputs."""
-    from chipbench import drivers
-
     cell = manifest.Cell(manifest.load_manifest(), workload)
-    driver = drivers.load(cell.driver)(cell, seed, rehearse)
+    driver = manifest.find("drivers", cell.driver)(cell, seed, rehearse)
     return driver.controls(cases)
 
 

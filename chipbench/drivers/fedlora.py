@@ -6,8 +6,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from chipbench import inputs
+from chipbench import inputs, manifest
 from chipbench.drivers.rounds import FOLLOWED, RoundsDriver
+from chipbench.reference import common
 
 
 class Driver(RoundsDriver):
@@ -15,7 +16,7 @@ class Driver(RoundsDriver):
 
     def build(self) -> None:
         from fedml_tpu.config import TrainArgs
-        from fedml_tpu.llm import TransformerLM, federated_lora
+        from fedml_tpu.llm import federated_lora
         from fedml_tpu.ops.flash_attention import flash_attn_fn
         from fedml_tpu.parallel.round import build_round_fn
 
@@ -23,14 +24,9 @@ class Driver(RoundsDriver):
         if t["seqs_per_silo"] != t["batch_size"] or t["epochs"] != 1:
             raise ValueError("the fedlora kind follows ONE local step a "
                              "silo: seqs_per_silo == batch_size, epochs 1")
-        lm = TransformerLM(
-            vocab_size=m["vocab_size"], d_model=m["hidden_size"],
-            n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
-            d_ff=m["intermediate_size"], scan_layers=True,
-            attn_fn=flash_attn_fn, remat=t["remat"])
-        self.base_shapes = jax.eval_shape(
-            lambda: lm.init(jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"])
+        lm, _spec = manifest.find("models", m["model_type"])(
+            m, attn_fn=flash_attn_fn, remat=t["remat"])
+        self.base_shapes = inputs.param_shapes(lm)
         base = self.base()
         targs = TrainArgs(epochs=1, batch_size=t["batch_size"],
                           learning_rate=t["learning_rate"],
@@ -87,8 +83,8 @@ class Driver(RoundsDriver):
         self.state = self.round_fn = self.data = None
 
     def reference(self, precision: str = "f32", **fault) -> dict:
-        ref = self.cell.reference()
-        out = ref.run_lora(self.base(), self.adapters(), self.x, self.y,
-                           FOLLOWED, self.traffic["learning_rate"],
-                           self.model, precision, **fault)
+        out = common.run_lora(
+            self.cell.reference().forward, self.base(), self.adapters(),
+            self.x, self.y, FOLLOWED, self.traffic["learning_rate"],
+            self.model, precision, **fault)
         return self.followed(out)

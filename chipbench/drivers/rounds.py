@@ -16,12 +16,13 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import compare
+from chipbench.drivers import Base
 
 FOLLOWED = 3        # rounds the reference follows
 MIN_TRACED = 3      # whole rounds a traced window holds at the least
 
 
-class RoundsDriver:
+class RoundsDriver(Base):
     rate_metric = ""            # the end-to-end metric this kind reports
 
     def __init__(self, cell, seed: int, rehearse: bool):

@@ -3,19 +3,29 @@ its own HTTP /predict with `stream: true` by an open loop at a fixed rate."""
 from __future__ import annotations
 
 import gc
+import statistics
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import inputs, loadgen
+from chipbench import inputs, loadgen, manifest
+from chipbench.drivers import Base
+from chipbench.reference import common
 
 DRAIN_S = 60.0          # a minute past the close, then a request failed
 PAD_TO = 256            # reference sequences are padded to a few lengths
 
+# the five spans a request's first token is made of, under `serving.request`
+FIVE = ("serving.http.in", "serving.engine.queue", "serving.engine.prefill",
+        "serving.engine.first_fetch", "serving.http.out")
 
-class Driver:
+
+class Driver(Base):
+    # a request's states, not what a thread was doing
+    states = ("serving.request",) + FIVE
+
     def __init__(self, cell, seed: int, rehearse: bool):
         self.cell, self.seed = cell, seed
         self.traffic, self.config, self.model = cell.sizes(rehearse)
@@ -30,20 +40,14 @@ class Driver:
                                 self.model["compute_dtype"])
 
     def setup(self) -> None:
-        from fedml_tpu.llm.transformer import TransformerLM
         from fedml_tpu.serving.scheduler import start_replica
 
-        m = self.model
-        lm = {"vocab_size": m["vocab_size"], "d_model": m["hidden_size"],
-              "n_layers": m["num_hidden_layers"],
-              "n_heads": m["num_attention_heads"],
-              "d_ff": m["intermediate_size"], "scan_layers": True}
-        self.shapes = jax.eval_shape(
-            lambda: TransformerLM(**lm).init(
-                jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+        lm, spec = manifest.find("models", self.model["model_type"])(
+            self.model)
+        self.shapes = inputs.param_shapes(lm)
         _job, self.runner = start_replica({
-            "model_kind": "lm", "lm": lm, "params": self.weights(),
-            "port": 0, "serve": dict(self.traffic["serve"])})
+            **spec, "params": self.weights(), "port": 0,
+            "serve": dict(self.traffic["serve"])})
         self.warm()
 
     def warm(self) -> None:
@@ -105,6 +109,13 @@ class Driver:
             # a failed request misses every tail: it waited to the end
             ttft.append((r.token_times[0] if r.ok else end) - r.plan.due)
             gaps += [b - a for a, b in zip(r.token_times, r.token_times[1:])]
+        # the whole shape beside the three statistics that are reported
+        shape = lambda v: " ".join(
+            f"p{q} {1e3 * loadgen.percentile(v, q):.2f}"
+            for q in (50, 75, 90, 95, 99)) + \
+            f" mean {1e3 * statistics.fmean(v):.2f} over {len(v)}"
+        print(f"[chipbench] first token ms: {shape(ttft)}\n"
+              f"[chipbench] token gap ms: {shape(gaps or [end])}", flush=True)
         failed = sum(not r.ok for r in rows)
         # a 200 the engine did not complete came from the predictor's
         # per-request fallback: not the path this cell times
@@ -121,7 +132,8 @@ class Driver:
             self.log = self.traced_log(rows, *span)
         return {"attempted": len(rows), "failed": failed,
                 "metrics": {
-                    "ttft_p95_ms": 1e3 * loadgen.percentile(ttft, 95),
+                    "ttft_p50_ms": 1e3 * loadgen.percentile(ttft, 50),
+                    "ttft_p90_ms": 1e3 * loadgen.percentile(ttft, 90),
                     "gap_p95_ms": 1e3 * loadgen.percentile(gaps or [end], 95)}}
 
     @staticmethod
@@ -151,6 +163,16 @@ class Driver:
         return {"admitted": admitted, "emitted_tokens": emitted,
                 "context_token_sum": ctx,
                 "processed_tokens": prompt_tokens + emitted}
+
+    # -------------------------------------------------------------- trace
+    def programs(self) -> list:
+        """The engine's step program (the admit buckets' text is not read)."""
+        eng = self.runner.predictor.engine
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            (eng.params, eng.adapters, eng._carry))
+        return [("step", eng._step_jit, shapes)]
 
     # -------------------------------------------------------------- check
     def free(self) -> None:
@@ -198,12 +220,14 @@ class Driver:
             out.append(at.max(-1) - at[np.arange(len(served)), served])
         return np.concatenate(out) if out else np.zeros((0,))
 
+    def reference_logits(self, precision: str = "f32"):
+        return common.sequence_logits(self.cell.reference().forward,
+                                      self.weights(), self.model, precision)
+
     def check(self) -> dict:
         self.free()
         picked = self.sample()
-        ref = self.cell.reference()
-        gaps = self.served_gaps(
-            picked, ref.sequence_logits(self.weights(), self.model))
+        gaps = self.served_gaps(picked, self.reference_logits())
         return {"served_logit_gap": float(gaps.max()) if gaps.size
                 else float("nan"),
                 "_compared_tokens": int(gaps.size),
@@ -221,9 +245,8 @@ class Driver:
                     Tracer("", 0.0, on=False))
         self.free()
         picked = self.sample()
-        ref = self.cell.reference()
         pairs = [(list(r.plan.tokens), list(r.tokens)) for r in picked]
-        f32 = ref.sequence_logits(self.weights(), self.model)
+        f32 = self.reference_logits()
         hi = [self.logits_at_served(f32, *p) for p in pairs]
         vocab = self.model["vocab_size"]
         flip = lambda s: s[:-1] + [(s[-1] + vocab // 2) % vocab]
@@ -235,7 +258,7 @@ class Driver:
                    gap_of(at, flip(p[1])) for at, p in zip(hi, pairs))}}
         del f32                     # one float32 copy of the weights at a time
         gc.collect()
-        low = ref.sequence_logits(self.weights(), self.model, "fp8")
+        low = self.reference_logits("fp8")
         out["control_fp8"] = {"served_logit_gap": max(
             gap_of(at, self.logits_at_served(low, *p).argmax(-1))
             for at, p in zip(hi, pairs))}

@@ -38,6 +38,13 @@ def path_str(path) -> str:
                     for p in path)
 
 
+def param_shapes(module, seq: int = 8):
+    """The parameter tree's shapes of a flax language model (token ids in,
+    logits out), without making a weight."""
+    return jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((1, seq), jnp.int32))["params"])
+
+
 def init_tree(shapes, seed: int, gain: float, dtype, salt: int = 0):
     """A parameter tree of the given shapes, every leaf from the seed."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)

@@ -1,17 +1,76 @@
-"""BENCHMARK.json and the data files it names, found by name.
+"""BENCHMARK.json and everything it names, found by name.
 
 A configuration is `configs/<name>.json` (the manifest gives the path), a
 traffic mix `traffic/<name>.json`, a per-layer metric `metrics/<name>.json`.
-Nothing here knows a cell, a configuration or a metric by name.
+The CODE those files name is found the same way, by `find(kind, name)`:
+
+    kind       named by                    file                    it exports
+    drivers    a traffic file's `driver`   drivers/<kind>.py       `Driver`
+    models     a model's `model_type`      models/<model_type>.py  `build`
+    reference  the configuration's name    reference/<config>.py   the module
+    work       a metric file's `work`      work/<name>.py          `<name>`
+    reducers   a metric file's `reducer`   reducers/<kind>.py      `<kind>`
+
+(`build(model, **options)`, `<name>(cell, log)`, `<kind>(spec, trace, ctx)`)
+so adding one is adding a file. The work functions and reducers that came
+with the harness live together in their directory's `__init__.py` (its
+`__all__` says which of its functions are of the kind) and are found there
+under the same rule: the directory is the kind's one home.
+Nothing here knows a cell, a configuration, a metric or a function by name.
 """
 from __future__ import annotations
 
 import importlib
 import json
+import re
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
+# what a file of each kind exports: a fixed attribute, the module itself
+# (None), or a function of the file's own name ("")
+EXPORTS = {"drivers": "Driver", "models": "build", "reference": None,
+           "work": "", "reducers": ""}
+CODE_NAME = re.compile(r"^[A-Za-z_][A-Za-z0-9_]*$")
+
+
+def find(kind: str, name: str):
+    """The code a data file names: `chipbench/<kind>/<name>.py`'s export,
+    or the function `<name>` that `chipbench/<kind>/__init__.py` holds. A
+    name that resolves to nothing is a KeyError that names the file looked
+    for."""
+    if kind not in EXPORTS:
+        raise KeyError(f"no such kind of code {kind!r}; there are "
+                       f"{sorted(EXPORTS)}")
+    if not CODE_NAME.match(str(name)):
+        raise KeyError(f"{kind} {name!r} is no name a file can have")
+    export = EXPORTS[kind]
+    path = HERE / kind / f"{name}.py"
+    if path.exists():
+        module = importlib.import_module(f"chipbench.{kind}.{name}")
+        found = module if export is None else getattr(
+            module, export or name, None)
+        if found is None:
+            raise KeyError(f"chipbench/{kind}/{name}.py defines no "
+                           f"{export or name!r}")
+        return found
+    home = importlib.import_module(f"chipbench.{kind}")
+    if export == "" and name in home.__all__:
+        return getattr(home, name)
+    raise KeyError(
+        f"{kind} {name!r}: no file chipbench/{kind}/{name}.py" + (
+            f", and chipbench/{kind}/__init__.py has no function of that name"
+            if export == "" else ""))
+
+
+def names(kind: str) -> list[str]:
+    """Every name `find(kind, ...)` resolves: the directory's files and, of
+    the kinds that are functions, those its `__init__.py` defines."""
+    found = {f.stem for f in (HERE / kind).glob("*.py")
+             if f.stem != "__init__"}
+    if EXPORTS[kind] == "":
+        found |= set(importlib.import_module(f"chipbench.{kind}").__all__)
+    return sorted(found)
 
 
 def load_json(path: Path) -> dict:
@@ -72,8 +131,7 @@ class Cell:
 
     def reference(self):
         """The configuration's plain reference: `reference/<config>.py`."""
-        return importlib.import_module(
-            f"chipbench.reference.{self.config_name}")
+        return find("reference", self.config_name)
 
     def metric_file(self, name: str) -> dict:
         return load_json(HERE / "metrics" / f"{name}.json")

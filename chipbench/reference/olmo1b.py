@@ -21,7 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference.common import HI, rounder, softmax_ce
+from chipbench.reference.common import HI, rounder
 
 NORM_EPS = 1e-6
 ROPE_BASE = 10000.0
@@ -83,55 +83,3 @@ def forward(params, tokens, model: dict, precision: str = "f32",
                         (params["blocks"], adapters))
     x = _rms_norm(x, params["final_norm"]["scale"])
     return mm(x, params["lm_head"]["kernel"])
-
-
-def lora_round(base, adapters, x, y, lr, model, precision="f32",
-               half_batch=False):
-    """One round of federated LoRA where every silo takes ONE local SGD
-    step over all its sequences: x, y [silos, seqs, T]. Every silo starts
-    from the same adapters, so the mean of the silos' changes is -lr times
-    the mean of their gradients. Returns (new adapters, mean loss).
-    `half_batch` plants the fault "half of the batch left out"."""
-    if half_batch:
-        x, y = x[:, : x.shape[1] // 2], y[:, : y.shape[1] // 2]
-    rows_x = x.reshape(-1, x.shape[-1])
-    rows_y = y.reshape(-1, y.shape[-1])
-    n = rows_x.shape[0]
-
-    def seq_loss(ad, xs, ys):
-        return softmax_ce(forward(base, xs, model, precision, ad), ys)
-
-    def one(acc, xy):
-        loss, g = jax.value_and_grad(seq_loss)(adapters, *xy)
-        return jax.tree.map(lambda a, b: a + b / n, acc, g), loss
-
-    grad, losses = jax.lax.scan(one, jax.tree.map(jnp.zeros_like, adapters),
-                                (rows_x, rows_y))
-    return (jax.tree.map(lambda a, g: a - lr * g, adapters, grad),
-            jnp.mean(losses))
-
-
-def run_lora(base, adapters0, x, y, rounds: int, lr, model,
-             precision="f32", half_batch=False):
-    """Follow `rounds` rounds (the data is the same every round, as the
-    program is given it). Returns the losses and the adapters after round 1
-    and after the last."""
-    f32 = lambda tree: jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
-                                    tree)
-    base, adapters0 = f32(base), f32(adapters0)
-    step = jax.jit(lambda b, a, xs, ys: lora_round(
-        b, a, xs, ys, lr, model, precision, half_batch))
-    ad, losses, first = adapters0, [], None
-    for _ in range(rounds):
-        ad, loss = step(base, ad, x, y)
-        losses.append(float(loss))
-        first = ad if first is None else first
-    return {"loss": losses, "params": [first, ad], "params0": adapters0}
-
-
-def sequence_logits(params, model: dict, precision: str = "f32"):
-    """A jitted tokens [T] -> logits [T, V] over float32 weights, for the
-    served-token comparison (one prompt with its served tokens a call)."""
-    params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params)
-    fn = jax.jit(lambda p, toks: forward(p, toks, model, precision))
-    return lambda tokens: fn(params, tokens)

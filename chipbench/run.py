@@ -4,7 +4,8 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 One process, no children. It finds the cell's configuration, traffic mix
-and per-layer metrics by name (chipbench/manifest.py), makes weights and
+and per-layer metrics, and the code they name, by name
+(chipbench/manifest.py), makes weights and
 data on the device from --seed, warms up this cell's shapes and no others
 (all of that is `setup_s`), measures for --seconds, reads the device's
 memory peak, and only then frees the program and runs the plain reference
@@ -15,9 +16,19 @@ line, and exits 1. Without a TPU (or with fewer chips than the cell asks
 for, or a device kind chipbench/peaks.json does not know): exit 2, nothing
 on stdout.
 
+With `--trace 1` the window is traced for a few seconds, and the trace is
+read together with what the program says of itself (chipbench/trace.py: the
+named scopes of its compiled programs, the recorder's spans on the device's
+clock, its counters' deltas), so that every per-layer metric of the cell
+comes off ONE trace through its own reducer (`manifest.find` finds it),
+and `breakdown` names an operation `<scope>:<kind>` and an idle gap by the
+program's span over it. A `[chipbench] plane {...}` line says how good the
+clock anchor was and what the scopes cover.
+
 `--rehearse-cpu` (never chosen automatically) runs the same control flow in
 the sandbox at the tiny sizes of the files' `rehearse` blocks, kernels
-interpreted, reading fixtures/ in place of a device trace; its last line is
+interpreted, reading fixtures/<cell>.plane.json, or the driver kind's
+fixtures/<kind>.plane.json, in place of a device trace; its last line is
 stamped "platform": "cpu" and is checked for form only. It is no result.
 """
 from __future__ import annotations
@@ -31,6 +42,7 @@ import faulthandler
 import json
 import os
 import shutil
+import statistics
 import sys
 import threading
 from pathlib import Path
@@ -84,7 +96,38 @@ def device_or_exit(cell, rehearse: bool):
     return dev, len(devs), peaks_all[dev.device_kind]
 
 
-def reduce_trace(cell, trace: dict, specs: list, log: dict, peaks: dict):
+def read_trace(cell, driver, tracer, trace_dir: Path, since: float):
+    """(trace, log, report) of the traced window: the device's planes, and
+    on the same clock what the program said of itself while it ran."""
+    from fedml_tpu.utils.events import recorder
+
+    from chipbench import reduce
+    from chipbench import trace as tr
+
+    spans = list(recorder.spans)
+    scopes = tr.compiled_scopes(driver.programs())
+    trace = reduce.load_xplane(reduce.find_xplane(trace_dir), cell.chips,
+                               annotations={s.name for s in spans})
+    lo, hi = reduce.window_of(trace)
+    trace["program"] = tr.program_rows(spans, tracer.anchor, lo, since)
+    for c in trace["chips"]:
+        c["scopes"] = scopes
+    errs = tr.anchor_error_us(trace["program"], trace.pop("annotations"),
+                              lo, hi)
+    trace["anchor"] = {
+        "bracket_us": tracer.bracket_s * 1e6, "matched": len(errs),
+        "error_us_median": statistics.median(errs) if errs else None,
+        "error_us_worst": max(errs) if errs else None}
+    report = {"anchor": trace["anchor"],
+              "spans_dropped": sum(recorder.dropped.values()),
+              "scoped_instructions": {
+                  k: f"{sum(bool(reduce.leaf(p)) for p in v.values())}/{len(v)}"
+                  for k, v in scopes.items()}}
+    return trace, {**driver.log, "counters": tracer.counters}, report
+
+
+def reduce_trace(cell, trace: dict, specs: list, log: dict, peaks: dict,
+                 states=()):
     from chipbench import reduce
 
     window_s, busy_s = reduce.window_seconds(trace), reduce.busy_seconds(trace)
@@ -93,10 +136,10 @@ def reduce_trace(cell, trace: dict, specs: list, log: dict, peaks: dict):
     metrics = {}
     for m in specs:
         spec = cell.metric_file(m["name"])
-        value = reduce.REDUCERS[spec["reducer"]](spec, trace, ctx)
+        value = manifest.find("reducers", spec["reducer"])(spec, trace, ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    return metrics, window_s, busy_s, reduce.breakdown(trace)
+    return metrics, window_s, busy_s, reduce.breakdown(trace, states=states)
 
 
 def main(argv=None) -> int:
@@ -120,6 +163,13 @@ def main(argv=None) -> int:
     from fedml_tpu.utils import enable_compilation_cache
 
     cache_dir = enable_compilation_cache()
+    # jax keys a cached program WITHOUT its metadata, so a hit may hand back
+    # the executable another tree compiled, carrying that tree's names (seen:
+    # a round program without one `fed.*` scope). A traced run reads names
+    # off its executables, so every run's keys include the metadata.
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     compiles = CompileCount()
     out_dir = manifest.HERE / "out" / cell.name
     trace_dir = out_dir / "trace"
@@ -129,17 +179,17 @@ def main(argv=None) -> int:
         f"{n_dev} x {dev.device_kind} ({dev.platform}); cache {cache_dir}"
         + ("  REHEARSAL: cpu, tiny sizes, no result" if rehearse else ""))
 
-    from chipbench import compare, drivers
+    from chipbench import compare
     from chipbench.trace import Tracer
 
-    driver = drivers.load(cell.driver)(cell, seed, rehearse)
+    driver = manifest.find("drivers", cell.driver)(cell, seed, rehearse)
     driver.setup()
     setup_s = time.perf_counter() - _T0
     say(f"set-up {setup_s:.2f} s")
 
     trace_s = min(float(driver.traffic.get("trace_seconds", 4.0)), seconds)
     tracer = Tracer(trace_dir, trace_s, on=traced and not rehearse)
-    c0 = compiles.n
+    since, c0 = time.perf_counter(), compiles.n
     res = driver.window(seconds, tracer)
     in_window = compiles.n - c0
     stats = dev.memory_stats() or {}
@@ -161,19 +211,24 @@ def main(argv=None) -> int:
 
         t_read = time.perf_counter()
         if rehearse:
-            trace = manifest.load_json(
-                manifest.HERE / "fixtures" / f"{cell.driver}.trace.json")
-            log = trace["log"]
+            kept = manifest.HERE / "fixtures" / f"{cell.name}.plane.json"
+            trace = manifest.load_json(kept if kept.exists() else
+                                       kept.with_name(
+                                           f"{cell.driver}.plane.json"))
+            log, report = trace["log"], {"anchor": trace.get("anchor", {})}
         else:
-            xp = reduce.find_xplane(trace_dir)
-            trace, log = reduce.load_xplane(xp, cell.chips), driver.log
+            trace, log, report = read_trace(cell, driver, tracer, trace_dir,
+                                            since)
             with open(out_dir / "trace.trimmed.json", "w") as f:
                 json.dump({**reduce.trim(trace), "log": log}, f)
             shutil.rmtree(trace_dir, ignore_errors=True)
         say(f"trace read {time.perf_counter() - t_read:.2f} s")
         specs = manifest.metrics_for(mf, cell.name, traced=True)
         obj["metrics"], device["window_s"], device["busy_s"], \
-            obj["breakdown"] = reduce_trace(cell, trace, specs, log, peaks)
+            obj["breakdown"] = reduce_trace(cell, trace, specs, log, peaks,
+                                            driver.states)
+        report.update(coverage=reduce.coverage(trace))
+        say("plane " + json.dumps(report))
     else:
         units = {m["name"]: m["unit"] for m in mf["end_to_end"]}
         values = {"setup_s": setup_s, **res["metrics"]}

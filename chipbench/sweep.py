@@ -5,10 +5,10 @@
 
 One process, one replica, one set-up; then a window per rate over the
 cell's own mix. Per rate it prints the completed share, the requests still
-in flight at the middle and at the close of the window, `ttft_p95_ms` and
-`gap_p95_ms` and the tokens served per second inside the window, and at the
-end names the knee: the most tokens per second any rate was served (the
-engine's capacity on this mix), over the mix's mean output length: the
+in flight at the middle and at the close of the window, the window's
+end-to-end metrics and the tokens served per second inside the window, and
+at the end names the knee: the most tokens per second any rate was served
+(the engine's capacity on this mix), over the mix's mean output length: the
 request rate above which a backlog has to grow. Four fifths of the knee goes
 into the cell's traffic file as a number, and this table into PERF.md. Not
 part of a benchmark run.
@@ -46,12 +46,12 @@ def main(argv=None) -> int:
         os.environ["JAX_PLATFORMS"] = "cpu"
     from fedml_tpu.utils import enable_compilation_cache
 
-    from chipbench import drivers
     from chipbench.trace import Tracer
 
     enable_compilation_cache()
     cell = manifest.Cell(manifest.load_manifest(), args.workload)
-    driver = drivers.load(cell.driver)(cell, args.seed, args.rehearse_cpu)
+    driver = manifest.find("drivers", cell.driver)(cell, args.seed,
+                                                   args.rehearse_cpu)
     driver.setup()
     table = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):

@@ -2,11 +2,20 @@
 
 Never from what the program says it did: a roofline or an mfu reads the
 same work whoever implements the step. Recomputed operations (remat) do not
-count. Every function takes the cell (its configuration and traffic files)
-and the harness's log of the traced window, and returns
+count. Every work function takes the cell (its configuration and traffic
+files) and the harness's log of the traced window, and returns
 {"flops": ..., "bytes": ...}.
+
+A metric file names one by `work`, and `manifest.find("work", name)` finds
+it: `work/<name>.py`'s function `<name>`, or one of those below (`__all__`).
+The counts here are the dense block's (every head a KV head, every layer
+full attention, a head of hidden / heads); a configuration they do not
+describe brings work functions of its own, as files.
 """
 from __future__ import annotations
+
+__all__ = ["resnet_train_flops", "lora_train_flops", "flash_fwd_call",
+           "flash_bwd_call", "paged_attention_traffic", "decode_flops"]
 
 
 # ------------------------------------------------------------- ResNet-18-GN
@@ -110,7 +119,3 @@ def decode_flops(cell, log: dict) -> dict:
     return {"flops": 2.0 * lm_matmul_params(cell.config["model"])
             * log["processed_tokens"], "bytes": 0.0}
 
-
-WORK = {f.__name__: f for f in (
-    resnet_train_flops, lora_train_flops, flash_fwd_call, flash_bwd_call,
-    paged_attention_traffic, decode_flops)}

@@ -14,7 +14,7 @@ def test_a_sound_run_is_correct_and_its_line_well_formed(capsys):
     rc, obj = rehearse(capsys, CELL, seconds=2.0)
     assert rc == 0 and obj["correct"] is True and obj["failed"] == 0
     assert obj["attempted"] > 3 and list(obj)[-1] == "compared"
-    assert {"ttft_p95_ms", "gap_p95_ms", "setup_s"} == set(obj["metrics"])
+    assert {"ttft_p50_ms", "ttft_p90_ms", "gap_p95_ms", "setup_s"} == set(obj["metrics"])
 
 
 def test_a_traced_rehearsal_reads_the_fixture_through_every_reducer(capsys):

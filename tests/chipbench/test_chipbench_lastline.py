@@ -101,6 +101,31 @@ def test_refuses(what):
     assert lastline.problems(obj, MF, CELL, traced), what
 
 
+@pytest.mark.parametrize("metric", MF["per_layer"], ids=lambda m: m["name"])
+def test_a_traced_line_may_lack_no_per_layer_metric_of_its_cell(metric):
+    """A reader that finds nothing leaves its metric out of the line, and the
+    line is then refused by name: a kernel, a scope, a span or a counter that
+    was renamed must not run on out of its reader's sight with exit 0."""
+    for cell in metric["workloads"]:
+        obj = good(True)
+        obj["metrics"] = {
+            m["name"]: {"value": 1.0, "unit": m["unit"]}
+            for m in manifest.metrics_for(MF, cell, True)
+            if m["name"] != metric["name"]}
+        why = lastline.problems(obj, MF, cell, True)
+        assert len(why) == 1 and repr(metric["name"]) in why[0], why
+
+
+def test_an_untraced_line_may_lack_nothing():
+    for cell in (w["name"] for w in MF["workloads"]):
+        for m in manifest.metrics_for(MF, cell, False):
+            obj = good(False)
+            obj["metrics"] = {
+                k["name"]: {"value": 1.0, "unit": k["unit"]}
+                for k in manifest.metrics_for(MF, cell, False) if k is not m}
+            assert lastline.problems(obj, MF, cell, False), (cell, m["name"])
+
+
 def test_a_rehearsal_line_is_checked_for_form_only():
     obj = good(False)
     obj["device"].update(platform="cpu", kind="cpu")

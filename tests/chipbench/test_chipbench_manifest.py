@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from chipbench import manifest, reduce, work
+from chipbench import manifest
 
 MF = manifest.load_manifest()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -88,9 +88,19 @@ def test_moves_names_a_metric_every_listed_cell_reports(metric):
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_has_its_files_and_reports_enough(cell):
     c = manifest.Cell(MF, cell)
-    assert c.driver in ("fedavg", "fedlora", "serve")
+    # a driver kind is a file: drivers/<kind>.py, with the rehearsal's trace
     assert (manifest.HERE / "drivers" / f"{c.driver}.py").exists()
+    assert (manifest.HERE / "fixtures" / f"{c.driver}.plane.json").exists()
+    driver = manifest.find("drivers", c.driver)(c, 1, True)
+    for method in ("setup", "window", "check", "controls", "programs"):
+        assert callable(getattr(driver, method)), (c.driver, method)
+    assert isinstance(driver.states, tuple)
+    assert driver.traffic["limits"], "a cell compares nothing"
     assert (manifest.HERE / "reference" / f"{c.config_name}.py").exists()
+    assert c.reference().__name__ == f"chipbench.reference.{c.config_name}"
+    if "model_type" in c.config["model"]:       # the program's model: a file
+        assert callable(manifest.find(
+            "models", c.config["model"]["model_type"]))
     assert c.traffic["limits"], "a cell compares nothing"
     assert "rehearse" in c.traffic and "rehearse" in c.config
     e2e = [m["name"] for m in manifest.metrics_for(MF, cell, False)]
@@ -102,8 +112,11 @@ def test_every_cell_has_its_files_and_reports_enough(cell):
             cfg["file"].startswith(p + "/") for p in MF["paths"])
         held = json.loads(path.read_text())
         assert held["source"] == cfg["source"]
+        # a cut sits at the top of the file or in its `model` group, with
+        # the value the source publishes stated beside it
         for key in cfg["reduced"]:
-            assert key in held
+            assert key in held or key in held["model"], (cfg["name"], key)
+            assert key in held["published"], (cfg["name"], key)
     assert {w["config"] for w in MF["workloads"]} == {
         c["name"] for c in MF["configs"]}
 
@@ -112,9 +125,9 @@ def test_every_cell_has_its_files_and_reports_enough(cell):
 def test_every_per_layer_metric_has_a_reader_of_its_own(metric):
     spec = manifest.load_json(
         manifest.HERE / "metrics" / f"{metric['name']}.json")
-    assert spec["reducer"] in reduce.REDUCERS
+    assert callable(manifest.find("reducers", spec["reducer"]))
     if "work" in spec:
-        assert spec["work"] in work.WORK
+        assert callable(manifest.find("work", spec["work"]))
     for pat in spec.get("programs", []) + spec.get("kernels", []):
         re.compile(pat)
     if "roofline" in metric["name"]:

@@ -1,19 +1,21 @@
 """The reduction from a trace to per-layer metrics: on the recorded fixture
-traces (trimmed copies of real v5e traces) every reducer kind gives the
-numbers read off them by hand, and a union of overlapping intervals never
-passes the window."""
+traces (fixtures/*.plane.json, trimmed copies of real v5e traces) the four
+reducer kinds that read the device's two lines give the numbers read off them
+by hand, and a union of overlapping intervals never passes the window. (The
+three kinds that read what the program says of itself:
+test_chipbench_spanplane.)"""
 import types
 
 import pytest
 
-from chipbench import manifest, reduce
+from chipbench import manifest, reduce, reducers
 
 PEAKS = manifest.load_json(manifest.HERE / "peaks.json")["TPU v5 lite"]
 MF = manifest.load_manifest()
 
 
 def fixture(kind):
-    return manifest.load_json(manifest.HERE / "fixtures" / f"{kind}.trace.json")
+    return manifest.load_json(manifest.HERE / "fixtures" / f"{kind}.plane.json")
 
 
 def ctx(cell, trace):
@@ -24,49 +26,52 @@ def ctx(cell, trace):
 
 def read(cell, name, trace):
     spec = manifest.Cell(MF, cell).metric_file(name)
-    return reduce.REDUCERS[spec["reducer"]](spec, trace, ctx(cell, trace))
+    return manifest.find("reducers", spec["reducer"])(
+        spec, trace, ctx(cell, trace))
 
 
-# ---- the fedavg fixture: ten rounds of jit_round_body on one v5e
+# ---- the fedavg fixture: three rounds of jit_round_body on one v5e
 def test_fedavg_fixture_window_is_the_harness_span():
     t = fixture("fedavg")
-    assert reduce.window_of(t) == (44841250, 44841250 + 4399810609)
-    assert reduce.window_seconds(t) == pytest.approx(4.399810609)
+    assert reduce.window_of(t) == (479927005, 479927005 + 1304799901)
+    assert reduce.window_seconds(t) == pytest.approx(1.304799901)
 
 
 def test_program_device_ms_on_the_fedavg_fixture():
-    # the ten executions last 426.85 .. 426.90 ms; their mean, by hand:
+    # the three executions last 426.83 .. 426.92 ms; their mean, by hand:
     t = fixture("fedavg")
     durs = [p[2] for p in t["chips"][0]["programs"]
             if p[0].startswith("jit_round_body")]
-    assert len(durs) == 10 and durs[0] == 426849772
+    assert durs == [426848747, 426916671, 426828703]
     assert read("resnet18gn_fedavg_c100", "round_device_ms.fedavg",
-                t) == pytest.approx(426.8662565)
+                t) == pytest.approx(426.864707)
 
 
 def test_program_gap_ms_on_the_fedavg_fixture():
-    # round 1 ends at 47809161 + 426849772 = 474658933 ns, round 2 starts
-    # 7796183 ns later; four small programs (594 + 673 + 593 + 4847 ns ...)
-    # run in between and are not idle time. The chip run printed 7.418433.
+    # round 1 ends at 482948569 + 426848747 = 909797316 ns, round 2 starts
+    # 7715429 ns later; four small programs (594 + 674 + 595 + 4852 ns)
+    # run in between and are not idle time: 7.708714 ms. The next gap is
+    # 8425118 - 6703 ns = 8.418415 ms; the median of two is their mean.
     t = fixture("fedavg")
     assert read("resnet18gn_fedavg_c100", "round_gap_ms.fedavg",
-                t) == pytest.approx(7.418433, abs=1e-6)
+                t) == pytest.approx((7.708714 + 8.418415) / 2, abs=1e-6)
 
 
 def test_mfu_on_the_fedavg_fixture():
-    # 96,000 samples x (3 x 1.1125 - 0.0035) GFLOP over 4.3998 s x 197 T
+    # 28,800 samples x (3 x 1.1108 - 0.0035) GFLOP over 1.3048 s x 197 T
     t = fixture("fedavg")
     assert read("resnet18gn_fedavg_c100", "mfu.fedavg",
-                t) == pytest.approx(36.87096269752395)
+                t) == pytest.approx(37.29888070568051)
 
 
 # ---- the fedlora fixture: three rounds, Mosaic flash kernels among the ops
 def test_fedlora_fixture_program_and_mfu():
     t = fixture("fedlora")
+    # 1696764874, 1696762521 and 1696766131 ns; 98,304 tokens in 5.1019 s
     assert read("olmo1b_fedlora_s8", "round_device_ms.fedlora",
-                t) == pytest.approx(1696.7707746666667)
+                t) == pytest.approx(1696.7645086666666)
     assert read("olmo1b_fedlora_s8", "mfu.fedlora",
-                t) == pytest.approx(50.10886544724606)
+                t) == pytest.approx(50.09982367198908)
 
 
 def test_kernel_roofline_on_the_fedlora_fixture():
@@ -101,10 +106,10 @@ def test_program_device_ms_per_count_from_the_log():
               4_000_000], ["jit_other(2)", 8_000_000, 1]], [],
              [[reduce.WINDOW_SPAN, 0, 10_000_000]])
     spec = {"programs": ["^jit__admit"], "per": "admitted"}
-    assert reduce.program_device_ms(spec, t, {"log": {"admitted": 3}}) == 2.0
-    assert reduce.program_device_ms(spec, t, {"log": {"admitted": 0}}) is None
+    assert reducers.program_device_ms(spec, t, {"log": {"admitted": 3}}) == 2.0
+    assert reducers.program_device_ms(spec, t, {"log": {"admitted": 0}}) is None
     med = {"programs": ["^jit__admit"], "per": "execution", "stat": "median"}
-    assert reduce.program_device_ms(med, t, {}) == 3.0
+    assert reducers.program_device_ms(med, t, {}) == 3.0
 
 
 def test_program_gap_ms_leaves_out_other_programs_and_long_waits():
@@ -114,19 +119,19 @@ def test_program_gap_ms_leaves_out_other_programs_and_long_waits():
     t = tiny(progs, [], [[reduce.WINDOW_SPAN, 0, 6_000_000]])
     # gaps: 200 - 40 (the admit) = 160 ns, 5,000,000 ns, 200 ns
     spec = {"programs": ["^jit_step"]}
-    assert reduce.program_gap_ms(spec, t, {}) == pytest.approx(200e-6)
+    assert reducers.program_gap_ms(spec, t, {}) == pytest.approx(200e-6)
     spec["ignore_gaps_over_ms"] = 1.0
-    assert reduce.program_gap_ms(spec, t, {}) == pytest.approx(180e-6)
+    assert reducers.program_gap_ms(spec, t, {}) == pytest.approx(180e-6)
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():
     t = tiny([["jit_round_body(1)", 0, 10]], [["fusion.1", 0, 10]])
     c = {"cell": types.SimpleNamespace(), "log": {}, "peaks": PEAKS,
          "window_s": 1e-6, "busy_s": 1e-8}
-    assert reduce.program_device_ms(
+    assert reducers.program_device_ms(
         {"programs": ["^jit_absent"]}, t, c) is None
-    assert reduce.program_gap_ms({"programs": ["^jit_round"]}, t, c) is None
-    assert reduce.kernel_roofline(
+    assert reducers.program_gap_ms({"programs": ["^jit_round"]}, t, c) is None
+    assert reducers.kernel_roofline(
         {"kernels": ["flash_fwd"], "work": "flash_fwd_call"}, t, c) is None
 
 
@@ -135,7 +140,7 @@ def test_mfu_over_busy_seconds_and_breakdown_form():
     t = fixture("fedlora")
     c = {"cell": cell, "log": {"processed_tokens": 1000}, "peaks": PEAKS,
          "window_s": 4.0, "busy_s": 2.0}
-    got = reduce.mfu({"work": "decode_flops", "over": "busy_s"}, t, c)
+    got = reducers.mfu({"work": "decode_flops", "over": "busy_s"}, t, c)
     params = 16 * (4 * 2048 ** 2 + 3 * 2048 * 8192) + 2048 * 50304
     assert got == pytest.approx(100 * 2 * params * 1000 / (2.0 * 197e12))
     bd = reduce.breakdown(t)

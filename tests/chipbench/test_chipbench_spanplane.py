@@ -1,21 +1,30 @@
-"""The span plane's readers (chipbench/spanplane.py): on hand-made traces
-each of the three reducer kinds and the breakdown's naming give the numbers
-worked out by hand; on fixtures/*.plane.json (trimmed copies of real v5e
-traces of PR 26) they give what the chip runs printed; the clock anchor
-maps a host span onto the window within a stated error; a reader that
-finds nothing returns nothing, and a share cannot pass 100%."""
+"""The span plane's readers (chipbench/reducers/, reduce.py's scopes and
+breakdown, trace.py's anchor): on hand-made traces each of the three reducer
+kinds and the breakdown's naming give the numbers worked out by hand; on
+fixtures/*.plane.json (trimmed copies of real v5e traces of PR 26) they give
+what the chip runs printed; the clock anchor maps a host span onto the
+window within a stated error; a reader that finds nothing returns nothing,
+and a share cannot pass 100%."""
 import json
 import types
 
 import pytest
 
-from chipbench import manifest, reduce, spanplane as sp
+from chipbench import manifest, reduce, run
+from chipbench import trace as tr
+from chipbench.drivers import serve
+from chipbench.reducers import (counter_ratio, grouped_ms, scope_share,
+                                span_stat)
 
 MF = manifest.load_manifest()
 PEAKS = manifest.load_json(manifest.HERE / "peaks.json")["TPU v5 lite"]
 CELLS = {"fedavg": "resnet18gn_fedavg_c100", "fedlora": "olmo1b_fedlora_s8",
          "serve": "olmo1b_decode_chat"}
 STEP, ADMIT = "jit__step_all(1)", "jit__admit(2)"
+
+
+def breakdown(trace):
+    return reduce.breakdown(trace, states=serve.Driver.states)
 
 
 def plane(kind):
@@ -29,7 +38,7 @@ def read(kind, name, trace=None):
     ctx = {"cell": cell, "log": trace["log"], "peaks": PEAKS,
            "window_s": reduce.window_seconds(trace),
            "busy_s": reduce.busy_seconds(trace)}
-    return sp.REDUCERS[spec["reducer"]](spec, trace, ctx)
+    return manifest.find("reducers", spec["reducer"])(spec, trace, ctx)
 
 
 # ----------------------------------------------------------- hand-made trace
@@ -56,12 +65,12 @@ def tiny():
 
 
 def test_leaf_is_the_innermost_scope_even_inside_parentheses():
-    assert sp.leaf("jit(f)/fed.collect/while/body/vmap(fed.local_sgd)/"
+    assert reduce.leaf("jit(f)/fed.collect/while/body/vmap(fed.local_sgd)/"
                    "jvp(lm.head)/mul") == "lm.head"
-    assert sp.leaf("jit(round_body)/fed.finalize/fed.health/vmap()/x") \
+    assert reduce.leaf("jit(round_body)/fed.finalize/fed.health/vmap()/x") \
         == "fed.health"
-    assert sp.leaf("jit(f)/while/body/dynamic_update_slice") == ""
-    assert sp.kind_of("bitcast_add_fusion.12") == "bitcast_add_fusion"
+    assert reduce.leaf("jit(f)/while/body/dynamic_update_slice") == ""
+    assert reduce.kind_of("bitcast_add_fusion.12") == "bitcast_add_fusion"
 
 
 def test_scope_share_by_leaf_unscoped_and_program():
@@ -70,17 +79,17 @@ def test_scope_share_by_leaf_unscoped_and_program():
     # unscoped 150 + 100, kv_write 50, of the 400 ns the step program's
     # operations take (the loop wrapper left out); the same instruction
     # name in the admit program is another operation
-    assert sp.scope_share({**step, "unscoped": True,
+    assert scope_share({**step, "unscoped": True,
                            "leaf": ["decode.kv_write"]}, t, c) \
         == pytest.approx(100 * 300 / 400)
-    assert sp.scope_share({**step, "leaf": ["decode.attn"]}, t, c) \
+    assert scope_share({**step, "leaf": ["decode.attn"]}, t, c) \
         == pytest.approx(100 * 100 / 400)
     # over the window's busy time, any program: sample is innermost there
-    assert sp.scope_share({"leaf": ["decode.sample"]}, t, c) \
+    assert scope_share({"leaf": ["decode.sample"]}, t, c) \
         == pytest.approx(100 * 100 / 700)
-    assert sp.scope_share({"holds": ["decode.mlp"]}, t, c) \
+    assert scope_share({"holds": ["decode.mlp"]}, t, c) \
         == pytest.approx(100 * 100 / 700)
-    assert sp.scope_share({"leaf": ["decode.mlp"]}, t, c) == 0.0
+    assert scope_share({"leaf": ["decode.mlp"]}, t, c) == 0.0
 
 
 def test_scope_share_is_a_union_and_refuses_to_pass_100():
@@ -88,22 +97,22 @@ def test_scope_share_is_a_union_and_refuses_to_pass_100():
     t["chips"][0]["ops"] += [["fusion.2", 120, 60]]     # overlaps itself
     spec = {"programs": ["^jit__step_all"], "over": "programs",
             "leaf": ["decode.attn"]}
-    assert sp.scope_share(spec, t, {}) == pytest.approx(100 * 100 / 400)
+    assert scope_share(spec, t, {}) == pytest.approx(100 * 100 / 400)
     with pytest.raises(ValueError, match="passes 100%"):
-        sp.scope_share({"leaf": ["decode.attn"]}, t, {"busy_s": 50e-9})
+        scope_share({"leaf": ["decode.attn"]}, t, {"busy_s": 50e-9})
 
 
 def test_readers_that_find_nothing_return_nothing_and_never_raise():
     t = tiny()
     t["chips"][0]["scopes"] = {}            # the parent: a program unscoped
     c = {"busy_s": 700e-9, "log": {}, "cell": types.SimpleNamespace()}
-    assert sp.scope_share({"leaf": ["decode.attn"]}, t, c) is None
-    assert sp.scope_share({"unscoped": True}, t, c) is None
+    assert scope_share({"leaf": ["decode.attn"]}, t, c) is None
+    assert scope_share({"unscoped": True}, t, c) is None
     del t["chips"][0]["scopes"], t["program"]   # a trace of before this PR
-    assert sp.scope_share({"holds": ["rematted_computation"]}, t, c) is None
-    assert sp.span_stat({"spans": ["serving.engine.queue"]}, t, c) is None
-    assert sp.counter_ratio({"counter": "a", "over": "b"}, t, c) is None
-    bd = sp.breakdown(t)                    # and the old naming stays
+    assert scope_share({"holds": ["rematted_computation"]}, t, c) is None
+    assert span_stat({"spans": ["serving.engine.queue"]}, t, c) is None
+    assert counter_ratio({"counter": "a", "over": "b"}, t, c) is None
+    bd = breakdown(t)                    # and the old naming stays
     assert dict(bd["device_ops"]) == pytest.approx(
         {"copy": 250e-9, "fusion": 250e-9})
     assert bd["idle_gaps"] == [["round", 300e-9]]
@@ -119,9 +128,9 @@ def test_span_stat_groups_by_request_and_by_round():
     t = {**tiny(), "program": rows}
     spec = {"spans": ["serving.http.in", "serving.http.out"],
             "group_by": "trace_id", "stat": "p95"}
-    assert sp.grouped_ms(spec, t) == {"a": 3.0, "b": 9.0}
-    assert sp.span_stat(spec, t, {}) == 9.0     # nearest rank above, of two
-    assert sp.span_stat({"spans": ["serving.engine.queue"]}, t, {}) == 7.0
+    assert grouped_ms(spec, t) == {"a": 3.0, "b": 9.0}
+    assert span_stat(spec, t, {}) == 9.0     # nearest rank above, of two
+    assert span_stat({"spans": ["serving.engine.queue"]}, t, {}) == 7.0
     rounds = [[n, 10 * r, d, f"t{r}", {"round": r}]
               for r, ds in enumerate(((1, 2, 3), (2, 2, 2), (10, 1, 1)))
               for n, d in zip(("fed.round.sample", "fed.round.dispatch",
@@ -131,10 +140,10 @@ def test_span_stat_groups_by_request_and_by_round():
     spec = {"spans": ["fed.round.sample", "fed.round.dispatch",
                       "fed.round.observe"], "group_by": "round",
             "stat": "median"}
-    assert sp.span_stat(spec, {**tiny(), "program": rounds}, {}) == 6.0
+    assert span_stat(spec, {**tiny(), "program": rounds}, {}) == 6.0
     inside = {**spec, "within": "window"}       # rows past the window's end
     late = [[r[0], r[1] + 2000, *r[2:]] for r in rounds]
-    assert sp.span_stat(inside, {**tiny(), "program": late}, {}) is None
+    assert span_stat(inside, {**tiny(), "program": late}, {}) is None
 
 
 def test_counter_ratio_takes_its_constant_from_the_traffic_file():
@@ -143,10 +152,10 @@ def test_counter_ratio_takes_its_constant_from_the_traffic_file():
     log = {"counters": {"serving.engine.slot_steps": 600,
                         "serving.engine.steps": 75}}
     slots = cell.traffic["serve"]["decode_slots"]
-    assert sp.counter_ratio(spec, {}, {"cell": cell, "log": log}) \
+    assert counter_ratio(spec, {}, {"cell": cell, "log": log}) \
         == pytest.approx(100 * 600 / (75 * slots))
     log["counters"]["serving.engine.steps"] = 0
-    assert sp.counter_ratio(spec, {}, {"cell": cell, "log": log}) is None
+    assert counter_ratio(spec, {}, {"cell": cell, "log": log}) is None
 
 
 def test_breakdown_names_operations_by_scope_and_gaps_by_program_span():
@@ -155,7 +164,7 @@ def test_breakdown_names_operations_by_scope_and_gaps_by_program_span():
     t["program"] = [["serving.engine.fetch", 690, 70, "x", {"kind": "step"}],
                     ["serving.engine.first_fetch", 0, 1000, "r", {}],
                     ["serving.request", 0, 1000, "r", {}]]
-    bd = sp.breakdown(t)
+    bd = breakdown(t)
     assert dict(bd["device_ops"]) == pytest.approx({
         "copy": 250e-9, "decode.attn:fusion": 100e-9,
         "decode.sample:fusion": 100e-9, "decode.kv_write:fusion": 50e-9})
@@ -168,6 +177,43 @@ def test_breakdown_names_operations_by_scope_and_gaps_by_program_span():
     assert all(len(v) <= 10 for v in bd.values())
 
 
+def test_coverage_reads_only_the_programs_whose_text_was_read():
+    t = tiny()
+    del t["chips"][0]["scopes"]["jit__admit"]       # as on the chip: the step
+    t["chips"][0]["ops"] += [["fusion.9", 560, 30]]     # not in the text
+    got = reduce.coverage(t)
+    assert got["by_scope_s"] == pytest.approx({
+        "(none)": 280e-9, "decode.attn": 100e-9, "decode.kv_write": 50e-9})
+    assert got["unscoped_kinds_s"] == pytest.approx(
+        {"copy": 250e-9, "fusion": 30e-9})
+    assert got["unmapped_s"] == pytest.approx(30e-9)
+
+
+def test_trim_keeps_each_scope_and_kind_with_its_path_and_the_programs_rows():
+    t = tiny()
+    t["program"] = [["serving.engine.fetch", 690, 70, "x", {}],
+                    ["serving.request", -5000, 100, "r", {}]]  # before it
+    t["chips"][0]["ops"] += [["fusion.2", 50_000, 100]]     # past the window
+    # three more steps of the same five operations, 10 us apart
+    for k in range(1, 4):
+        t["chips"][0]["ops"] += [[n, s + 10_000 * k, d] for n, s, d in
+                                 tiny()["chips"][0]["ops"][:5]]
+        t["chips"][0]["programs"] += [[STEP, 100 + 10_000 * k, 600]]
+    t["host"][0][2] = 40_000
+    small = reduce.trim(t, keep_ops=5, per_kind=1)
+    chip = small["chips"][0]
+    names = [o[0] for o in chip["ops"]]
+    # the first five (no wrapper among them: the step's four and the
+    # admit's one), then one more of each (scope, kind, program), copy.5
+    # being of copy.4's, and one loop wrapper
+    assert names.count("fusion.2") == 3 and names.count("copy.5") == 1
+    assert names.count("while.1") == 1 and len(names) == 9
+    assert chip["ops"] == sorted(chip["ops"], key=lambda o: o[1])
+    assert chip["scopes"]["jit__step_all"]["fusion.3"].endswith("scatter")
+    assert len(chip["programs"]) == 5 and small["program"] == t["program"]
+    assert reduce.window_of(small) == reduce.window_of(t)
+
+
 def test_anchor_maps_a_perf_counter_span_onto_the_window():
     """The window annotation began at 5,000,000 ns of the device's
     timebase when perf_counter read 100.0 s; a span timed 100.25..100.75 s
@@ -178,10 +224,10 @@ def test_anchor_maps_a_perf_counter_span_onto_the_window():
                                    meta={"round": 4, "ids": [1, 2]}),
              types.SimpleNamespace(name="early", start=90.0, end=99.0,
                                    trace_id="u", meta={})]
-    rows = sp.program_rows(spans, 100.0, 5_000_000, since=99.5)
+    rows = tr.program_rows(spans, 100.0, 5_000_000, since=99.5)
     assert rows == [["fed.round.fetch", 255_000_000, 500_000_000, "t",
                      {"round": 4}]]
-    errs = sp.anchor_error_us(
+    errs = tr.anchor_error_us(
         rows, [["fed.round.fetch", 255_040_000, 499_000_000],
                ["fed.round.fetch", 1, 5], ["other", 255_000_000, 1]],
         5_000_000, 905_000_000)
@@ -189,53 +235,48 @@ def test_anchor_maps_a_perf_counter_span_onto_the_window():
 
 
 def test_plane_tracer_off_is_a_no_op():
-    tr = sp.PlaneTracer("", 0.0, on=False)
-    tr.start(), tr.open(), tr.stop()
-    assert not tr.active and tr.counters == {} and not tr.done
+    t = tr.Tracer("", 0.0, on=False)
+    t.start(), t.open(), t.stop()
+    assert not t.active and t.counters == {} and not t.done
 
 
-def test_requests_adds_up_the_five_and_finds_the_slowest():
+def test_the_replica_s_first_token_adds_up_the_five_of_a_whole_request():
     def req(tid, t0, parts):
         rows, at = [], t0
-        for name, ms in zip(sp.FIVE, parts):
+        for name, ms in zip(serve.FIVE, parts):
             rows.append([name, at, ms * 1_000_000, tid, {}])
             at += ms * 1_000_000
         return rows
     rows = req("a", 0, (1, 10, 100, 50, 2)) + req("b", 7, (1, 200, 300, 60, 3))
     rows += [["serving.engine.queue", 3, 5, "c", {}]]      # not whole
-    out = sp.requests({"program": rows}, slowest=1)
-    assert (out["requests"], out["of"]) == (2, 3)
-    assert out["engine_spans_worst_gap_ms"] == 0.0
-    assert out["slowest_1_mean_ms"]["engine.prefill"] == 300.0
-    assert out["mean_ms"]["engine.queue"] == 105.0
-    assert sp.requests({"program": []}) == {}
+    spec = manifest.Cell(MF, CELLS["serve"]).metric_file("ttft_p95_ms.serve")
+    assert set(spec["spans"]) == set(serve.FIVE)
+    assert grouped_ms(spec, {"program": rows}) == {"a": 163.0, "b": 564.0}
+    assert span_stat(spec, {"program": rows}, {}) == 564.0
+    assert span_stat(spec, {"program": []}, {}) is None
 
 
-# --------------------------------------------------- the pending entries
-def test_pending_entries_keep_to_the_manifests_contract():
-    from test_chipbench_manifest import NAME, SOURCES, UNIT
+# ------------------------------------------ the nine entries, in the manifest
+NINE = ["agg_share.fedavg", "round_host_ms.fedavg", "recompute_share.fedlora",
+        "pool_copy_share.serve", "slot_occupancy.serve",
+        "queue_wait_p95_ms.serve", "prefill_p95_ms.serve",
+        "first_fetch_p95_ms.serve", "http_first_p95_ms.serve"]
 
-    held = manifest.load_json(sp.PENDING)["per_layer"]
-    names = [m["name"] for m in held]
-    assert len(names) == 9 == len(set(names))
-    assert not set(names) & {m["name"] for m in MF["per_layer"]}
-    e2e = {m["name"]: m for m in MF["end_to_end"]}
-    layers = {m["layer"] for m in MF["per_layer"]}
-    for m in held:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-        assert m["layer"] in layers | {"replica front end"}
-        for cell in m["workloads"]:
-            assert manifest.applies(e2e[m["moves"]], cell)
-        spec = manifest.load_json(
-            manifest.HERE / "metrics" / f"{m['name']}.json")
-        assert spec["reducer"] in sp.REDUCERS and spec["reads"]
-    for cell in CELLS.values():
-        assert sp.pending_for(MF, cell)
-    assert [m["name"] for m in sp.pending_for(MF, CELLS["fedlora"])] \
-        == ["recompute_share.fedlora"]
+
+def test_the_nine_entries_are_in_the_manifest_with_readers_found_by_name():
+    held = {m["name"]: m for m in MF["per_layer"]}
+    assert len(MF["per_layer"]) == 22 and set(NINE) <= set(held)
+    assert not (manifest.HERE / "spanplane.json").exists()
+    for name in NINE:
+        spec = manifest.load_json(manifest.HERE / "metrics" / f"{name}.json")
+        assert spec["reducer"] in ("scope_share", "span_stat",
+                                   "counter_ratio") and spec["reads"]
+        assert callable(manifest.find("reducers", spec["reducer"]))
+    by_cell = {c: [m["name"] for m in manifest.metrics_for(MF, c, True)
+                   if m["name"] in NINE] for c in CELLS.values()}
+    assert [len(by_cell[CELLS[k]]) for k in ("fedavg", "fedlora", "serve")] \
+        == [2, 1, 6]
+    assert by_cell[CELLS["fedlora"]] == ["recompute_share.fedlora"]
 
 
 # ------------------------------------ fixtures: trimmed real v5e traces, PR 26
@@ -244,10 +285,10 @@ def by_hand(trace, programs, pick):
     programs, and of all their operations (a TPU core runs them one after
     another, so a sum is the union)."""
     num = den = 0
-    for (_n, _s, dur), prog, path in sp.scoped_ops(trace):
+    for (_n, _s, dur), prog, path in reduce.scoped_ops(trace):
         if prog.startswith(programs):
             den += dur
-            num += dur * bool(pick(sp.leaf(path), path))
+            num += dur * bool(pick(reduce.leaf(path), path))
     return num, den
 
 
@@ -262,14 +303,15 @@ def test_plane_fixture_keeps_the_old_keys_and_adds_the_new(kind):
     held = {n for v in chip["scopes"].values() for n in v}
     # (the serving cell maps its step program only, not the admit buckets)
     assert held <= ops and len(held) > 0.5 * len(ops)
-    # every metric of the twelve still reads something off it
+    # every metric of the cell reads something off it
     cell = manifest.Cell(MF, CELLS[kind])
     for m in manifest.metrics_for(MF, CELLS[kind], traced=True):
         spec = cell.metric_file(m["name"])
         ctx = {"cell": cell, "log": t["log"], "peaks": PEAKS,
                "window_s": reduce.window_seconds(t),
                "busy_s": reduce.busy_seconds(t)}
-        assert reduce.REDUCERS[spec["reducer"]](spec, t, ctx) is not None
+        assert manifest.find("reducers", spec["reducer"])(
+            spec, t, ctx) is not None
     assert t["anchor"]["bracket_us"] < 50
     if t["anchor"]["matched"]:
         # the anchor's measured error, stated in PERF.md as under 1 ms
@@ -283,7 +325,7 @@ def test_agg_share_on_the_fedavg_fixture():
     num, den = by_hand(t, "jit_round_body", lambda lf, _p: lf in agg)
     got = read("fedavg", "agg_share.fedavg", t)
     assert got == pytest.approx(100 * num / den, rel=1e-3) and 0 < got < 100
-    leaves = {sp.leaf(p) for v in t["chips"][0]["scopes"].values()
+    leaves = {reduce.leaf(p) for v in t["chips"][0]["scopes"].values()
               for p in v.values()}
     assert agg | {"fed.local_sgd"} <= leaves
 
@@ -307,7 +349,7 @@ def test_round_host_ms_on_the_fedavg_fixture():
 
 
 def test_breakdown_on_the_fedavg_fixture_names_layers_and_program_spans():
-    bd = sp.breakdown(plane("fedavg"))
+    bd = breakdown(plane("fedavg"))
     names = [n for n, _ in bd["device_ops"]]
     assert names[0] != "fusion" and any(n.startswith("fed.local_sgd:")
                                         for n in names)
@@ -324,12 +366,12 @@ def test_recompute_share_on_the_fedlora_fixture():
     assert got == pytest.approx(100 * num / den, rel=1e-3) and 0 < got < 100
     paths = [p for v in t["chips"][0]["scopes"].values() for p in v.values()]
     # the recompute, the backward proper and the forward of one layer part
-    mlp = [p for p in paths if sp.leaf(p) == "lm.mlp"]
+    mlp = [p for p in paths if reduce.leaf(p) == "lm.mlp"]
     assert any("rematted_computation" in p for p in mlp)
     assert any("transpose(jvp" in p and "rematted_computation" not in p
                for p in mlp)
     assert any("transpose" not in p for p in mlp)
-    assert [n for n, _ in sp.breakdown(t)["device_ops"]][0].startswith("lm.")
+    assert [n for n, _ in breakdown(t)["device_ops"]][0].startswith("lm.")
 
 
 def test_serving_metrics_on_the_serve_fixture():
@@ -344,9 +386,9 @@ def test_serving_metrics_on_the_serve_fixture():
                                 / (c["serving.engine.steps"] * 16))
     assert 0 < occ <= 100
     # every request of the run kept its five spans under one trace id
-    out = sp.requests(t)
-    assert out["requests"] == out["of"] == 100
-    assert out["engine_spans_worst_gap_ms"] < 1.0
+    whole = read("serve", "ttft_p95_ms.serve", t)
+    five = manifest.Cell(MF, CELLS["serve"]).metric_file("ttft_p95_ms.serve")
+    assert len(grouped_ms(five, t)) == 100
     parts = {n: read("serve", f"{n}.serve", t) for n in (
         "queue_wait_p95_ms", "prefill_p95_ms", "first_fetch_p95_ms",
         "http_first_p95_ms")}
@@ -356,10 +398,10 @@ def test_serving_metrics_on_the_serve_fixture():
         "first_fetch_p95_ms": 137.599359, "http_first_p95_ms": 1.853629})
     assert occ == pytest.approx(62.30769230769231)
     # a tail is no sum of tails, but the parts bound the whole
-    assert out["replica_ttft_p95_ms"] <= sum(parts.values())
+    assert max(parts.values()) < whole <= sum(parts.values())
     assert parts["prefill_p95_ms"] > parts["queue_wait_p95_ms"] \
         > parts["http_first_p95_ms"]
-    bd = sp.breakdown(t)
+    bd = breakdown(t)
     assert any(n == "decode.attn:paged_attention" for n, _ in bd["device_ops"])
     assert all(not n.startswith("serving.request") for n, _ in bd["idle_gaps"])
 
@@ -367,13 +409,13 @@ def test_serving_metrics_on_the_serve_fixture():
 # ------------------------------------------- the whole flow, in the sandbox
 @pytest.mark.parametrize("kind", sorted(CELLS))
 def test_rehearsal_prints_every_metric_old_and_new(kind, capsys):
-    rc = sp.main(["--workload", CELLS[kind], "--seed", "3", "--seconds",
-                  "0.5", "--rehearse-cpu"])
+    rc = run.main(["--workload", CELLS[kind], "--seed", "3", "--seconds",
+                   "0.5", "--trace", "1", "--rehearse-cpu"])
     lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
     obj = json.loads(lines[-1])
-    want = [m["name"] for m in manifest.metrics_for(MF, CELLS[kind], True)] \
-        + [m["name"] for m in sp.pending_for(MF, CELLS[kind])]
+    want = [m["name"] for m in manifest.metrics_for(MF, CELLS[kind], True)]
     assert rc == 0 and sorted(obj["metrics"]) == sorted(want)
+    assert any(l.startswith("[chipbench] plane ") for l in lines)
     assert obj["device"]["platform"] == "cpu"       # stamped: no result
     assert all(len(obj["breakdown"][k]) <= 10 for k in obj["breakdown"])
 
@@ -381,20 +423,18 @@ def test_rehearsal_prints_every_metric_old_and_new(kind, capsys):
 def test_compiled_scopes_of_the_round_program_and_the_step_program():
     """Where the scope paths come from on the chip: the compiled text of
     the programs a driver drove (here at rehearsal sizes, on the CPU)."""
-    from chipbench import drivers
-
     for cell, module, some in (
             ("resnet18gn_fedavg_c100", "jit_round_body",
              {"fed.local_sgd", "fed.accumulate", "fed.finalize"}),
             ("olmo1b_decode_chat", "jit__step_all",
              {"decode.attn", "decode.mlp", "decode.head"})):
         c = manifest.Cell(MF, cell)
-        driver = drivers.load(c.driver)(c, 3, True)
+        driver = manifest.find("drivers", c.driver)(c, 3, True)
         driver.setup()
         try:
-            held = sp.compiled_scopes(driver)
+            held = tr.compiled_scopes(driver.programs())
         finally:
             driver.free()
         assert module in held
-        assert some <= {sp.leaf(p) for p in held[module].values()}
+        assert some <= {reduce.leaf(p) for p in held[module].values()}
         assert "" in held[module].values()      # compiler-made: no path

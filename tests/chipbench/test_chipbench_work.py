@@ -62,7 +62,7 @@ def test_flash_and_paged_by_hand():
     assert got["flops"] == 100 * 2 * 2 * 8 * 2
 
 
-@pytest.mark.parametrize("name", sorted(work.WORK))
+@pytest.mark.parametrize("name", manifest.names("work"))
 def test_every_work_function_returns_flops_and_bytes(name):
     tr = {"seq_len": 4, "lora_rank": 2, "lora_targets": ["wq"],
           "batch_size": 1}
@@ -70,5 +70,5 @@ def test_every_work_function_returns_flops_and_bytes(name):
          "stage_sizes": [1], "num_classes": 2}
     log = {"samples": 1, "tokens": 4, "context_token_sum": 1,
            "processed_tokens": 1}
-    got = work.WORK[name](cell(m, tr), log)
+    got = manifest.find("work", name)(cell(m, tr), log)
     assert set(got) == {"flops", "bytes"} and got["flops"] > 0
