@@ -51,10 +51,14 @@ class ServerState:
 
 @struct.dataclass
 class ClientMetrics:
-    """Linear-aggregable training metrics (sums, not means)."""
+    """Linear-aggregable training metrics (sums, not means). `extra`: what
+    the model counted of itself on the way ({name: number}, summed over the
+    local steps and then over the clients like the rest), None for a model
+    that counts nothing."""
     loss_sum: jax.Array
     correct: jax.Array
     count: jax.Array
+    extra: Any = None
 
 
 def masked_softmax_ce(logits: jax.Array, y: jax.Array, mask: jax.Array):
@@ -255,27 +259,31 @@ def local_sgd(
     obj = objective or masked_softmax_ce
 
     def loss_fn(p, batch):
-        logits = apply_fn({"params": p}, batch["x"])
-        return obj(logits, batch["y"], batch["mask"])
+        # an apply fn may return (logits, {name: number}): what the model
+        # counted of itself in this call (llm.federated_lora)
+        out = apply_fn({"params": p}, batch["x"])
+        logits, extra = out if isinstance(out, tuple) else (out, None)
+        loss, correct, cnt = obj(logits, batch["y"], batch["mask"])
+        return loss, (correct, cnt, extra)
 
     def step(carry, batch):
         p, s = carry
-        (loss, (correct, cnt)), grads = jax.value_and_grad(
-            lambda pp, b: (lambda l, c, n: (l, (c, n)))(*loss_fn(pp, b))
-        , has_aux=True)(p, batch)
+        (loss, (correct, cnt, extra)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(p, batch)
         if grad_correction is not None:
             grads = grad_correction(grads, p)
         updates, s = opt.update(grads, s, p)
         p = optax.apply_updates(p, updates)
         nonempty = (cnt > 0).astype(jnp.float32)
-        return (p, s), (loss * cnt, correct, cnt, nonempty)
+        return (p, s), (loss * cnt, correct, cnt, nonempty, extra)
 
-    (params, opt_state), (losses, corrects, counts, steps) = jax.lax.scan(
+    (params, opt_state), (losses, corrects, counts, steps, extras) = jax.lax.scan(
         lambda carry, idx: step(
             carry, {k: v[idx] for k, v in shard.items()}),
         (params, opt_state), batch_idx,
     )
-    metrics = ClientMetrics(losses.sum(), corrects.sum(), counts.sum())
+    metrics = ClientMetrics(losses.sum(), corrects.sum(), counts.sum(),
+                            jax.tree.map(lambda a: a.sum(0), extras))
     if return_opt_state:
         return params, metrics, steps.sum(), opt_state
     return params, metrics, steps.sum()

@@ -42,6 +42,7 @@ from ..ops import tree as tu
 from ..parallel.round import _localize
 from ..parallel.seq import ring_attention, ulysses_attention
 from .lora import count_params, lora_apply_fn, lora_init, lora_merge
+from .moe import COUNTERS, fold_counters
 from .transformer import TransformerLM
 
 Pytree = Any
@@ -76,10 +77,18 @@ def federated_lora(model: TransformerLM, base_params: Pytree, t: TrainArgs,
     from ..models.hub import mixed_precision_apply
 
     adapters = lora_init(rng, base_params, rank=rank, targets=targets)
+    apply = model.apply
+    if getattr(model, "has_counters", False):
+        # what the model sows of itself (the expert layers' pairs and
+        # fullest expert) rides the round's metrics beside the loss
+        def apply(variables, x, *args, **kwargs):
+            logits, sown = model.apply(variables, x, *args,
+                                       mutable=[COUNTERS], **kwargs)
+            return logits, fold_counters(sown[COUNTERS])
     # honor TrainArgs.compute_dtype like the Simulator path does
     # (simulator.py): bf16 runs the merged matmuls on the MXU while the
     # adapters/optimizer stay f32
-    base_apply = mixed_precision_apply(model.apply, t.compute_dtype)
+    base_apply = mixed_precision_apply(apply, t.compute_dtype)
 
     def fedavg_over(base):
         return make_fedavg(lora_apply_fn(base_apply, base, alpha), t)
@@ -129,6 +138,15 @@ def make_fedllm_seq_round(
     module-level path cannot express (flax nn.scan rejects a collective
     inside the scanned block); the hand-written scan here can.
     """
+    from .decode import unserved
+
+    lacking = unserved(model)
+    if lacking:
+        # ring and ulysses attention are full-causal over as many KV heads
+        # as heads, and the model is rebuilt below from the dense fields
+        raise NotImplementedError(
+            "the sequence-parallel round trains the dense block only; this "
+            "model has: " + "; ".join(s.split(":")[0] for s in lacking))
     n_seq = mesh.shape[seq_axis]
     if attn == "ring":
         attn_fn = functools.partial(ring_attention, axis_name=seq_axis)

@@ -54,6 +54,51 @@ def layer_scope(part: str):
     return jax.named_scope(f"decode.{part}")
 
 
+def unserved(model) -> list:
+    """What a TransformerLM has that no decode program here can run, one
+    sentence a mechanism. The decode bodies below are the DENSE block's
+    (as many KV heads as heads, every layer full attention with rotary
+    positions, a SwiGLU); llm/transformer.py's block trains more than they
+    serve."""
+    out = []
+    if not hasattr(model, "kinds"):     # no TransformerLM: nothing to depart in
+        return out
+    kinds = model.kinds
+    if (model.n_kv_heads or model.n_heads) != model.n_heads or (
+            model.head_dim or model.d_model // model.n_heads
+    ) * model.n_heads != model.d_model:
+        out.append(
+            "grouped KV heads: the paged kernel (ops/paged_attention.py) and "
+            "the prefill, step and verify bodies of llm/decode.py split wk "
+            "and wv into as many heads of d_model / n_heads as wq")
+    if any(a == "window" for a, _ in kinds):
+        out.append(
+            "window layers: the KV cache and the page allocator of "
+            "serving/engine.py keep every position of every layer and give "
+            "no page back once it has left a layer's window")
+    if any(f == "moe" for _, f in kinds):
+        out.append(
+            "expert layers: the decode step has no router and no grouped "
+            "product over held experts (llm/moe.py runs in training only)")
+    if model.qk_norm or not model.rope_full or model.norm_eps != 1e-6 \
+            or model.rope_base != 10000.0:
+        out.append(
+            "per-head q/k norms, layers without rotary positions, another "
+            "norm eps or rope base: the decode bodies fix the dense block's")
+    return out
+
+
+def require_servable(model) -> None:
+    """Refuse, by the mechanism lacking, a model the decode programs
+    cannot run (a drafting head for self-speculation is none of theirs
+    either: `spec_decode` drafts from the request's own history)."""
+    lacking = unserved(model)
+    if lacking:
+        raise NotImplementedError(
+            "this TransformerLM cannot be served yet; the decode path "
+            "lacks: " + "; ".join(lacking))
+
+
 def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     """Convert an UNROLLED TransformerLM param tree (block_0..block_{L-1})
     to the stacked scan-layers layout ({"blocks": [L, ...]}) the decode
@@ -62,7 +107,13 @@ def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
         return params
     from ..ops.tree import tree_stack
 
-    stacked = tree_stack([params[f"block_{i}"] for i in range(n_layers)])
+    blocks = [params[f"block_{i}"] for i in range(n_layers)]
+    if len({jax.tree.structure(b) for b in blocks}) > 1:
+        raise NotImplementedError(
+            "the layers' parameters differ in kind (a dense and an expert "
+            "feed-forward): the decode path scans ONE block over a stacked "
+            "[L, ...] tree and has no expert layer")
+    stacked = tree_stack(blocks)
     out = {k: v for k, v in params.items() if not k.startswith("block_")}
     out["blocks"] = stacked
     return out

@@ -1,0 +1,203 @@
+"""Sparse expert feed-forward layer, held a share at a time.
+
+The layer a mixture-of-experts decoder has in the dense feed-forward's
+place: a router scores every token against ALL `n_experts` experts (float32,
+sigmoid), the `top_k` largest of score + selection bias are chosen, their
+scores are normalised over the chosen and scaled, and the token's output is
+the weighted sum of the chosen experts' SwiGLUs plus a shared expert every
+token passes through.
+
+Expert parallelism divides the experts over chips. This module is told which
+experts it HOLDS (`MoE.held = (first, count)`): it routes over all of them,
+normalises over all the chosen whether held here or not, and computes the
+part of the sum its own experts give. On one chip that partial sum (plus the
+shared expert) is the layer's output; across chips an exchange would bring
+the tokens in and the parts back, and nothing here stands in for it.
+
+No token is dropped, whatever the imbalance: the (token, expert) pairs routed
+here are sorted by expert into one row buffer of the worst-case length
+(every token choosing `top_k` held experts), and a grouped matrix product
+(Pallas `megablox.gmm`, whose grid is sized by the group sizes at run time)
+multiplies each expert's rows by its weights, so the matmul's cost follows
+the pairs routed here and not the buffer. Moving rows in and out is written
+as gathers in both directions (`_take_rows`): the transpose of a gather is a
+scatter-add, which the TPU serialises.
+
+Scopes (PERF.md section 3): `moe.route`, `moe.dispatch`, `moe.experts`,
+`moe.combine`, `moe.shared`. Counters, sown into the `counters` collection
+(`llm.federated_lora` reads them into the round's metrics): `moe_pairs`, the
+pairs computed here in this call, and `moe_max_rows`, the rows of the fullest
+held expert.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+from ..ops.flash_attention import _auto_block, _auto_interpret
+
+COUNTERS = "counters"
+# (rows, contraction, columns) tile caps of the grouped product; each is
+# halved until it divides (rows) or capped at the dimension
+GMM_TILES = (512, 1024, 1024)
+
+
+@dataclasses.dataclass(frozen=True)
+class MoE:
+    """One expert layer's shape. `n_experts` is the router's width (every
+    expert of the layer, wherever it lives); `held` = (first, count) names
+    the experts this module holds, None for all of them."""
+    n_experts: int
+    top_k: int
+    d_expert: int
+    held: Optional[tuple] = None
+    n_shared: int = 1
+    scale: float = 1.0           # routed_scaling_factor
+    norm_topk: bool = True
+
+    @property
+    def first(self) -> int:
+        return self.held[0] if self.held else 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held else self.n_experts
+
+
+def fold_counters(sown) -> dict:
+    """{name: one number} of a `counters` collection: a name holding `max`
+    folds by maximum over the layers that sowed it, every other by sum."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(sown)[0]:
+        name = next(str(p.key) for p in reversed(path) if hasattr(p, "key"))
+        leaf = jnp.asarray(leaf, jnp.float32)
+        if name not in out:
+            out[name] = leaf
+        elif "max" in name:
+            out[name] = jnp.maximum(out[name], leaf)
+        else:
+            out[name] = out[name] + leaf
+    return out
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, back_idx, back_ok):
+    """x[idx], rows moved by a (partial) permutation. Its transpose is
+    written as a gather too: row r of x gets the sum of the cotangent rows
+    `back_idx[r]` where `back_ok[r]` (the rows it was copied to)."""
+    return x[idx]
+
+
+def _take_rows_fwd(x, idx, back_idx, back_ok):
+    return x[idx], (back_idx, back_ok)
+
+
+def _take_rows_bwd(res, g):
+    back_idx, back_ok = res
+    rows = jnp.where(back_ok[..., None], g[back_idx], 0)
+    return (rows.sum(-2, dtype=jnp.float32).astype(g.dtype), None, None, None)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def grouped_matmul(rows, weights, sizes):
+    """rows [P, K] sorted by group, weights [G, K, N], sizes [G] (their
+    sum at most P): rows of group g times weights[g]. Rows past the sum
+    are not computed and hold whatever the buffer held."""
+    p, k = rows.shape
+    tiles = (_auto_block(p, GMM_TILES[0]), min(k, GMM_TILES[1]),
+             min(weights.shape[-1], GMM_TILES[2]))
+    return megablox.gmm(rows, weights, sizes, rows.dtype, tiles,
+                        interpret=_auto_interpret())
+
+
+def route(h, kernel, bias, spec: MoE):
+    """(chosen experts [N, k] int32, their weights [N, k] float32): scores
+    sigmoid(h Wr) in float32 over all experts, the k largest of score +
+    bias chosen (the bias chooses only), weights the chosen scores
+    normalised over all k and scaled."""
+    hi = jax.lax.Precision.HIGHEST
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                               kernel.astype(jnp.float32), precision=hi))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if spec.norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, spec.scale * w
+
+
+class Kernel(nn.Module):
+    """One weight under the leaf name `kernel` (the role LoRA, the
+    quantiser and the benchmark's initialiser read off a path): [din, dout],
+    or [experts, din, dout] for the held experts' stack."""
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=tuple(range(len(self.shape) - 2)))
+        return self.param("kernel", init, self.shape)
+
+
+class ExpertLayer(nn.Module):
+    """[B, T, d] -> [B, T, d]: the held experts' part of the routed sum
+    plus the shared expert."""
+    spec: MoE
+
+    @nn.compact
+    def __call__(self, h):
+        sp = self.spec
+        d = h.shape[-1]
+        rows = h.reshape(-1, d)
+        n, k, f = rows.shape[0], sp.top_k, sp.d_expert
+
+        def expert_kernel(name, din, dout):
+            return Kernel((sp.n_held, din, dout), name=f"experts_{name}")()
+
+        with jax.named_scope("moe.route"):
+            idx, w = route(
+                rows, Kernel((d, sp.n_experts), name="router")(),
+                self.param("e_score_correction_bias", nn.initializers.zeros,
+                           (sp.n_experts,)), sp)
+
+        with jax.named_scope("moe.dispatch"):
+            # pairs (token, slot) routed to a held expert, sorted by expert;
+            # the others sort to the end and are never multiplied
+            local = idx - sp.first
+            here = (local >= 0) & (local < sp.n_held)              # [N, k]
+            key = jnp.where(here, local, sp.n_held).reshape(-1)    # [P]
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inv = jnp.argsort(order).astype(jnp.int32)     # pair -> its row
+            sizes = jnp.sum(key[:, None] == jnp.arange(sp.n_held)[None, :],
+                            axis=0, dtype=jnp.int32)
+            n_here = jnp.sum(sizes)
+            xs = _take_rows(rows, order // k, inv.reshape(n, k), here)
+        self.sow(COUNTERS, "moe_pairs", n_here)
+        self.sow(COUNTERS, "moe_max_rows", jnp.max(sizes))
+
+        with jax.named_scope("moe.experts"):
+            gate = grouped_matmul(xs, expert_kernel("w_gate", d, f), sizes)
+            up = grouped_matmul(xs, expert_kernel("w_up", d, f), sizes)
+            ys = grouped_matmul(nn.silu(gate) * up,
+                                expert_kernel("w_down", f, d), sizes)
+
+        with jax.named_scope("moe.combine"):
+            filled = (jnp.arange(n * k) < n_here)[:, None]
+            z = _take_rows(ys, inv, order[:, None], filled).reshape(n, k, d)
+            z = jnp.where(here[..., None], z, 0).astype(jnp.float32)
+            y = jnp.einsum("nk,nkd->nd", w, z).astype(h.dtype)
+
+        with jax.named_scope("moe.shared"):
+            wide = f * sp.n_shared
+            gate = nn.Dense(wide, use_bias=False, name="shared_w_gate")(rows)
+            up = nn.Dense(wide, use_bias=False, name="shared_w_up")(rows)
+            y = y + nn.Dense(d, use_bias=False, name="shared_w_down")(
+                nn.silu(gate) * up)
+        return y.reshape(h.shape)

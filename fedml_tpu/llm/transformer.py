@@ -19,6 +19,7 @@ TPU-first details:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import flax.linen as nn
@@ -26,6 +27,12 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.seq import dense_causal_attention
+from .moe import ExpertLayer, MoE
+
+# a layer's kind: (attention, feed-forward). "full" attention is causal over
+# the whole sequence, "window" over the last `window` positions; the
+# feed-forward is the "dense" SwiGLU or the "moe" expert layer (llm/moe.py)
+DENSE_LAYER = ("full", "dense")
 
 
 def rope(x: jax.Array, pos: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -54,9 +61,20 @@ class RMSNorm(nn.Module):
 
 
 class Block(nn.Module):
+    """The ONE decoder block. Its defaults are the dense block (as many KV
+    heads as heads, heads of d_model / n_heads, full causal attention with
+    rotary positions, SwiGLU); the further fields say where a layer departs
+    from it, and a layer that departs nowhere has today's parameters."""
     n_heads: int
     d_ff: int
     attn_fn: Optional[Callable] = None
+    n_kv_heads: Optional[int] = None    # fewer than n_heads: grouped heads
+    head_dim: Optional[int] = None      # d_model // n_heads when None
+    norm_eps: float = 1e-6
+    rope_base: Optional[float] = 10000.0    # None: no rotary positions here
+    window: Optional[int] = None        # i sees j only where 0 <= i-j < window
+    qk_norm: bool = False               # RMSNorm over each head of q and k
+    moe: Optional[MoE] = None           # the expert layer in the SwiGLU's place
 
     @nn.compact
     def __call__(self, x, pos):
@@ -65,22 +83,31 @@ class Block(nn.Module):
         # rematted_computation around them, so forward, backward and the
         # recomputed forward of each part can be told apart by name
         d_model = x.shape[-1]
-        dh = d_model // self.n_heads
+        dh = self.head_dim or d_model // self.n_heads
+        n_kv = self.n_kv_heads or self.n_heads
+        norm = functools.partial(RMSNorm, eps=self.norm_eps)
         with jax.named_scope("lm.attn"):
-            h = RMSNorm()(x)
-            q = nn.Dense(d_model, use_bias=False, name="wq")(h)
-            k = nn.Dense(d_model, use_bias=False, name="wk")(h)
-            v = nn.Dense(d_model, use_bias=False, name="wv")(h)
-            split = lambda a: a.reshape(a.shape[:2] + (self.n_heads, dh))
+            h = norm()(x)
+            q = nn.Dense(self.n_heads * dh, use_bias=False, name="wq")(h)
+            k = nn.Dense(n_kv * dh, use_bias=False, name="wk")(h)
+            v = nn.Dense(n_kv * dh, use_bias=False, name="wv")(h)
+            split = lambda a: a.reshape(a.shape[:2] + (-1, dh))
             q, k, v = split(q), split(k), split(v)
-            q, k = rope(q, pos), rope(k, pos)
+            if self.qk_norm:
+                q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+            if self.rope_base is not None:
+                q, k = (rope(q, pos, self.rope_base),
+                        rope(k, pos, self.rope_base))
             attn = self.attn_fn or dense_causal_attention
-            o = attn(q, k, v)
-            o = o.reshape(o.shape[:2] + (d_model,))
+            o = (attn(q, k, v) if self.window is None
+                 else attn(q, k, v, window=self.window))
+            o = o.reshape(o.shape[:2] + (self.n_heads * dh,))
             x = x + nn.Dense(d_model, use_bias=False, name="wo")(o)
 
         with jax.named_scope("lm.mlp"):
-            h = RMSNorm()(x)
+            h = norm()(x)
+            if self.moe is not None:
+                return x + ExpertLayer(self.moe, name="moe")(h)
             gate = nn.Dense(self.d_ff, use_bias=False, name="w_gate")(h)
             up = nn.Dense(self.d_ff, use_bias=False, name="w_up")(h)
             x = x + nn.Dense(d_model, use_bias=False, name="w_down")(
@@ -111,29 +138,80 @@ class TransformerLM(nn.Module):
     # step = the flax remat_scan pattern). llm/lora.py and llm/quant.py
     # both understand the stacked [L, din, dout] kernel layout.
     scan_layers: bool = False
+    # Where the model departs from the dense block (Block's fields; the
+    # defaults ARE the dense block, parameter for parameter). `layer_kinds`
+    # gives each layer's (attention, feed-forward) kind, DENSE_LAYER for
+    # every layer when None: "window" layers see the last `window`
+    # positions, "full" layers the whole causal half, with rotary
+    # positions only if `rope_full`; "moe" layers hold `moe`'s experts.
+    n_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
+    rope_full: bool = True
+    window: Optional[int] = None
+    qk_norm: bool = False
+    moe: Optional[MoE] = None
+    layer_kinds: Optional[tuple] = None
+
+    @property
+    def kinds(self) -> tuple:
+        kinds = self.layer_kinds or (DENSE_LAYER,) * self.n_layers
+        kinds = tuple(tuple(k) for k in kinds)
+        if len(kinds) != self.n_layers or any(
+                a not in ("full", "window") or f not in ("dense", "moe")
+                for a, f in kinds):
+            raise ValueError(
+                f"layer_kinds must give {self.n_layers} (attention, "
+                "feed-forward) pairs of full|window and dense|moe, got "
+                f"{kinds}")
+        return kinds
+
+    @property
+    def has_counters(self) -> bool:
+        """Whether a call sows into the `counters` collection (the expert
+        layers do: llm/moe.py)."""
+        return any(f == "moe" for _, f in self.kinds)
+
+    def block(self, kind, **kw) -> Block:
+        attention, ff = kind
+        if attention == "window" and not self.window:
+            raise ValueError("a window layer needs `window`")
+        if ff == "moe" and self.moe is None:
+            raise ValueError("a moe layer needs `moe`")
+        windowed = attention == "window"
+        cls = Block
+        if self.remat:
+            cls = nn.remat(Block, prevent_cse=not self.scan_layers)
+        return cls(
+            self.n_heads, self.d_ff, self.attn_fn, self.n_kv_heads,
+            self.head_dim, self.norm_eps,
+            self.rope_base if windowed or self.rope_full else None,
+            self.window if windowed else None, self.qk_norm,
+            self.moe if ff == "moe" else None, **kw)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, pos_offset=0):
         pos = pos_offset + jnp.arange(tokens.shape[1])
+        kinds = self.kinds
         with jax.named_scope("lm.embed"):
             x = nn.Embed(self.vocab_size, self.d_model, name="embed")(tokens)
         if self.scan_layers:
-            block = Block
-            if self.remat:
-                block = nn.remat(block, prevent_cse=False)
+            if len(set(kinds)) > 1:
+                raise ValueError(
+                    "scan_layers stacks ONE block's parameters over the "
+                    f"layers; {sorted(set(kinds))} differ in theirs: leave "
+                    "the layers unrolled")
             x, _ = nn.scan(
                 lambda mdl, carry, _xs: (mdl(carry, pos), None),
                 variable_axes={"params": 0},
                 split_rngs={"params": True},
                 length=self.n_layers,
-            )(block(self.n_heads, self.d_ff, self.attn_fn, name="blocks"),
-              x, None)
+            )(self.block(kinds[0], name="blocks"), x, None)
         else:
-            block_cls = nn.remat(Block) if self.remat else Block
-            for i in range(self.n_layers):
-                x = block_cls(self.n_heads, self.d_ff, self.attn_fn,
-                              name=f"block_{i}")(x, pos)
+            for i, kind in enumerate(kinds):
+                x = self.block(kind, name=f"block_{i}")(x, pos)
         with jax.named_scope("lm.head"):
-            x = RMSNorm(name="final_norm")(x)
+            x = RMSNorm(self.norm_eps, name="final_norm")(x)
             return nn.Dense(self.vocab_size, use_bias=False,
                             name="lm_head")(x)

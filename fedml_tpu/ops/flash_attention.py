@@ -55,8 +55,28 @@ def _dot(a, b, contract):
         preferred_element_type=jnp.float32, precision=prec)
 
 
+def _band(qi, step, n_steps, block_q: int, block_k: int, window):
+    """(the K block a grid step holds, whether it can hold a visible key).
+    Full causal: the grid walks every K block and those wholly in the Q
+    block's future are skipped. Windowed (equal blocks): the grid walks
+    only the `n_steps` blocks of the band, which ends on the diagonal;
+    a step before the sequence's start holds nothing."""
+    if window is None:
+        return step, step * block_k < (qi + 1) * block_q
+    kb = qi + step - (n_steps - 1)
+    return kb, kb >= 0
+
+
+def _visible(qpos, kpos, window):
+    """Causal, and in a window layer only the last `window` positions:
+    0 <= qpos - kpos < window."""
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
-                block_q: int, block_k: int, scale: float):
+                block_q: int, block_k: int, scale: float, window=None):
     qi, kj = pl.program_id(1), pl.program_id(2)
     n_kb = pl.num_programs(2)
 
@@ -66,8 +86,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
         m_acc[...] = jnp.full_like(m_acc, _NEG)
         l_acc[...] = jnp.zeros_like(l_acc)
 
+    kb_i, live = _band(qi, kj, n_kb, block_q, block_k, window)
+
     # causal: K blocks entirely in this Q block's future contribute nothing
-    @pl.when(kj * block_k < (qi + 1) * block_q)
+    @pl.when(live)
     def _compute():
         # matmuls run in the INPUT dtype (bf16 training -> full MXU rate)
         # with f32 accumulation; softmax state stays f32. HIGHEST is free
@@ -79,12 +101,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
         s = _dot(q, kb, ((1,), (1,))) * scale                 # [BQ, BK] f32
         qpos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (bq, 1), 0)
-        kpos = kj * block_k + jax.lax.broadcasted_iota(
+        kpos = kb_i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        s = jnp.where(qpos >= kpos, s, _NEG)
+        seen = _visible(qpos, kpos, window)
+        s = jnp.where(seen, s, _NEG)
         m = m_acc[:, :1]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # a row whose window starts after this block sees none of it:
+            # its max is still _NEG and exp(0) would count every key
+            p = jnp.where(seen, p, 0.0)
         corr = jnp.exp(m - m_new)
         l_new = l_acc[:, :1] * corr + p.sum(axis=1, keepdims=True)
         o_acc[...] = o_acc[...] * corr + _dot(
@@ -101,25 +128,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
         lse_ref[0, qi] = m_acc[:, 0] + jnp.log(l[:, 0])
 
 
-def _flash_fwd(q, k, v, block_q: int, block_k: int, interpret: bool):
-    """q/k/v: [BH, T, D] -> (o [BH, T, D], lse [BH, n_qb, block_q] f32).
+def _steps(t: int, block_q: int, block_k: int, window) -> int:
+    """Grid steps along the streamed operand: every block (full causal) or
+    the band's blocks only (windowed: equal blocks, the diagonal block and
+    those the window reaches back into)."""
+    if window is None:
+        return t // block_k
+    if block_q != block_k:
+        raise ValueError("a windowed call takes equal blocks, got "
+                         f"({block_q}, {block_k})")
+    return min(t // block_k, 1 + -(-(window - 1) // block_k))
+
+
+def _flash_fwd(q, k, v, block_q: int, block_k: int, interpret: bool,
+               window=None):
+    """q: [BH, T, D], k/v: [BKV, T, D] with BH a multiple of BKV (query
+    head i reads KV head i // group; the repeat is an index map, never an
+    array) -> (o [BH, T, D], lse [BH, n_qb, block_q] f32).
     The LSE side output is shaped in q-block rows (not [BH, T]) because
     Mosaic requires the last two block dims to be (8,128)-tiled or full;
     its block is the whole per-batch row set (T floats — trivial VMEM),
     revisited across the grid and written one row per q-block."""
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     scale = d ** -0.5
     n_qb = t // block_q
-    grid = (bh, n_qb, t // block_k)
+    n_steps = _steps(t, block_q, block_k, window)
+    grid = (bh, n_qb, n_steps)
     kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k, scale=scale)
+        _fwd_kernel, block_q=block_q, block_k=block_k, scale=scale,
+        **({} if window is None else {"window": window}))
+    kv = (lambda b: b) if group == 1 else (lambda b: b // group)
+    if window is None:
+        kblock = lambda b, i, j: (kv(b), j, 0)
+    else:       # only the band's blocks are ever fetched
+        kblock = lambda b, i, j: (
+            kv(b), jnp.maximum(i + j - (n_steps - 1), 0), 0)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kblock),
+            pl.BlockSpec((1, block_k, d), kblock),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -135,7 +186,7 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, interpret: bool):
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum l
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
     )(q, k, v)
 
 
@@ -203,7 +254,8 @@ def _blocked_bwd(q, k, v, o, do, block_k: int):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
-               dq_acc, *, block_q: int, block_k: int, scale: float):
+               dq_acc, *, block_q: int, block_k: int, scale: float,
+               window=None):
     qi, kj = pl.program_id(1), pl.program_id(2)
     n_kb = pl.num_programs(2)
 
@@ -211,15 +263,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(kj * block_k < (qi + 1) * block_q)
+    kb_i, live = _band(qi, kj, n_kb, block_q, block_k, window)
+
+    @pl.when(live)
     def _compute():
         q, kb, vb, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = _dot(q, kb, ((1,), (1,))) * scale
         bq = q.shape[0]
         qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        kpos = kj * block_k + jax.lax.broadcasted_iota(
+        kpos = kb_i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        p = jnp.where(qpos >= kpos,
+        p = jnp.where(_visible(qpos, kpos, window),
                       jnp.exp(s - lse_ref[0, qi][:, None]), 0.0)
         dp = _dot(do, vb, ((1,), (1,)))
         ds = p * (dp - dlt_ref[0, qi][:, None]) * scale
@@ -232,17 +286,33 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
-                block_q: int, block_k: int, scale: float):
-    kj, qi = pl.program_id(1), pl.program_id(2)
-    n_qb = pl.num_programs(2)
+                block_q: int, block_k: int, scale: float,
+                n_qb=None, q_steps=None, window=None):
+    """One K/V block gathers from every Q block that sees it. The last
+    grid dimension runs over the Q heads that share this KV head, and for
+    each over its Q blocks: all of them (full causal; those before the K
+    block skipped) or the band's `q_steps` (windowed: the diagonal block
+    and those whose window reaches back to this one)."""
+    kj, step = pl.program_id(1), pl.program_id(2)
+    n_steps = pl.num_programs(2)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    if q_steps is None:            # one Q head a KV head, every Q block
+        qi, live = step, (step + 1) * block_q > kj * block_k
+    elif window is None:
+        qi = step % q_steps
+        live = (qi + 1) * block_q > kj * block_k
+    else:
+        qi = kj + step % q_steps
+        live = qi < n_qb
+        qi = jnp.minimum(qi, n_qb - 1)
+
     # causal: Q blocks strictly before this K block see none of it
-    @pl.when((qi + 1) * block_q > kj * block_k)
+    @pl.when(live)
     def _compute():
         q, kb, vb, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = _dot(q, kb, ((1,), (1,))) * scale
@@ -250,57 +320,81 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         kpos = kj * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        p = jnp.where(qpos >= kpos,
+        p = jnp.where(_visible(qpos, kpos, window),
                       jnp.exp(s - lse_ref[0, qi][:, None]), 0.0)
         dv_acc[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
         dp = _dot(do, vb, ((1,), (1,)))
         ds = p * (dp - dlt_ref[0, qi][:, None]) * scale
         dk_acc[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == n_steps - 1)
     def _out():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _pallas_bwd(q, k, v, o, lse_q, do, block_q: int, block_k: int,
-                interpret: bool):
+                interpret: bool, window=None):
     """Pallas dQ + dK/dV. The LSE comes from the forward kernel (free side
     output); delta=rowsum(o·do) is one fused elementwise pass in plain jax.
     Both ride in [BH, n_qb, block_q], loaded whole per batch·head (T floats
     — trivial VMEM) and indexed by the q-block program id: Mosaic requires
     the last two block dims be (8,128)-tiled or full, which rules out
-    (1, 1, block_q) slabs."""
+    (1, 1, block_q) slabs. With fewer KV heads than Q heads (k/v:
+    [BKV, T, D]) dK/dV sum over the group inside the kernel's grid."""
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     scale = d ** -0.5
     delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1)
     n_qb = t // block_q
     dlt_q = delta.reshape(bh, n_qb, block_q)
+    n_steps = _steps(t, block_q, block_k, window)
+    windowed = {} if window is None else {"window": window}
+    suffix = "" if window is None else "_window"
+    kv = (lambda b: b) if group == 1 else (lambda b: b // group)
 
     spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    if window is None:
+        spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0))
+    else:
+        spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (
+            kv(b), jnp.maximum(i + j - (n_steps - 1), 0), 0))
     spec_row_q = pl.BlockSpec((1, n_qb, block_q), lambda b, i, j: (b, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
-                          scale=scale),
-        grid=(bh, t // block_q, t // block_k),
+                          scale=scale, **windowed),
+        grid=(bh, n_qb, n_steps),
         in_specs=[spec_q, spec_k, spec_k, spec_q, spec_row_q, spec_row_q],
         out_specs=spec_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" + suffix,
     )(q, k, v, do, lse_q, dlt_q)
 
-    # dK/dV grid: (bh, k-block, q-block) — q streams, k/v accumulate
+    # dK/dV grid: (bkv, k-block, q-head of the group x q-block) — q streams,
+    # k/v accumulate
     spec_kk = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    spec_qq = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-    spec_row_qq = pl.BlockSpec((1, n_qb, block_q), lambda b, i, j: (b, 0, 0))
+    if group == 1 and window is None:
+        steps, walk = n_qb, {}
+        head = lambda b, j: b
+        qblock = lambda i, j: j
+    else:
+        q_steps = n_qb if window is None else n_steps
+        steps = group * q_steps
+        walk = {"n_qb": n_qb, "q_steps": q_steps}
+        head = lambda b, j: b * group + j // q_steps
+        qblock = ((lambda i, j: j % q_steps) if window is None else
+                  (lambda i, j: jnp.minimum(i + j % q_steps, n_qb - 1)))
+    spec_qq = pl.BlockSpec((1, block_q, d),
+                           lambda b, i, j: (head(b, j), qblock(i, j), 0))
+    spec_row_qq = pl.BlockSpec((1, n_qb, block_q),
+                               lambda b, i, j: (head(b, j), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
-                          scale=scale),
-        grid=(bh, t // block_k, t // block_q),
+                          scale=scale, **walk, **windowed),
+        grid=(k.shape[0], t // block_k, steps),
         in_specs=[spec_qq, spec_kk, spec_kk, spec_qq, spec_row_qq,
                   spec_row_qq],
         out_specs=[spec_kk, spec_kk],
@@ -309,24 +403,25 @@ def _pallas_bwd(q, k, v, o, lse_q, do, block_q: int, block_k: int,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" + suffix,
     )(q, k, v, do, lse_q, dlt_q)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, block_q, block_k, interpret):
-    return _flash_fwd(q, k, v, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, block_q, block_k, interpret, window):
+    return _flash_fwd(q, k, v, block_q, block_k, interpret, window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, block_q, block_k, interpret):
-    o, lse_q = _flash_fwd(q, k, v, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, block_q, block_k, interpret, window):
+    o, lse_q = _flash_fwd(q, k, v, block_q, block_k, interpret, window)
     return o, (q, k, v, o, lse_q)
 
 
-def _flash_vjp_bwd(block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(block_q, block_k, interpret, window, res, do):
     q, k, v, o, lse_q = res
-    return _pallas_bwd(q, k, v, o, lse_q, do, block_q, block_k, interpret)
+    return _pallas_bwd(q, k, v, o, lse_q, do, block_q, block_k, interpret,
+                       window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -347,12 +442,31 @@ def _auto_block(t: int, cap: int) -> int:
 
 def flash_attention(q, k, v, block_q: int | None = None,
                     block_k: int | None = None,
-                    interpret: bool | None = None):
-    """Causal flash attention. q/k/v: [BH, T, D]; T must be divisible by the
-    block sizes (auto-chosen when omitted: large blocks amortize grid/DMA
+                    interpret: bool | None = None,
+                    window: int | None = None):
+    """Causal flash attention. q: [BH, T, D]; k/v: [BKV, T, D], BH a
+    multiple of BKV (grouped heads: query head i reads KV head i // group,
+    heads of one batch row adjacent). T must be divisible by the block
+    sizes (auto-chosen when omitted: large blocks amortize grid/DMA
     overhead — the measured v5e sweep put (512, 1024) 1.8-1.9x ahead of
-    XLA's own fused attention at T=4k-8k, where (128, 128) trailed it)."""
+    XLA's own fused attention at T=4k-8k, where (128, 128) trailed it).
+    `window`: position i sees j only where 0 <= i - j < window; the grid
+    then walks only the blocks of that band (equal blocks, 512 by default:
+    at T=8192, 64 heads over 8, window 128 the v5e took 12.5 ms forward and
+    backward against 14.0 at 128, 13.7 at 256, 17.2 at 1024), under kernel
+    names of their own (`flash_fwd_window`, ...), so a trace tells them
+    from the full calls."""
     t = q.shape[1]
+    if q.shape[0] % k.shape[0] or k.shape != v.shape:
+        raise ValueError(f"{q.shape[0]} query heads cannot share "
+                         f"{k.shape[0]} KV heads")
+    if window is not None:
+        if window >= t:
+            window = None           # the band is the whole causal half
+        else:
+            band = _auto_block(t, max(512, window))
+            block_q = band if block_q is None else block_q
+            block_k = block_q if block_k is None else block_k
     block_q = _auto_block(t, 512) if block_q is None else min(block_q, t)
     block_k = _auto_block(t, 1024) if block_k is None else min(block_k, t)
     if t % block_q or t % block_k:
@@ -361,12 +475,13 @@ def flash_attention(q, k, v, block_q: int | None = None,
             f"({block_q}, {block_k})")
     if interpret is None:
         interpret = _auto_interpret()
-    return _flash(q, k, v, block_q, block_k, bool(interpret))
+    return _flash(q, k, v, block_q, block_k, bool(interpret), window)
 
 
-def flash_attn_fn(q, k, v):
-    """attn_fn adapter for TransformerLM: [B, T, H, D] in/out."""
+def flash_attn_fn(q, k, v, window: int | None = None):
+    """attn_fn adapter for TransformerLM: q [B, T, H, D], k/v [B, T, KV, D]
+    (KV dividing H) in, [B, T, H, D] out."""
     b, t, h, d = q.shape
-    fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
-    o = flash_attention(fold(q), fold(k), fold(v))
+    fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, t, d)
+    o = flash_attention(fold(q), fold(k), fold(v), window=window)
     return jnp.moveaxis(o.reshape(b, h, t, d), 1, 2)

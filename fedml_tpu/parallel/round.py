@@ -524,6 +524,8 @@ def make_round_parts(
             "train_acc": summed.correct / n,
             "n_samples": summed.count,
         }
+        if summed.extra:        # what the model counted of itself
+            metrics.update(summed.extra)
         if health:
             metrics["health"] = health
         if faults:

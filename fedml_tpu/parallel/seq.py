@@ -35,15 +35,26 @@ _NEG = -1e9  # finite "-inf": keeps exp() NaN-free for fully-masked rows
 
 
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                           q_offset=0, k_offset=0) -> jax.Array:
-    """Reference causal attention. q/k/v: [B, T, H, D] -> [B, T, H, D].
-    Offsets give the global position of element 0 (used when chunks of a
-    sharded sequence are compared)."""
+                           q_offset=0, k_offset=0,
+                           window: int | None = None) -> jax.Array:
+    """Reference causal attention. q: [B, T, H, D], k/v: [B, T, KV, D] with
+    KV dividing H (query head i reads KV head i // (H / KV)) ->
+    [B, T, H, D]. Offsets give the global position of element 0 (used when
+    chunks of a sharded sequence are compared). `window`: position i sees
+    j only where 0 <= i - j < window."""
     scale = q.shape[-1] ** -0.5
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     qpos = q_offset + jnp.arange(q.shape[1])
     kpos = k_offset + jnp.arange(k.shape[1])
     mask = qpos[:, None] >= kpos[None, :]
+    if window is not None:
+        mask = mask & (qpos[:, None] - kpos[None, :] < window)
+    if k.shape[2] != q.shape[2]:
+        b, t, h, d = q.shape
+        qg = q.reshape(b, t, k.shape[2], h // k.shape[2], d)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k) * scale
+        p = jax.nn.softmax(jnp.where(mask, s, _NEG), axis=-1)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(q.shape)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)

@@ -492,12 +492,14 @@ class DecodeEngine:
                  admit_batch: int = 1):
         from ..llm.decode import (
             layer_scope, make_kv_decode, make_paged_kv_decode,
-            ngram_propose, stack_adapter_blocks, stack_blocks,
+            ngram_propose, require_servable, stack_adapter_blocks,
+            stack_blocks,
         )
 
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1; got {n_slots}")
         enable_compilation_cache()   # before the first trace
+        require_servable(model)
         self.model = model
         self.max_len = int(max_len)
         self.n_slots = int(n_slots)

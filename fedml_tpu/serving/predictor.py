@@ -255,6 +255,9 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                  spec_decode: str = "off", spec_k: int = 4,
                  kv_quant: str = "off", admit_batch: int = 1,
                  drain_timeout_s: float = 30.0):
+        from ..llm.decode import require_servable
+
+        require_servable(model)
         self.model = model
         self.params = params
         self.detokenize = detokenize

@@ -177,6 +177,15 @@ def start_replica(spec: dict):
         from .predictor import lm_predictor_from_serve_knobs
 
         lm = dict(spec.get("lm", {}))
+        known = {"vocab_size", "d_model", "n_layers", "n_heads", "d_ff",
+                 "scan_layers", "max_len"}
+        if set(lm) - known:
+            # the keys of a block this replica would silently not build
+            raise NotImplementedError(
+                f"start_replica builds the dense block only; the lm recipe "
+                f"also asks for {sorted(set(lm) - known)}: grouped KV heads, "
+                "window layers and expert layers cannot be served yet "
+                "(llm/decode.py `unserved` says which mechanism each lacks)")
         model = TransformerLM(
             vocab_size=int(lm["vocab_size"]),
             d_model=int(lm["d_model"]), n_layers=int(lm["n_layers"]),
