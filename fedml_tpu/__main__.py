@@ -794,6 +794,13 @@ def _top_frame(snap: dict, source: str, prev: dict = None,
                 free = g.get("serving_kv_pages_free", 0)
                 seg += (f"  pages {int(pt - free)}/{int(pt)} "
                         f"({(pt - free) / pt * 100:.0f}%)")
+            # share of the page table the decode steps had to walk: pages
+            # live slots attended over steps x (slots x max_pages)
+            table = (c.get("serving_engine_steps_total", 0)
+                     * g.get("serving_engine_table_pages", 0))
+            if table:
+                walked = c.get("serving_engine_page_steps_total", 0)
+                seg += f"  walk {walked / table * 100:.1f}%"
             hits = int(c.get("serving_prefix_hits_total", 0))
             miss = int(c.get("serving_prefix_misses_total", 0))
             if hits + miss:

@@ -173,7 +173,8 @@ class ServeArgs:
     kv_page_size > 0):
       paged_kernel      — fused Pallas paged-attention decode kernel
                           (ops/paged_attention.py): pages read in place,
-                          no gather copy
+                          no gather copy, and only the pages live slots
+                          hold are walked
       spec_decode       — "ngram" turns on greedy-exact self-drafted
                           speculative decoding ("off" default)
       spec_k            — draft tokens per speculative window (needs

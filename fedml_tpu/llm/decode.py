@@ -443,10 +443,12 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     `kernel=True` swaps step/verify's gather-then-attend for the fused
     Pallas paged-attention kernel (ops/paged_attention.py) that reads
     each slot's pages IN PLACE via the device-side page table — no
-    virtually-contiguous copy, per-token attention HBM traffic goes from
-    O(2·context) to O(context). chunk (prefill) keeps the gather: its
-    cost is amortized over the whole prompt and the kernel is the
-    decode-side hot path. `mesh` (with an `mp` axis) shard_maps the
+    virtually-contiguous copy, and it walks only the pages that hold a
+    position the slot's queries attend (from `pos`, C and `active`: a
+    retired slot costs no page read), where the gather moves every
+    slot's whole table row whatever is live. chunk (prefill) keeps the
+    gather: its cost is amortized over the whole prompt and the kernel is
+    the decode-side hot path. `mesh` (with an `mp` axis) shard_maps the
     kernel over the heads axis — the same layout
     partition.paged_kv_cache_spec pins on the pool, reaching the kernel
     with zero resharding. Token identity vs the gather path is pinned in
@@ -567,22 +569,25 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     if kernel:
         from ..ops.paged_attention import paged_attention
 
-        attn_fused = paged_attention
+        def attn_fused(q, k_pool, v_pool, pages, pos, active, *scales):
+            return paged_attention(q, k_pool, v_pool, pages, pos, *scales,
+                                   active=active)
+
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
 
             # heads are independent in attention, so the mp split of the
             # pool (partition.paged_kv_cache_spec) reaches the kernel
             # as-is: each device runs it over its own heads, the page
-            # table/positions replicated — no resharding, no collective
-            # (the int8 scales split the same heads axis:
+            # table/positions/active mask replicated — no resharding, no
+            # collective (the int8 scales split the same heads axis:
             # partition.paged_kv_scale_spec)
             heads = P(None, None, "mp", None)
-            in_specs = (heads, heads, heads, P(None, None), P(None))
+            in_specs = (heads, heads, heads, P(None, None), P(None), P(None))
             if quant:
                 in_specs += (P(None, "mp"), P(None, "mp"))
             attn_fused = jax.shard_map(
-                paged_attention, mesh=mesh, in_specs=in_specs,
+                attn_fused, mesh=mesh, in_specs=in_specs,
                 out_specs=heads, check_vma=False)
 
     def verify(params, adapters, cache, pages, pos, tokens, active):
@@ -622,10 +627,11 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     # fused path: this layer's pages read in place by the
                     # Pallas kernel, straight out of the carried pool — no
                     # virtually-contiguous copy materializes (int8 pools
-                    # ride in as-is; the kernel dequants each slab in VMEM)
+                    # ride in as-is; the kernel dequants each slab in VMEM).
+                    # `active` lets it skip a retired slot's stale row
                     scales = (pool["ks"], pool["vs"]) if quant else ()
                     o = attn_fused(q, pool["k"], pool["v"], base + pages,
-                                   pos, *scales)
+                                   pos, active, *scales)
                 else:
                     kk, vv = kv_pages(pool, base + pages)
                     scale = q.shape[-1] ** -0.5

@@ -6,15 +6,15 @@ materializes every slot's pages into a virtually-contiguous
 [S, max_pages * page_size, H, Dh] sequence with an XLA gather, then runs
 dense masked attention over it — per decode token that is one full copy
 of each slot's context through HBM before a single FLOP of attention.
-This kernel removes the copy: the device-side page table rides in as a
-SCALAR-PREFETCH operand, the BlockSpec index map reads it to DMA exactly
-one (page_size, H, Dh) K and V slab per grid step straight from the
-pool, and a flash-style online softmax (running max m, running sum l,
-o accumulator in VMEM scratch — the ops/flash_attention.py recurrence)
-folds each page's contribution in as it streams. Per-token attention
-HBM traffic drops from O(context copied + context read) to O(context
-read), and the transient gather buffer disappears from the memory
-high-water mark.
+This kernel removes the copy: the pool stays in HBM (`pl.ANY`), the
+device-side page table rides in as a SCALAR-PREFETCH operand, and the
+kernel itself DMAs the (page_size, H, Dh) K and V slabs the table names
+straight from the pool, a block of pages at a time, while a flash-style
+online softmax (running max m, running sum l, o accumulator — the
+ops/flash_attention.py recurrence) folds each block in as it streams.
+Per-token attention HBM traffic drops from O(context copied + context
+read) to O(context read), and the transient gather buffer disappears from
+the memory high-water mark.
 
 Shape contract (one transformer layer's pages; the decode layer scan
 carries the whole pool flat as `[L * P, page_size, H, Dh]` and calls this
@@ -29,6 +29,7 @@ layer's and the page table picks this layer's slabs out of it in place):
                            entries beyond a slot's reservation are 0,
                            the reserved null/trash page)
     pos    [S] int32       first query position per slot
+    active [S] bool        slots whose rows are wanted (default: all)
     ->     [S, C, H, Dh]
 
 With an int8 pool (`kv_quant: int8`), the per-(page, head) f32 scales
@@ -36,10 +37,10 @@ With an int8 pool (`kv_quant: int8`), the per-(page, head) f32 scales
 [S, H, max_pages] (S * max_pages * H floats — noise next to one slab)
 and ride as two further operands blocked per slot: a (1, H) row of the
 [P, H] array is not a legal TPU block (the last two block dims must be
-(8, 128)-tiled or full), a whole (H, max_pages) slot view is. Each grid
-step picks its page's [H, 1] column with a lane mask — heads already on
-sublanes, which is where the (page_size, H, Dh) slab wants them — and
-dequantizes the int8 slab in VMEM: the pool crosses HBM at one byte per
+(8, 128)-tiled or full), a whole (H, max_pages) slot view is. Each loop
+iteration picks its pages' [H, 1] columns with a lane mask — heads already
+on sublanes, which is where the (page_size, H, Dh) slab wants them — and
+dequantizes the int8 slabs in VMEM: the pool crosses HBM at one byte per
 element, which is the whole point.
 
 Semantics match the gather path exactly: query i of slot s attends
@@ -47,15 +48,21 @@ virtual positions <= pos[s] + i of the slot's page-table view (the
 active-mask write redirect and the null-page-0 convention live in the
 caller — writes land before attention, and positions past `pos` are
 masked here, so null-page garbage is never read into a live result).
-Pages entirely past a slot's last query are skipped with pl.when — their
-MXU work is elided (the slab DMA still runs; for short slots the table
-points those steps at page 0).
 
-Grid: (S, max_pages); the page-grid dimension executes sequentially per
-slot, so the (m, l, o) accumulators carry across it in VMEM scratch and
-the output block (revisited every page step) is written once at the
-final page. Scores/accumulation are f32; matmuls run in the input dtype
-with f32 accumulation (bf16 pools keep full MXU rate).
+Grid: (S,), one step a slot, and inside it a loop over page BLOCKS whose
+trip count is the slot's own: ceil((pos + C) / page_size) pages hold a
+position some query attends, rounded up to `_BLOCK_PAGES`, and 0 for a
+slot that is not `active` (its stale `pos` and table row are never
+looked at; its output row is zeros, which the engine discards). Each
+iteration waits for its block's slabs in one of two VMEM buffers (one
+DMA semaphore a buffer, K and V apart) after starting the next block's
+into the other, so the walk costs what is LIVE — pages past a slot's
+last query are neither copied nor computed, where a grid over
+(S, max_pages) paid a grid step for every table entry (PERF.md section
+6, PR 31). A block's tail past the live pages re-reads table entries
+the mask then discards. The (m, l, o) state is the loop's carry.
+Scores/accumulation are f32; matmuls run in the input dtype with f32
+accumulation (bf16 pools keep full MXU rate).
 
 CPU (tests / virtual meshes) runs the same kernel under
 `interpret=True` automatically — the tier-1 identity pins in
@@ -75,7 +82,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
-_LANES = 128  # scratch minor dim: the TPU lane count; m/l stay lane-broadcast
+# Pages a block: one loop iteration DMAs this many (page_size, H, Dh) K
+# and V slabs and folds them into the softmax as ONE [block * page_size]
+# key tile. Fixed from the chip's three-fill table (PERF.md section 6,
+# PR 31); tables shorter than a block take one block of the whole table.
+_BLOCK_PAGES = 8
 
 
 def _dot(a, b, contract, batch):
@@ -87,66 +98,103 @@ def _dot(a, b, contract, batch):
         preferred_element_type=jnp.float32, precision=prec)
 
 
-def _kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-            page_size: int, scale: float, quant: bool):
+def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+            block: int, scale: float, quant: bool):
     if quant:
         # int8 pool: this slot's per-(head, page) scales [1, H, max_pages],
         # page-table-gathered by the caller
-        ks_ref, vs_ref, o_ref, o_acc, m_acc, l_acc = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = rest
     else:
-        o_ref, o_acc, m_acc, l_acc = rest
+        o_ref, k_buf, v_buf, sems = rest
         ks_ref = vs_ref = None
-    s_idx, pj = pl.program_id(0), pl.program_id(1)
-    n_pb = pl.num_programs(1)
+    s_idx = pl.program_id(0)
     pos = pos_ref[s_idx]
+    max_pages = pages_ref.shape[1]
+    page_size, h, dh = k_buf.shape[2:]
     c = q_ref.shape[1]
+    t_blk = block * page_size
+    # THE bound of the walk: blocks that hold a live position of THIS slot
+    # (0 for a retired slot — no DMA, no MXU work, a zero output row)
+    n_blocks = pl.cdiv(live_ref[s_idx], block)
 
-    @pl.when(pj == 0)
-    def _init():
-        o_acc[...] = jnp.zeros_like(o_acc)
-        m_acc[...] = jnp.full_like(m_acc, _NEG)
-        l_acc[...] = jnp.zeros_like(l_acc)
+    def copies(b, buf):
+        """The block's 2 * `block` slab DMAs, pool -> VMEM buffer `buf`:
+        the page table entry picks each slab straight out of the pool. A
+        block's tail past the table re-reads the last entry (any real page
+        is finite; its positions are masked below)."""
+        out = []
+        for i in range(block):
+            page = pages_ref[s_idx, jnp.minimum(b * block + i, max_pages - 1)]
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[buf, i], sems.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[buf, i], sems.at[1, buf]))
+        return out
 
-    # pages entirely past the slot's LAST query position contribute nothing
-    @pl.when(pj * page_size <= pos + c - 1)
-    def _compute():
-        q = q_ref[0]                                   # [C, H, Dh]
-        kb = k_ref[0]                                  # [ps, H, Dh]
-        vb = v_ref[0]
+    @pl.when(n_blocks > 0)
+    def _first():
+        for cp in copies(0, 0):
+            cp.start()
+
+    q = q_ref[0]                                       # [C, H, Dh]
+
+    def body(b, carry):
+        m, l, o = carry
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():                                   # double buffering
+            for cp in copies(b + 1, 1 - buf):
+                cp.start()
+
+        for cp in copies(b, buf):
+            cp.wait()
+        kb = k_buf[buf]                                # [block, ps, H, Dh]
+        vb = v_buf[buf]
         if quant:
-            # in-place dequant of the DMA'd slab: the pool stays int8 in
+            # in-place dequant of the DMA'd slabs: the pool stays int8 in
             # HBM and on the wire; f32 rows exist only in VMEM, cast to
             # the query dtype so the MXU contract matches the bf16 path.
-            # This page's scale column comes out of the slot's
+            # Each page's scale column comes out of the slot's
             # [H, max_pages] view by lane mask (a dynamic lane index is
             # not a Mosaic load; one nonzero term keeps the sum exact).
-            here = jax.lax.broadcasted_iota(
-                jnp.int32, ks_ref.shape[1:], 1) == pj
-            ksc = jnp.where(here, ks_ref[0], 0.0).sum(axis=1, keepdims=True)
-            vsc = jnp.where(here, vs_ref[0], 0.0).sum(axis=1, keepdims=True)
-            kb = (kb.astype(jnp.float32) * ksc[None]).astype(q.dtype)
-            vb = (vb.astype(jnp.float32) * vsc[None]).astype(q.dtype)
-        # scores per head: batch H, contract Dh -> [H, C, ps]
+            lane = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
+
+            def column(ref, i):
+                return jnp.where(lane == b * block + i, ref[0], 0.0).sum(
+                    axis=1, keepdims=True)[None, None]  # [1, 1, H, 1]
+
+            def dequant(slabs, ref):
+                return jnp.concatenate([
+                    (slabs[i:i + 1].astype(jnp.float32)
+                     * column(ref, i)).astype(q.dtype)
+                    for i in range(block)])
+
+            kb, vb = dequant(kb, ks_ref), dequant(vb, vs_ref)
+        kb = kb.reshape(t_blk, h, dh)
+        vb = vb.reshape(t_blk, h, dh)
+        # scores per head: batch H, contract Dh -> [H, C, block * ps]
         s = _dot(q, kb, ((2,), (2,)), ((1,), (1,))) * scale
         qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
-        vpos = pj * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(vpos <= qpos, s, _NEG)
-        m = m_acc[:, :, :1]                            # [H, C, 1]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        vpos = b * t_blk + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, t_blk), 2)
+        s = jnp.where((vpos <= qpos) & (vpos < max_pages * page_size),
+                      s, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))   # [H, C, 1]
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l_acc[:, :, :1] * corr + p.sum(axis=-1, keepdims=True)
-        # [H, C, ps] x [ps, H, Dh]: batch H, contract ps -> [H, C, Dh]
-        o_acc[...] = o_acc[...] * corr + _dot(
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        # [H, C, T] x [T, H, Dh]: batch H, contract T -> [H, C, Dh]
+        o_new = o * corr + _dot(
             p.astype(vb.dtype), vb, ((2,), (0,)), ((0,), (1,)))
-        m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
-        l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+        return m_new, l_new, o_new
 
-    @pl.when(pj == n_pb - 1)
-    def _finalize():
-        l = jnp.maximum(l_acc[:, :, :1], 1e-30)
-        o_ref[0] = jnp.moveaxis(o_acc[...] / l, 0, 1).astype(o_ref.dtype)
+    _, l, o = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.full((h, c, 1), _NEG, jnp.float32),        # running max m
+        jnp.zeros((h, c, 1), jnp.float32),             # running sum l
+        jnp.zeros((h, c, dh), jnp.float32)))           # o accumulator
+    o_ref[0] = jnp.moveaxis(o / jnp.maximum(l, 1e-30), 0, 1).astype(
+        o_ref.dtype)
 
 
 def _auto_interpret() -> bool:
@@ -154,43 +202,37 @@ def _auto_interpret() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(q, k_pool, v_pool, pages, pos, scales, interpret: bool):
+def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool):
     s_, c, h, dh = q.shape
     page_size = k_pool.shape[1]
     max_pages = pages.shape[1]
-    scale = dh ** -0.5
+    block = min(_BLOCK_PAGES, max_pages)
     quant = scales is not None
-    in_specs = [
-        pl.BlockSpec((1, c, h, dh), lambda s, p, pt, ps_: (s, 0, 0, 0)),
-        # THE paged read: the page table entry picks which pool slab
-        # this grid step sees — no gathered copy ever materializes
-        pl.BlockSpec((1, page_size, h, dh),
-                     lambda s, p, pt, ps_: (pt[s, p], 0, 0, 0)),
-        pl.BlockSpec((1, page_size, h, dh),
-                     lambda s, p, pt, ps_: (pt[s, p], 0, 0, 0)),
-    ]
-    operands = [pages, pos, q, k_pool, v_pool]
+    # pages that hold a position some query of the slot attends; a retired
+    # slot's stale `pos` counts for nothing
+    live = jnp.where(
+        active, jnp.minimum(pl.cdiv(pos + c, page_size), max_pages), 0)
+    slot = pl.BlockSpec((1, c, h, dh), lambda s, *_: (s, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; DMA'd by hand
+    in_specs = [slot, pool, pool]
+    operands = [pages, live, pos, q, k_pool, v_pool]
     if quant:
         # per-(page, head) f32 scales [P, H] -> this call's page-table
         # view [S, H, max_pages], one whole (H, max_pages) block per slot
-        spec = pl.BlockSpec((1, h, max_pages),
-                            lambda s, p, pt, ps_: (s, 0, 0))
+        spec = pl.BlockSpec((1, h, max_pages), lambda s, *_: (s, 0, 0))
         in_specs += [spec, spec]
         operands += [jnp.swapaxes(sc[pages], 1, 2) for sc in scales]
+    buf = pltpu.VMEM((2, block, page_size, h, dh), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,     # pages + pos steer the index maps
-        grid=(s_, max_pages),
+        num_scalar_prefetch=3,     # pages, live page counts, pos
+        grid=(s_,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, h, dh),
-                               lambda s, p, pt, ps_: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, c, dh), jnp.float32),      # o accumulator
-            pltpu.VMEM((h, c, _LANES), jnp.float32),  # running max m
-            pltpu.VMEM((h, c, _LANES), jnp.float32),  # running sum l
-        ],
+        out_specs=slot,
+        scratch_shapes=[buf, buf,                      # K, V double buffers
+                        pltpu.SemaphoreType.DMA((2, 2))],  # [K/V, buffer]
     )
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, scale=scale,
+        functools.partial(_kernel, block=block, scale=dh ** -0.5,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, c, h, dh), q.dtype),
@@ -200,19 +242,26 @@ def _call(q, k_pool, v_pool, pages, pos, scales, interpret: bool):
 
 
 def paged_attention(q, k_pool, v_pool, pages, pos,
-                    k_scales=None, v_scales=None,
+                    k_scales=None, v_scales=None, active=None,
                     interpret: bool | None = None):
     """Fused paged decode attention (module docstring has the contract).
 
     q [S, C, H, Dh], k/v pool [P, page_size, H, Dh], pages [S, max_pages]
     int32, pos [S] int32 -> [S, C, H, Dh]. With an int8 pool, k_scales /
     v_scales [P, H] f32 per-(page, head) scales must both ride along —
-    each slab is dequantized in VMEM right after its DMA."""
+    each slab is dequantized in VMEM right after its DMA. `active` [S]
+    bool (default: every slot) marks the slots whose rows are wanted: a
+    slot that is not active costs no page read and returns zeros."""
     if interpret is None:
         interpret = _auto_interpret()
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
+    n_slots = q.shape[0]
     pages = jnp.asarray(pages, jnp.int32)
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (q.shape[0],))
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (n_slots,))
+    active = jnp.broadcast_to(
+        jnp.asarray(True if active is None else active, jnp.bool_),
+        (n_slots,))
     scales = None if k_scales is None else (k_scales, v_scales)
-    return _call(q, k_pool, v_pool, pages, pos, scales, bool(interpret))
+    return _call(q, k_pool, v_pool, pages, pos, active, scales,
+                 bool(interpret))

@@ -141,7 +141,10 @@ replica death; the engine carries the first and last:
 Telemetry rides the existing planes: `serving.ttft` / `serving.tbt`
 histograms, `serving.slots_active` gauge, `serving.tokens_total` counter,
 `serving.engine.*` counters (`steps` / `slot_steps`: drained step frames
-and the live slots in them, whose ratio is the slot occupancy), and
+and the live slots in them, whose ratio is the slot occupancy;
+`page_steps`: the pages those slots' queries attended, which over `steps` x
+the `serving.engine.table_pages` gauge is the share of the page table a
+step has to walk), and
 `serving.engine.admit` / `.fetch` spans on the Chrome trace — all visible
 in `/metrics` and `python -m fedml_tpu top`. Each request also leaves three
 contiguous spans in its caller's trace once its consumer has the first
@@ -533,6 +536,8 @@ class DecodeEngine:
             self._prefix: dict[bytes, _PrefixEntry] = {}
             self._ticks = 0
             _mx.set_gauge("serving.kv_pages_budget", self._usable)
+            _mx.set_gauge("serving.engine.table_pages",
+                          self.n_slots * self._max_pages)
             _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
         elif n_pages or prefill_chunk:
             raise ValueError(
@@ -1655,7 +1660,7 @@ class DecodeEngine:
                 toks = np.asarray(toks_dev)
                 counts = np.asarray(counts_dev)
             live = counts > 0
-            self._count_step(int(live.sum()))
+            self._count_step(live, window=toks.shape[1])
             if live.any():
                 # every live slot consumed spec_k drafts and banked
                 # count - 1 beyond the guaranteed token — the accept
@@ -1672,7 +1677,7 @@ class DecodeEngine:
             with recorder.span("serving.engine.fetch", kind="step"):
                 toks = np.asarray(toks_dev)
                 mask = np.asarray(mask_dev)
-            self._count_step(int(mask.sum()))
+            self._count_step(mask)
             for slot in np.nonzero(mask)[0]:
                 self._deliver(int(slot), int(toks[slot]), first=False)
         # publish the POST-delivery host occupancy, not the frame's entry
@@ -1682,13 +1687,27 @@ class DecodeEngine:
         _mx.set_gauge("serving.slots_active",
                       sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
 
-    @staticmethod
-    def _count_step(live_slots: int) -> None:
-        """One drained step (or verify) frame: the program ran over every
-        slot, `live_slots` of them were doing a request's work. Their
-        ratio over a run is the engine's slot occupancy."""
+    def _count_step(self, live: np.ndarray, window: int = 1) -> None:
+        """One drained step (or verify) frame, counted BEFORE its tokens
+        are delivered: the program ran over every slot, the `live` ones
+        were doing a request's work (their share over a run is the
+        engine's slot occupancy). `page_steps` adds up the pages those
+        slots' queries attended — prompt + emitted + the frame's `window`
+        of queries, in pages — which over `steps x table_pages` is the
+        share of the page table the paged kernel has to walk."""
+        slots = np.nonzero(live)[0]
         _mx.inc("serving.engine.steps")
-        _mx.inc("serving.engine.slot_steps", live_slots)
+        _mx.inc("serving.engine.slot_steps", len(slots))
+        if not self._paged:
+            return
+        pages = 0
+        for slot in slots:
+            st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+            if st is not None:
+                # the frame's first query sits on the last emitted token
+                attended = len(st.req.tokens) + len(st.out) - 1 + window
+                pages += min(-(-attended // self._page_size), self._max_pages)
+        _mx.inc("serving.engine.page_steps", pages)
 
     def _deliver(self, slot: int, tok: int, first: bool) -> None:
         st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)

@@ -14,7 +14,8 @@ tiled or full) — and nothing failed until someone looked. Two nets:
   cannot run them: numerics on the chip are `chip_smoke.py`'s job.)
 
 Shapes are chip_smoke.py's: flash at [B*H, T, Dh] = [64, 2048, 128]; paged
-at S=8 slots, H=16, Dh=128, page 16, 2048-token tables.
+at S=8 slots, H=16, Dh=128, page 16, 2048-token tables, and once at the
+serving cell's 16 slots.
 """
 import math
 import re
@@ -50,18 +51,21 @@ def _flash_case(kind, dtype):
             {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
 
 
-def _paged_case(c, dtype, quant):
-    q = S((SLOTS, c, HEADS, DH), dtype)
-    pool = S((N_PAGES, PAGE, HEADS, DH), jnp.int8 if quant else dtype)
-    pages, pos = S((SLOTS, MAX_PAGES), jnp.int32), S((SLOTS,), jnp.int32)
+def _paged_case(c, dtype, quant, slots=SLOTS):
+    """`active` rides along as the decode step passes it (llm/decode.py)."""
+    n_pages = slots * MAX_PAGES + 1
+    q = S((slots, c, HEADS, DH), dtype)
+    pool = S((n_pages, PAGE, HEADS, DH), jnp.int8 if quant else dtype)
+    pages, pos = S((slots, MAX_PAGES), jnp.int32), S((slots,), jnp.int32)
+    active = S((slots,), jnp.bool_)
     if not quant:
-        return (lambda q, k, v, pg, po: paged_attention(
-            q, k, v, pg, po, interpret=False),
-            (q, pool, pool, pages, pos), {"paged_attention"})
-    sc = S((N_PAGES, HEADS), jnp.float32)
-    return (lambda q, k, v, pg, po, ks, vs: paged_attention(
-        q, k, v, pg, po, ks, vs, interpret=False),
-        (q, pool, pool, pages, pos, sc, sc), {"paged_attention"})
+        return (lambda q, k, v, pg, po, act: paged_attention(
+            q, k, v, pg, po, active=act, interpret=False),
+            (q, pool, pool, pages, pos, active), {"paged_attention"})
+    sc = S((n_pages, HEADS), jnp.float32)
+    return (lambda q, k, v, pg, po, ks, vs, act: paged_attention(
+        q, k, v, pg, po, ks, vs, active=act, interpret=False),
+        (q, pool, pool, pages, pos, sc, sc, active), {"paged_attention"})
 
 
 CASES = {
@@ -75,6 +79,8 @@ CASES = {
     "paged_c4_f32": lambda: _paged_case(4, jnp.float32, False),
     "paged_c1_int8": lambda: _paged_case(1, jnp.bfloat16, True),
     "paged_c4_int8": lambda: _paged_case(4, jnp.bfloat16, True),
+    # the serving cell's own step (BENCHMARK.json olmo1b_decode_chat)
+    "paged_c1_bf16_s16": lambda: _paged_case(1, jnp.bfloat16, False, slots=16),
 }
 
 
@@ -103,7 +109,7 @@ def v5e():
 
 
 @pytest.mark.parametrize("name", [
-    n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16", "paged_c4_int8")
+    n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16_s16", "paged_c4_int8")
     else pytest.param(n, marks=pytest.mark.slow)    # tier-1 is at its cap
     for n in sorted(CASES)])
 def test_kernel_compiles_with_mosaic(name, v5e):

@@ -409,3 +409,9 @@ def test_top_line_shows_page_occupancy_and_prefix_rate():
     frame = _top_frame(snap, "test")
     assert "pages 5/20 (25%)" in frame
     assert "prefix 75%" in frame
+    assert "walk" not in frame          # no step frame drained yet
+    _mx.inc("serving.engine.steps", 10)
+    _mx.inc("serving.engine.page_steps", 41)
+    _mx.set_gauge("serving.engine.table_pages", 16 * 128)
+    snap = parse_prometheus(render_prometheus(_mx.snapshot()))
+    assert "walk 0.2%" in _top_frame(snap, "test")      # 41 / (10 x 2,048)

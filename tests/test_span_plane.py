@@ -155,17 +155,29 @@ def test_a_ticket_submitted_outside_any_span_starts_its_own_trace(lm):
 def test_slot_steps_count_the_tokens_step_frames_delivered(lm, paged):
     """`serving.engine.steps` counts drained step frames, `slot_steps` the
     live slots in them: every token but a request's first comes from one
-    live slot of one step frame, and occupancy cannot pass the slots."""
+    live slot of one step frame, and occupancy cannot pass the slots.
+    `page_steps` (paged only) adds up the pages each of those steps'
+    query attended: prompt + the tokens emitted so far, in pages."""
     model, params = lm
     kw = {"page_size": 8, "prefill_chunk": 8} if paged else {}
     eng = DecodeEngine(model, params, n_slots=3, max_len=MAXLEN, **kw).start()
+    prompts = (5, 11, 8, 4, 17)
     try:
         tickets = [eng.submit(_prompt(n, seed=n), new)
-                   for n, new in ((5, 7), (11, 3), (8, 9), (4, 1), (17, 6))]
+                   for n, new in zip(prompts, (7, 3, 9, 1, 6))]
         outs = [t.result(timeout=120) for t in tickets]
     finally:
         eng.stop()
-    c = _mx.snapshot()["counters"]
+    snap = _mx.snapshot()
+    c = snap["counters"]
+    if paged:
+        assert c["serving.engine.page_steps"] == sum(
+            -(-(n + emitted) // 8)
+            for n, o in zip(prompts, outs) for emitted in range(1, len(o)))
+        assert snap["gauges"]["serving.engine.table_pages"] == (
+            3 * -(-MAXLEN // 8))
+    else:
+        assert "serving.engine.page_steps" not in c
     by_steps = sum(len(o) - 1 for o in outs)
     assert c["serving.engine.slot_steps"] == by_steps > 0
     assert c["serving.engine.steps"] >= max(len(o) - 1 for o in outs)
