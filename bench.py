@@ -981,158 +981,6 @@ def bench_serving_tp() -> dict:
     }
 
 
-def bench_serving_paged(quick: bool = False) -> dict:
-    """Paged-KV serving rows (ISSUE 7) — three measured claims:
-
-    (a) SLOTS AT FIXED HBM: the paged engine serves the same 8-slot
-        concurrent workload as the contiguous engine out of a page pool
-        holding 1/8 the persistent KV rows, token-identity asserted;
-        `serving_paged_hbm_ratio` = (S * max_len) / (usable_pages *
-        page_size) — the contiguous layout burns max_len rows per slot
-        no matter what the requests use, the pool holds live tokens.
-    (b) TTFT under CONCURRENT ADMISSION, chunked vs monolithic prefill:
-        one 224-token prompt + 7 eight-token prompts admitted together
-        (prefix cache off so every number is a real prefill). With
-        monolithic admission every short prompt's first token waits
-        behind the long prefill program; with prefill_chunk=16 the
-        admission round-robin bounds the wait at one chunk — the shorts'
-        p99 drops toward their own prefill time.
-    (c) PREFIX-HIT vs cold TTFT across prompt lengths: identical-prompt
-        resubmission skips the resident pages, so hit TTFT stays ~flat
-        in prompt length while cold TTFT grows with it.
-
-    CPU figures prove the mechanisms; on TPU the same programs gain HBM
-    bandwidth and the ratios in (a) translate directly to replica
-    memory (ROADMAP: memory, not compute, sets replica count)."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    from fedml_tpu.llm.transformer import TransformerLM
-    from fedml_tpu.serving.engine import DecodeEngine
-
-    if quick:
-        dims = dict(vocab_size=128, d_model=128, n_layers=2, n_heads=4,
-                    d_ff=256)
-    else:
-        dims = dict(vocab_size=256, d_model=256, n_layers=2, n_heads=8,
-                    d_ff=512)
-    model = TransformerLM(**dims, scan_layers=True)
-    params = model.init(jax.random.key(0),
-                        jnp.zeros((1, 16), jnp.int32))["params"]
-    rs = np.random.RandomState(0)
-    S, max_len, ps = 8, 256, 16
-
-    def prompt(n, stream):
-        return rs.randint(1, dims["vocab_size"], n).tolist() \
-            if stream is None else \
-            np.random.RandomState(stream).randint(
-                1, dims["vocab_size"], n).tolist()
-
-    def run_all(eng, reqs, new):
-        tickets = [eng.submit(p, new) for p in reqs]
-        outs = [t.result(timeout=600) for t in tickets]
-        return outs, tickets
-
-    # ------------------------------------------------- (a) slots at fixed HBM
-    # 8 concurrent requests of <= 32 live tokens each: 2 pages apiece ->
-    # a 16-page pool (+ null page) serves all 8 at once where the
-    # contiguous cache would hold 8 x 256 rows
-    prompts_a = [prompt(n, None) for n in (10, 14, 12, 9, 13, 11, 10, 12)]
-    new_a = 18
-    cont = DecodeEngine(model, params, n_slots=S, max_len=max_len).start()
-    try:
-        cont.submit(prompts_a[0], new_a).result(timeout=600)   # compile
-        want, _ = run_all(cont, prompts_a, new_a)
-    finally:
-        cont.stop()
-    paged = DecodeEngine(model, params, n_slots=S, max_len=max_len,
-                         page_size=ps, n_pages=17, prefill_chunk=16,
-                         prefix_cache=False).start()
-    try:
-        paged.submit(prompts_a[0], new_a).result(timeout=600)  # compile
-        got, _ = run_all(paged, prompts_a, new_a)
-    finally:
-        paged.stop()
-    hbm_ratio = (S * max_len) / (16 * ps)
-    identical = got == want
-
-    # ------------------------- (b) concurrent-admission TTFT, chunked on/off
-    def admission_ttfts(chunk):
-        """(long prompt's TTFT, sorted shorts' TTFTs) in ms — separated
-        because the claim is about the SHORTS: with monolithic admission
-        they queue behind the long prefill program; chunked admission
-        bounds their wait at chunk granularity. The long prompt itself
-        PAYS for chunking (more dispatches + interleaved decode steps) —
-        that trade is the point, and both sides are reported."""
-        eng = DecodeEngine(model, params, n_slots=S, max_len=max_len,
-                           page_size=ps, n_pages=33, prefill_chunk=chunk,
-                           prefix_cache=False, fetch_chunk=1).start()
-        try:
-            # warm every program off the clock (same shapes as the run)
-            warm = [eng.submit(prompt(224, 91), 2)] + \
-                   [eng.submit(prompt(8, 92 + i), 2) for i in range(7)]
-            for t in warm:
-                t.result(timeout=600)
-            long_t = eng.submit(prompt(224, 81), 8)
-            shorts = [eng.submit(prompt(8, 82 + i), 8) for i in range(7)]
-            for t in [long_t] + shorts:
-                t.result(timeout=600)
-            return ((long_t.t_first - long_t.t_submit) * 1e3,
-                    sorted((t.t_first - t.t_submit) * 1e3 for t in shorts))
-        finally:
-            eng.stop()
-
-    long_mono, ttft_mono = admission_ttfts(0)
-    long_chunk, ttft_chunk = admission_ttfts(16)
-    p = lambda xs, q: xs[min(int(q * len(xs)), len(xs) - 1)]  # noqa: E731
-
-    # ----------------------------- (c) prefix-hit vs cold TTFT by prompt len
-    eng = DecodeEngine(model, params, n_slots=2, max_len=max_len,
-                       page_size=ps, n_pages=65, prefill_chunk=16,
-                       fetch_chunk=1).start()
-    prefix_rows = {}
-    try:
-        for i, plen in enumerate((64, 128, 224)):
-            # distinct stream per length: no cross-length prefix hits
-            ptoks = prompt(plen, 70 + i)
-            warm = eng.submit(prompt(plen, 60 + i), 4)   # compile, off-clock
-            warm.result(timeout=600)
-            cold = eng.submit(ptoks, 4)
-            cold.result(timeout=600)
-            hit = eng.submit(ptoks, 4)
-            hit.result(timeout=600)
-            prefix_rows[plen] = (
-                round((cold.t_first - cold.t_submit) * 1e3, 2),
-                round((hit.t_first - hit.t_submit) * 1e3, 2))
-    finally:
-        eng.stop()
-    flat = round(prefix_rows[224][1] / max(prefix_rows[64][1], 1e-9), 2)
-
-    return {
-        "serving_paged_hbm_ratio": round(hbm_ratio, 1),
-        "serving_paged_tokens_identical": identical,
-        "serving_paged_slots": S,
-        "serving_paged_ttft_p50_ms_monolithic": round(p(ttft_mono, 0.5), 1),
-        "serving_paged_ttft_p99_ms_monolithic": round(p(ttft_mono, 0.99), 1),
-        "serving_paged_ttft_p50_ms_chunked": round(p(ttft_chunk, 0.5), 1),
-        "serving_paged_ttft_p99_ms_chunked": round(p(ttft_chunk, 0.99), 1),
-        "serving_paged_ttft_long_ms_monolithic": round(long_mono, 1),
-        "serving_paged_ttft_long_ms_chunked": round(long_chunk, 1),
-        "serving_paged_prefix_ttft_ms_by_len": {
-            str(k): {"cold": v[0], "hit": v[1]}
-            for k, v in prefix_rows.items()},
-        "serving_paged_prefix_hit_flatness_224_over_64": flat,
-        "serving_paged_config": (
-            f"slots{S} maxlen{max_len} page{ps} d{dims['d_model']} "
-            f"L{dims['n_layers']} vocab{dims['vocab_size']}; (a) pool 16 "
-            "pages vs contiguous 8x256 rows; (b) 1x224tok + 7x8tok "
-            "concurrent, chunk16 vs whole-prompt; (c) cold vs resubmit, "
-            "prefill_chunk16" + (" quick" if quick else "")),
-    }
-
-
 def bench_serving_kernel(quick: bool = False) -> dict:
     """Pallas paged-attention kernel row (ISSUE 11, leg 1): decode
     step tok/s at LONG context through the paged engine with the fused
@@ -2122,11 +1970,6 @@ _HEADLINE_KEYS = (
     # tensor-parallel serving (ISSUE 6): mp=1 vs mp=2 engine row
     "serving_tp_scaling_mp2_vs_mp1", "serving_tp_tokens_per_sec_mp2",
     "serving_tp_tokens_identical",
-    # paged KV + prefix + chunked prefill (ISSUE 7)
-    "serving_paged_hbm_ratio", "serving_paged_tokens_identical",
-    "serving_paged_ttft_p99_ms_chunked",
-    "serving_paged_ttft_p99_ms_monolithic",
-    "serving_paged_prefix_hit_flatness_224_over_64",
     # decode raw speed (ISSUE 11): fused paged-attention kernel +
     # speculative decoding
     "serving_paged_kernel_ratio_vs_gather",
@@ -2238,7 +2081,6 @@ def main():
         ("w1_reliable_comm_error", bench_reliable_comm),
         ("comm_codec_error", bench_comm_codec, quick),
         ("serving_cb_error", bench_serving_cb, quick),
-        ("serving_paged_error", bench_serving_paged, quick),
         ("serving_paged_kernel_error", bench_serving_kernel, quick),
         ("serving_density_error", bench_serving_density, quick),
         ("serving_spec_error", bench_serving_spec, quick),

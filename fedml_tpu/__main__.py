@@ -787,8 +787,8 @@ def _top_frame(snap: dict, source: str, prev: dict = None,
             tr = rate("serving_tokens_total")
             if tr is not None:
                 seg += f"  tok/s {tr:.1f}"
-            # paged-KV plane (serving/engine.py page_size > 0): physical
-            # page occupancy + prefix-cache hit rate
+            # the engine's page pool: physical page occupancy +
+            # prefix-cache hit rate
             pt = g.get("serving_kv_pages_budget")
             if pt:
                 free = g.get("serving_kv_pages_free", 0)
@@ -1330,7 +1330,7 @@ def cmd_diagnosis(args) -> int:
         # the slot engine, 8 concurrent requests — every request must get
         # exactly one response, more than one slot must have been active
         # at once, and the compiled-program set must stay bounded (one
-        # step program + one admit program per prompt bucket).
+        # step program + one admit program per chunk bucket).
         import threading as _th
         import time as _t
 
@@ -1380,15 +1380,14 @@ def cmd_diagnosis(args) -> int:
                 "programs": counts}
 
     def serving_paged_smoke():
-        # the paged-KV serving plane end-to-end (ISSUE 7): a tiny LM on
-        # the PAGED engine under a page budget well below the contiguous
-        # equivalent, 8 concurrent requests sharing a common prompt
+        # the engine's page pool end-to-end (ISSUE 7): a tiny LM under a
+        # page budget below what every slot at max_len would take (the
+        # default pool), 6 concurrent requests sharing a common prompt
         # prefix — allocation must serve all of them, the prefix cache
         # must hit (the shared head is resident after the first
         # admission), retirement must reclaim pages (free + resident
         # prefix pages == the full budget afterwards), and the compiled-
-        # program set must stay bounded (one paged step + pow2 chunk
-        # buckets).
+        # program set must stay bounded (one step + pow2 chunk buckets).
         import jax as _jax
         import jax.numpy as _jnp
         import numpy as _np
@@ -1407,7 +1406,7 @@ def cmd_diagnosis(args) -> int:
         # bucket, so the probe compiles ONE chunk program + one step —
         # this probe runs twice inside tier-1, keep it lean
         prompts = [head + rs.randint(1, 64, 4).tolist() for _ in range(6)]
-        # 19 usable pages vs the contiguous equivalent of
+        # 19 usable pages vs the default pool's
         # slots * max_len / page_size = 3 * 32 / 4 = 24
         eng = DecodeEngine(model, params, n_slots=3, max_len=32,
                            page_size=4, n_pages=20, prefill_chunk=4).start()

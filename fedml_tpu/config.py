@@ -160,17 +160,22 @@ class ServeArgs:
     graftlint's knob-drift rule cross-checks it against the predictor
     and fleet mappings, so this docstring is prose, not a key list:
       decode_slots      — >0 starts the continuous-batching DecodeEngine
-                          (serving/engine.py) with that many slots
+                          (serving/engine.py) with that many slots over
+                          one pool of KV pages; every knob below but
+                          engine_max_len, sampler_cache_size and
+                          drain_timeout_s needs it
+      kv_page_size      — KV rows a page (default 16); kv_n_pages sizes
+                          the pool, prefill_chunk the admission chunk,
+                          prefix_cache reuses identical prompt prefixes
       engine_max_len    — per-slot KV capacity (prompt + max_new <= this)
       engine_eos_id     — token id that retires a slot early (omit: none)
       engine_fetch_chunk — device frames kept in flight before the host
                           fetches (dispatch-ahead depth)
       sampler_cache_size — LRU cap on per-top_k compiled samplers
       engine_mp          — >1 runs the engine tensor-parallel over an
-                          {"mp": N} mesh (weights + persistent KV cache
+                          {"mp": N} mesh (weights + persistent KV pool
                           sharded via the parallel/partition.py registry)
-    Decode-speed knobs (ISSUE 11 — both need the paged engine,
-    kv_page_size > 0):
+    Decode-speed knobs (ISSUE 11 — both need the engine, decode_slots):
       paged_kernel      — fused Pallas paged-attention decode kernel
                           (ops/paged_attention.py): pages read in place,
                           no gather copy, and only the pages live slots

@@ -19,6 +19,12 @@ bias-free kernels) with attention specialized to the decode shapes:
   cached K/V (masked at positions > pos), writes its own K/V at pos, and
   the layer scan threads the cache through as scanned inputs/outputs.
 
+That pair (`make_kv_decode`) is the PER-REQUEST path: a cache per call,
+sized to the request, inside `make_generate`'s one program — and the
+oracle the engine's tests compare against. The continuous-batching engine
+(serving/engine.py) runs `make_paged_kv_decode`'s four programs over one
+persistent pool of KV pages.
+
 Per-token cost drops from O(T·D²) (full recompute of every position's
 projections) to O(D² + T·D): at max_len=256 that is ~two orders of
 magnitude fewer projection FLOPs per generated token.
@@ -169,6 +175,36 @@ def _rope_rows(x, pos_rows, base: float = 10000.0):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _block_math(dtype, eps: float, alpha: float):
+    """The dense block's math (llm/quant.py — one implementation, shared
+    with the in-scan training forward) bound to a decode factory's dtype /
+    eps / alpha. Both factories below unpack the same seven closures:
+    (norm, dq, merged, qkv, mlp, head, split_ads)."""
+
+    def norm(x, scale):
+        return rms_norm(x, scale, eps)
+
+    def dq(leaf):
+        return dequant_leaf(leaf, dtype)
+
+    def merged(bl, ad_l, name, rank_scale):
+        return merged_kernel(bl, ad_l, name, rank_scale, dtype)
+
+    def qkv(bl, ad_l, rank_scale, h, n_hd):
+        return project_qkv(bl, ad_l, rank_scale, h, n_hd, dtype)
+
+    def mlp(bl, ad_l, rank_scale, x):
+        return swiglu_mlp(bl, ad_l, rank_scale, x, dtype, eps)
+
+    def head(params, top_ads, rank_scale, x):
+        return lm_head_logits(params, top_ads, rank_scale, x, dtype, eps)
+
+    def split_ads(adapters):
+        return split_adapters(adapters, alpha)
+
+    return norm, dq, merged, qkv, mlp, head, split_ads
+
+
 def make_kv_decode(n_heads: int, alpha: float = 16.0,
                    dtype=jnp.float32, eps: float = 1e-6,
                    prefill_attn_fn=None):
@@ -190,28 +226,8 @@ def make_kv_decode(n_heads: int, alpha: float = 16.0,
 
     prefill_attn = prefill_attn_fn or dense_causal_attention
 
-    # block math shared with the in-scan training forward (quant.py) —
-    # one implementation, bound to this decode's dtype/eps/alpha
-    def norm(x, scale):
-        return rms_norm(x, scale, eps)
-
-    def dq(leaf):
-        return dequant_leaf(leaf, dtype)
-
-    def merged(bl, ad_l, name, rank_scale):
-        return merged_kernel(bl, ad_l, name, rank_scale, dtype)
-
-    def split_ads(adapters):
-        return split_adapters(adapters, alpha)
-
-    def head_logits(params, top_ads, rank_scale, x):
-        return lm_head_logits(params, top_ads, rank_scale, x, dtype, eps)
-
-    def qkv(bl, ad_l, rank_scale, h, n_hd):
-        return project_qkv(bl, ad_l, rank_scale, h, n_hd, dtype)
-
-    def mlp(bl, ad_l, rank_scale, x):
-        return swiglu_mlp(bl, ad_l, rank_scale, x, dtype, eps)
+    norm, dq, merged, qkv, mlp, head, split_ads = _block_math(
+        dtype, eps, alpha)
 
     def prefill(params, adapters, tokens, max_len: int, length=None):
         """tokens may be right-PADDED to a fixed bucket; `length` (traced
@@ -251,7 +267,7 @@ def make_kv_decode(n_heads: int, alpha: float = 16.0,
                 jnp.asarray(length, jnp.int32), (x.shape[0],))
             last = jax.vmap(lambda xi, li: jax.lax.dynamic_index_in_dim(
                 xi, li - 1, axis=0, keepdims=False))(x, lengths)
-        logits = head_logits(params, top_ads, rank_scale, last[:, None])
+        logits = head(params, top_ads, rank_scale, last[:, None])
         return {"k": ck, "v": cv}, logits[:, 0]
 
     def step(params, adapters, cache, pos, token):
@@ -287,7 +303,7 @@ def make_kv_decode(n_heads: int, alpha: float = 16.0,
 
         x, (ck, cv) = jax.lax.scan(
             body, x, (params["blocks"], blk_ads, cache["k"], cache["v"]))
-        logits = head_logits(params, top_ads, rank_scale, x)
+        logits = head(params, top_ads, rank_scale, x)
         return {"k": ck, "v": cv}, logits[:, 0]
 
     return prefill, step
@@ -348,14 +364,13 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                          dtype=jnp.float32, eps: float = 1e-6,
                          kernel: bool = False, mesh=None,
                          quant: bool = False):
-    """Paged variant of make_kv_decode for the block-allocated engine
-    cache (serving/engine.py): K/V live in a POOL of fixed-size pages
-    `[L, n_pages, page_size, H, Dh]` instead of one contiguous
-    `[L, S, max_len, H, Dh]` buffer, and each slot's logical sequence is
-    described by an int32 page-table row mapping virtual position
-    `t -> (row[t // page_size], t % page_size)`. Pages are what make the
-    engine's HBM proportional to LIVE tokens (and lets identical prompt
-    prefixes share physical pages) rather than `slots x max_len`.
+    """The decode engine's programs (serving/engine.py): K/V live in a
+    persistent POOL of fixed-size pages `[L, n_pages, page_size, H, Dh]`,
+    and each slot's logical sequence is described by an int32 page-table
+    row mapping virtual position `t -> (row[t // page_size], t %
+    page_size)`. Pages are what make the engine's HBM proportional to
+    LIVE tokens (and let identical prompt prefixes share physical pages)
+    rather than `slots x max_len`.
 
     Returns (chunk, step, verify, chunk_batch):
 
@@ -375,12 +390,11 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         -> (cache, logits)     # ALL slots one token: pages [S, max_pages],
                                # pos/token [S]. `active` REDIRECTS inactive
                                # slots' garbage K/V write to the reserved
-                               # null page 0 — unlike the contiguous
-                               # layout, an inactive slot's stale page-
-                               # table entry may point at a page that was
-                               # freed and re-allocated to ANOTHER slot,
-                               # so "write lands on a frozen position" is
-                               # no longer a safe place to park it.
+                               # null page 0: an inactive slot's stale
+                               # page-table entry may point at a page that
+                               # was freed and re-allocated to ANOTHER
+                               # slot, so its own old position is not a
+                               # safe place to park the write.
     verify(params, adapters, cache, pages, pos, tokens, active)
         -> (cache, logits)     # ALL slots, C tokens each (tokens
                                # [S, C] at positions pos..pos+C-1;
@@ -436,9 +450,9 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     positions beyond a slot's `pos`, which the live mask discards.
     Attention gathers each slot's pages into a virtually-contiguous
     [max_pages * page_size] sequence, so the math (and, pinned in tests,
-    the greedy tokens) matches the contiguous cache — the gather is the
-    XLA-level cost of paging; the win is that the PERSISTENT pool holds
-    only `n_pages * page_size` rows.
+    the greedy tokens) matches make_kv_decode's per-request cache — the
+    gather is the XLA-level cost of paging; the win is that the
+    PERSISTENT pool holds only `n_pages * page_size` rows.
 
     `kernel=True` swaps step/verify's gather-then-attend for the fused
     Pallas paged-attention kernel (ops/paged_attention.py) that reads
@@ -454,24 +468,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     with zero resharding. Token identity vs the gather path is pinned in
     tests/test_decode_kernel_spec.py."""
     ps = int(page_size)
-
-    def norm(x, scale):
-        return rms_norm(x, scale, eps)
-
-    def dq(leaf):
-        return dequant_leaf(leaf, dtype)
-
-    def merged(bl, ad_l, name, rank_scale):
-        return merged_kernel(bl, ad_l, name, rank_scale, dtype)
-
-    def qkv(bl, ad_l, rank_scale, h, n_hd):
-        return project_qkv(bl, ad_l, rank_scale, h, n_hd, dtype)
-
-    def mlp(bl, ad_l, rank_scale, x):
-        return swiglu_mlp(bl, ad_l, rank_scale, x, dtype, eps)
-
-    def head(params, top_ads, rank_scale, x):
-        return lm_head_logits(params, top_ads, rank_scale, x, dtype, eps)
+    norm, dq, merged, qkv, mlp, head, split_ads = _block_math(
+        dtype, eps, alpha)
 
     def scan_layers(layer, x, params, blk_ads, cache):
         """THE layer scan of all four programs. The pool leaves ride the
@@ -524,7 +522,7 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         return one("k", "ks"), one("v", "vs")
 
     def chunk(params, adapters, cache, pages_row, tokens, t0, length):
-        blk_ads, top_ads, rank_scale = split_adapters(adapters, alpha)
+        blk_ads, top_ads, rank_scale = split_ads(adapters)
         emb = dq(params["embed"]["embedding"])
         x = emb[tokens]                                   # [1, C, D]
         c = tokens.shape[1]
@@ -595,7 +593,7 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         C == 1 is the plain decode step). Query i of slot s sits at
         global position pos[s] + i; its K/V write lands there BEFORE
         attention, so the window attends to itself causally."""
-        blk_ads, top_ads, rank_scale = split_adapters(adapters, alpha)
+        blk_ads, top_ads, rank_scale = split_ads(adapters)
         emb = dq(params["embed"]["embedding"])
         x = emb[tokens]                                   # [S, C, D]
         s_, c = tokens.shape
@@ -662,7 +660,7 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         positions (per-row t0), chunk-shaped write masking (tokens past
         a row's length — and PAD rows entirely — redirect to the null
         page), per-row last-live-position logits."""
-        blk_ads, top_ads, rank_scale = split_adapters(adapters, alpha)
+        blk_ads, top_ads, rank_scale = split_ads(adapters)
         emb = dq(params["embed"]["embedding"])
         x = emb[tokens]                                   # [B, C, D]
         b_, c = tokens.shape

@@ -14,7 +14,7 @@ of truth for how parameters get shardings in this repo:
 - the `CentralizedTrainer` shards its params through it when
   `device_args.mesh_shape` names an `mp` axis,
 - the serving `DecodeEngine` shards its weights AND its persistent KV
-  cache through it (`kv_cache_spec`) to run tensor-parallel.
+  page pool through it (`paged_kv_cache_spec`) to run tensor-parallel.
 
 Train and serve resolving through ONE table is what keeps checkpoints
 mesh-compatible across the two planes (a silently different serve layout is
@@ -251,24 +251,14 @@ def fed_data_rules(axis: str = "clients") -> Rules:
     return ((r"^(x|y|mask)$", P(axis)),)
 
 
-def kv_cache_spec(axis: str = "mp"):
-    """PartitionSpec for the DecodeEngine's persistent KV cache
-    `[L, S, max_len, H, Dh]`: heads sharded over `axis` — the decode-side
-    continuation of the column-split attention projections (each chip
-    holds the K/V of its own heads; no cross-chip traffic inside
-    attention, one all-reduce at the wo row-matmul)."""
-    from jax.sharding import PartitionSpec as P
-
-    return P(None, None, None, axis, None)
-
-
 def paged_kv_cache_spec(axis: str = "mp"):
-    """PartitionSpec for the PAGED engine KV pool
-    `[L, n_pages, page_size, H, Dh]` (serving/engine.py page_size > 0):
-    heads sharded over `axis`, page axes replicated — the same Megatron
-    continuation as `kv_cache_spec`, with the slot/time axes replaced by
-    the page pool. The int32 page table `[S, max_pages]` rides the carry
-    replicated (it is indexed identically on every chip)."""
+    """PartitionSpec for the DecodeEngine's persistent KV page pool
+    `[L, n_pages, page_size, H, Dh]` (serving/engine.py): heads sharded
+    over `axis`, page axes replicated — the decode-side continuation of
+    the column-split attention projections (each chip holds the K/V of
+    its own heads; no cross-chip traffic inside attention, one all-reduce
+    at the wo row-matmul). The int32 page table `[S, max_pages]` rides the
+    carry replicated (it is indexed identically on every chip)."""
     from jax.sharding import PartitionSpec as P
 
     return P(None, None, None, axis, None)

@@ -55,9 +55,9 @@ def lm_predictor_from_config(cfg, model, params, adapters=None,
     (YAML key `serve_args`, alias `serve` — validated at load,
     config.py): `decode_slots` > 0 starts the continuous-batching engine,
     `engine_max_len`/`engine_eos_id`/`engine_fetch_chunk`/
-    `sampler_cache_size`/`kv_cache` tune it; `kv_page_size` > 0 selects
-    the paged KV cache with `kv_n_pages`/`prefill_chunk`/`prefix_cache`
-    (engine module docstring). This is the config-side
+    `sampler_cache_size`/`kv_cache` tune it; `kv_page_size` (default 16)
+    is the page of its KV pool, with `kv_n_pages`/`prefill_chunk`/
+    `prefix_cache` (engine module docstring). This is the config-side
     consumer of cfg.serve_args; the deploy path (scheduler.start_replica)
     feeds the serve-spec dict through the SAME knob mapping
     (predictor.lm_predictor_from_serve_knobs)."""

@@ -1,69 +1,40 @@
 """Continuous-batching decode engine: slot-based LLM serving on one
-persistent, donated KV cache.
+persistent, donated, PAGED KV pool.
 
 Why: the per-request serving path (serving/predictor.py GreedyLMPredictor)
 runs each request's prefill+decode as its own device program end-to-end, so
 N concurrent users get N serialized programs — aggregate tokens/sec is flat
-in concurrency while the chip idles between requests. The decode plumbing
-already supports per-row write positions (llm/decode.py `step(params,
-adapters, cache, pos, token)` with `pos: [B]`), which is exactly the
-primitive continuous batching needs; this module turns it into an engine
-(the vLLM-style iteration-level scheduler, minus paging: slots are
-fixed-stride rows of one cache).
+in concurrency while the chip idles between requests. This module is the
+vLLM-style iteration-level scheduler over llm/decode.py's paged programs
+(`make_paged_kv_decode`): per-slot positions, block-allocated KV storage.
 
 Shape of the thing:
 
-- The engine owns S decode *slots* backed by ONE persistent KV cache
-  (`{"k","v"}: [L, S, max_len, H, Dh]`) that stays device-resident across
-  requests — no per-request cache allocation, and every jitted call
-  DONATES the carry so XLA updates it in place.
-- Admission: a free slot + a waiting request -> one bucketed prefill
-  (prompts right-padded to a power-of-two bucket, real length traced; same
-  bucketing contract as the per-request path) whose K/V rows are written
-  into the persistent cache at the slot index via `dynamic_update_slice`
-  over the slot axis. The prefill's last-position logits yield the
-  request's FIRST token inside the same program.
-- Every engine iteration advances ALL slots one token through a single
-  jitted step with per-slot `pos`, per-slot traced temperature + rng seed,
-  and an active-mask so idle slots are inert (their K/V writes land on
-  frozen positions and are fully overwritten by the next admission's
-  prefill row).
-- Retirement is decided ON DEVICE: a slot deactivates when it hits its
-  per-request token budget (`limit`) or emits `eos_id`; the host merely
-  observes the mask in fetched frames, completes the ticket, and returns
-  the slot to the free list.
-- The host loop dispatches ahead: step/admit outputs queue as device
-  arrays and are fetched in small chunks (`fetch_chunk`), so admission and
-  retirement bookkeeping overlap device execution — no per-step
-  `device_get` barrier.
-
-Compiled-program set stays BOUNDED: one step program (all S slots, every
-temperature/seed traced) + one admit program per prompt bucket
-(log2(max_len) of them at most). `program_counts()` exposes the live jit
-cache sizes; tests pin them.
-
-PAGED MODE (`page_size > 0`, ISSUE 7) rebuilds the KV storage as block
-allocation — the production serving memory + latency plane:
-
-- The cache becomes a POOL `[L, kv_n_pages, page_size, H, Dh]` plus an
-  int32 `[S, max_pages]` page table INSIDE the donated carry (the jitted
-  step gathers each slot's pages into a virtually-contiguous sequence;
-  llm/decode.py make_paged_kv_decode). Persistent HBM is
-  `kv_n_pages x page_size` token rows — sized to LIVE tokens — instead
-  of `S x max_len` whether slots use it or not; page 0 is the reserved
-  null page that absorbs inactive/padded writes.
+- The engine owns S decode *slots* backed by ONE persistent KV POOL
+  (`{"k","v"}: [L, kv_n_pages, page_size, H, Dh]`) plus an int32
+  `[S, max_pages]` page table INSIDE the donated carry: it stays
+  device-resident across requests, every jitted call DONATES the carry so
+  XLA updates it in place, and a program moves the rows it touches, never
+  the pool. Persistent HBM is `kv_n_pages x page_size` token rows; page 0
+  is the reserved null page that absorbs inactive/padded writes. The
+  default pool (`n_pages=None`) holds every slot at `max_len`; pass fewer
+  pages to size it to LIVE tokens.
 - Admission allocates a request's pages (ceil((prompt+max_new)/page_size),
   reserved up front so a mid-decode slot can never hit page exhaustion)
   from a host free list; retirement returns them. The free list + prefix
   map are host state — the page TABLE is the device-side structure the
-  kernels consume; allocation is a host decision because prefix sharing
+  programs consume; allocation is a host decision because prefix sharing
   keys on token content the device never sees.
 - CHUNKED PREFILL: admission writes the prompt in `prefill_chunk`-sized
-  pieces, ONE chunk per engine iteration, round-robin across in-flight
-  admissions — decode slots advance between chunks, so a long prompt no
-  longer stalls all S slots for its full prefill, and a short prompt
-  admitted alongside a long one reaches its first token in time
-  proportional to its OWN length.
+  pieces (0 = the whole prompt in one chunk), ONE chunk per engine
+  iteration, round-robin across in-flight admissions — decode slots
+  advance between chunks, so a long prompt does not stall all S slots for
+  its full prefill, and a short prompt admitted alongside a long one
+  reaches its first token in time proportional to its OWN length. Chunks
+  are right-padded to a power-of-two bucket (real length traced; the same
+  bucketing contract as the per-request path); the FINAL chunk's
+  last-position logits yield the request's first token inside the same
+  program.
 - PREFIX CACHE: full pages of a prompt are registered in a content-hash
   chain map (hash over token IDS per page, chained — resident pages are
   ref-counted; refs==0 entries stay resident and evict LRU, leaf-first,
@@ -72,21 +43,34 @@ allocation — the production serving memory + latency plane:
   prompt_len - 1 so the first-token logits are always computed), so
   identical system prompts — the dominant traffic shape — stop
   recomputing K/V and their TTFT goes ~flat in prompt length.
+- Every engine iteration advances ALL slots one token through a single
+  jitted step with per-slot `pos`, per-slot traced temperature + rng seed,
+  and an active-mask so idle slots are inert (their K/V writes are
+  redirected to the null page).
+- Retirement is decided ON DEVICE: a slot deactivates when it hits its
+  per-request token budget (`limit`) or emits `eos_id`; the host merely
+  observes the mask in fetched frames, completes the ticket, and returns
+  the slot and its pages to the free lists.
+- The host loop dispatches ahead: step/admit outputs queue as device
+  arrays and are fetched in small chunks (`fetch_chunk`), so admission and
+  retirement bookkeeping overlap device execution — no per-step
+  `device_get` barrier.
 
-Paged greedy output is TOKEN-IDENTICAL to the contiguous engine and the
-per-request path (pinned in tests/test_paged_engine.py), and the program
-set stays bounded: one paged step program + one chunk program per chunk
-bucket (log2(prefill_chunk) of them at most).
+Compiled-program set stays BOUNDED: one step program (all S slots, every
+temperature/seed traced) + one chunk program per chunk bucket
+(log2(prefill_chunk) + 1 of them at most). `program_counts()` exposes the
+live jit cache sizes; tests pin them.
 
-DECODE RAW SPEED (ISSUE 11) — two paged-mode legs, both token-identity
-pinned (tests/test_decode_kernel_spec.py):
+DECODE RAW SPEED (ISSUE 11) — two legs, both token-identity pinned
+(tests/test_decode_kernel_spec.py):
 
 - `paged_kernel=True` swaps the step's gather-then-attend for the fused
   Pallas paged-attention kernel (ops/paged_attention.py): pages are read
-  IN PLACE through the device-side page table, the virtually-contiguous
-  copy never materializes, per-token attention HBM traffic halves. The
-  gather path stays as the test oracle; CPU runs the same kernel under
-  interpret mode, so tier-1 exercises the real kernel body.
+  IN PLACE through the device-side page table, only those that hold a
+  position a live slot attends; the virtually-contiguous copy never
+  materializes. The gather path stays as the test oracle; CPU runs the
+  same kernel under interpret mode, so tier-1 exercises the real kernel
+  body.
 - `spec_decode="ngram"` attacks per-token latency itself: each
   iteration self-drafts `spec_k` tokens from the slot's OWN history
   (prompt-lookup n-gram — no second model), verifies the whole window
@@ -102,16 +86,18 @@ pinned (tests/test_decode_kernel_spec.py):
 
 Capacity contract per slot: `prompt_len + max_new_tokens <= max_len`
 (no step bucketing — the engine emits exactly the tokens asked for, so
-unlike the per-request path max_new_tokens is not rounded up). Paged
-mode ADDS the page-budget term: ceil((prompt + max_new) / page_size)
-must fit the usable pool (kv_n_pages - 1 — page 0 is reserved);
+unlike the per-request path max_new_tokens is not rounded up) AND the
+page-budget term: ceil((prompt + max_new) / page_size) must fit the
+usable pool (kv_n_pages - 1 — page 0 is reserved);
 `admissible()` is the one capacity oracle the predictor's routing and
 degrade refusal consult, and the submit error message states the page
 math.
 
 Equivalence contract: for identical prompts, greedy engine output is
 token-identical to the per-request path — the slot axis is data-parallel
-through the decode math (pinned in tests/test_serving_engine.py).
+through the decode math, and the page table only renames where a
+position's K/V rows live (pinned in tests/test_serving_engine.py and
+tests/test_paged_engine.py).
 
 FLEET ROBUSTNESS (ISSUE 9) — the three production failure shapes a
 federated deployment meets are model churn, overload, and mid-request
@@ -419,8 +405,8 @@ def prepare_adapter_swap(current: Pytree, adapters: Pytree, n_layers: int,
 
 class _SlotState:
     """Host-side view of an occupied slot (the device mask is the source
-    of truth for retirement; this mirrors it frame-by-frame). Paged mode
-    additionally tracks what retirement must release: `entries` (prefix
+    of truth for retirement; this mirrors it frame-by-frame), with what
+    retirement must release: `entries` (prefix
     pages this slot holds a ref on) and `private` (pages owned outright —
     the prompt tail, the decode budget, and any page whose registration
     lost a race to a concurrent identical prompt)."""
@@ -450,37 +436,35 @@ class DecodeEngine:
     per-request path, which compiles a static-k cutoff).
 
     `mesh` (a jax Mesh with an `mp` axis) runs the engine TENSOR-PARALLEL:
-    weights and the persistent KV cache shard over `mp` via the
+    weights and the persistent KV pool shard over `mp` via the
     parallel/partition.py rule registry (`partition_rules` overrides the
     default `transformer_lm` table) — the scale-out path for models whose
-    KV cache + weights exceed one chip's HBM. Greedy output is
-    token-identical across mp sizes (pinned at mp=1 vs mp=2 in tests).
+    KV pool + weights exceed one chip's HBM (pages replicate; the pool
+    shards its heads axis). Greedy output is token-identical across mp
+    sizes (pinned at mp=1 vs mp=2 in tests).
 
-    `page_size > 0` selects the PAGED KV cache (module docstring):
-    `n_pages` sizes the pool (default = contiguous capacity + the null
-    page; pass less to trade peak concurrency for HBM), `prefill_chunk`
-    bounds how many prompt tokens one admission program processes
-    (0 = whole prompt in one chunk), `prefix_cache` toggles content-hash
-    prefix page reuse. Composes with `mesh` (pages replicate; the pool
-    shards its heads axis). Paged greedy output is token-identical to
-    contiguous (pinned in tests/test_paged_engine.py).
+    `page_size` (>= 1) is the KV page (module docstring): `n_pages` sizes
+    the pool (default = every slot at max_len + the null page; pass less
+    to trade peak concurrency for HBM), `prefill_chunk` bounds how many
+    prompt tokens one admission program processes (0 = whole prompt in
+    one chunk), `prefix_cache` toggles content-hash prefix page reuse.
 
-    `paged_kernel=True` (paged only) runs decode attention through the
-    fused Pallas kernel (ops/paged_attention.py — pages read in place,
-    no gather copy); `spec_decode="ngram"` + `spec_k` (paged only) turns
-    each iteration into a self-drafted speculative verify window that
-    emits up to spec_k + 1 tokens, greedy-exact (module docstring).
-    Both compose with each other and with `mesh`.
+    `paged_kernel=True` runs decode attention through the fused Pallas
+    kernel (ops/paged_attention.py — pages read in place, no gather
+    copy); `spec_decode="ngram"` + `spec_k` turns each iteration into a
+    self-drafted speculative verify window that emits up to spec_k + 1
+    tokens, greedy-exact (module docstring). Both compose with each
+    other and with `mesh`.
 
-    `kv_quant="int8"` (paged only) stores the persistent pool in int8
-    with per-(page, head) scales riding the carry — half the KV HBM per
-    slot, so ~2x decode slots at a fixed pool budget, for a <1pt greedy
+    `kv_quant="int8"` stores the persistent pool in int8 with
+    per-(page, head) scales riding the carry — half the KV HBM per slot,
+    so ~2x decode slots at a fixed pool budget, for a <1pt greedy
     match-rate delta (quantize-at-write / dequantize-at-gather; the
-    Pallas kernel dequants each slab in VMEM). `admit_batch` > 1 (paged
-    only) admits up to that many same-bucket pending prompts per engine
-    iteration through ONE batched chunk program — burst TTFT p99 stops
-    paying one dispatch per request. Both compose with each other, the
-    kernel, spec decode, and `mesh`."""
+    Pallas kernel dequants each slab in VMEM). `admit_batch` > 1 admits
+    up to that many same-bucket pending prompts per engine iteration
+    through ONE batched chunk program — burst TTFT p99 stops paying one
+    dispatch per request. Both compose with each other, the kernel, spec
+    decode, and `mesh`."""
 
     def __init__(self, model, params: Pytree,
                  adapters: Optional[Pytree] = None, *,
@@ -488,15 +472,14 @@ class DecodeEngine:
                  eos_id: Optional[int] = None,
                  dtype=None, fetch_chunk: int = 2,
                  mesh=None, partition_rules=None,
-                 page_size: int = 0, n_pages: Optional[int] = None,
+                 page_size: int = 16, n_pages: Optional[int] = None,
                  prefill_chunk: int = 0, prefix_cache: bool = True,
                  paged_kernel: bool = False, spec_decode: str = "off",
                  spec_k: int = 4, kv_quant: str = "off",
                  admit_batch: int = 1):
         from ..llm.decode import (
-            layer_scope, make_kv_decode, make_paged_kv_decode,
-            ngram_propose, require_servable, stack_adapter_blocks,
-            stack_blocks,
+            layer_scope, make_paged_kv_decode, ngram_propose,
+            require_servable, stack_adapter_blocks, stack_blocks,
         )
 
         if n_slots < 1:
@@ -507,65 +490,41 @@ class DecodeEngine:
         self.max_len = int(max_len)
         self.n_slots = int(n_slots)
         self.fetch_chunk = max(1, int(fetch_chunk))
-        # ---------------------------------------------------- paged layout
-        # page_size > 0 selects the block/paged KV cache; 0 keeps the
-        # contiguous [L, S, max_len, H, Dh] layout (still preferable when
-        # every request genuinely runs to ~max_len: no gather, no page
-        # bookkeeping). The paged knobs are refused in contiguous mode so
-        # a config asking for them is never silently ignored.
-        self._paged = int(page_size or 0) > 0
-        if self._paged:
-            self._page_size = int(page_size)
-            self._max_pages = -(-self.max_len // self._page_size)
-            # default pool = contiguous capacity + the reserved null page;
-            # the memory win comes from passing a SMALLER kv_n_pages
-            self._n_pages = (int(n_pages) if n_pages
-                             else self.n_slots * self._max_pages + 1)
-            self._usable = self._n_pages - 1   # page 0 is the null page
-            if self._n_pages < 2:
-                raise ValueError(
-                    f"kv_n_pages must be >= 2 (page 0 is the reserved "
-                    f"null page); got {self._n_pages}")
-            if int(prefill_chunk) < 0:
-                raise ValueError(
-                    f"prefill_chunk must be >= 0 (0 = whole-prompt "
-                    f"chunks); got {prefill_chunk}")
-            self._prefill_chunk = int(prefill_chunk)
-            self._prefix_on = bool(prefix_cache)
-            self._free_pages: list[int] = list(range(1, self._n_pages))
-            self._prefix: dict[bytes, _PrefixEntry] = {}
-            self._ticks = 0
-            _mx.set_gauge("serving.kv_pages_budget", self._usable)
-            _mx.set_gauge("serving.engine.table_pages",
-                          self.n_slots * self._max_pages)
-            _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
-        elif n_pages or prefill_chunk:
+        # ------------------------------------------------------ page pool
+        if int(page_size) < 1:
             raise ValueError(
-                "kv_n_pages/prefill_chunk configure the PAGED cache — "
-                "set page_size > 0 (they would be silently ignored in "
-                "contiguous mode)")
+                f"page_size must be >= 1 KV rows a page; got {page_size}")
+        self._page_size = int(page_size)
+        self._max_pages = -(-self.max_len // self._page_size)
+        # default pool = every slot at max_len + the reserved null page;
+        # the memory win comes from passing a SMALLER kv_n_pages
+        self._n_pages = (int(n_pages) if n_pages
+                         else self.n_slots * self._max_pages + 1)
+        self._usable = self._n_pages - 1   # page 0 is the null page
+        if self._n_pages < 2:
+            raise ValueError(
+                f"kv_n_pages must be >= 2 (page 0 is the reserved "
+                f"null page); got {self._n_pages}")
+        if int(prefill_chunk) < 0:
+            raise ValueError(
+                f"prefill_chunk must be >= 0 (0 = whole-prompt "
+                f"chunks); got {prefill_chunk}")
+        self._prefill_chunk = int(prefill_chunk)
+        self._prefix_on = bool(prefix_cache)
+        self._free_pages: list[int] = list(range(1, self._n_pages))
+        self._prefix: dict[bytes, _PrefixEntry] = {}
+        self._ticks = 0
+        _mx.set_gauge("serving.kv_pages_budget", self._usable)
+        _mx.set_gauge("serving.engine.table_pages",
+                      self.n_slots * self._max_pages)
+        _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
         # ------------------------------------------- decode-speed knobs
-        # Both legs live on the paged layout: the kernel reads the page
-        # pool in place, and speculation's verify-and-rollback rides the
-        # page table (rejected positions are re-written by the next
-        # verify window). Asking for either without paging would be
-        # silently ignored — refuse instead.
         self._kernel_on = bool(paged_kernel)
-        if self._kernel_on and not self._paged:
-            raise ValueError(
-                "paged_kernel fuses attention over the PAGED KV pool — "
-                "set page_size > 0 (in contiguous mode the knob would be "
-                "silently ignored)")
         if spec_decode not in ("off", "ngram"):
             raise ValueError(
                 f"spec_decode must be 'off' or 'ngram'; got {spec_decode!r}")
         self._spec_on = spec_decode == "ngram"
         self._spec_k = int(spec_k)
-        if self._spec_on and not self._paged:
-            raise ValueError(
-                "spec_decode verifies draft windows over the PAGED KV "
-                "cache (write positions roll back through the page "
-                "table) — set page_size > 0")
         if self._spec_on and self._spec_k < 1:
             raise ValueError(
                 f"spec_k must be >= 1 draft tokens; got {spec_k}")
@@ -573,21 +532,10 @@ class DecodeEngine:
             raise ValueError(
                 f"kv_quant must be 'off' or 'int8'; got {kv_quant!r}")
         self._quant = kv_quant == "int8"
-        if self._quant and not self._paged:
-            raise ValueError(
-                "kv_quant stores the PAGED KV pool in int8 (per-page-"
-                "per-head scales ride the page table) — set page_size "
-                "> 0 (in contiguous mode the knob would be silently "
-                "ignored)")
         self._admit_batch = int(admit_batch)
         if self._admit_batch < 1:
             raise ValueError(
                 f"admit_batch must be >= 1; got {admit_batch}")
-        if self._admit_batch > 1 and not self._paged:
-            raise ValueError(
-                "admit_batch groups PAGED admission chunks into one "
-                "batched prefill program — set page_size > 0 (in "
-                "contiguous mode the knob would be silently ignored)")
         self._admissions: deque[_Admission] = deque()
         # -1 never matches a token id, so eos retirement is inert
         self._eos = -1 if eos_id is None else int(eos_id)
@@ -607,8 +555,8 @@ class DecodeEngine:
         # registry (parallel/partition.py — the SAME table the round
         # programs and CentralizedTrainer resolve, so train and serve
         # layouts cannot drift), adapters replicate (they are the round
-        # payload), and the persistent KV cache [L, S, max_len, H, Dh]
-        # shards its HEADS axis (partition.kv_cache_spec) — the decode-side
+        # payload), and the persistent KV pool [L, P, page, H, Dh] shards
+        # its HEADS axis (partition.paged_kv_cache_spec) — the decode-side
         # continuation of the column-split attention projections. GSPMD
         # inserts the one all-reduce per block at the wo row matmul; with
         # mp=1 the placement is a no-op and the engine stays token-
@@ -630,7 +578,7 @@ class DecodeEngine:
             if model.n_heads % mp:
                 raise ValueError(
                     f"n_heads {model.n_heads} is not divisible by mp={mp}"
-                    " — the KV cache shards the heads axis")
+                    " — the KV pool shards the heads axis")
             rules = (partition_rules
                      if partition_rules is not None
                      else partition.transformer_lm_rules("mp"))
@@ -641,22 +589,15 @@ class DecodeEngine:
             if self.adapters is not None:
                 self.adapters = partition.shard_params(
                     self.adapters, mesh, "lora")
-            # both layouts are 5-D with heads at axis 3; the paged spec is
-            # its own registry entry so the page axes are named, not
-            # incidentally covered
-            self.kv_spec = (partition.paged_kv_cache_spec("mp")
-                            if self._paged else partition.kv_cache_spec("mp"))
+            self.kv_spec = partition.paged_kv_cache_spec("mp")
             kv_sharding = NamedSharding(mesh, self.kv_spec)
             rep_sharding = NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
 
-        if self._paged:
-            (chunk_fn, paged_step, paged_verify,
-             chunk_batch_fn) = make_paged_kv_decode(
-                model.n_heads, self._page_size, dtype=kv_dtype,
-                kernel=self._kernel_on, mesh=mesh, quant=self._quant)
-        else:
-            prefill, step = make_kv_decode(model.n_heads, dtype=kv_dtype)
+        (chunk_fn, paged_step, paged_verify,
+         chunk_batch_fn) = make_paged_kv_decode(
+            model.n_heads, self._page_size, dtype=kv_dtype,
+            kernel=self._kernel_on, mesh=mesh, quant=self._quant)
         S, eos, max_len_ = self.n_slots, self._eos, self.max_len
 
         def pick(logits, temp, key):
@@ -677,12 +618,93 @@ class DecodeEngine:
                 return jnp.where(temp > 0.0, sampled.astype(jnp.int32),
                                  greedy)
 
-        def _decode_tail(carry, cache, logits, extra=None):
-            """Shared post-forward step logic: sample/argmax the next
-            token per slot, advance active positions, retire on budget or
-            eos — ON DEVICE. `extra` carries layout-specific keys (the
-            paged page table) through unchanged."""
+        def _admit(params, adapters, carry, tokens, t0, clen, slot,
+                   row, temp, seed, limit, final, plen):
+            """ONE chunk of one request's prefill into the paged
+            carry: the slot's page-table row is (re)written, the
+            chunk's K/V land in its pages, and — on the FINAL chunk —
+            the last-position logits yield the first token and the
+            slot's rows arm. Non-final chunks set the same rows
+            (harmless while active stays False) so one program covers
+            every chunk; everything but the token buffer is traced."""
+            pages = carry["pages"].at[slot].set(row)
+            cache, logits = chunk_fn(params, adapters, carry["cache"],
+                                     row, tokens, t0, clen)
+            key = jax.random.fold_in(jax.random.key(seed), plen)
+            first = pick(logits[0], temp, key)
+            # active iff this was the last chunk, the first token did
+            # not end it, and there is budget left (limit = plen +
+            # max_new - 1: the position after which no step token is owed)
+            active = final & (first != eos) & (plen < limit)
+            out = {
+                "cache": cache,
+                "pages": pages,
+                "pos": carry["pos"].at[slot].set(plen),
+                "tok": carry["tok"].at[slot].set(first),
+                "active": carry["active"].at[slot].set(active),
+                "temp": carry["temp"].at[slot].set(temp),
+                "seed": carry["seed"].at[slot].set(seed),
+                "limit": carry["limit"].at[slot].set(limit),
+            }
+            if self._spec_on:
+                # the chunk's real tokens land in the slot's history
+                # row (the n-gram draft source); padded tail indices
+                # point past max_len and are dropped by the scatter
+                cidx = jnp.arange(tokens.shape[1])
+                hidx = jnp.where(cidx < clen, t0 + cidx, max_len_)
+                out["hist"] = carry["hist"].at[slot, hidx].set(
+                    tokens[0])
+            return out, first
+
+        def _admit_many(params, adapters, carry, tokens, t0s, clens,
+                        slots, rows, temps, seeds, limits, finals,
+                        plens):
+            """admit_batch > 1: B same-bucket prefill chunks through
+            ONE batched chunk program (llm/decode.py chunk_batch) —
+            page reservations were already claimed host-side in one
+            critical section; this is the device half. PAD rows
+            (batch padded to its pow2 bucket) carry slot == n_slots,
+            which every per-slot scatter DROPS (out-of-range scatter
+            indices are discarded under jit), an all-zero page row
+            (writes land on the null page) and clen 0."""
+            pages = carry["pages"].at[slots].set(rows)
+            cache, logits = chunk_batch_fn(
+                params, adapters, carry["cache"], rows, tokens,
+                t0s, clens)
+            keys = jax.vmap(
+                lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
+                    seeds, plens)
+            firsts = pick(logits, temps, keys)
+            actives = finals & (firsts != eos) & (plens < limits)
+            out = {
+                "cache": cache,
+                "pages": pages,
+                "pos": carry["pos"].at[slots].set(plens),
+                "tok": carry["tok"].at[slots].set(firsts),
+                "active": carry["active"].at[slots].set(actives),
+                "temp": carry["temp"].at[slots].set(temps),
+                "seed": carry["seed"].at[slots].set(seeds),
+                "limit": carry["limit"].at[slots].set(limits),
+            }
+            if self._spec_on:
+                cidx = jnp.arange(tokens.shape[1])[None, :]
+                hidx = jnp.where(cidx < clens[:, None],
+                                 t0s[:, None] + cidx, max_len_)
+                out["hist"] = carry["hist"].at[
+                    slots[:, None], hidx].set(tokens)
+            return out, firsts
+
+        def _step_all(params, adapters, carry):
+            """Advance every slot one token through ONE program: sample or
+            argmax the next token per slot, advance active positions,
+            retire on budget or eos — ON DEVICE. The active mask rides
+            INTO the forward: an inactive slot's stale page-table entry
+            may point at a page re-allocated to another request, so its
+            garbage write is redirected to the null page."""
             active, temp = carry["active"], carry["temp"]
+            cache, logits = paged_step(
+                params, adapters, carry["cache"], carry["pages"],
+                carry["pos"], carry["tok"], active)
             keys = jax.vmap(
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1))(
                     carry["seed"], carry["pos"])
@@ -691,6 +713,7 @@ class DecodeEngine:
             act2 = active & (pos2 < carry["limit"]) & (nxt != eos)
             out = {
                 "cache": cache,
+                "pages": carry["pages"],
                 "pos": pos2,
                 "tok": jnp.where(active, nxt, carry["tok"]),
                 "active": act2,
@@ -698,223 +721,90 @@ class DecodeEngine:
                 "seed": carry["seed"],
                 "limit": carry["limit"],
             }
-            if extra:
-                out.update(extra)
+            if self._spec_on:
+                out["hist"] = carry["hist"]
             # emitted token per slot + the entry mask saying which are real
             return out, (nxt, active)
 
-        if self._paged:
-            def _admit(params, adapters, carry, tokens, t0, clen, slot,
-                       row, temp, seed, limit, final, plen):
-                """ONE chunk of one request's prefill into the paged
-                carry: the slot's page-table row is (re)written, the
-                chunk's K/V land in its pages, and — on the FINAL chunk —
-                the last-position logits yield the first token and the
-                slot's rows arm. Non-final chunks set the same rows
-                (harmless while active stays False) so one program covers
-                every chunk; everything but the token buffer is traced."""
-                pages = carry["pages"].at[slot].set(row)
-                cache, logits = chunk_fn(params, adapters, carry["cache"],
-                                         row, tokens, t0, clen)
-                key = jax.random.fold_in(jax.random.key(seed), plen)
-                first = pick(logits[0], temp, key)
-                # active iff this was the last chunk, the first token did
-                # not end it, and there is budget left (limit = plen +
-                # max_new - 1, as in contiguous mode)
-                active = final & (first != eos) & (plen < limit)
-                out = {
-                    "cache": cache,
-                    "pages": pages,
-                    "pos": carry["pos"].at[slot].set(plen),
-                    "tok": carry["tok"].at[slot].set(first),
-                    "active": carry["active"].at[slot].set(active),
-                    "temp": carry["temp"].at[slot].set(temp),
-                    "seed": carry["seed"].at[slot].set(seed),
-                    "limit": carry["limit"].at[slot].set(limit),
-                }
-                if self._spec_on:
-                    # the chunk's real tokens land in the slot's history
-                    # row (the n-gram draft source); padded tail indices
-                    # point past max_len and are dropped by the scatter
-                    cidx = jnp.arange(tokens.shape[1])
-                    hidx = jnp.where(cidx < clen, t0 + cidx, max_len_)
-                    out["hist"] = carry["hist"].at[slot, hidx].set(
-                        tokens[0])
-                return out, first
+        spec_c = self._spec_k + 1
 
-            def _admit_many(params, adapters, carry, tokens, t0s, clens,
-                            slots, rows, temps, seeds, limits, finals,
-                            plens):
-                """admit_batch > 1: B same-bucket prefill chunks through
-                ONE batched chunk program (llm/decode.py chunk_batch) —
-                page reservations were already claimed host-side in one
-                critical section; this is the device half. PAD rows
-                (batch padded to its pow2 bucket) carry slot == n_slots,
-                which every per-slot scatter DROPS (out-of-range scatter
-                indices are discarded under jit), an all-zero page row
-                (writes land on the null page) and clen 0."""
-                pages = carry["pages"].at[slots].set(rows)
-                cache, logits = chunk_batch_fn(
-                    params, adapters, carry["cache"], rows, tokens,
-                    t0s, clens)
-                keys = jax.vmap(
-                    lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
-                        seeds, plens)
-                firsts = pick(logits, temps, keys)
-                actives = finals & (firsts != eos) & (plens < limits)
-                out = {
-                    "cache": cache,
-                    "pages": pages,
-                    "pos": carry["pos"].at[slots].set(plens),
-                    "tok": carry["tok"].at[slots].set(firsts),
-                    "active": carry["active"].at[slots].set(actives),
-                    "temp": carry["temp"].at[slots].set(temps),
-                    "seed": carry["seed"].at[slots].set(seeds),
-                    "limit": carry["limit"].at[slots].set(limits),
-                }
-                if self._spec_on:
-                    cidx = jnp.arange(tokens.shape[1])[None, :]
-                    hidx = jnp.where(cidx < clens[:, None],
-                                     t0s[:, None] + cidx, max_len_)
-                    out["hist"] = carry["hist"].at[
-                        slots[:, None], hidx].set(tokens)
-                return out, firsts
-
-            def _step_all(params, adapters, carry):
-                """Advance every slot one token. The active mask rides
-                INTO the kernel: an inactive slot's stale page-table entry
-                may point at a page re-allocated to another request, so
-                its garbage write is redirected to the null page instead
-                of parking on a frozen position."""
-                cache, logits = paged_step(
-                    params, adapters, carry["cache"], carry["pages"],
-                    carry["pos"], carry["tok"], carry["active"])
-                extra = {"pages": carry["pages"]}
-                if self._spec_on:
-                    extra["hist"] = carry["hist"]
-                return _decode_tail(carry, cache, logits, extra=extra)
-
-            spec_c = self._spec_k + 1
-
-            def _spec_all(params, adapters, carry):
-                """Speculative iteration, ALL slots: self-draft spec_k
-                tokens from each slot's own history (ngram_propose),
-                verify the whole window [tok, d1..dk] in ONE target
-                forward over the paged cache, emit the longest prefix
-                the target itself would have produced. By construction
-                the emitted stream is token-identical to plain decode:
-                token i is only accepted when every input before it was
-                the target's own pick, so its logits — and therefore
-                its pick, greedy or seeded — are exactly the plain
-                path's. Rejected positions' K/V writes are garbage, and
-                the rollback is positional: pos advances only past
-                accepted tokens, so the NEXT window re-writes those
-                very pages before anything can attend to them."""
-                s_idx = jnp.arange(S)
-                pos, tok = carry["pos"], carry["tok"]
-                active, temp = carry["active"], carry["temp"]
-                # the current token is real history at its write position
-                # — anchor it before drafting so the trailing n-gram
-                # includes it. INACTIVE slots write nothing (index
-                # max_len drops): their pos/tok are stale, and a slot
-                # mid-chunked-admission shares this hist buffer — a
-                # stale write could corrupt the incoming prompt's
-                # history and poison its draft anchors (never its
-                # output; drafts are proposals)
-                hist = carry["hist"].at[
-                    s_idx, jnp.where(active, pos, max_len_)].set(tok)
-                drafts = ngram_propose(hist, pos, spec_c - 1)
-                inputs = jnp.concatenate([tok[:, None], drafts], axis=1)
-                widx = pos[:, None] + jnp.arange(spec_c)
-                # record the window inputs (accepted ones are permanent
-                # history; rejected ones sit past the new pos and are
-                # overwritten before the draft matcher can anchor on
-                # them); inactive slots and out-of-range indices drop
-                hist = hist.at[
-                    s_idx[:, None],
-                    jnp.where(active[:, None] & (widx < max_len_),
-                              widx, max_len_)].set(inputs)
-                cache, logits = paged_verify(
-                    params, adapters, carry["cache"], carry["pages"],
-                    pos, inputs, active)
-                # the SAME rng schedule as the plain step (fold_in at
-                # write-position + 1) — seeded sampling stays pinned
-                # across spec on/off
-                keys = jax.vmap(
-                    lambda s, p: jax.vmap(
-                        lambda q: jax.random.fold_in(
-                            jax.random.key(s), q + 1))(
-                                p + jnp.arange(spec_c)))(
-                                    carry["seed"], pos)
-                # THE pick (greedy/sampled select), vmapped over the
-                # window axis — one selection implementation, so the
-                # spec-on == spec-off identity can't drift from a
-                # future pick() edit
-                g = jax.vmap(pick, in_axes=(1, None, 1),
-                             out_axes=1)(logits, temp, keys)
-                # token i is emitted iff every input before it was the
-                # target's own pick, nothing before it ended the
-                # request, and the budget has room — the in-jit
-                # statement of greedy-exact acceptance
-                emits = [active]
-                for i in range(1, spec_c):
-                    emits.append(emits[-1]
-                                 & (inputs[:, i] == g[:, i - 1])
-                                 & (g[:, i - 1] != eos)
-                                 & (pos + i < carry["limit"]))
-                emit = jnp.stack(emits, axis=1)
-                n_acc = emit.sum(axis=1).astype(jnp.int32)
-                last = g[s_idx, jnp.maximum(n_acc - 1, 0)]
-                pos2 = jnp.where(active, pos + n_acc, pos)
-                tok2 = jnp.where(active, last, tok)
-                act2 = active & (pos2 < carry["limit"]) & (last != eos)
-                out = {"cache": cache, "pages": carry["pages"],
-                       "pos": pos2, "tok": tok2, "active": act2,
-                       "temp": temp, "seed": carry["seed"],
-                       "limit": carry["limit"], "hist": hist}
-                return out, (g, jnp.where(active, n_acc, 0))
-        else:
-            def _admit(params, adapters, carry, tokens, length, slot, temp,
-                       seed, limit):
-                """Prefill one request into slot `slot` of the donated
-                carry: K/V rows land at the slot index of the persistent
-                cache, the prompt's last-position logits yield the first
-                token, and the slot's pos/tok/active/temp/seed/limit rows
-                are set."""
-                row, logits = prefill(params, adapters, tokens, max_len_,
-                                      length=length)
-                key = jax.random.fold_in(jax.random.key(seed), length)
-                first = pick(logits[0], temp, key)
-                start = (0, slot, 0, 0, 0)
-                cache = {
-                    "k": jax.lax.dynamic_update_slice(
-                        carry["cache"]["k"], row["k"], start),
-                    "v": jax.lax.dynamic_update_slice(
-                        carry["cache"]["v"], row["v"], start),
-                }
-                # active iff the first token did not end it and there is
-                # budget left (limit = length + max_new - 1: the position
-                # after which no further step token is owed)
-                active = (first != eos) & (length < limit)
-                return {
-                    "cache": cache,
-                    "pos": carry["pos"].at[slot].set(length),
-                    "tok": carry["tok"].at[slot].set(first),
-                    "active": carry["active"].at[slot].set(active),
-                    "temp": carry["temp"].at[slot].set(temp),
-                    "seed": carry["seed"].at[slot].set(seed),
-                    "limit": carry["limit"].at[slot].set(limit),
-                }, first
-
-            def _step_all(params, adapters, carry):
-                """Advance every slot one token through ONE program.
-                Inactive slots are inert: pos frozen, tok unchanged, their
-                (garbage) K/V write lands on a frozen position that the
-                next admission's full prefill row overwrites."""
-                cache, logits = step(params, adapters, carry["cache"],
-                                     carry["pos"], carry["tok"])
-                return _decode_tail(carry, cache, logits)
-
+        def _spec_all(params, adapters, carry):
+            """Speculative iteration, ALL slots: self-draft spec_k
+            tokens from each slot's own history (ngram_propose),
+            verify the whole window [tok, d1..dk] in ONE target
+            forward over the paged cache, emit the longest prefix
+            the target itself would have produced. By construction
+            the emitted stream is token-identical to plain decode:
+            token i is only accepted when every input before it was
+            the target's own pick, so its logits — and therefore
+            its pick, greedy or seeded — are exactly the plain
+            path's. Rejected positions' K/V writes are garbage, and
+            the rollback is positional: pos advances only past
+            accepted tokens, so the NEXT window re-writes those
+            very pages before anything can attend to them."""
+            s_idx = jnp.arange(S)
+            pos, tok = carry["pos"], carry["tok"]
+            active, temp = carry["active"], carry["temp"]
+            # the current token is real history at its write position
+            # — anchor it before drafting so the trailing n-gram
+            # includes it. INACTIVE slots write nothing (index
+            # max_len drops): their pos/tok are stale, and a slot
+            # mid-chunked-admission shares this hist buffer — a
+            # stale write could corrupt the incoming prompt's
+            # history and poison its draft anchors (never its
+            # output; drafts are proposals)
+            hist = carry["hist"].at[
+                s_idx, jnp.where(active, pos, max_len_)].set(tok)
+            drafts = ngram_propose(hist, pos, spec_c - 1)
+            inputs = jnp.concatenate([tok[:, None], drafts], axis=1)
+            widx = pos[:, None] + jnp.arange(spec_c)
+            # record the window inputs (accepted ones are permanent
+            # history; rejected ones sit past the new pos and are
+            # overwritten before the draft matcher can anchor on
+            # them); inactive slots and out-of-range indices drop
+            hist = hist.at[
+                s_idx[:, None],
+                jnp.where(active[:, None] & (widx < max_len_),
+                          widx, max_len_)].set(inputs)
+            cache, logits = paged_verify(
+                params, adapters, carry["cache"], carry["pages"],
+                pos, inputs, active)
+            # the SAME rng schedule as the plain step (fold_in at
+            # write-position + 1) — seeded sampling stays pinned
+            # across spec on/off
+            keys = jax.vmap(
+                lambda s, p: jax.vmap(
+                    lambda q: jax.random.fold_in(
+                        jax.random.key(s), q + 1))(
+                            p + jnp.arange(spec_c)))(
+                                carry["seed"], pos)
+            # THE pick (greedy/sampled select), vmapped over the
+            # window axis — one selection implementation, so the
+            # spec-on == spec-off identity can't drift from a
+            # future pick() edit
+            g = jax.vmap(pick, in_axes=(1, None, 1),
+                         out_axes=1)(logits, temp, keys)
+            # token i is emitted iff every input before it was the
+            # target's own pick, nothing before it ended the
+            # request, and the budget has room — the in-jit
+            # statement of greedy-exact acceptance
+            emits = [active]
+            for i in range(1, spec_c):
+                emits.append(emits[-1]
+                             & (inputs[:, i] == g[:, i - 1])
+                             & (g[:, i - 1] != eos)
+                             & (pos + i < carry["limit"]))
+            emit = jnp.stack(emits, axis=1)
+            n_acc = emit.sum(axis=1).astype(jnp.int32)
+            last = g[s_idx, jnp.maximum(n_acc - 1, 0)]
+            pos2 = jnp.where(active, pos + n_acc, pos)
+            tok2 = jnp.where(active, last, tok)
+            act2 = active & (pos2 < carry["limit"]) & (last != eos)
+            out = {"cache": cache, "pages": carry["pages"],
+                   "pos": pos2, "tok": tok2, "active": act2,
+                   "temp": temp, "seed": carry["seed"],
+                   "limit": carry["limit"], "hist": hist}
+            return out, (g, jnp.where(active, n_acc, 0))
         # the carry is DONATED: the cache never round-trips host<->device
         # and XLA may update the slot rows in place. On an mp mesh the
         # carry's output shardings are PINNED (cache on the heads split,
@@ -934,7 +824,7 @@ class DecodeEngine:
             if self._spec_on:
                 self._spec_jit = _mx.track_jit(
                     jax.jit(_spec_all, donate_argnums=(2,)), "engine_spec")
-            if self._paged and self._admit_batch > 1:
+            if self._admit_batch > 1:
                 self._admit_many_jit = _mx.track_jit(jax.jit(
                     _admit_many, donate_argnums=(2,)), "engine_admit_many")
             carry_sh = None
@@ -945,6 +835,7 @@ class DecodeEngine:
             # donated in-place update into a full cache copy
             carry_sh = {
                 "cache": {"k": kv_sharding, "v": kv_sharding},
+                "pages": rep_sharding,
                 "pos": rep_sharding, "tok": rep_sharding,
                 "active": rep_sharding, "temp": rep_sharding,
                 "seed": rep_sharding, "limit": rep_sharding,
@@ -954,8 +845,6 @@ class DecodeEngine:
                     mesh, partition.paged_kv_scale_spec("mp"))
                 carry_sh["cache"]["ks"] = scale_sharding
                 carry_sh["cache"]["vs"] = scale_sharding
-            if self._paged:
-                carry_sh["pages"] = rep_sharding
             if self._spec_on:
                 carry_sh["hist"] = rep_sharding
             self._admit_jit = _mx.track_jit(jax.jit(
@@ -971,18 +860,15 @@ class DecodeEngine:
                     out_shardings=(carry_sh,
                                    (rep_sharding, rep_sharding))),
                     "engine_spec")
-            if self._paged and self._admit_batch > 1:
+            if self._admit_batch > 1:
                 self._admit_many_jit = _mx.track_jit(jax.jit(
                     _admit_many, donate_argnums=(2,),
                     out_shardings=(carry_sh, rep_sharding)),
                     "engine_admit_many")
 
         head = model.d_model // model.n_heads
-        if self._paged:
-            z = (model.n_layers, self._n_pages, self._page_size,
-                 model.n_heads, head)
-        else:
-            z = (model.n_layers, S, self.max_len, model.n_heads, head)
+        z = (model.n_layers, self._n_pages, self._page_size,
+             model.n_heads, head)
         pool_dtype = jnp.int8 if self._quant else kv_dtype
         cache = {"k": jnp.zeros(z, pool_dtype),
                  "v": jnp.zeros(z, pool_dtype)}
@@ -998,6 +884,7 @@ class DecodeEngine:
         _mx.set_gauge("serving.kv_bytes_per_slot", kv_bytes // S)
         self._carry = {
             "cache": cache,
+            "pages": jnp.zeros((S, self._max_pages), jnp.int32),
             "pos": jnp.zeros((S,), jnp.int32),
             "tok": jnp.zeros((S,), jnp.int32),
             "active": jnp.zeros((S,), bool),
@@ -1005,9 +892,6 @@ class DecodeEngine:
             "seed": jnp.zeros((S,), jnp.uint32),
             "limit": jnp.zeros((S,), jnp.int32),
         }
-        if self._paged:
-            self._carry["pages"] = jnp.zeros((S, self._max_pages),
-                                             jnp.int32)
         if self._spec_on:
             # per-slot token history (prompt + generated): the draft
             # source, written by admission chunks and the verify
@@ -1197,27 +1081,21 @@ class DecodeEngine:
     # -------------------------------------------------------------- capacity
     def admissible(self, prompt_len: int, max_new: int) -> bool:
         """THE engine capacity oracle: True iff a (prompt_len, max_new)
-        request can ever be admitted. Contiguous: prompt + max_new <=
-        max_len. Paged: additionally ceil((prompt + max_new) / page_size)
-        <= the usable page budget. The predictor's routing consults this
+        request can ever be admitted: prompt + max_new <= max_len AND
+        ceil((prompt + max_new) / page_size) <= the usable page budget.
+        The predictor's routing consults this
         (not static max_len math) so a request the page budget refuses
         falls back to the per-request path instead of 400ing, and one
         paging admits is never degraded into a per-request 400."""
         prompt_len, max_new = int(prompt_len), int(max_new)
         if prompt_len + max_new > self.max_len:
             return False
-        if self._paged:
-            need = -(-(prompt_len + max_new) // self._page_size)
-            return need <= self._usable
-        return True
+        need = -(-(prompt_len + max_new) // self._page_size)
+        return need <= self._usable
 
     def capacity_error(self, prompt_len: int, max_new: int) -> str:
         """The message submit() raises for an inadmissible request —
-        states the page math in paged mode so a 400 is actionable."""
-        if not self._paged:
-            return (f"prompt {prompt_len} + max_new_tokens {max_new} "
-                    f"exceeds max_len {self.max_len} (engine slot capacity "
-                    "contract: prompt + max_new_tokens <= max_len)")
+        states the page math so a 400 is actionable."""
         tot = prompt_len + max_new
         need = -(-tot // self._page_size)
         return (f"prompt {prompt_len} + max_new_tokens {max_new} = {tot} "
@@ -1225,17 +1103,17 @@ class DecodeEngine:
                 f"pages, but the engine budget is {self._usable} usable "
                 f"pages (kv_n_pages {self._n_pages} minus the reserved "
                 f"null page) with per-request cap max_len {self.max_len} "
-                "(paged capacity contract: prompt + max_new_tokens <= "
+                "(capacity contract: prompt + max_new_tokens <= "
                 "max_len AND ceil((prompt + max_new_tokens) / "
                 "kv_page_size) <= kv_n_pages - 1)")
 
     # ------------------------------------------------------- introspection
     @property
     def kv_page_size(self) -> int:
-        """Page size of the paged KV cache (0 = contiguous layout) —
-        advertised on /info so the gateway's prefix-affinity hash uses
-        the replica's real page geometry."""
-        return self._page_size if self._paged else 0
+        """Page size of the KV pool — advertised on /info so the
+        gateway's prefix-affinity hash uses the replica's real page
+        geometry."""
+        return self._page_size
 
     def prefix_digests(self, limit: int = 64) -> list:
         """Hex digests of resident FIRST-page prefix-cache keys — the
@@ -1246,7 +1124,7 @@ class DecodeEngine:
         engine-thread-owned prefix map: the advertised set is a routing
         HINT — a stale entry costs one least-loaded fallback, never
         correctness."""
-        if not (self._paged and self._prefix_on):
+        if not self._prefix_on:
             return []
         out = []
         for key, ent in list(self._prefix.items()):
@@ -1258,10 +1136,9 @@ class DecodeEngine:
 
     def program_counts(self) -> dict:
         """Live compiled-program counts: {"step": 1, "admit": <=
-        log2(max_len)} in steady state — the retrace guard tests pin.
-        In paged mode "admit" is the chunk program (<= log2(prefill_chunk)
-        + 1 buckets: chunks are prefill_chunk-sized except a final
-        pow2-bucketed remainder)."""
+        log2(prefill_chunk) + 1} in steady state — the retrace guard tests
+        pin. "admit" is the chunk program (chunks are prefill_chunk-sized
+        except a final pow2-bucketed remainder)."""
         pairs = [("step", self._step_jit), ("admit", self._admit_jit)]
         if self._spec_jit is not None:
             # spec mode replaces the step dispatch with ONE verify-window
@@ -1293,10 +1170,7 @@ class DecodeEngine:
                     # iteration's dispatches hold their own references,
                     # every later one reads the new tree
                     self._apply_swap(swap)
-                if self._paged:
-                    self._advance_admissions(pending)
-                else:
-                    self._admit_ready(pending)
+                self._advance_admissions(pending)
                 # step when any occupied slot is past admission — a slot
                 # mid-chunked-prefill is inert on device, and a step over
                 # ONLY such slots would be a wasted dispatch
@@ -1335,37 +1209,7 @@ class DecodeEngine:
             self._fail_outstanding(
                 RuntimeError(f"decode engine failed: {type(e).__name__}: {e}"))
 
-    def _admit_ready(self, pending: deque) -> None:
-        while True:
-            with self._cond:
-                if not (self._free and self._waiting):
-                    return
-                req = self._waiting.popleft()
-                slot = self._free.pop()
-                # claim the slot in the SAME critical section as the pop:
-                # a stop() racing a long admit compile must find the
-                # request either in _waiting or in _slots — never in
-                # between (its ticket would hang its HTTP thread 600s)
-                self._slots[slot] = _SlotState(req)
-                _mx.set_gauge("serving.engine.queue", len(self._waiting))
-            with recorder.span("serving.engine.admit", slot=slot,
-                               prompt=len(req.tokens)):
-                # the SAME bucket fn as the per-request path, so both
-                # paths share one bounded prompt-bucket set
-                pb = min(_bucket(len(req.tokens), pow2_cap=self.max_len),
-                         self.max_len)
-                buf = np.zeros((1, pb), np.int32)
-                buf[0, :len(req.tokens)] = req.tokens
-                limit = len(req.tokens) + req.max_new - 1
-                self._carry, first = self._admit_jit(
-                    self.params, self.adapters, self._carry,
-                    jnp.asarray(buf), jnp.int32(len(req.tokens)),
-                    jnp.int32(slot), jnp.float32(req.temperature),
-                    jnp.uint32(req.seed), jnp.int32(limit))
-            self._prefilled(req.ticket)
-            pending.append(("admit", slot, first))
-
-    # ----------------------------------------------- paged admission plane
+    # ----------------------------------------------------- admission plane
     # All of the page machinery below runs on the ENGINE THREAD only
     # (_advance_admissions from the loop, _release_slot_pages via _drain's
     # _deliver) — the free list and prefix map need no lock; _cond still
@@ -1698,8 +1542,6 @@ class DecodeEngine:
         slots = np.nonzero(live)[0]
         _mx.inc("serving.engine.steps")
         _mx.inc("serving.engine.slot_steps", len(slots))
-        if not self._paged:
-            return
         pages = 0
         for slot in slots:
             st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
@@ -1735,12 +1577,11 @@ class DecodeEngine:
                 _mx.observe("serving.tbt",
                             (now - st.t_first) / (len(st.out) - 1))
             st.req.ticket.t_done = now
-            if self._paged:
-                # release BEFORE the done event: a waiter returning from
-                # result() (the diagnosis probe, capacity tests) must
-                # observe the pool already reclaimed — releasing after
-                # set() leaves a window where free+resident < budget
-                self._release_slot_pages(st)
+            # release BEFORE the done event: a waiter returning from
+            # result() (the diagnosis probe, capacity tests) must
+            # observe the pool already reclaimed — releasing after
+            # set() leaves a window where free+resident < budget
+            self._release_slot_pages(st)
             st.req.ticket._finish()
             with self._cond:
                 self._slots[slot] = None
@@ -1763,13 +1604,12 @@ class DecodeEngine:
             # release the waiting swapper with the failure, not a timeout
             swap.error = err
             swap.applied.set()
-        if self._paged:
-            # the device cache is garbage after a crash — every page and
-            # every cached prefix goes with it
-            self._admissions.clear()
-            self._free_pages = list(range(1, self._n_pages))
-            self._prefix.clear()
-            _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
+        # the device cache is garbage after a crash — every page and
+        # every cached prefix goes with it
+        self._admissions.clear()
+        self._free_pages = list(range(1, self._n_pages))
+        self._prefix.clear()
+        _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
         # last-value-wins gauges would otherwise report the pre-crash
         # depth/occupancy forever
         _mx.set_gauge("serving.engine.queue", 0)

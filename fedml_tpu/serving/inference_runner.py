@@ -104,10 +104,10 @@ class FedMLInferenceRunner:
                 digests, stamped on every /predict response (and the SSE
                 head) so the gateway learns residency off the warm path
                 without polling /info. None for non-engine predictors
-                and contiguous/prefix-off engines — the headers' absence
-                IS the "no affinity signal" case."""
+                — the headers' absence IS the "no affinity signal" case
+                (a prefix-off engine advertises no digest)."""
                 eng = getattr(runner.predictor, "engine", None)
-                if eng is None or not getattr(eng, "kv_page_size", 0):
+                if eng is None:
                     return None
                 return {"X-KV-Page-Size": str(eng.kv_page_size),
                         "X-Prefix-Digest": ",".join(eng.prefix_digests())}
@@ -136,7 +136,7 @@ class FedMLInferenceRunner:
                                          if eng is not None else None),
                         "draining": (bool(eng._draining)
                                      if eng is not None else False),
-                        "kv_page_size": (getattr(eng, "kv_page_size", 0)
+                        "kv_page_size": (eng.kv_page_size
                                          if eng is not None else 0),
                         "prefix_digests": (eng.prefix_digests()
                                            if eng is not None else []),

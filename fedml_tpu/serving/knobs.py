@@ -22,7 +22,8 @@ from __future__ import annotations
 
 # knob -> spec. Kinds: "int" (min), "num" (strict: >0 vs >=0), "bool",
 # "choice" (choices). "requires" names the gating knob whose absence makes
-# this one silently dead (refused at config load). "consumer" names the
+# this one silently dead (refused at config load); "off" is the value that
+# leaves the knob off, so naming it asks for nothing. "consumer" names the
 # mapping that must read the knob: "predictor" =
 # predictor.lm_predictor_from_serve_knobs, "fleet" =
 # scheduler.fleet_knobs.
@@ -38,7 +39,7 @@ KNOBS = {
     "sampler_cache_size": {"kind": "int", "min": 1,
                            "consumer": "predictor"},
     "kv_cache":           {"kind": "bool", "consumer": "predictor"},
-    "engine_mp":          {"kind": "int", "min": 1,
+    "engine_mp":          {"kind": "int", "min": 1, "off": 1,
                            "consumer": "predictor",
                            "requires": "decode_slots"},
     "kv_page_size":       {"kind": "int", "min": 1,
@@ -46,24 +47,25 @@ KNOBS = {
                            "requires": "decode_slots"},
     "kv_n_pages":         {"kind": "int", "min": 2,
                            "consumer": "predictor",
-                           "requires": "kv_page_size"},
+                           "requires": "decode_slots"},
     "prefill_chunk":      {"kind": "int", "min": 0,
                            "consumer": "predictor",
-                           "requires": "kv_page_size"},
+                           "requires": "decode_slots"},
     "prefix_cache":       {"kind": "bool", "consumer": "predictor",
-                           "requires": "kv_page_size"},
-    "paged_kernel":       {"kind": "bool", "consumer": "predictor",
-                           "requires": "kv_page_size"},
-    "spec_decode":        {"kind": "choice", "choices": ["off", "ngram"],
+                           "requires": "decode_slots"},
+    "paged_kernel":       {"kind": "bool", "off": False,
                            "consumer": "predictor",
-                           "requires": "kv_page_size"},
+                           "requires": "decode_slots"},
+    "spec_decode":        {"kind": "choice", "choices": ["off", "ngram"],
+                           "off": "off", "consumer": "predictor",
+                           "requires": "decode_slots"},
     "spec_k":             {"kind": "int", "min": 1,
                            "consumer": "predictor",
                            "requires": "spec_decode"},
     "kv_quant":           {"kind": "choice", "choices": ["off", "int8"],
-                           "consumer": "predictor",
-                           "requires": "kv_page_size"},
-    "admit_batch":        {"kind": "int", "min": 1,
+                           "off": "off", "consumer": "predictor",
+                           "requires": "decode_slots"},
+    "admit_batch":        {"kind": "int", "min": 1, "off": 1,
                            "consumer": "predictor",
                            "requires": "decode_slots"},
     "drain_timeout_s":    {"kind": "num", "strict": False,
@@ -138,42 +140,6 @@ def validate_serve_args(extra: dict) -> None:
                     f"serve_args.{knob} must be a "
                     f"{'positive' if strict else 'non-negative'} number; "
                     f"got {val!r}")
-    # engine_mp only takes effect inside the engine (decode_slots > 0):
-    # a config asking for tensor-parallel serving without the engine
-    # would silently run single-chip per-request — refuse at load
-    # instead (the other engine_* knobs double as per-request knobs,
-    # e.g. engine_max_len sizes both paths, so only this one is gated)
-    mp_knob = extra.get("engine_mp")
-    if mp_knob is not None and int(mp_knob) > 1 \
-            and not extra.get("decode_slots"):
-        raise ValueError(
-            "serve_args.engine_mp > 1 requires decode_slots > 0 — "
-            "tensor-parallel serving runs inside the decode engine; "
-            "without slots the knob would be silently ignored")
-    # paged-cache knobs (serving/engine.py page_size > 0) are gated
-    # the same way: each only takes effect inside the paged engine,
-    # so a config naming one without its prerequisite would silently
-    # serve contiguous/per-request — refuse at load instead
-    if extra.get("kv_page_size") and not extra.get("decode_slots"):
-        raise ValueError(
-            "serve_args.kv_page_size requires decode_slots > 0 — the "
-            "paged KV cache lives inside the decode engine; without "
-            "slots the knob would be silently ignored")
-    for knob in ("kv_n_pages", "prefill_chunk", "prefix_cache"):
-        if extra.get(knob) is not None and not extra.get("kv_page_size"):
-            raise ValueError(
-                f"serve_args.{knob} requires kv_page_size > 0 (the "
-                "paged KV cache) — without paging the knob would be "
-                "silently ignored")
-    # decode-speed knobs (ISSUE 11): the Pallas paged-attention kernel
-    # and n-gram speculative decoding both live inside the PAGED engine
-    # — same gating discipline, a knob that would be silently ignored
-    # is refused at load
-    if extra.get("paged_kernel") and not extra.get("kv_page_size"):
-        raise ValueError(
-            "serve_args.paged_kernel requires kv_page_size > 0 — the "
-            "fused kernel reads the paged KV pool in place; without "
-            "paging the knob would be silently ignored")
     sd = extra.get("spec_decode")
     if sd is not None:
         # YAML 1.1 reads an unquoted `off` as boolean False — that IS
@@ -191,19 +157,11 @@ def validate_serve_args(extra: dict) -> None:
             raise ValueError(
                 "serve_args.spec_decode must be 'off' or 'ngram'; "
                 f"got {sd!r}")
-        if sd != "off" and not extra.get("kv_page_size"):
-            raise ValueError(
-                "serve_args.spec_decode requires kv_page_size > 0 — "
-                "speculative verify-and-rollback rides the paged KV "
-                "cache's page table; without paging the knob would "
-                "be silently ignored")
     if extra.get("spec_k") is not None and sd in (None, "off"):
         raise ValueError(
             "serve_args.spec_k requires spec_decode: ngram — "
             "the draft length only exists under speculation; "
             "without it the knob would be silently ignored")
-    # serving-density knobs (ISSUE 16): int8 KV pages, batched
-    # admission, and gateway prefix-affinity routing — same discipline
     kq = extra.get("kv_quant")
     if kq is not None:
         # YAML 1.1 reads unquoted `off` as False — the documented
@@ -218,24 +176,30 @@ def validate_serve_args(extra: dict) -> None:
         if kq not in KNOBS["kv_quant"]["choices"]:
             raise ValueError(
                 f"serve_args.kv_quant must be 'off' or 'int8'; got {kq!r}")
-        if kq != "off" and not extra.get("kv_page_size"):
-            raise ValueError(
-                "serve_args.kv_quant requires kv_page_size > 0 — int8 "
-                "KV storage is a property of the paged pool (per-page-"
-                "per-head scales ride the page table); without paging "
-                "the knob would be silently ignored")
-    ab = extra.get("admit_batch")
-    if ab is not None and int(ab) > 1 and not extra.get("decode_slots"):
-        raise ValueError(
-            "serve_args.admit_batch > 1 requires decode_slots > 0 — "
-            "batched admission groups the decode engine's prefill "
-            "chunks; without slots the knob would be silently ignored")
+    # every knob of the decode engine (the page pool, the kernel,
+    # speculation, int8 pages, batched admission, engine_mp) only takes
+    # effect inside it: a config naming one without decode_slots would
+    # silently serve per-request — refuse at load instead (the other
+    # engine_* knobs double as per-request knobs, e.g. engine_max_len
+    # sizes both paths, so they are not gated)
+    if not extra.get("decode_slots"):
+        for knob, spec in KNOBS.items():
+            val = extra.get(knob)
+            if spec.get("requires") != "decode_slots" or val is None:
+                continue
+            if spec["kind"] == "int":
+                val = int(val)
+            if "off" not in spec or val != spec["off"]:
+                raise ValueError(
+                    f"serve_args.{knob} requires decode_slots > 0 — the "
+                    "knob configures the decode engine (serving/engine.py); "
+                    "without slots it would be silently ignored")
     if extra.get("affinity_routing"):
-        if not extra.get("kv_page_size") \
+        if not extra.get("decode_slots") \
                 or extra.get("prefix_cache") is False:
             raise ValueError(
                 "serve_args.affinity_routing requires the engine prefix "
-                "cache (kv_page_size > 0, prefix_cache not disabled) — "
+                "cache (decode_slots > 0, prefix_cache not disabled) — "
                 "affinity routes requests to the replica whose cache "
                 "already holds their prefix; without one the knob would "
                 "be silently ignored")

@@ -122,7 +122,7 @@ def lm_predictor_from_serve_knobs(sv: dict, model, params,
     (scheduler.start_replica reads the spec's serve dict) — one mapping,
     so the two surfaces cannot drift."""
     eos = sv.get("engine_eos_id")
-    n_pages = sv.get("kv_n_pages")
+    page, n_pages = sv.get("kv_page_size"), sv.get("kv_n_pages")
     return GreedyLMPredictor(
         model, params, adapters=adapters, detokenize=detokenize,
         max_len=int(sv.get("engine_max_len", default_max_len)),
@@ -132,7 +132,7 @@ def lm_predictor_from_serve_knobs(sv: dict, model, params,
         engine_fetch_chunk=int(sv.get("engine_fetch_chunk", 2)),
         sampler_cache_size=int(sv.get("sampler_cache_size", 4)),
         engine_mp=int(sv.get("engine_mp", 0)),
-        kv_page_size=int(sv.get("kv_page_size", 0)),
+        kv_page_size=None if page is None else int(page),
         kv_n_pages=None if n_pages is None else int(n_pages),
         prefill_chunk=int(sv.get("prefill_chunk", 0)),
         prefix_cache=bool(sv.get("prefix_cache", True)),
@@ -219,22 +219,22 @@ class GreedyLMPredictor(_InstrumentedPredictor):
 
     decode_slots=S (requires kv_cache=True) additionally starts the
     continuous-batching DecodeEngine (serving/engine.py): S slots share
-    one persistent donated KV cache and concurrent requests decode in the
-    SAME device steps instead of serializing — single-prompt requests
-    without top_k route there (greedy output token-identical to the
-    per-request path); batched and top_k requests keep the per-request
-    path. stop() shuts the engine down.
+    one persistent donated pool of KV pages and concurrent requests
+    decode in the SAME device steps instead of serializing —
+    single-prompt requests without top_k route there (greedy output
+    token-identical to the per-request path); batched and top_k requests
+    keep the per-request path. stop() shuts the engine down.
 
-    kv_page_size=P (requires decode_slots) swaps the engine's cache for
-    the block/PAGED layout — kv_n_pages sizes the pool, prefill_chunk
-    enables chunked-prefill admission, prefix_cache reuses identical
-    prompt-prefix pages (engine module docstring has the full story);
-    engine capacity then becomes the page budget, consulted through
+    The engine's knobs all need decode_slots: kv_page_size=P is the page
+    (None = the engine's default, 16), kv_n_pages sizes the pool,
+    prefill_chunk enables chunked-prefill admission, prefix_cache reuses
+    identical prompt-prefix pages (engine module docstring has the full
+    story); engine capacity is the page budget, consulted through
     engine.admissible() so routing and the 400/degrade contracts follow
     the real constraint.
 
     paged_kernel=True / spec_decode="ngram" (+ spec_k) turn on the
-    paged engine's decode-speed legs (serving/engine.py: fused Pallas
+    engine's decode-speed legs (serving/engine.py: fused Pallas
     paged attention; greedy-exact self-drafted speculation). Neither
     changes routing or the degrade contract: both are token-identical
     to the plain engine — speculation keeps the engine's per-position
@@ -249,7 +249,7 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                  compute_dtype: Optional[str] = None,
                  decode_slots: int = 0, eos_id: Optional[int] = None,
                  sampler_cache_size: int = 4, engine_fetch_chunk: int = 2,
-                 engine_mp: int = 0, kv_page_size: int = 0,
+                 engine_mp: int = 0, kv_page_size: Optional[int] = None,
                  kv_n_pages: Optional[int] = None, prefill_chunk: int = 0,
                  prefix_cache: bool = True, paged_kernel: bool = False,
                  spec_decode: str = "off", spec_k: int = 4,
@@ -274,34 +274,18 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                 "decode_slots (the continuous-batching engine, "
                 "serving/engine.py) needs kv_cache=True — the engine IS "
                 "the KV-cached decode with a slot axis")
-        if (kv_page_size or kv_n_pages or prefill_chunk) \
-                and not decode_slots:
+        named = [knob for knob, on in (
+            ("kv_page_size", kv_page_size is not None),
+            ("kv_n_pages", kv_n_pages), ("prefill_chunk", prefill_chunk),
+            ("paged_kernel", paged_kernel),
+            ("spec_decode", spec_decode != "off"),
+            ("kv_quant", kv_quant != "off"),
+            ("admit_batch", int(admit_batch) > 1)) if on]
+        if named and not decode_slots:
             raise ValueError(
-                "kv_page_size/kv_n_pages/prefill_chunk configure the "
-                "PAGED decode engine — they need decode_slots > 0 "
+                f"{'/'.join(named)} configure the decode ENGINE "
+                "(serving/engine.py) — they need decode_slots > 0 "
                 "(otherwise they would be silently ignored)")
-        if (paged_kernel or spec_decode != "off") and not kv_page_size:
-            # both decode-speed legs live on the paged layout (the
-            # kernel reads the page pool in place; speculation rolls
-            # write positions back through the page table) — without it
-            # they would be silently ignored
-            raise ValueError(
-                "paged_kernel/spec_decode need the PAGED engine "
-                "(kv_page_size > 0, which itself needs decode_slots) — "
-                "otherwise they would be silently ignored")
-        if kv_quant != "off" and not kv_page_size:
-            # int8 KV is a property of the PAGED pool (per-page-per-head
-            # scales ride the page table) — without it the knob would be
-            # silently ignored
-            raise ValueError(
-                "kv_quant stores the PAGED KV pool in int8 — it needs "
-                "kv_page_size > 0 (which itself needs decode_slots); "
-                "otherwise it would be silently ignored")
-        if int(admit_batch) > 1 and not decode_slots:
-            raise ValueError(
-                "admit_batch batches the decode ENGINE's admissions — "
-                "it needs decode_slots > 0 (otherwise it would be "
-                "silently ignored)")
 
         if adapters is not None and not kv_cache:
             # the recompute path drives model.apply, which knows nothing of
@@ -380,11 +364,11 @@ class GreedyLMPredictor(_InstrumentedPredictor):
             self._samplers_lock = threading.Lock()
             if decode_slots:
                 # continuous batching (serving/engine.py): S slots share
-                # one persistent donated KV cache; requests stream through
-                # the engine thread instead of serializing on this
+                # one persistent donated KV page pool; requests stream
+                # through the engine thread instead of serializing on this
                 # predictor's jit calls. engine_mp > 1 runs the engine
                 # tensor-parallel over an {"mp": N} device mesh (weights +
-                # KV cache sharded via the parallel/partition.py registry).
+                # KV pool sharded via the parallel/partition.py registry).
                 from .engine import DecodeEngine
 
                 mesh = None
@@ -397,7 +381,9 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                     n_slots=int(decode_slots), max_len=max_len,
                     eos_id=eos_id, dtype=kv_dtype,
                     fetch_chunk=engine_fetch_chunk, mesh=mesh,
-                    page_size=kv_page_size, n_pages=kv_n_pages,
+                    **({} if kv_page_size is None
+                       else {"page_size": int(kv_page_size)}),
+                    n_pages=kv_n_pages,
                     prefill_chunk=prefill_chunk,
                     prefix_cache=prefix_cache,
                     paged_kernel=paged_kernel, spec_decode=spec_decode,
@@ -576,7 +562,7 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         # requests (need a static-k compiled cutoff) stay on the
         # per-request path. Capacity rides the ENGINE's oracle
         # (engine.admissible — exact prompt + max_new <= max_len, plus
-        # the page budget in paged mode), not static max_len math: a
+        # the page budget), not static max_len math: a
         # request the page budget refuses falls through to the
         # per-request path below when that path can serve it honestly,
         # instead of 400ing a request this replica could answer. Routing

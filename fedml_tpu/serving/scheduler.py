@@ -170,9 +170,9 @@ def start_replica(spec: dict):
         # LLM replica: llm/TransformerLM + GreedyLMPredictor. "lm" carries
         # the model recipe, "serve" the ServeArgs.extra knobs (config.py) —
         # decode_slots > 0 brings the replica up on the continuous-batching
-        # engine (serving/engine.py), otherwise per-request decode;
-        # kv_page_size > 0 selects the engine's paged KV cache (with
-        # kv_n_pages/prefill_chunk/prefix_cache riding the same dict).
+        # engine (serving/engine.py) and its pool of KV pages (kv_page_size,
+        # default 16, with kv_n_pages/prefill_chunk/prefix_cache riding the
+        # same dict), otherwise per-request decode.
         from ..llm.transformer import TransformerLM
         from .predictor import lm_predictor_from_serve_knobs
 

@@ -56,7 +56,7 @@ def per_req(setup):
 @pytest.fixture(scope="module")
 def eng_gather(setup):
     """The gather-path paged engine: THE oracle both legs are pinned
-    against (itself pinned equal to contiguous + per-request in
+    against (itself pinned equal to the per-request path in
     test_paged_engine.py)."""
     model, params = setup
     eng = DecodeEngine(model, params, n_slots=3, max_len=MAXLEN,
@@ -281,27 +281,22 @@ def test_spec_retrace_guard(eng_spec):
 
 # ------------------------------------------------------------- satellites
 def test_knob_gating(setup):
-    """Both legs live on the paged layout — asking for either anywhere
-    it would be silently ignored is refused (engine, predictor)."""
+    """Both legs live in the engine — asking for either where it would
+    be silently ignored is refused (predictor without slots), and a bad
+    value is refused wherever it is given."""
     model, params = setup
-    with pytest.raises(ValueError, match="page_size > 0"):
-        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
-                     paged_kernel=True)
-    with pytest.raises(ValueError, match="page_size > 0"):
-        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
-                     spec_decode="ngram")
     with pytest.raises(ValueError, match="'off' or 'ngram'"):
         DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                      page_size=PS, spec_decode="draft")
     with pytest.raises(ValueError, match="spec_k"):
         DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                      page_size=PS, spec_decode="ngram", spec_k=0)
-    with pytest.raises(ValueError, match="kv_page_size"):
+    with pytest.raises(ValueError, match="paged_kernel.*decode_slots"):
         GreedyLMPredictor(model, params, max_len=MAXLEN, kv_cache=True,
-                          decode_slots=2, paged_kernel=True)
-    with pytest.raises(ValueError, match="kv_page_size"):
+                          paged_kernel=True)
+    with pytest.raises(ValueError, match="spec_decode.*decode_slots"):
         GreedyLMPredictor(model, params, max_len=MAXLEN, kv_cache=True,
-                          decode_slots=2, spec_decode="ngram")
+                          spec_decode="ngram")
 
 
 def test_serve_args_decode_speed_validation():
@@ -325,12 +320,10 @@ def test_serve_args_decode_speed_validation():
         Config.from_dict({"serve": {"decode_slots": 2, "kv_page_size": PS,
                                     "spec_decode": True}})
     for bad, msg in (
-            ({"decode_slots": 2, "paged_kernel": True},
-             "requires kv_page_size"),
+            ({"paged_kernel": True}, "requires decode_slots"),
             ({"decode_slots": 2, "kv_page_size": PS,
               "paged_kernel": "y"}, "boolean"),
-            ({"decode_slots": 2, "spec_decode": "ngram"},
-             "requires kv_page_size"),
+            ({"spec_decode": "ngram"}, "requires decode_slots"),
             ({"decode_slots": 2, "kv_page_size": PS,
               "spec_decode": "draft"}, "'off' or 'ngram'"),
             ({"decode_slots": 2, "kv_page_size": PS, "spec_k": 4},
@@ -354,7 +347,8 @@ def test_lm_predictor_from_config_decode_speed_knobs(setup):
         "spec_decode": "ngram", "spec_k": 2}})
     pred = lm_predictor_from_config(cfg, model, params)
     try:
-        assert pred.engine is not None and pred.engine._paged
+        assert pred.engine is not None
+        assert pred.engine.kv_page_size == PS
         assert pred.engine._kernel_on is True
         assert pred.engine._spec_on is True
         assert pred.engine._spec_k == 2

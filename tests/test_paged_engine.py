@@ -1,9 +1,11 @@
 """Paged KV cache, prefix reuse, and chunked prefill (ISSUE 7).
 
-The contracts the paged engine lives by:
-- TOKEN IDENTITY: paged greedy (and seeded-sampling) output equals the
-  contiguous engine's and the per-request path's, including mid-flight
-  admission/retirement over shared prefix pages and on an mp=2 mesh;
+The contracts the engine's page pool lives by:
+- TOKEN IDENTITY: greedy output equals the per-request path's, and a
+  seeded draw equals the documented one computed from the per-request
+  path's logits, including mid-flight admission/retirement over shared
+  prefix pages and on an mp=2 mesh; a default-constructed engine (pages
+  of 16) holds the same across a page boundary;
 - bounded programs: one paged step program + pow2 chunk buckets, no
   matter how many requests stream through;
 - prefix-cache hygiene: refs released on retirement, no cross-request
@@ -66,17 +68,6 @@ def eng_paged(setup):
     eng.stop()
 
 
-@pytest.fixture(scope="module")
-def eng_cont(setup):
-    """Contiguous reference engine (the seeded-sampling identity pin —
-    the per-request path's rng schedule differs, so contiguous-vs-paged
-    is the comparison that proves the paged layout changes nothing)."""
-    model, params = setup
-    eng = DecodeEngine(model, params, n_slots=2, max_len=MAXLEN).start()
-    yield eng
-    eng.stop()
-
-
 def _prompts(ns, seed=0):
     rs = np.random.RandomState(seed)
     return [rs.randint(1, V, n).tolist() for n in ns]
@@ -92,9 +83,8 @@ def test_paged_greedy_token_identical_mid_flight_shared_pages(
         setup, per_req, eng_paged):
     """PINNED: 6 prompts — two sharing an 8-token prefix (shared pages +
     a prefix hit mid-run) — through 3 paged slots with chunked prefill,
-    vs the per-request path (itself pinned equal to the contiguous
-    engine in test_serving_engine.py). Admissions and retirements
-    interleave mid-flight; every output must match token for token."""
+    vs the per-request path. Admissions and retirements interleave
+    mid-flight; every output must match token for token."""
     shared = _prompts((8,), seed=9)[0]
     prompts = _prompts((6, 10, 8, 5)) + [shared + p
                                          for p in _prompts((3, 5), seed=2)]
@@ -104,22 +94,109 @@ def test_paged_greedy_token_identical_mid_flight_shared_pages(
     assert [t.result(timeout=120) for t in tickets] == want
 
 
-def test_paged_seeded_sampling_identical_to_contiguous(eng_cont, eng_paged):
-    """Sampling equivalence: the paged engine draws the exact tokens the
-    contiguous engine draws for the same (seed, temperature) — the rng
-    schedule (fold_in(key(seed), pos)) is layout-independent — and the
-    usual same-seed/diff-seed contract holds within the paged engine."""
+@pytest.fixture(scope="module")
+def kv_oracle():
+    """The per-request path's own prefill/step (llm/decode.py
+    make_kv_decode), jitted once for the module."""
+    from fedml_tpu.llm.decode import make_kv_decode
+
+    prefill, step = make_kv_decode(H)
+    return jax.jit(prefill, static_argnums=(3,)), jax.jit(step)
+
+
+def _documented_draw(kv_oracle, params, prompt, n, temperature, seed):
+    """The engine's documented draw, computed WITHOUT the engine: token i
+    of a request is categorical(fold_in(key(seed), plen + i), logits /
+    temperature) — the first off the prefill's last-position logits, each
+    later one off the step that wrote position plen + i - 1 (so the key
+    is fold_in(key(seed), pos + 1))."""
+    prefill, step = kv_oracle
+    key = jax.random.key(jnp.uint32(seed))
+    plen = len(prompt)
+
+    def draw(i, logits):
+        return jax.random.categorical(
+            jax.random.fold_in(key, plen + i),
+            logits[0].astype(jnp.float32) / temperature)
+
+    cache, logits = prefill(params, None, jnp.asarray([prompt]), MAXLEN)
+    out = [draw(0, logits)]
+    for i in range(1, n):
+        cache, logits = step(params, None, cache, plen + i - 1,
+                             out[-1][None].astype(jnp.int32))
+        out.append(draw(i, logits))
+    return [int(t) for t in out]
+
+
+def test_paged_seeded_sampling_is_the_documented_draw(setup, kv_oracle,
+                                                      eng_paged):
+    """Sampling keeps an oracle that is not the engine: the paged engine
+    draws the exact tokens the documented schedule gives over the
+    per-request path's logits for the same (seed, temperature) — the rng
+    schedule is independent of where K/V rows live — and the usual
+    same-seed/diff-seed contract holds within the engine."""
     prompt = _prompts((8,), seed=11)[0]
-    w7 = eng_cont.submit(prompt, 8, temperature=2.0, seed=7)
-    w8 = eng_cont.submit(prompt, 8, temperature=2.0, seed=8)
     a = eng_paged.submit(prompt, 8, temperature=2.0, seed=7)
     b = eng_paged.submit(prompt, 8, temperature=2.0, seed=7)
     c = eng_paged.submit(prompt, 8, temperature=2.0, seed=8)
-    w7, w8, a, b, c = (t.result(timeout=120) for t in (w7, w8, a, b, c))
-    assert a == w7
-    assert c == w8
+    a, b, c = (t.result(timeout=120) for t in (a, b, c))
+    params = setup[1]
+    assert a == _documented_draw(kv_oracle, params, prompt, 8, 2.0, 7)
+    assert c == _documented_draw(kv_oracle, params, prompt, 8, 2.0, 8)
     assert a == b
     assert a != c
+
+
+@pytest.fixture(scope="module")
+def per_req_long(setup):
+    """A per-request reference with room for a 32-step bucket."""
+    model, params = setup
+    return GreedyLMPredictor(model, params, max_len=64, kv_cache=True)
+
+
+@pytest.mark.parametrize("case", [
+    {"prompt": 15, "new": 8}, {"prompt": 16, "new": 8},
+    {"prompt": 17, "new": 8},
+    # the last page is a partial one: 40 = 2.5 pages of 16
+    {"prompt": 20, "new": 20, "max_len": 40},
+    {"serve": {"decode_slots": 2, "kv_n_pages": 8}},
+    {"serve": {"decode_slots": 2, "paged_kernel": True}},
+], ids=["prompt15", "prompt16", "prompt17", "max_len40", "serve_kv_n_pages",
+        "serve_paged_kernel"])
+def test_default_engine_runs_pages_of_16(setup, per_req_long, case):
+    """An engine nobody gave a page size runs pages of 16 through the same
+    programs as one that was: greedy tokens equal the per-request path's
+    on both sides of a page boundary and into a partial last page, and a
+    `serve` block that names a pool knob but no `kv_page_size` loads and
+    serves from the engine."""
+    model, params = setup
+    if "serve" in case:
+        from fedml_tpu.config import Config
+        from fedml_tpu.serving import lm_predictor_from_config
+
+        cfg = Config.from_dict(
+            {"serve": {"engine_max_len": MAXLEN, **case["serve"]}})
+        pred = lm_predictor_from_config(cfg, model, params)
+        try:
+            assert pred.engine.kv_page_size == 16
+            req = {"tokens": _prompts((9,), seed=5)[0], "max_new_tokens": 6}
+            done = _mx.snapshot()["counters"].get(
+                "serving.engine.completions", 0)
+            assert pred.predict(req) == per_req_long.predict(req)
+            assert _mx.snapshot()["counters"][
+                "serving.engine.completions"] == done + 1
+        finally:
+            pred.stop()
+        return
+    eng = DecodeEngine(model, params, n_slots=2,
+                       max_len=case.get("max_len", MAXLEN)).start()
+    try:
+        assert eng.kv_page_size == 16
+        prompt = _prompts((case["prompt"],), seed=3)[0]
+        assert eng.submit(prompt, case["new"]).result(timeout=120) == \
+            _want(per_req_long, [prompt], [case["new"]])[0]
+    finally:
+        eng.stop()
 
 
 def test_paged_program_set_bounded_retrace_guard(eng_paged):
@@ -141,7 +218,7 @@ def test_paged_mp2_token_identical(setup, per_req):
     devices): weights Megatron-split, the page POOL sharded on its heads
     axis (partition.paged_kv_cache_spec), page table replicated — greedy
     output token-identical to the unmeshed paths (per-request pinned ==
-    contiguous == paged mp=1, the other links in the chain above)."""
+    paged mp=1, the other link in the chain above)."""
     from fedml_tpu.parallel.mesh import make_mesh
 
     model, params = setup
@@ -276,7 +353,7 @@ def test_paged_capacity_contract_and_page_math_message(setup):
     # the message states the page math
     assert "ceil(21/4) = 6" in str(ei.value)
     assert "5 usable" in str(ei.value)
-    # default pool (no n_pages) admits exactly what contiguous does
+    # default pool (no n_pages): every slot can run to max_len
     eng = DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                        page_size=PS)
     assert eng.admissible(9, MAXLEN - 9)
@@ -335,8 +412,8 @@ def test_paged_pool_reclaimed_after_retirement(eng_paged):
 # ------------------------------------------------------------- satellites
 def test_paged_knob_gating(setup):
     model, params = setup
-    with pytest.raises(ValueError, match="page_size > 0"):
-        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN, n_pages=8)
+    with pytest.raises(ValueError, match="page_size must be >= 1"):
+        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN, page_size=0)
     with pytest.raises(ValueError, match="kv_n_pages must be >= 2"):
         DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                      page_size=PS, n_pages=1)
@@ -359,11 +436,9 @@ def test_serve_args_paged_config_validation():
     for bad, msg in (
             ({"decode_slots": 2, "kv_page_size": 0}, "kv_page_size"),
             ({"kv_page_size": 8}, "requires decode_slots"),
-            ({"decode_slots": 2, "kv_n_pages": 8}, "requires kv_page_size"),
-            ({"decode_slots": 2, "prefill_chunk": 8},
-             "requires kv_page_size"),
-            ({"decode_slots": 2, "prefix_cache": False},
-             "requires kv_page_size"),
+            ({"kv_n_pages": 8}, "requires decode_slots"),
+            ({"prefill_chunk": 8}, "requires decode_slots"),
+            ({"prefix_cache": False}, "requires decode_slots"),
             ({"decode_slots": 2, "kv_page_size": 8, "prefix_cache": "y"},
              "boolean"),
             ({"decode_slots": 2, "kv_page_size": 8, "kv_n_pages": 1},
@@ -373,7 +448,7 @@ def test_serve_args_paged_config_validation():
 
 
 def test_lm_predictor_from_config_paged_knobs(setup):
-    """The config bridge builds a PAGED engine from YAML (structural —
+    """The config bridge builds the engine's pool from YAML (structural —
     engine output identity is pinned above; predict here would only
     re-compile the same programs)."""
     from fedml_tpu.config import Config
@@ -385,8 +460,8 @@ def test_lm_predictor_from_config_paged_knobs(setup):
         "kv_n_pages": 20, "prefill_chunk": 4, "prefix_cache": False}})
     pred = lm_predictor_from_config(cfg, model, params)
     try:
-        assert pred.engine is not None and pred.engine._paged
-        assert pred.engine._page_size == PS
+        assert pred.engine is not None
+        assert pred.engine.kv_page_size == PS
         assert pred.engine._n_pages == 20
         assert pred.engine._prefill_chunk == 4
         assert pred.engine._prefix_on is False

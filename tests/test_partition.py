@@ -80,8 +80,9 @@ def test_flagship_golden_resolved_table():
     flat = {partition.path_name(path): spec for path, spec in
             jax.tree_util.tree_flatten_with_path(specs)[0]}
     assert flat == golden
-    # the serve-side KV cache shards the heads axis of [L, S, T, H, Dh]
-    assert partition.kv_cache_spec("mp") == P(None, None, None, "mp", None)
+    # the serve-side KV pool shards the heads axis of [L, P, page, H, Dh]
+    assert partition.paged_kv_cache_spec("mp") == P(
+        None, None, None, "mp", None)
     # unrolled float layout also fully covered (no UnmatchedParamError)
     _m2, p2 = _flagship(scan=False)
     partition.resolve("transformer_lm", p2)

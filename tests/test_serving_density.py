@@ -178,8 +178,8 @@ def test_spec_decode_composes_with_int8(setup, eng_int8):
 def test_serve_args_gating():
     """serve_args-layer refusal: each density knob without its substrate
     is a hard error naming the missing knob, never a silent no-op."""
-    with pytest.raises(ValueError, match="kv_page_size"):
-        validate_serve_args({"kv_quant": "int8", "decode_slots": 2})
+    with pytest.raises(ValueError, match="kv_quant requires decode_slots"):
+        validate_serve_args({"kv_quant": "int8"})
     with pytest.raises(ValueError, match="not a mode"):
         validate_serve_args({"kv_quant": True, "decode_slots": 2,
                              "kv_page_size": 4})
@@ -200,21 +200,15 @@ def test_ctor_gating(setup):
     """The engine and predictor enforce the same substrate requirements
     for callers that bypass serve_args."""
     model, params = setup
-    with pytest.raises(ValueError, match="page_size"):
-        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
-                     kv_quant="int8")
-    with pytest.raises(ValueError, match="page_size"):
-        DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
-                     admit_batch=2)
     with pytest.raises(ValueError, match="admit_batch"):
         DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                      page_size=PS, admit_batch=0)
     with pytest.raises(ValueError, match="kv_quant"):
         DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                      page_size=PS, kv_quant="int4")
-    with pytest.raises(ValueError, match="kv_page_size"):
+    with pytest.raises(ValueError, match="kv_quant.*decode_slots"):
         GreedyLMPredictor(model, params, max_len=MAXLEN, kv_cache=True,
-                          decode_slots=2, kv_quant="int8")
+                          kv_quant="int8")
     with pytest.raises(ValueError, match="decode_slots"):
         GreedyLMPredictor(model, params, max_len=MAXLEN, kv_cache=True,
                           admit_batch=2)

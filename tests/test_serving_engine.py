@@ -56,7 +56,7 @@ def per_req(setup):
 
 @pytest.fixture(scope="module")
 def eng_shared(setup):
-    """Shared 2-slot contiguous engine for the tests that don't need a
+    """Shared 2-slot default engine for the tests that don't need a
     bespoke knob (eos/fetch_chunk/slot-count pins build their own). The
     conftest swaps a fresh metrics registry per test, so counter
     assertions on the shared engine stay per-test."""
@@ -136,7 +136,7 @@ def test_engine_single_token_and_capacity_contract(per_req, eng_shared):
     ok = eng_shared.submit(prompt, MAXLEN - len(prompt))
     assert len(ok.result(timeout=120)) == MAXLEN - len(prompt)
     # ...one more is refused loudly (no step bucketing in the contract)
-    with pytest.raises(ValueError, match="slot capacity"):
+    with pytest.raises(ValueError, match=f"cap max_len {MAXLEN}"):
         eng_shared.submit(prompt, MAXLEN - len(prompt) + 1)
     with pytest.raises(ValueError, match="at least one prompt token"):
         eng_shared.submit([], 4)

@@ -145,22 +145,21 @@ def test_a_ticket_submitted_outside_any_span_starts_its_own_trace(lm):
         three = [s for s in recorder.spans[n0:] if s.name in FIVE]
         assert [s.name for s in three] == list(FIVE[1:4])
         assert len({s.trace_id for s in three}) == 1 and three[0].trace_id
-        assert three[1].meta["chunks"] == 1     # contiguous: one admit
+        assert three[1].meta["chunks"] == 1     # prefill_chunk 0: one chunk
         assert three[2].end == tk.t_first and three[0].start == tk.t_submit
     finally:
         eng.stop()
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
-def test_slot_steps_count_the_tokens_step_frames_delivered(lm, paged):
+def test_slot_steps_count_the_tokens_step_frames_delivered(lm):
     """`serving.engine.steps` counts drained step frames, `slot_steps` the
     live slots in them: every token but a request's first comes from one
     live slot of one step frame, and occupancy cannot pass the slots.
-    `page_steps` (paged only) adds up the pages each of those steps'
-    query attended: prompt + the tokens emitted so far, in pages."""
+    `page_steps` adds up the pages each of those steps' query attended:
+    prompt + the tokens emitted so far, in pages."""
     model, params = lm
-    kw = {"page_size": 8, "prefill_chunk": 8} if paged else {}
-    eng = DecodeEngine(model, params, n_slots=3, max_len=MAXLEN, **kw).start()
+    eng = DecodeEngine(model, params, n_slots=3, max_len=MAXLEN,
+                       page_size=8, prefill_chunk=8).start()
     prompts = (5, 11, 8, 4, 17)
     try:
         tickets = [eng.submit(_prompt(n, seed=n), new)
@@ -170,14 +169,11 @@ def test_slot_steps_count_the_tokens_step_frames_delivered(lm, paged):
         eng.stop()
     snap = _mx.snapshot()
     c = snap["counters"]
-    if paged:
-        assert c["serving.engine.page_steps"] == sum(
-            -(-(n + emitted) // 8)
-            for n, o in zip(prompts, outs) for emitted in range(1, len(o)))
-        assert snap["gauges"]["serving.engine.table_pages"] == (
-            3 * -(-MAXLEN // 8))
-    else:
-        assert "serving.engine.page_steps" not in c
+    assert c["serving.engine.page_steps"] == sum(
+        -(-(n + emitted) // 8)
+        for n, o in zip(prompts, outs) for emitted in range(1, len(o)))
+    assert snap["gauges"]["serving.engine.table_pages"] == (
+        3 * -(-MAXLEN // 8))
     by_steps = sum(len(o) - 1 for o in outs)
     assert c["serving.engine.slot_steps"] == by_steps > 0
     assert c["serving.engine.steps"] >= max(len(o) - 1 for o in outs)
