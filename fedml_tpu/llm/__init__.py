@@ -6,8 +6,10 @@ the TPU-native equivalent).
 Two compositions:
 
 1. `federated_lora(...)` — the flat path: adapters ARE the federated model.
-   `lora_apply_fn` turns (adapters -> logits) into an ordinary apply fn, so
-   the WHOLE existing stack — round engine (parallel/round.py), algorithms,
+   `adapted_apply_fn` (`lora_apply_fn` for a TransformerLM: the same view,
+   the adapted projections' backward in rank-r products) turns
+   (adapters -> logits) into an ordinary apply fn, so the WHOLE existing
+   stack — round engine (parallel/round.py), algorithms,
    compression, DP, defenses, cross-silo managers — trains and exchanges
    only adapter pytrees with zero new code. Base weights never move.
 
@@ -43,7 +45,7 @@ from ..parallel.round import _localize
 from ..parallel.seq import ring_attention, ulysses_attention
 from .lora import count_params, lora_apply_fn, lora_init, lora_merge
 from .moe import COUNTERS, fold_counters
-from .transformer import TransformerLM
+from .transformer import TransformerLM, adapted_apply_fn
 
 Pytree = Any
 
@@ -74,8 +76,6 @@ def federated_lora(model: TransformerLM, base_params: Pytree, t: TrainArgs,
     consumed — the live ones are `out.server_state.params` / `.extra`
     (copy first, `jax.tree.map(jnp.array, tree)`, to keep a second
     handle)."""
-    from ..models.hub import mixed_precision_apply
-
     adapters = lora_init(rng, base_params, rank=rank, targets=targets)
     apply = model.apply
     if getattr(model, "has_counters", False):
@@ -85,13 +85,13 @@ def federated_lora(model: TransformerLM, base_params: Pytree, t: TrainArgs,
             logits, sown = model.apply(variables, x, *args,
                                        mutable=[COUNTERS], **kwargs)
             return logits, fold_counters(sown[COUNTERS])
-    # honor TrainArgs.compute_dtype like the Simulator path does
-    # (simulator.py): bf16 runs the merged matmuls on the MXU while the
-    # adapters/optimizer stay f32
-    base_apply = mixed_precision_apply(apply, t.compute_dtype)
 
     def fedavg_over(base):
-        return make_fedavg(lora_apply_fn(base_apply, base, alpha), t)
+        # TrainArgs.compute_dtype as the Simulator path honors it
+        # (simulator.py): bf16 runs the merged matmuls on the MXU while the
+        # adapters/optimizer stay f32
+        return make_fedavg(
+            adapted_apply_fn(model, base, alpha, t.compute_dtype, apply), t)
 
     avg = fedavg_over(base_params)   # its server side never calls the apply
 
@@ -182,19 +182,16 @@ def make_fedllm_seq_round(
                 jnp.float32)
     else:
         # same architecture, sequence-parallel attention bound to the mesh
-        # axis; compute_dtype honored like the flat path
-        # (mixed_precision_apply)
-        from ..models.hub import mixed_precision_apply
-
+        # axis; compute_dtype honored and the adapters reached as on the
+        # flat path (adapted_apply_fn)
         spmodel = TransformerLM(
             vocab_size=model.vocab_size, d_model=model.d_model,
             n_layers=model.n_layers, n_heads=model.n_heads, d_ff=model.d_ff,
             attn_fn=attn_fn)
-        sp_apply = mixed_precision_apply(spmodel.apply, t.compute_dtype)
 
         def sp_logits(base, a, x, off):
-            merged = lora_merge(base, a, alpha)
-            return sp_apply({"params": merged}, x, pos_offset=off)
+            return adapted_apply_fn(spmodel, base, alpha, t.compute_dtype)(
+                {"params": a}, x, pos_offset=off)
 
     opt = optax.sgd(t.learning_rate,
                     momentum=t.momentum if t.momentum else None)

@@ -44,8 +44,9 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.seq import ring_attention
-from .lora import lora_init, lora_merge
+from .lora import lora_init
 from .tp import tp_param_specs
+from .transformer import adapted_apply_fn
 
 Pytree = Any
 
@@ -190,8 +191,8 @@ def build_scaled_fedllm(model_cls, mesh: Mesh, *, vocab_size: int,
             else:
                 dense_base = (dequantize_tree(base, dtype) if quantize_base
                               else base)
-                merged = lora_merge(dense_base, ad, alpha)
-                logits = model.apply({"params": merged}, tokens)
+                logits = adapted_apply_fn(model, dense_base, alpha)(
+                    {"params": ad}, tokens)
             logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
             ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
             return -ll.mean()

@@ -15,18 +15,24 @@ TPU-first details:
 - all matmuls are [B*T, D] x [D, F] shapes that XLA tiles onto the MXU;
   bfloat16 compute composes via models/hub.mixed_precision_apply.
 - weights are plain pytrees — LoRA (llm/lora.py) and federated aggregation
-  operate on them without touching this module.
+  operate on them without touching this module. What the Block does know of
+  LoRA is the backward of an adapted projection: handed the factors beside
+  the merged kernels (`adapted_apply_fn`), its `nn.Dense` sites multiply
+  through `lora.adapted_dot_general`; handed none, they are plain.
 """
 from __future__ import annotations
 
 import functools
+import re
 from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax import traverse_util
 
 from ..parallel.seq import dense_causal_attention
+from .lora import LORA, adapted_dot_general, lora_merge
 from .moe import ExpertLayer, MoE
 
 # a layer's kind: (attention, feed-forward). "full" attention is causal over
@@ -60,6 +66,16 @@ class RMSNorm(nn.Module):
         return (x * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * scale
 
 
+def _dense(parent: nn.Module, features: int, name: str) -> nn.Dense:
+    """`parent`'s bias-free projection `name`: plain, or, where the `lora`
+    collection holds factors for its kernel, with the adapted product's
+    backward."""
+    ab = parent.variables.get(LORA, {}).get(name, {}).get("kernel")
+    return nn.Dense(
+        features, use_bias=False, name=name,
+        dot_general=ab and adapted_dot_general(ab["a"], ab["b"]))
+
+
 class Block(nn.Module):
     """The ONE decoder block. Its defaults are the dense block (as many KV
     heads as heads, heads of d_model / n_heads, full causal attention with
@@ -88,9 +104,9 @@ class Block(nn.Module):
         norm = functools.partial(RMSNorm, eps=self.norm_eps)
         with jax.named_scope("lm.attn"):
             h = norm()(x)
-            q = nn.Dense(self.n_heads * dh, use_bias=False, name="wq")(h)
-            k = nn.Dense(n_kv * dh, use_bias=False, name="wk")(h)
-            v = nn.Dense(n_kv * dh, use_bias=False, name="wv")(h)
+            q = _dense(self, self.n_heads * dh, "wq")(h)
+            k = _dense(self, n_kv * dh, "wk")(h)
+            v = _dense(self, n_kv * dh, "wv")(h)
             split = lambda a: a.reshape(a.shape[:2] + (-1, dh))
             q, k, v = split(q), split(k), split(v)
             if self.qk_norm:
@@ -102,16 +118,15 @@ class Block(nn.Module):
             o = (attn(q, k, v) if self.window is None
                  else attn(q, k, v, window=self.window))
             o = o.reshape(o.shape[:2] + (self.n_heads * dh,))
-            x = x + nn.Dense(d_model, use_bias=False, name="wo")(o)
+            x = x + _dense(self, d_model, "wo")(o)
 
         with jax.named_scope("lm.mlp"):
             h = norm()(x)
             if self.moe is not None:
                 return x + ExpertLayer(self.moe, name="moe")(h)
-            gate = nn.Dense(self.d_ff, use_bias=False, name="w_gate")(h)
-            up = nn.Dense(self.d_ff, use_bias=False, name="w_up")(h)
-            x = x + nn.Dense(d_model, use_bias=False, name="w_down")(
-                nn.silu(gate) * up)
+            gate = _dense(self, self.d_ff, "w_gate")(h)
+            up = _dense(self, self.d_ff, "w_up")(h)
+            x = x + _dense(self, d_model, "w_down")(nn.silu(gate) * up)
         return x
 
 
@@ -167,6 +182,13 @@ class TransformerLM(nn.Module):
                 f"{kinds}")
         return kinds
 
+    @staticmethod
+    def adapts(path: str) -> bool:
+        """Whether the kernel at `path` is one a Block multiplies itself,
+        through `_dense`: handed factors in the `lora` collection, it wants
+        its merged kernel as a constant (`adapted_apply_fn`)."""
+        return bool(re.fullmatch(r"(blocks|block_\d+)/[^/]+/kernel", path))
+
     @property
     def has_counters(self) -> bool:
         """Whether a call sows into the `counters` collection (the expert
@@ -204,7 +226,7 @@ class TransformerLM(nn.Module):
                     "the layers unrolled")
             x, _ = nn.scan(
                 lambda mdl, carry, _xs: (mdl(carry, pos), None),
-                variable_axes={"params": 0},
+                variable_axes={"params": 0, LORA: 0},
                 split_rngs={"params": True},
                 length=self.n_layers,
             )(self.block(kinds[0], name="blocks"), x, None)
@@ -215,3 +237,43 @@ class TransformerLM(nn.Module):
             x = RMSNorm(self.norm_eps, name="final_norm")(x)
             return nn.Dense(self.vocab_size, use_bias=False,
                             name="lm_head")(x)
+
+
+def adapted_apply_fn(model: nn.Module, base_params, alpha: float = 16.0,
+                     compute_dtype: str = "float32",
+                     apply_fn: Optional[Callable] = None) -> Callable:
+    """`lora.lora_apply_fn` over `model.apply` (or `apply_fn`, a wrapper of
+    it): the same (adapters -> logits) view and the same forward, one
+    product over each merged kernel, with the merge where it was, once a
+    call and outside any rematerialised block. What differs is the backward
+    of the projections the model says it multiplies through
+    `lora.adapted_dot_general` (`model.adapts(path)`; a TransformerLM's are
+    its Blocks' own): their merged kernel goes in as a constant and the
+    factors beside it in the `lora` collection, so their gradients are
+    rank-r products. An adapter of any other kernel (the head, an expert
+    layer's, every kernel of a module that declares none) is differentiated
+    through the merge as before. `compute_dtype` is
+    `hub.mixed_precision_apply`'s, for the merged parameters and the input
+    alone: the factors stay float32."""
+    from ..models.hub import mixed_precision_apply
+
+    apply_fn = apply_fn or model.apply
+    adapts = getattr(model, "adapts", lambda path: False)
+
+    def wrapped(variables, x, *args, **kwargs):
+        adapters = variables["params"]
+        sited = {k: v for k, v in adapters.items() if adapts(k)}
+        rest = {k: v for k, v in adapters.items() if k not in sited}
+        merged = lora_merge(lora_merge(base_params, rest, alpha),
+                            jax.lax.stop_gradient(sited), alpha)
+        factors = traverse_util.unflatten_dict({
+            k: {"a": (alpha / v["a"].shape[-1]) * v["a"], "b": v["b"]}
+            for k, v in sited.items()}, sep="/")
+
+        def adapted(vs, x, *args, **kwargs):
+            return apply_fn({**vs, LORA: factors}, x, *args, **kwargs)
+
+        return mixed_precision_apply(adapted, compute_dtype)(
+            {"params": merged}, x, *args, **kwargs)
+
+    return wrapped
