@@ -801,6 +801,13 @@ def _top_frame(snap: dict, source: str, prev: dict = None,
             if table:
                 walked = c.get("serving_engine_page_steps_total", 0)
                 seg += f"  walk {walked / table * 100:.1f}%"
+            # share of the keys its queries saw that the selection let into
+            # the softmax: under 100% only where an indexer selects
+            # (llm/latent.py); the model's arithmetic, not the kernel's walk
+            seen = c.get("serving_engine_context_keys_total", 0)
+            picked = c.get("serving_engine_selected_keys_total", 0)
+            if seen and picked < seen:
+                seg += f"  selected {picked / seen * 100:.0f}%"
             hits = int(c.get("serving_prefix_hits_total", 0))
             miss = int(c.get("serving_prefix_misses_total", 0))
             if hits + miss:

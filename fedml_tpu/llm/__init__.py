@@ -141,6 +141,11 @@ def make_fedllm_seq_round(
     from .decode import unserved
 
     lacking = unserved(model)
+    if getattr(model, "latent", None) is not None or (
+            model.norm_eps, model.rope_base) != (1e-6, 10000.0):
+        lacking = lacking + [
+            "latent attention, another norm eps or rope base: the block "
+            "is rebuilt from the dense fields"]
     if lacking:
         # ring and ulysses attention are full-causal over as many KV heads
         # as heads, and the model is rebuilt below from the dense fields

@@ -23,7 +23,10 @@ That pair (`make_kv_decode`) is the PER-REQUEST path: a cache per call,
 sized to the request, inside `make_generate`'s one program — and the
 oracle the engine's tests compare against. The continuous-batching engine
 (serving/engine.py) runs `make_paged_kv_decode`'s four programs over one
-persistent pool of KV pages.
+persistent pool of KV pages, or, for a model of latent-attention layers
+(llm/latent.py; a dense or an expert feed-forward a layer),
+`make_paged_latent_decode`'s four over a pool of latent rows with no heads
+axis. `unserved(model)` says what neither set runs.
 
 Per-token cost drops from O(T·D²) (full recompute of every position's
 projections) to O(D² + T·D): at max_len=256 that is ~two orders of
@@ -51,8 +54,9 @@ Pytree = Any
 
 def layer_scope(part: str):
     """`decode.<part>` as a `jax.named_scope`: the names a device trace
-    shows for the decode programs' layers (kv_write, attn, mlp, head; the
-    engine adds sample). What a layer scan itself does (slicing the
+    shows for the decode programs' layers (kv_write, attn, mlp, head, and in
+    the latent programs index and moe; the engine adds sample). What a layer
+    scan itself does (slicing the
     stacked weights; until PR 27 also moving the KV pool in and out) is
     left outside every scope on purpose: it reads as the program's time
     under no layer (PERF.md section 3). One place for the names, so a
@@ -62,17 +66,22 @@ def layer_scope(part: str):
 
 def unserved(model) -> list:
     """What a TransformerLM has that no decode program here can run, one
-    sentence a mechanism. The decode bodies below are the DENSE block's
-    (as many KV heads as heads, every layer full attention with rotary
-    positions, a SwiGLU); llm/transformer.py's block trains more than they
+    sentence a mechanism. Two sets of programs exist: the DENSE block's
+    (`make_paged_kv_decode`: as many KV heads as heads, every layer full
+    attention with rotary positions, a SwiGLU; the model's own norm eps and
+    rope base) and the LATENT block's (`make_paged_latent_decode`: latent
+    attention under its indexer in EVERY layer, a SwiGLU or the expert
+    layer a layer); llm/transformer.py's block trains more than they
     serve."""
     out = []
     if not hasattr(model, "kinds"):     # no TransformerLM: nothing to depart in
         return out
     kinds = model.kinds
+    latent = [a == "latent" for a, _ in kinds]
     if (model.n_kv_heads or model.n_heads) != model.n_heads or (
-            model.head_dim or model.d_model // model.n_heads
-    ) * model.n_heads != model.d_model:
+            not any(latent) and (
+                model.head_dim or model.d_model // model.n_heads
+            ) * model.n_heads != model.d_model):
         out.append(
             "grouped KV heads: the paged kernel (ops/paged_attention.py) and "
             "the prefill, step and verify bodies of llm/decode.py split wk "
@@ -82,15 +91,20 @@ def unserved(model) -> list:
             "window layers: the KV cache and the page allocator of "
             "serving/engine.py keep every position of every layer and give "
             "no page back once it has left a layer's window")
-    if any(f == "moe" for _, f in kinds):
+    if any(latent) and not all(latent):
         out.append(
-            "expert layers: the decode step has no router and no grouped "
-            "product over held experts (llm/moe.py runs in training only)")
-    if model.qk_norm or not model.rope_full or model.norm_eps != 1e-6 \
-            or model.rope_base != 10000.0:
+            "latent layers beside layers of per-head keys and values: a "
+            "page pool holds ONE kind of row (serving/engine.py allocates "
+            "`kv` and `ik` leaves or `k` and `v` leaves, never both)")
+    if any(f == "moe" for _, f in kinds) and not all(latent):
         out.append(
-            "per-head q/k norms, layers without rotary positions, another "
-            "norm eps or rope base: the decode bodies fix the dense block's")
+            "expert layers under full or window attention: only the latent "
+            "programs (make_paged_latent_decode) run llm/moe.py's expert "
+            "layer in the decode step; the dense block's have no router")
+    if model.qk_norm or not model.rope_full:
+        out.append(
+            "per-head q/k norms, layers without rotary positions: the "
+            "decode bodies fix the dense block's")
     return out
 
 
@@ -107,22 +121,34 @@ def require_servable(model) -> None:
 
 def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     """Convert an UNROLLED TransformerLM param tree (block_0..block_{L-1})
-    to the stacked scan-layers layout ({"blocks": [L, ...]}) the decode
-    path consumes. Scan-layout trees pass through unchanged."""
+    to the layout the decode path consumes: `{"blocks": [L, ...]}`, every
+    leaf stacked on a leading layer axis, where every layer has the same
+    parameters (the dense block's programs scan over it), and a TUPLE of
+    the layers' trees as they are where they differ in kind (a dense
+    feed-forward, then expert layers): the latent programs run such layers
+    unrolled, each layer's weights read where they lie. Nothing is copied
+    for the tuple, so a tree that fills half the device still fits.
+    Trees already in either layout pass through unchanged."""
     if "blocks" in params:
         return params
+    blocks = [params[f"block_{i}"] for i in range(n_layers)]
+    out = {k: v for k, v in params.items() if not k.startswith("block_")}
+    if len({jax.tree.structure(b) for b in blocks}) > 1:
+        out["blocks"] = tuple(blocks)
+        return out
     from ..ops.tree import tree_stack
 
-    blocks = [params[f"block_{i}"] for i in range(n_layers)]
-    if len({jax.tree.structure(b) for b in blocks}) > 1:
-        raise NotImplementedError(
-            "the layers' parameters differ in kind (a dense and an expert "
-            "feed-forward): the decode path scans ONE block over a stacked "
-            "[L, ...] tree and has no expert layer")
-    stacked = tree_stack(blocks)
-    out = {k: v for k, v in params.items() if not k.startswith("block_")}
-    out["blocks"] = stacked
+    out["blocks"] = tree_stack(blocks)
     return out
+
+
+def block_layers(blocks) -> list:
+    """The layers' parameter trees, one a layer, of either layout
+    `stack_blocks` gives."""
+    if isinstance(blocks, (tuple, list)):
+        return list(blocks)
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    return [jax.tree.map(lambda a: a[i], blocks) for i in range(n)]
 
 
 def stack_adapter_blocks(adapters: Optional[Pytree],
@@ -363,7 +389,7 @@ def _kv_quant_write(pool, scales, wpage, woff, vals):
 def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                          dtype=jnp.float32, eps: float = 1e-6,
                          kernel: bool = False, mesh=None,
-                         quant: bool = False):
+                         quant: bool = False, rope_base: float = 10000.0):
     """The decode engine's programs (serving/engine.py): K/V live in a
     persistent POOL of fixed-size pages `[L, n_pages, page_size, H, Dh]`,
     and each slot's logical sequence is described by an int32 page-table
@@ -466,7 +492,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     kernel over the heads axis — the same layout
     partition.paged_kv_cache_spec pins on the pool, reaching the kernel
     with zero resharding. Token identity vs the gather path is pinned in
-    tests/test_decode_kernel_spec.py."""
+    tests/test_decode_kernel_spec.py. `eps` and `rope_base` are the
+    model's own (`norm_eps`, `rope_base`)."""
     ps = int(page_size)
     norm, dq, merged, qkv, mlp, head, split_ads = _block_math(
         dtype, eps, alpha)
@@ -538,8 +565,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr[None, :])
-                k = _rope_rows(k, posr[None, :])
+                q = _rope_rows(q, posr[None, :], rope_base)
+                k = _rope_rows(k, posr[None, :], rope_base)
             pool = kv_write(pool, base + wpage, woff, k[0], v[0])
             with layer_scope("attn"):
                 # gather AFTER the write so the chunk attends to itself;
@@ -617,8 +644,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr)
-                k = _rope_rows(k, posr)
+                q = _rope_rows(q, posr, rope_base)
+                k = _rope_rows(k, posr, rope_base)
             pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
                 if kernel:
@@ -681,8 +708,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
                 q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr)
-                k = _rope_rows(k, posr)
+                q = _rope_rows(q, posr, rope_base)
+                k = _rope_rows(k, posr, rope_base)
             pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
                 kk, vv = kv_pages(pool, base + pages)
@@ -707,6 +734,207 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     x, lengths)
             logits = head(params, top_ads, rank_scale, last[:, None])
         return cache, logits[:, 0]
+
+    return chunk, step, verify, chunk_batch
+
+
+LATENT_ADAPTERS = (
+    "LoRA adapters on a latent-attention model: the decode programs merge "
+    "adapters into wq/wk/wv/wo and the SwiGLU (llm/quant.py merged_kernel) "
+    "and know no low-rank projection; merge them first (llm.lora.lora_merge)")
+
+
+def make_paged_latent_decode(model, page_size: int, dtype=jnp.float32):
+    """`make_paged_kv_decode`'s four programs for a model whose layers are
+    latent attention under an indexer's selection (llm/latent.py), with a
+    dense or an expert feed-forward (llm/moe.py) a layer. Same arguments,
+    same page table, same null page 0, same returns.
+
+    The pool has NO heads axis: a token is ONE row a layer, in two leaves,
+    `kv` `[L, P, page, width]` holding `c_kv || k_rope` (padded to whole
+    lanes: `Latent.width`) and `ik` `[L, P, page, index_dim]` holding the
+    indexer's key. Both are threaded through the layers flat
+    (`[L * P, ...]`, layer l at pages `l * P + id`, as the K/V pool is), and
+    a prefix page brings both back.
+
+    One body for all four programs (a chunk is a batch of one): the new
+    rows are written, then attention runs in the ABSORBED form over the
+    slot's pages in place: `ops.paged_attention.index_scores` walks the
+    live pages of `ik` for the indexer's scores, `latent.select_top` picks
+    each query's `index_topk` positions among those it may see, and
+    `ops.paged_attention.latent_attention` walks the live pages of `kv`
+    under that selection. What is per (query, key) is sized by a BUCKET of
+    the table (`ladder`), chosen by `lax.switch` from the farthest position
+    any active slot's query reaches, and the kernels' walks by each slot's
+    own live pages: a slot that holds 40 tokens, or none, pays for neither
+    the table nor its neighbours. While no query sees more than
+    `index_topk` positions the indexer's scores are not computed at all
+    (every position is selected); its keys are written all the same.
+
+    The layers run UNROLLED (`block_layers(params["blocks"])`), the pool
+    threaded through them flat, each layer's weights read where they lie:
+    a scan over stacked layers slices every layer's weights out of the
+    stack, a copy, a step (the dense programs' `pool_copy_share`), and an
+    expert layer's stack is 1.2 GB (my chip run, PR 34, call 2: 60% of the
+    step was that copy). The model's own `norm_eps` and `rope_base` apply. LoRA adapters, int8
+    pages and an `mp` mesh are the engine's to refuse: these programs take
+    `adapters` for the signature's sake and want none."""
+    from ..ops.paged_attention import (
+        index_scores, latent_attention, latent_block_pages,
+    )
+    from . import latent as la
+    from .moe import ExpertLayer
+
+    lat, n_heads = model.latent, model.n_heads
+    eps, base = model.norm_eps, model.rope_base
+    ps = int(page_size)
+
+    def whole(n_pages: int) -> int:
+        """`n_pages` rounded up to whole blocks of the kernels' walk."""
+        block = latent_block_pages(n_pages)
+        return -(-n_pages // block) * block
+
+    def ladder(max_pages: int) -> list:
+        """Page counts of the buckets: what holds `index_topk` positions,
+        then four times as much a rung, up to the table; whole blocks."""
+        top = whole(max_pages)
+        rungs = [min(whole(-(-lat.index_topk // ps)), top)]
+        while rungs[-1] < top:
+            rungs.append(min(whole(4 * rungs[-1]), top))
+        return rungs
+
+    def blocked_positions(n_pages: int):
+        """[n_blocks, 1, block * page_size]: the virtual position of every
+        key of a bucket, in the kernels' blocked layout."""
+        t_blk = latent_block_pages(n_pages) * ps
+        return jnp.arange(n_pages * ps, dtype=jnp.int32).reshape(
+            -1, 1, t_blk)
+
+    def attend(n_pages: int, select: bool):
+        """One rung: attention over the first `n_pages` of every table."""
+        def run(qf, qi, wi, pool, pages, live, posr):
+            pages, live = pages[:, :n_pages], jnp.minimum(live, n_pages)
+            seen = (blocked_positions(n_pages)[None]
+                    <= posr[:, None, :, None])          # [B, NB, C, T_blk]
+            if select:
+                with layer_scope("index"):
+                    scores = index_scores(qi, wi, pool["ik"], pages, live)
+                    seen = la.select_top(scores, seen, lat.index_topk,
+                                         (1, 3))
+            with layer_scope("attn"):
+                bias = jnp.where(seen, 0.0, la.RULED_OUT).astype(jnp.float32)
+                return latent_attention(qf, pool["kv"], pages, live, bias,
+                                        lat.kv_rank)
+        return run
+
+    def forward(params, adapters, cache, pages, tokens, pos0, wmask, active):
+        """tokens [B, C] at positions pos0 .. pos0 + C - 1 of their slots'
+        tables `pages` [B, max_pages]; `wmask` [B, C] says whose rows are
+        written (the others' go to the null page), `active` [B] whose
+        queries are wanted. -> (hidden [B, C, d], cache)."""
+        if adapters:
+            raise NotImplementedError(LATENT_ADAPTERS)
+        x = dequant_leaf(params["embed"]["embedding"], dtype)[tokens]
+        b_, c = tokens.shape
+        pos0 = jnp.asarray(pos0, jnp.int32)
+        posr = pos0[:, None] + jnp.arange(c)                  # [B, C]
+        max_pages = pages.shape[1]
+        rowidx = posr // ps
+        wpage = jnp.where(
+            wmask & (rowidx < max_pages),
+            pages[jnp.arange(b_)[:, None],
+                  jnp.minimum(rowidx, max_pages - 1)], 0)
+        woff = posr % ps
+        rungs = ladder(max_pages)
+        pages = jnp.pad(pages, ((0, 0), (0, rungs[-1] - max_pages)))
+        # pages that hold a position some wanted query may see, and the
+        # rung that holds the farthest of them
+        reach = jnp.where(active, pos0 + c, 0)
+        live = -(-reach // ps)
+        selecting = [r for r in rungs if r * ps > lat.index_topk]
+        branches = [attend(rungs[0], False)] + [
+            attend(r, True) for r in selecting]
+        # rung 0 while no query sees more than `index_topk` positions, else
+        # the first selecting rung that holds the farthest position
+        short = jnp.sum(-(-jnp.max(reach) // ps) > jnp.asarray(
+            selecting or [0], jnp.int32))
+        rung = jnp.where(jnp.max(reach) <= lat.index_topk, 0,
+                         1 + jnp.minimum(short, len(selecting) - 1))
+
+        def layer(x, pool, lbase, bl):
+            with layer_scope("attn"):
+                h = la.rms_norm(x, dequant_leaf(bl["RMSNorm_0"]["scale"],
+                                                dtype), eps)
+                c_q, q_nope, q_rope, c_kv, k_rope = la.project(
+                    bl, h, posr, lat, n_heads, eps, base)
+                qf = la.absorb_queries(bl, q_nope, q_rope, lat)
+            with layer_scope("index"):
+                qi, ki, wi = la.index_inputs(bl, h, c_q, posr, lat, eps, base)
+            with layer_scope("kv_write"):
+                pool = {
+                    "kv": pool["kv"].at[lbase + wpage, woff].set(
+                        la.cached_row(c_kv, k_rope, lat)),
+                    "ik": pool["ik"].at[lbase + wpage, woff].set(ki)}
+            operands = (qf, qi, wi, pool, lbase + pages, live, posr)
+            o_lat = (branches[0](*operands) if len(branches) == 1 else
+                     jax.lax.switch(rung, branches, *operands))
+            with layer_scope("attn"):
+                x = x + la.expand_values(bl, o_lat, lat) @ dequant_leaf(
+                    bl["wo"]["kernel"], dtype)
+            if "moe" not in bl:
+                with layer_scope("mlp"):
+                    return swiglu_mlp(bl, None, 0.0, x, dtype, eps), pool
+            with layer_scope("moe"):
+                h = la.rms_norm(x, dequant_leaf(bl["RMSNorm_1"]["scale"],
+                                                dtype), eps)
+                return x + ExpertLayer(model.moe).apply(
+                    {"params": bl["moe"]}, h), pool
+
+        n_pool = cache["kv"].shape[1]
+        pool = {name: leaf.reshape((-1,) + leaf.shape[2:])
+                for name, leaf in cache.items()}
+        for i, bl in enumerate(block_layers(params["blocks"])):
+            x, pool = layer(x, pool, i * n_pool, bl)
+        return x, {name: leaf.reshape(cache[name].shape)
+                   for name, leaf in pool.items()}
+
+    def head(params, x):
+        with layer_scope("head"):
+            return lm_head_logits(params, None, 0.0, x, dtype, eps)
+
+    def chunk(params, adapters, cache, pages_row, tokens, t0, length):
+        length = jnp.asarray(length, jnp.int32)
+        x, cache = forward(
+            params, adapters, cache, pages_row[None], tokens,
+            jnp.asarray(t0, jnp.int32)[None],
+            (jnp.arange(tokens.shape[1]) < length)[None],
+            jnp.ones((1,), bool))
+        last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
+                                            keepdims=False)
+        return cache, head(params, last[None, None])[:, 0]
+
+    def verify(params, adapters, cache, pages, pos, tokens, active):
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                               (tokens.shape[0],))
+        x, cache = forward(
+            params, adapters, cache, pages, tokens, pos,
+            jnp.broadcast_to(active[:, None], tokens.shape), active)
+        return cache, head(params, x)
+
+    def step(params, adapters, cache, pages, pos, token, active):
+        cache, logits = verify(params, adapters, cache, pages, pos,
+                               token[:, None], active)
+        return cache, logits[:, 0]
+
+    def chunk_batch(params, adapters, cache, pages, tokens, t0, lengths):
+        lengths = jnp.asarray(lengths, jnp.int32)
+        x, cache = forward(
+            params, adapters, cache, pages, tokens, t0,
+            jnp.arange(tokens.shape[1])[None, :] < lengths[:, None],
+            lengths > 0)
+        last = jax.vmap(lambda xr, n: jax.lax.dynamic_index_in_dim(
+            xr, jnp.maximum(n, 1) - 1, axis=0, keepdims=False))(x, lengths)
+        return cache, head(params, last[:, None])[:, 0]
 
     return chunk, step, verify, chunk_batch
 

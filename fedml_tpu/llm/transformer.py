@@ -32,13 +32,18 @@ import jax.numpy as jnp
 from flax import traverse_util
 
 from ..parallel.seq import dense_causal_attention
+from . import latent as latent_attention
+from .latent import Latent
 from .lora import LORA, adapted_dot_general, lora_merge
-from .moe import ExpertLayer, MoE
+from .moe import ExpertLayer, Kernel, MoE
 
 # a layer's kind: (attention, feed-forward). "full" attention is causal over
-# the whole sequence, "window" over the last `window` positions; the
+# the whole sequence, "window" over the last `window` positions, "latent" is
+# latent attention under its indexer's selection (llm/latent.py); the
 # feed-forward is the "dense" SwiGLU or the "moe" expert layer (llm/moe.py)
 DENSE_LAYER = ("full", "dense")
+# the kernels a Block multiplies through `_dense`
+_DENSE_SITES = "wq|wk|wv|wo|w_gate|w_up|w_down"
 
 
 def rope(x: jax.Array, pos: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -66,6 +71,21 @@ class RMSNorm(nn.Module):
         return (x * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * scale
 
 
+class Affine(nn.Module):
+    """A norm's parameters under the leaf names `scale` (and `bias`), as a
+    dict: the norm itself is the caller's (llm/latent.py's functions take a
+    block's parameters, whoever declared them)."""
+    n: int
+    bias: bool = False
+
+    @nn.compact
+    def __call__(self):
+        out = {"scale": self.param("scale", nn.initializers.ones, (self.n,))}
+        if self.bias:
+            out["bias"] = self.param("bias", nn.initializers.zeros, (self.n,))
+        return out
+
+
 def _dense(parent: nn.Module, features: int, name: str) -> nn.Dense:
     """`parent`'s bias-free projection `name`: plain, or, where the `lora`
     collection holds factors for its kernel, with the adapted product's
@@ -91,6 +111,45 @@ class Block(nn.Module):
     window: Optional[int] = None        # i sees j only where 0 <= i-j < window
     qk_norm: bool = False               # RMSNorm over each head of q and k
     moe: Optional[MoE] = None           # the expert layer in the SwiGLU's place
+    latent: Optional[Latent] = None     # latent attention in q/k/v's place
+
+    @nn.nowrap
+    def latent_params(self, d_model: int) -> dict:
+        """The parameters llm/latent.py's functions read, declared here."""
+        lat = self.latent
+        kernels = {
+            "wq_a": (d_model, lat.q_rank),
+            "wq_b": (lat.q_rank, self.n_heads * (lat.nope + lat.rope)),
+            "wkv_a": (d_model, lat.kv_rank + lat.rope),
+            "wkv_b": (lat.kv_rank, self.n_heads * (lat.nope + lat.v_dim)),
+            "index_wq": (lat.q_rank, lat.index_heads * lat.index_dim),
+            "index_wk": (d_model, lat.index_dim),
+            "index_w": (d_model, lat.index_heads)}
+        out = {name: {"kernel": Kernel(shape, name=name)()}
+               for name, shape in kernels.items()}
+        out["q_a_norm"] = Affine(lat.q_rank, name="q_a_norm")()
+        out["kv_a_norm"] = Affine(lat.kv_rank, name="kv_a_norm")()
+        out["index_k_norm"] = Affine(lat.index_dim, True,
+                                     name="index_k_norm")()
+        return out
+
+    @nn.nowrap
+    def heads_attention(self, h, pos, dh: int, n_kv: int, norm):
+        """Attention over per-head keys and values projected from `h`."""
+        q = _dense(self, self.n_heads * dh, "wq")(h)
+        k = _dense(self, n_kv * dh, "wk")(h)
+        v = _dense(self, n_kv * dh, "wv")(h)
+        split = lambda a: a.reshape(a.shape[:2] + (-1, dh))
+        q, k, v = split(q), split(k), split(v)
+        if self.qk_norm:
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+        if self.rope_base is not None:
+            q, k = (rope(q, pos, self.rope_base),
+                    rope(k, pos, self.rope_base))
+        attn = self.attn_fn or dense_causal_attention
+        o = (attn(q, k, v) if self.window is None
+             else attn(q, k, v, window=self.window))
+        return o.reshape(o.shape[:2] + (self.n_heads * dh,))
 
     @nn.compact
     def __call__(self, x, pos):
@@ -104,20 +163,12 @@ class Block(nn.Module):
         norm = functools.partial(RMSNorm, eps=self.norm_eps)
         with jax.named_scope("lm.attn"):
             h = norm()(x)
-            q = _dense(self, self.n_heads * dh, "wq")(h)
-            k = _dense(self, n_kv * dh, "wk")(h)
-            v = _dense(self, n_kv * dh, "wv")(h)
-            split = lambda a: a.reshape(a.shape[:2] + (-1, dh))
-            q, k, v = split(q), split(k), split(v)
-            if self.qk_norm:
-                q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
-            if self.rope_base is not None:
-                q, k = (rope(q, pos, self.rope_base),
-                        rope(k, pos, self.rope_base))
-            attn = self.attn_fn or dense_causal_attention
-            o = (attn(q, k, v) if self.window is None
-                 else attn(q, k, v, window=self.window))
-            o = o.reshape(o.shape[:2] + (self.n_heads * dh,))
+            if self.latent is not None:
+                o = latent_attention.full_attention(
+                    self.latent_params(d_model), h, pos[None], self.latent,
+                    self.n_heads, self.norm_eps, self.rope_base)
+            else:
+                o = self.heads_attention(h, pos, dh, n_kv, norm)
             x = x + _dense(self, d_model, "wo")(o)
 
         with jax.named_scope("lm.mlp"):
@@ -167,6 +218,7 @@ class TransformerLM(nn.Module):
     window: Optional[int] = None
     qk_norm: bool = False
     moe: Optional[MoE] = None
+    latent: Optional[Latent] = None
     layer_kinds: Optional[tuple] = None
 
     @property
@@ -174,12 +226,12 @@ class TransformerLM(nn.Module):
         kinds = self.layer_kinds or (DENSE_LAYER,) * self.n_layers
         kinds = tuple(tuple(k) for k in kinds)
         if len(kinds) != self.n_layers or any(
-                a not in ("full", "window") or f not in ("dense", "moe")
-                for a, f in kinds):
+                a not in ("full", "window", "latent")
+                or f not in ("dense", "moe") for a, f in kinds):
             raise ValueError(
                 f"layer_kinds must give {self.n_layers} (attention, "
-                "feed-forward) pairs of full|window and dense|moe, got "
-                f"{kinds}")
+                "feed-forward) pairs of full|window|latent and dense|moe, "
+                f"got {kinds}")
         return kinds
 
     @staticmethod
@@ -187,7 +239,8 @@ class TransformerLM(nn.Module):
         """Whether the kernel at `path` is one a Block multiplies itself,
         through `_dense`: handed factors in the `lora` collection, it wants
         its merged kernel as a constant (`adapted_apply_fn`)."""
-        return bool(re.fullmatch(r"(blocks|block_\d+)/[^/]+/kernel", path))
+        return bool(re.fullmatch(
+            rf"(blocks|block_\d+)/({_DENSE_SITES})/kernel", path))
 
     @property
     def has_counters(self) -> bool:
@@ -201,6 +254,8 @@ class TransformerLM(nn.Module):
             raise ValueError("a window layer needs `window`")
         if ff == "moe" and self.moe is None:
             raise ValueError("a moe layer needs `moe`")
+        if attention == "latent" and self.latent is None:
+            raise ValueError("a latent layer needs `latent`")
         windowed = attention == "window"
         cls = Block
         if self.remat:
@@ -210,7 +265,8 @@ class TransformerLM(nn.Module):
             self.head_dim, self.norm_eps,
             self.rope_base if windowed or self.rope_full else None,
             self.window if windowed else None, self.qk_norm,
-            self.moe if ff == "moe" else None, **kw)
+            self.moe if ff == "moe" else None,
+            self.latent if attention == "latent" else None, **kw)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, pos_offset=0):

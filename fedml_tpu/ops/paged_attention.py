@@ -265,3 +265,225 @@ def paged_attention(q, k_pool, v_pool, pages, pos,
     scales = None if k_scales is None else (k_scales, v_scales)
     return _call(q, k_pool, v_pool, pages, pos, active, scales,
                  bool(interpret))
+
+
+# --------------------------------------------------------------------------
+# Latent pages: a page holds ONE row a token (no heads axis), the compressed
+# key/value `c_kv || k_rope` in one pool leaf and the indexer's key in
+# another. Both kernels below walk a slot's LIVE pages as the kernel above
+# does (grid over slots, a loop over page blocks whose trip count is the
+# slot's own, hand-written double-buffered DMAs straight out of the pool),
+# and both speak one BLOCKED layout for what is per (query, key):
+# `[S, n_blocks, C, block * page_size]`, block b holding virtual positions
+# `b * block * page_size ...`. A block past a slot's live pages is never
+# touched: the scores there are whatever the buffer held, and the caller's
+# selection reads them as not valid.
+_LATENT_BLOCK_PAGES = 16        # pages a block: 256 positions at page 16
+_INDEX_QUERIES = 64             # queries a grid step of `index_scores`
+_LATENT_QUERIES = 32            # queries a grid step of `latent_attention`
+_VMEM_LIMIT = 100 * 2 ** 20
+
+
+def latent_block_pages(n_pages: int) -> int:
+    """Pages a block of the latent kernels' walk, for a table of `n_pages`
+    (which must be a multiple of it)."""
+    return min(_LATENT_BLOCK_PAGES, n_pages)
+
+
+def _query_tile(c: int, cap: int) -> int:
+    """Queries a grid step: the largest halving of `cap` that divides `c`
+    into whole sublane tiles of 8, else all `c` at once (a block's last two
+    dims must be (8, 128)-tiled or the array's own)."""
+    b = min(cap, c)
+    while c % b:
+        b //= 2
+    return b if b % 8 == 0 or b == c else c
+
+
+def _page_walk(pages_ref, live_ref, pool, buf, sems, block: int, body, init):
+    """`fori_loop` over the page blocks of slot `program_id(0)` that hold a
+    live page: block b's `block` slabs are waited for in `buf[b % 2]` after
+    block b + 1's were started into the other half. `body(b, slabs,
+    carry)` sees the block as `[block, page_size, width]`."""
+    s_idx = pl.program_id(0)
+    n_pages = pages_ref.shape[1]
+    n_blocks = pl.cdiv(live_ref[s_idx], block)
+
+    def copies(b, half):
+        return [pltpu.make_async_copy(
+            pool.at[pages_ref[s_idx, jnp.minimum(b * block + i, n_pages - 1)]],
+            buf.at[half, i], sems.at[half]) for i in range(block)]
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for cp in copies(0, 0):
+            cp.start()
+
+    def step(b, carry):
+        half = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            for cp in copies(b + 1, 1 - half):
+                cp.start()
+
+        for cp in copies(b, half):
+            cp.wait()
+        return body(b, buf[half], carry)
+
+    return jax.lax.fori_loop(0, n_blocks, step, init)
+
+
+def _index_kernel(pages_ref, live_ref, q_ref, w_ref, k_hbm, o_ref, k_buf,
+                  sems, *, block: int, heads: int):
+    page_size, di = k_buf.shape[2:]
+    t_blk = block * page_size
+    q = q_ref[0]                                   # [Cb * Hi, Di]
+    w = w_ref[0]                                   # [Cb * Hi, 1] f32
+    cb = q.shape[0] // heads
+
+    def body(b, slabs, carry):
+        kb = slabs.reshape(t_blk, di)
+        d = _dot(q, kb, ((1,), (1,)), ((), ()))    # [Cb * Hi, T_blk]
+        d = jnp.maximum(d, 0.0) * w
+        o_ref[0, b] = d.reshape(cb, heads, t_blk).sum(axis=1)
+        return carry
+
+    _page_walk(pages_ref, live_ref, k_hbm, k_buf, sems, block, body, 0)
+
+
+def _latent_kernel(pages_ref, live_ref, q_ref, bias_ref, kv_hbm, o_ref,
+                   kv_buf, sems, *, block: int, heads: int, rank: int):
+    page_size, width = kv_buf.shape[2:]
+    t_blk = block * page_size
+    q = q_ref[0]                                   # [Cb * H, width]
+    rows = q.shape[0]
+    cb = rows // heads
+
+    def body(b, slabs, carry):
+        m, l, o = carry
+        kv = slabs.reshape(t_blk, width)
+        c_kv = kv[:, :rank]
+        s = _dot(q, kv, ((1,), (1,)), ((), ()))             # [rows, T_blk]
+        bias = bias_ref[0, b]                               # [Cb, T_blk]
+        if cb == 1:
+            s = s + bias
+        else:
+            s = (s.reshape(cb, heads, t_blk)
+                 + bias[:, None, :]).reshape(rows, t_blk)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        # a key the bias rules out weighs nothing, even while every key a
+        # row has seen so far is ruled out (m still at the floor)
+        p = jnp.where(s > 0.5 * _NEG, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        o_new = o * corr + _dot(p.astype(c_kv.dtype), c_kv,
+                                ((1,), (0,)), ((), ()))
+        return m_new, l_new, o_new
+
+    _, l, o = _page_walk(
+        pages_ref, live_ref, kv_hbm, kv_buf, sems, block, body,
+        (jnp.full((rows, 1), _NEG, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32),
+         jnp.zeros((rows, rank), jnp.float32)))
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _latent_call(kernel, name, operands, in_specs, out_spec, out_shape,
+                 pool, pages, live, tiles, block, interpret):
+    page_size, width = pool.shape[1:]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                     # pages, live page counts
+        grid=(pages.shape[0], tiles),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((2, block, page_size, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret, name=name,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
+    )(pages, live, *operands, pool)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _index_call(q, w, k_pool, pages, live, interpret: bool):
+    s_, c, hi, di = q.shape
+    block = latent_block_pages(pages.shape[1])
+    n_blocks, t_blk = pages.shape[1] // block, block * k_pool.shape[1]
+    cb = _query_tile(c, _INDEX_QUERIES)
+    rows = pl.BlockSpec((1, cb * hi, di), lambda s, j, *_: (s, j, 0))
+    wrow = pl.BlockSpec((1, cb * hi, 1), lambda s, j, *_: (s, j, 0))
+    out = pl.BlockSpec((1, n_blocks, cb, t_blk), lambda s, j, *_: (s, 0, j, 0))
+    return _latent_call(
+        functools.partial(_index_kernel, block=block, heads=hi),
+        "index_scores",
+        [q.reshape(s_, c * hi, di),
+         w.astype(jnp.float32).reshape(s_, c * hi, 1)],
+        [rows, wrow], out,
+        jax.ShapeDtypeStruct((s_, n_blocks, c, t_blk), jnp.float32),
+        k_pool, pages, live, c // cb, block, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "interpret"))
+def _attend_call(q, kv_pool, pages, live, bias, rank: int, interpret: bool):
+    s_, c, h, width = q.shape
+    block = latent_block_pages(pages.shape[1])
+    n_blocks, t_blk = bias.shape[1], bias.shape[3]
+    cb = _query_tile(c, _LATENT_QUERIES)
+    rows = pl.BlockSpec((1, cb * h, width), lambda s, j, *_: (s, j, 0))
+    bia = pl.BlockSpec((1, n_blocks, cb, t_blk), lambda s, j, *_: (s, 0, j, 0))
+    out = pl.BlockSpec((1, cb * h, rank), lambda s, j, *_: (s, j, 0))
+    o = _latent_call(
+        functools.partial(_latent_kernel, block=block, heads=h, rank=rank),
+        "latent_attention", [q.reshape(s_, c * h, width), bias],
+        [rows, bia], out,
+        jax.ShapeDtypeStruct((s_, c * h, rank), q.dtype),
+        kv_pool, pages, live, c // cb, block, interpret)
+    return o.reshape(s_, c, h, rank)
+
+
+def _latent_args(pages, live, n_slots):
+    pages = jnp.asarray(pages, jnp.int32)
+    if pages.shape[1] % latent_block_pages(pages.shape[1]):
+        raise ValueError(
+            f"a table of {pages.shape[1]} pages is no whole number of "
+            f"blocks of {_LATENT_BLOCK_PAGES}")
+    return pages, jnp.broadcast_to(jnp.asarray(live, jnp.int32), (n_slots,))
+
+
+def index_scores(q, w, k_pool, pages, live, interpret: bool | None = None):
+    """The indexer's scores of every query against its slot's live pages.
+
+    q [S, C, Hi, Di] index queries, w [S, C, Hi] their heads' weights,
+    k_pool [P, page_size, Di] the index keys' pool, pages [S, n_pages]
+    int32 (a multiple of `latent_block_pages(n_pages)`), live [S] int32
+    the pages of each slot that hold a position some query may see (0: the
+    slot costs nothing) -> [S, n_blocks, C, block * page_size] float32,
+    `sum_j w_j relu(q_j . k_t)` at block `t // (block * page_size)`.
+    Blocks past `live` are not written."""
+    pages, live = _latent_args(pages, live, q.shape[0])
+    return _index_call(q, w, k_pool, pages, live,
+                       _auto_interpret() if interpret is None
+                       else bool(interpret))
+
+
+def latent_attention(q, kv_pool, pages, live, bias, rank: int,
+                     interpret: bool | None = None):
+    """Attention of C queries x H heads a slot over the ONE row a token its
+    latent pages hold, in the absorbed form: q [S, C, H, width] (scaled
+    already), kv_pool [P, page_size, width] rows `c_kv || k_rope || 0`
+    (`width` is rank + rope rounded up to whole lanes of 128: the chip
+    stores a row in whole lanes whatever its shape says, and a page slab is
+    copied in whole lanes; q's padding is zeros too, so one product over
+    `width` is the score), pages / live as `index_scores`, bias
+    [S, n_blocks, C, block * page_size] float32 in the blocked layout: 0
+    where query c attends the key, `_NEG` where it does not (not selected,
+    not yet written, past the query) -> [S, C, H, rank], the weighted sum
+    of the attended `c_kv`. A query that attends nothing among the live
+    pages gets zeros."""
+    pages, live = _latent_args(pages, live, q.shape[0])
+    return _attend_call(q, kv_pool, pages, live, bias, int(rank),
+                        _auto_interpret() if interpret is None
+                        else bool(interpret))

@@ -275,6 +275,25 @@ def paged_kv_scale_spec(axis: str = "mp"):
     return P(None, None, axis)
 
 
+def paged_latent_cache_spec(axis: str = "mp", size: int = 1):
+    """PartitionSpec for both leaves of a LATENT page pool (latents
+    `[L, n_pages, page_size, width]`, indexer keys `[L, n_pages, page_size,
+    index_dim]`: llm/decode.py make_paged_latent_decode). A token's row is
+    shared by every head, so there is no heads axis for `axis` to split:
+    the pool replicates, and an `axis` of more than one chip is refused by
+    the mechanism lacking rather than served from a pool that every chip
+    would hold whole."""
+    from jax.sharding import PartitionSpec as P
+
+    if size > 1:
+        raise NotImplementedError(
+            f"{axis}={size} over latent pages: the page pool shards its "
+            "heads axis (paged_kv_cache_spec) and a latent row has none; "
+            "splitting the up-projections' heads over chips that each hold "
+            "the whole pool is not built")
+    return P()
+
+
 TABLES = {
     "transformer_lm": transformer_lm_rules,
     "mlp_cnn": mlp_cnn_rules,

@@ -18,7 +18,14 @@ Shape of the thing:
   the pool. Persistent HBM is `kv_n_pages x page_size` token rows; page 0
   is the reserved null page that absorbs inactive/padded writes. The
   default pool (`n_pages=None`) holds every slot at `max_len`; pass fewer
-  pages to size it to LIVE tokens.
+  pages to size it to LIVE tokens. A model of latent-attention layers
+  (llm/latent.py) has the pool's other layout, which has NO heads axis:
+  `{"kv": [L, kv_n_pages, page_size, width], "ik": [..., index_dim]}`, one
+  compressed row and one indexer key a token, and
+  `make_paged_latent_decode`'s programs in `make_paged_kv_decode`'s place
+  (expert layers in the step; int8 pages, `mp > 1` and LoRA adapters
+  refused for it by name). Everything below (pages, the table, chunked
+  prefill, the prefix cache, retirement) is the same for both.
 - Admission allocates a request's pages (ceil((prompt+max_new)/page_size),
   reserved up front so a mid-decode slot can never hit page exhaustion)
   from a host free list; retirement returns them. The free list + prefix
@@ -130,8 +137,12 @@ histograms, `serving.slots_active` gauge, `serving.tokens_total` counter,
 and the live slots in them, whose ratio is the slot occupancy;
 `page_steps`: the pages those slots' queries attended, which over `steps` x
 the `serving.engine.table_pages` gauge is the share of the page table a
-step has to walk), and
-`serving.engine.admit` / `.fetch` spans on the Chrome trace — all visible
+step has to walk; `context_keys` / `selected_keys`: the keys the queries of
+drained steps and dispatched prefill chunks see and those their softmax
+weighs, fewer only where an indexer selects: arithmetic on positions, not
+what the kernel read), the
+`serving.prompt_tokens` / `serving.prefix_hit_tokens` counters of admission,
+and `serving.engine.admit` / `.fetch` spans on the Chrome trace — all visible
 in `/metrics` and `python -m fedml_tpu top`. Each request also leaves three
 contiguous spans in its caller's trace once its consumer has the first
 token (`Ticket._record_spans`): `serving.engine.queue`, `.prefill`,
@@ -141,6 +152,7 @@ token (`Ticket._record_spans`): `serving.engine.queue`, `.prefill`,
 from __future__ import annotations
 
 import hashlib
+import heapq
 import logging
 import threading
 import time
@@ -454,7 +466,8 @@ class DecodeEngine:
     copy); `spec_decode="ngram"` + `spec_k` turns each iteration into a
     self-drafted speculative verify window that emits up to spec_k + 1
     tokens, greedy-exact (module docstring). Both compose with each
-    other and with `mesh`.
+    other and with `mesh`. (Latent pages have ONE attention path, the
+    kernels': `paged_kernel` chooses nothing for such a model.)
 
     `kv_quant="int8"` stores the persistent pool in int8 with
     per-(page, head) scales riding the carry — half the KV HBM per slot,
@@ -478,8 +491,10 @@ class DecodeEngine:
                  spec_k: int = 4, kv_quant: str = "off",
                  admit_batch: int = 1):
         from ..llm.decode import (
-            layer_scope, make_paged_kv_decode, ngram_propose,
-            require_servable, stack_adapter_blocks, stack_blocks,
+            LATENT_ADAPTERS, layer_scope, make_paged_kv_decode,
+            make_paged_latent_decode,
+            ngram_propose, require_servable, stack_adapter_blocks,
+            stack_blocks,
         )
 
         if n_slots < 1:
@@ -532,6 +547,17 @@ class DecodeEngine:
             raise ValueError(
                 f"kv_quant must be 'off' or 'int8'; got {kv_quant!r}")
         self._quant = kv_quant == "int8"
+        # latent pages (llm/latent.py): one row a token and no heads axis
+        latent = getattr(model, "latent", None)
+        # keys a query attends at most (None: every key it sees)
+        self._topk = latent.index_topk if latent else None
+        if latent and self._quant:
+            raise NotImplementedError(
+                "kv_quant='int8' on latent pages: the int8 pool keeps a "
+                "scale per (page, head) (llm/decode.py _kv_quant_write) and "
+                "a latent row has no heads")
+        if latent and adapters:
+            raise NotImplementedError(LATENT_ADAPTERS)
         self._admit_batch = int(admit_batch)
         if self._admit_batch < 1:
             raise ValueError(
@@ -575,6 +601,8 @@ class DecodeEngine:
                     "'mp' axis (the tensor-parallel axis the rule tables "
                     "shard over)")
             mp = mesh.shape["mp"]
+            if latent:
+                partition.paged_latent_cache_spec("mp", mp)   # refuses mp > 1
             if model.n_heads % mp:
                 raise ValueError(
                     f"n_heads {model.n_heads} is not divisible by mp={mp}"
@@ -594,10 +622,16 @@ class DecodeEngine:
             rep_sharding = NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
 
-        (chunk_fn, paged_step, paged_verify,
-         chunk_batch_fn) = make_paged_kv_decode(
-            model.n_heads, self._page_size, dtype=kv_dtype,
-            kernel=self._kernel_on, mesh=mesh, quant=self._quant)
+        if latent:
+            (chunk_fn, paged_step, paged_verify,
+             chunk_batch_fn) = make_paged_latent_decode(
+                model, self._page_size, dtype=kv_dtype)
+        else:
+            (chunk_fn, paged_step, paged_verify,
+             chunk_batch_fn) = make_paged_kv_decode(
+                model.n_heads, self._page_size, dtype=kv_dtype,
+                eps=model.norm_eps, kernel=self._kernel_on, mesh=mesh,
+                quant=self._quant, rope_base=model.rope_base)
         S, eos, max_len_ = self.n_slots, self._eos, self.max_len
 
         def pick(logits, temp, key):
@@ -870,8 +904,14 @@ class DecodeEngine:
         z = (model.n_layers, self._n_pages, self._page_size,
              model.n_heads, head)
         pool_dtype = jnp.int8 if self._quant else kv_dtype
-        cache = {"k": jnp.zeros(z, pool_dtype),
-                 "v": jnp.zeros(z, pool_dtype)}
+        if latent:
+            # one row a token and no heads: the compressed key/value and
+            # the indexer's key (llm/decode.py make_paged_latent_decode)
+            cache = {"kv": jnp.zeros(z[:3] + (latent.width,), kv_dtype),
+                     "ik": jnp.zeros(z[:3] + (latent.index_dim,), kv_dtype)}
+        else:
+            cache = {"k": jnp.zeros(z, pool_dtype),
+                     "v": jnp.zeros(z, pool_dtype)}
         if self._quant:
             zs = (model.n_layers, self._n_pages, model.n_heads)
             cache["ks"] = jnp.zeros(zs, jnp.float32)
@@ -1252,19 +1292,29 @@ class DecodeEngine:
         entries (refs == 0, kids == 0) under pressure. None = the pool is
         pinned by in-flight requests right now — the caller re-queues and
         retries after a retirement frees pages."""
-        while len(self._free_pages) < n:
-            victim, vkey = None, None
-            for k, e in self._prefix.items():
-                if e.refs == 0 and e.kids == 0 and (
-                        victim is None or e.tick < victim.tick):
-                    victim, vkey = e, k
-            if victim is None:
+        short = n - len(self._free_pages)
+        if short > 0:
+            # the evictable leaves by age, gathered ONCE: a scan of the map
+            # for every page freed held the engine thread for tens of
+            # seconds once a 32,768-page pool was full of 1,000-page
+            # documents (PERF.md section 6, PR 34)
+            leaves = [(e.tick, k) for k, e in self._prefix.items()
+                      if e.refs == 0 and e.kids == 0]
+            heapq.heapify(leaves)
+            while short > 0 and leaves:
+                _tick, vkey = heapq.heappop(leaves)
+                victim = self._prefix.pop(vkey)
+                parent = (self._prefix.get(victim.parent)
+                          if victim.parent is not None else None)
+                if parent is not None:
+                    parent.kids -= 1
+                    if parent.kids == 0 and parent.refs == 0:
+                        heapq.heappush(leaves, (parent.tick, victim.parent))
+                self._free_pages.append(victim.page)
+                _mx.inc("serving.prefix_evictions")
+                short -= 1
+            if short > 0:
                 return None
-            del self._prefix[vkey]
-            if victim.parent is not None and victim.parent in self._prefix:
-                self._prefix[victim.parent].kids -= 1
-            self._free_pages.append(victim.page)
-            _mx.inc("serving.prefix_evictions")
         pages = [self._free_pages.pop() for _ in range(n)]
         _mx.set_gauge("serving.kv_pages_free", len(self._free_pages))
         return pages
@@ -1329,6 +1379,8 @@ class DecodeEngine:
             row[:len(hits)] = [e.page for e in hits]
             row[len(hits):total] = fresh
             req.ticket.prefill["hit_pages"] = len(hits)
+            _mx.inc("serving.prompt_tokens", len(req.tokens))
+            _mx.inc("serving.prefix_hit_tokens", len(hits) * ps)
             if hits:
                 _mx.inc("serving.prefix_hits")
             elif self._prefix_on:
@@ -1373,6 +1425,7 @@ class DecodeEngine:
                 jnp.int32(limit), jnp.bool_(final), jnp.int32(plen))
         _mx.inc("serving.engine.prefill_chunks")
         self._prefilled(req.ticket, final)
+        self._count_keys(adm.t0, clen)
         if final:
             self._register_prefix(adm)
             pending.append(("admit", adm.slot, first))
@@ -1444,6 +1497,7 @@ class DecodeEngine:
         _mx.observe("serving.engine.admit_batch", b)
         for i, adm in enumerate(group):
             self._prefilled(adm.req.ticket, bool(finals[i]))
+            self._count_keys(adm.t0, int(clens[i]))
             if finals[i]:
                 self._register_prefix(adm)
                 pending.append(("admit", adm.slot, firsts[i]))
@@ -1547,9 +1601,30 @@ class DecodeEngine:
             st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
             if st is not None:
                 # the frame's first query sits on the last emitted token
-                attended = len(st.req.tokens) + len(st.out) - 1 + window
-                pages += min(-(-attended // self._page_size), self._max_pages)
+                first = len(st.req.tokens) + len(st.out) - 1
+                pages += min(-(-(first + window) // self._page_size),
+                             self._max_pages)
+                self._count_keys(first, window)
         _mx.inc("serving.engine.page_steps", pages)
+
+    def _count_keys(self, first: int, n: int) -> None:
+        """`n` queries at positions first .. first + n - 1 of one sequence:
+        `context_keys` adds the keys each of them sees (its position + 1),
+        `selected_keys` those its attention weighs: all of them, or the
+        `index_topk` a latent model's indexer selects. Arithmetic on
+        positions, the model's own: the latent kernel still WALKS every
+        live row under the selection's mask (`page_steps` counts the walk),
+        so their ratio is the share of the context that enters the softmax,
+        not the share of it that is read."""
+        seen = n * first + n * (n + 1) // 2
+        _mx.inc("serving.engine.context_keys", seen)
+        if self._topk is None or first + n <= self._topk:
+            _mx.inc("serving.engine.selected_keys", seen)
+            return
+        full = max(0, min(n, self._topk - first))   # queries that see <= topk
+        _mx.inc("serving.engine.selected_keys",
+                full * first + full * (full + 1) // 2
+                + (n - full) * self._topk)
 
     def _deliver(self, slot: int, tok: int, first: bool) -> None:
         st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)

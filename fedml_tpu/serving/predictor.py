@@ -258,6 +258,15 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         from ..llm.decode import require_servable
 
         require_servable(model)
+        # a latent-attention model has decode programs in the engine alone
+        # (llm/decode.py make_paged_latent_decode): no per-request path
+        # exists to degrade to, or to take batched rows and top_k
+        self._engine_only = getattr(model, "latent", None) is not None
+        if self._engine_only and not decode_slots:
+            raise NotImplementedError(
+                "latent attention is served by the decode engine only "
+                "(serve decode_slots > 0): the per-request programs "
+                "(llm/decode.py make_kv_decode) are the dense block's")
         self.model = model
         self.params = params
         self.detokenize = detokenize
@@ -536,7 +545,8 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         - engine-only capacity: prompt + bucket(max_new) over max_len
           would turn a previously-valid request into a permanent,
           misleading 400"""
-        return ((temperature > 0 and seed is not None)
+        return (self._engine_only
+                or (temperature > 0 and seed is not None)
                 or self.eos_id is not None
                 or prompt_len + _bucket(max(new, 1), pow2_cap=self.max_len)
                 > self.max_len)
@@ -615,6 +625,11 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         # the bucket to the remaining space instead would mint one static
         # scan length (= one fresh XLA compile) per distinct prompt length
         # near the buffer edge.
+        if self._engine_only:
+            raise InvalidRequest(
+                "this model is served by the decode engine only: one "
+                "prompt a request, no top_k, within the engine's capacity ("
+                + self.engine.capacity_error(len(toks), max(new, 1)) + ")")
         steps = _bucket(max(new, 1), pow2_cap=self.max_len)
         if len(toks) + steps > self.max_len:
             raise InvalidRequest(

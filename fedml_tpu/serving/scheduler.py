@@ -177,20 +177,39 @@ def start_replica(spec: dict):
         from .predictor import lm_predictor_from_serve_knobs
 
         lm = dict(spec.get("lm", {}))
+        # the recipe's fields ARE TransformerLM's: the dense block's sizes,
+        # and where the model departs from it its own norm eps and rope
+        # base, `latent` (llm.latent.Latent's fields), `moe` (llm.moe.MoE's)
+        # and `layer_kinds`, a (attention, feed-forward) pair a layer
         known = {"vocab_size", "d_model", "n_layers", "n_heads", "d_ff",
-                 "scan_layers", "max_len"}
+                 "scan_layers", "max_len", "norm_eps", "rope_base", "latent",
+                 "moe", "layer_kinds"}
         if set(lm) - known:
             # the keys of a block this replica would silently not build
             raise NotImplementedError(
-                f"start_replica builds the dense block only; the lm recipe "
-                f"also asks for {sorted(set(lm) - known)}: grouped KV heads, "
-                "window layers and expert layers cannot be served yet "
-                "(llm/decode.py `unserved` says which mechanism each lacks)")
+                f"start_replica does not build what the lm recipe asks for "
+                f"with {sorted(set(lm) - known)}: grouped KV heads and "
+                "window layers cannot be served yet (llm/decode.py "
+                "`unserved` says which mechanism each lacks)")
+        more = {k: lm[k] for k in ("norm_eps", "rope_base") if k in lm}
+        if "latent" in lm:
+            from ..llm.latent import Latent
+
+            more["latent"] = Latent(**lm["latent"])
+        if "moe" in lm:
+            from ..llm.moe import MoE
+
+            moe = dict(lm["moe"])
+            if moe.get("held") is not None:
+                moe["held"] = tuple(moe["held"])
+            more["moe"] = MoE(**moe)
+        if "layer_kinds" in lm:
+            more["layer_kinds"] = tuple(tuple(k) for k in lm["layer_kinds"])
         model = TransformerLM(
             vocab_size=int(lm["vocab_size"]),
             d_model=int(lm["d_model"]), n_layers=int(lm["n_layers"]),
             n_heads=int(lm["n_heads"]), d_ff=int(lm["d_ff"]),
-            scan_layers=bool(lm.get("scan_layers", False)))
+            scan_layers=bool(lm.get("scan_layers", False)), **more)
         # serve knobs go through the SAME mapping as the config route
         # (predictor.lm_predictor_from_serve_knobs) — one source of
         # defaults, the two surfaces cannot drift

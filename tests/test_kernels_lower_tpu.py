@@ -25,7 +25,9 @@ import jax.numpy as jnp
 import pytest
 
 from fedml_tpu.ops.flash_attention import flash_attention
-from fedml_tpu.ops.paged_attention import paged_attention
+from fedml_tpu.ops.paged_attention import (
+    index_scores, latent_attention, latent_block_pages, paged_attention,
+)
 
 S = jax.ShapeDtypeStruct
 BH, T, DH = 64, 2048, 128
@@ -68,7 +70,32 @@ def _paged_case(c, dtype, quant, slots=SLOTS):
         (q, pool, pool, pages, pos, sc, sc, active), {"paged_attention"})
 
 
+def _latent_case(kind, slots, c, n_pages):
+    """The latent pool's two kernels at GLM-5's widths (64 heads over ONE
+    640-wide row a token, 32 index heads of 128), page 16: a decode step of
+    16 slots over the whole 2,048-page table and a 512-token prefill chunk."""
+    n_pool, block = 16 * 2048 + 1, latent_block_pages(n_pages)
+    pages, live = S((slots, n_pages), jnp.int32), S((slots,), jnp.int32)
+    if kind == "index":
+        return (lambda q, w, k, pg, lv: index_scores(
+            q, w, k, pg, lv, interpret=False),
+            (S((slots, c, 32, 128), jnp.bfloat16),
+             S((slots, c, 32), jnp.float32),
+             S((n_pool, PAGE, 128), jnp.bfloat16), pages, live),
+            {"index_scores"})
+    bias = S((slots, n_pages // block, c, block * PAGE), jnp.float32)
+    return (lambda q, kv, pg, lv, b: latent_attention(
+        q, kv, pg, lv, b, 512, interpret=False),
+        (S((slots, c, 64, 640), jnp.bfloat16),
+         S((n_pool, PAGE, 640), jnp.bfloat16), pages, live, bias),
+        {"latent_attention"})
+
+
 CASES = {
+    "latent_index_step": lambda: _latent_case("index", 16, 1, 2048),
+    "latent_index_chunk": lambda: _latent_case("index", 1, 512, 2048),
+    "latent_attend_step": lambda: _latent_case("attend", 16, 1, 2048),
+    "latent_attend_chunk": lambda: _latent_case("attend", 1, 512, 512),
     "flash_fwd_bf16": lambda: _flash_case("fwd", jnp.bfloat16),
     "flash_fwd_bwd_bf16": lambda: _flash_case("fwd_bwd", jnp.bfloat16),
     "flash_fwd_bwd_f32": lambda: _flash_case("fwd_bwd", jnp.float32),
@@ -109,7 +136,9 @@ def v5e():
 
 
 @pytest.mark.parametrize("name", [
-    n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16_s16", "paged_c4_int8")
+    n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16_s16", "paged_c4_int8",
+               "latent_index_step", "latent_attend_step",
+               "latent_attend_chunk")
     else pytest.param(n, marks=pytest.mark.slow)    # tier-1 is at its cap
     for n in sorted(CASES)])
 def test_kernel_compiles_with_mosaic(name, v5e):

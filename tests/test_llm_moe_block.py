@@ -264,9 +264,9 @@ def test_a_model_that_counts_nothing_keeps_the_rounds_three_metrics():
 def test_the_decode_path_refuses_each_mechanism_it_lacks_by_name():
     lacking = decode.unserved(kexaone_like())
     assert [s.split(":")[0] for s in lacking] == [
-        "grouped KV heads", "window layers", "expert layers",
-        "per-head q/k norms, layers without rotary positions, another norm "
-        "eps or rope base"]
+        "grouped KV heads", "window layers",
+        "expert layers under full or window attention",
+        "per-head q/k norms, layers without rotary positions"]
     with pytest.raises(NotImplementedError, match="grouped KV heads.*"
                        "window layers.*expert layers"):
         decode.require_servable(kexaone_like())
@@ -289,10 +289,11 @@ def test_serving_entry_points_refuse_the_model_before_building_anything():
         start_replica({"model_kind": "lm", "params": {}, "lm": {
             "vocab_size": 8, "d_model": 32, "n_layers": 1, "n_heads": 4,
             "d_ff": 64, "n_kv_heads": 2}})
+    # layers of two kinds are not stacked: a tuple of the layers as they are
     tok = jnp.zeros((1, 8), jnp.int32)
     params = lm.init(jax.random.key(0), tok)["params"]
-    with pytest.raises(NotImplementedError, match="differ in kind"):
-        decode.stack_blocks(params, 3)
+    layers = decode.stack_blocks(params, 3)["blocks"]
+    assert isinstance(layers, tuple) and len(layers) == 3
 
 
 def test_the_sequence_parallel_round_refuses_what_it_would_silently_drop():
