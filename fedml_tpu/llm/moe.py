@@ -15,19 +15,23 @@ shared expert) is the layer's output; across chips an exchange would bring
 the tokens in and the parts back, and nothing here stands in for it.
 
 No token is dropped, whatever the imbalance: the (token, expert) pairs routed
-here are sorted by expert into one row buffer of the worst-case length
-(every token choosing `top_k` held experts), and a grouped matrix product
-(Pallas `megablox.gmm`, whose grid is sized by the group sizes at run time)
-multiplies each expert's rows by its weights, so the matmul's cost follows
-the pairs routed here and not the buffer. Moving rows in and out is written
-as gathers in both directions (`_take_rows`): the transpose of a gather is a
-scatter-add, which the TPU serialises.
+here are sorted by expert into one row buffer whose SHAPE is the worst case
+(every token choosing `top_k` held experts), of which only the first `n_here`
+rows, the pairs that did land here, are ever moved or multiplied. A grouped
+matrix product (Pallas `megablox.gmm`, whose grid is sized by the group sizes
+at run time) multiplies each expert's rows by its weights, and the moves
+around it (`_dispatch` tokens to rows, `_combine` rows back to tokens, each
+with the other's form as its backward) are the Pallas kernels of
+ops/routed_rows.py, whose walk is bounded by `n_here` at run time: rows past
+it are neither read nor written.
 
 Scopes (PERF.md section 3): `moe.route`, `moe.dispatch`, `moe.experts`,
 `moe.combine`, `moe.shared`. Counters, sown into the `counters` collection
 (`llm.federated_lora` reads them into the round's metrics): `moe_pairs`, the
-pairs computed here in this call, and `moe_max_rows`, the rows of the fullest
-held expert.
+pairs computed here in this call, `moe_max_rows`, the rows of the fullest
+held expert, and `moe_rows_walked`, the buffer rows the dispatch walked
+(`moe_pairs` rounded up to the kernel's row block: over tokens x top_k it
+says how far the bound engages, 1.0 when every token chooses held experts).
 """
 from __future__ import annotations
 
@@ -39,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
+from ..ops import routed_rows
 from ..ops.flash_attention import _auto_block, _auto_interpret
 
 COUNTERS = "counters"
@@ -86,24 +91,114 @@ def fold_counters(sown) -> dict:
 
 
 @jax.custom_vjp
-def _take_rows(x, idx, back_idx, back_ok):
-    """x[idx], rows moved by a (partial) permutation. Its transpose is
-    written as a gather too: row r of x gets the sum of the cotangent rows
-    `back_idx[r]` where `back_ok[r]` (the rows it was copied to)."""
-    return x[idx]
+def _dispatch(x, order, inv, here, n_here):
+    """Tokens to buffer rows: row r < n_here is the token of pair
+    `order[r]`. x [N, d]; order [P] the pairs sorted by held expert, inv
+    [N, k] its inverse (pair -> row), here [N, k] the pairs routed here."""
+    return routed_rows.rows_out(x, order // inv.shape[1], n_here)
 
 
-def _take_rows_fwd(x, idx, back_idx, back_ok):
-    return x[idx], (back_idx, back_ok)
+def _dispatch_fwd(x, order, inv, here, n_here):
+    return _dispatch(x, order, inv, here, n_here), (inv, here, n_here)
 
 
-def _take_rows_bwd(res, g):
-    back_idx, back_ok = res
-    rows = jnp.where(back_ok[..., None], g[back_idx], 0)
-    return (rows.sum(-2, dtype=jnp.float32).astype(g.dtype), None, None, None)
+@jax.jit
+def _dispatch_bwd(res, g):
+    inv, here, n_here = res
+    # a token gets the sum of the cotangent rows it was copied to
+    return (routed_rows.rows_back(g, inv, here, None, n_here),
+            None, None, None, None)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, order, inv, here, n_here):
+    """Buffer rows back to tokens: token n gets `sum_j w[n, j] ys[inv[n,
+    j]]` over its pairs routed here, accumulated in float32."""
+    return routed_rows.rows_back(ys, inv, here, w, n_here)
+
+
+def _combine_fwd(ys, w, order, inv, here, n_here):
+    return (_combine(ys, w, order, inv, here, n_here),
+            (ys, w, order, inv, here, n_here))
+
+
+@jax.jit
+def _combine_bwd(res, dy):
+    ys, w, order, inv, here, n_here = res
+    # row r gets its pair's weight times its token's cotangent; a weight
+    # gets its row's product with it (the weights depend on h through the
+    # router's scores)
+    dys = routed_rows.rows_out(dy, order // w.shape[1], n_here,
+                               w.reshape(-1)[order])
+    dw = routed_rows.rows_dots(ys, inv, here, dy, n_here)
+    return dys, dw.astype(w.dtype), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _silu_mul(gate, up):
+    g, u = gate.astype(jnp.float32), up.astype(jnp.float32)
+    return (g * jax.nn.sigmoid(g) * u,)
+
+
+def _silu_mul_grads(gate, up, dact):
+    g, u, da = (a.astype(jnp.float32) for a in (gate, up, dact))
+    sg = jax.nn.sigmoid(g)
+    return da * u * sg * (1.0 + g * (1.0 - sg)), da * g * sg
+
+
+@jax.custom_vjp
+@jax.jit
+def _swiglu(gate, up, n_here):
+    """silu(gate) * up on the buffer's live rows (float32 inside, rounded
+    once); rows past their last block hold whatever the buffer held."""
+    return routed_rows.live_map(
+        _silu_mul, n_here, [gate, up], [(gate.shape[1:], gate.dtype)],
+        name="moe_swiglu")[0]
+
+
+def _swiglu_fwd(gate, up, n_here):
+    return _swiglu(gate, up, n_here), (gate, up, n_here)
+
+
+@jax.jit
+def _swiglu_bwd(res, dact):
+    gate, up, n_here = res
+    dgate, dup = routed_rows.live_map(
+        _silu_mul_grads, n_here, [gate, up, dact],
+        [(gate.shape[1:], gate.dtype), (up.shape[1:], up.dtype)],
+        name="moe_swiglu_bwd")
+    return dgate, dup, None
+
+
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@jax.custom_vjp
+def _twice(xs, n_here):
+    """(xs, xs), for the two products that read the buffer: the sum of their
+    cotangents, which jax would add over the whole buffer, is taken over
+    the live rows."""
+    return xs, xs
+
+
+def _twice_fwd(xs, n_here):
+    return (xs, xs), n_here
+
+
+@jax.jit
+def _twice_bwd(n_here, gs):
+    a, b = gs
+    return routed_rows.live_map(
+        lambda a, b: (a.astype(jnp.float32) + b.astype(jnp.float32),),
+        n_here, [a, b], [(a.shape[1:], a.dtype)], name="moe_rows_add")[0], None
+
+
+_twice.defvjp(_twice_fwd, _twice_bwd)
 
 
 def grouped_matmul(rows, weights, sizes):
@@ -178,21 +273,23 @@ class ExpertLayer(nn.Module):
             sizes = jnp.sum(key[:, None] == jnp.arange(sp.n_held)[None, :],
                             axis=0, dtype=jnp.int32)
             n_here = jnp.sum(sizes)
-            xs = _take_rows(rows, order // k, inv.reshape(n, k), here)
+            inv = inv.reshape(n, k)
+            xs = _dispatch(rows, order, inv, here, n_here)
         self.sow(COUNTERS, "moe_pairs", n_here)
         self.sow(COUNTERS, "moe_max_rows", jnp.max(sizes))
+        self.sow(COUNTERS, "moe_rows_walked",
+                 routed_rows.rows_walked(n_here, n * k))
 
         with jax.named_scope("moe.experts"):
-            gate = grouped_matmul(xs, expert_kernel("w_gate", d, f), sizes)
-            up = grouped_matmul(xs, expert_kernel("w_up", d, f), sizes)
-            ys = grouped_matmul(nn.silu(gate) * up,
+            xs_gate, xs_up = _twice(xs, n_here)
+            gate = grouped_matmul(xs_gate, expert_kernel("w_gate", d, f),
+                                  sizes)
+            up = grouped_matmul(xs_up, expert_kernel("w_up", d, f), sizes)
+            ys = grouped_matmul(_swiglu(gate, up, n_here),
                                 expert_kernel("w_down", f, d), sizes)
 
         with jax.named_scope("moe.combine"):
-            filled = (jnp.arange(n * k) < n_here)[:, None]
-            z = _take_rows(ys, inv, order[:, None], filled).reshape(n, k, d)
-            z = jnp.where(here[..., None], z, 0).astype(jnp.float32)
-            y = jnp.einsum("nk,nkd->nd", w, z).astype(h.dtype)
+            y = _combine(ys, w, order, inv, here, n_here)
 
         with jax.named_scope("moe.shared"):
             wide = f * sp.n_shared

@@ -28,6 +28,7 @@ from fedml_tpu.ops.flash_attention import flash_attention
 from fedml_tpu.ops.paged_attention import (
     index_scores, latent_attention, latent_block_pages, paged_attention,
 )
+from fedml_tpu.ops.routed_rows import rows_back, rows_dots, rows_out
 
 S = jax.ShapeDtypeStruct
 BH, T, DH = 64, 2048, 128
@@ -91,7 +92,35 @@ def _latent_case(kind, slots, c, n_pages):
         {"latent_attention"})
 
 
+def _routed_case(kind, n):
+    """The expert layer's row moves at K-EXAONE's and GLM-5's width (rows
+    of 6,144 bfloat16, top 8): the training cell's 8,192 tokens a silo, a
+    512-token prefill chunk, a decode step's 16 slots."""
+    k, d = 8, 6144
+    x = S((n, d), jnp.bfloat16)
+    buf = S((n * k, d), jnp.bfloat16)
+    pairs, live = S((n, k), jnp.int32), S((), jnp.int32)
+    if kind == "out":
+        return (lambda x, i, m, s: rows_out(x, i, m, s, interpret=False),
+                (x, S((n * k,), jnp.int32), live, S((n * k,), jnp.float32)),
+                {"moe_rows_out"})
+    ok = S((n, k), jnp.bool_)
+    if kind == "back":
+        return (lambda b, i, o, w, m: rows_back(b, i, o, w, m,
+                                                interpret=False),
+                (buf, pairs, ok, S((n, k), jnp.float32), live),
+                {"moe_to_slabs", "moe_rows_back"})
+    return (lambda b, i, o, dy, m: rows_dots(b, i, o, dy, m,
+                                             interpret=False),
+            (buf, pairs, ok, x, live), {"moe_to_slabs", "moe_rows_dots"})
+
+
 CASES = {
+    "routed_out_silo": lambda: _routed_case("out", 8192),
+    "routed_back_silo": lambda: _routed_case("back", 8192),
+    "routed_dots_silo": lambda: _routed_case("dots", 8192),
+    "routed_out_step": lambda: _routed_case("out", 16),
+    "routed_back_chunk": lambda: _routed_case("back", 512),
     "latent_index_step": lambda: _latent_case("index", 16, 1, 2048),
     "latent_index_chunk": lambda: _latent_case("index", 1, 512, 2048),
     "latent_attend_step": lambda: _latent_case("attend", 16, 1, 2048),
@@ -138,7 +167,8 @@ def v5e():
 @pytest.mark.parametrize("name", [
     n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16_s16", "paged_c4_int8",
                "latent_index_step", "latent_attend_step",
-               "latent_attend_chunk")
+               "latent_attend_chunk", "routed_out_silo", "routed_back_silo",
+               "routed_dots_silo")
     else pytest.param(n, marks=pytest.mark.slow)    # tier-1 is at its cap
     for n in sorted(CASES)])
 def test_kernel_compiles_with_mosaic(name, v5e):

@@ -200,6 +200,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_held_experts():
                                atol=2e-5)
     assert int(sown["counters"]["moe_pairs"][0]) == 2 * 16 * 2   # all of them
     assert int(sown["counters"]["moe_max_rows"][0]) == 2 * 16
+    # the bound engages nowhere: the dispatch walked the whole buffer
+    assert int(sown["counters"]["moe_rows_walked"][0]) == 2 * 16 * 2
 
 
 def test_a_share_that_no_token_picks_gives_the_shared_expert_alone():
@@ -212,6 +214,7 @@ def test_a_share_that_no_token_picks_gives_the_shared_expert_alone():
     _, shared = dense_experts(h[0], p, spec, (4, 4))
     np.testing.assert_allclose(out[0], shared, atol=2e-5)
     assert int(sown["counters"]["moe_pairs"][0]) == 0
+    assert int(sown["counters"]["moe_rows_walked"][0]) == 0
 
 
 # ------------------------------------------- federated LoRA over the block
