@@ -138,17 +138,21 @@ def make_fedllm_seq_round(
     module-level path cannot express (flax nn.scan rejects a collective
     inside the scanned block); the hand-written scan here can.
     """
-    from .decode import unserved
+    from .decode import engine_only, unserved
 
+    # its own condition, not the decode path's: ring and ulysses attention
+    # are full-causal over as many KV heads as heads, and the model is
+    # rebuilt below from the dense fields
     lacking = unserved(model)
-    if getattr(model, "latent", None) is not None or (
-            model.norm_eps, model.rope_base) != (1e-6, 10000.0):
+    why = engine_only(model)
+    if why:
+        lacking = lacking + [why + ": the block is rebuilt from the dense "
+                             "fields"]
+    if (model.norm_eps, model.rope_base) != (1e-6, 10000.0):
         lacking = lacking + [
-            "latent attention, another norm eps or rope base: the block "
-            "is rebuilt from the dense fields"]
+            "another norm eps or rope base: the block is rebuilt from the "
+            "dense fields"]
     if lacking:
-        # ring and ulysses attention are full-causal over as many KV heads
-        # as heads, and the model is rebuilt below from the dense fields
         raise NotImplementedError(
             "the sequence-parallel round trains the dense block only; this "
             "model has: " + "; ".join(s.split(":")[0] for s in lacking))

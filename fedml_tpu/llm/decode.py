@@ -26,7 +26,8 @@ oracle the engine's tests compare against. The continuous-batching engine
 persistent pool of KV pages, or, for a model of latent-attention layers
 (llm/latent.py; a dense or an expert feed-forward a layer),
 `make_paged_latent_decode`'s four over a pool of latent rows with no heads
-axis. `unserved(model)` says what neither set runs.
+axis. `unserved(model)` says what neither set runs, `engine_only(model)`
+what of the rest the per-request pair cannot.
 
 Per-token cost drops from O(T·D²) (full recompute of every position's
 projections) to O(D² + T·D): at max_len=256 that is ~two orders of
@@ -66,26 +67,19 @@ def layer_scope(part: str):
 
 def unserved(model) -> list:
     """What a TransformerLM has that no decode program here can run, one
-    sentence a mechanism. Two sets of programs exist: the DENSE block's
-    (`make_paged_kv_decode`: as many KV heads as heads, every layer full
-    attention with rotary positions, a SwiGLU; the model's own norm eps and
-    rope base) and the LATENT block's (`make_paged_latent_decode`: latent
-    attention under its indexer in EVERY layer, a SwiGLU or the expert
-    layer a layer); llm/transformer.py's block trains more than they
-    serve."""
+    sentence a mechanism. Two sets of programs exist: the block of per-head
+    keys and values (`make_paged_kv_decode`: grouped or as many KV heads as
+    heads, per-head q/k norms, every layer full attention with rotary
+    positions, a SwiGLU or the expert layer, causal or a diffusion block's
+    mask; the model's own norm eps and rope base) and the LATENT block's
+    (`make_paged_latent_decode`: latent attention under its indexer in
+    EVERY layer, a SwiGLU or the expert layer a layer); llm/transformer.py's
+    block trains more than they serve."""
     out = []
     if not hasattr(model, "kinds"):     # no TransformerLM: nothing to depart in
         return out
     kinds = model.kinds
     latent = [a == "latent" for a, _ in kinds]
-    if (model.n_kv_heads or model.n_heads) != model.n_heads or (
-            not any(latent) and (
-                model.head_dim or model.d_model // model.n_heads
-            ) * model.n_heads != model.d_model):
-        out.append(
-            "grouped KV heads: the paged kernel (ops/paged_attention.py) and "
-            "the prefill, step and verify bodies of llm/decode.py split wk "
-            "and wv into as many heads of d_model / n_heads as wq")
     if any(a == "window" for a, _ in kinds):
         out.append(
             "window layers: the KV cache and the page allocator of "
@@ -96,16 +90,29 @@ def unserved(model) -> list:
             "latent layers beside layers of per-head keys and values: a "
             "page pool holds ONE kind of row (serving/engine.py allocates "
             "`kv` and `ik` leaves or `k` and `v` leaves, never both)")
-    if any(f == "moe" for _, f in kinds) and not all(latent):
+    if not model.rope_full:
         out.append(
-            "expert layers under full or window attention: only the latent "
-            "programs (make_paged_latent_decode) run llm/moe.py's expert "
-            "layer in the decode step; the dense block's have no router")
-    if model.qk_norm or not model.rope_full:
-        out.append(
-            "per-head q/k norms, layers without rotary positions: the "
-            "decode bodies fix the dense block's")
+            "layers without rotary positions: the decode bodies rotate "
+            "every layer's queries and keys")
     return out
+
+
+def engine_only(model) -> str:
+    """Why the PER-REQUEST pair (`make_kv_decode`, and `GreedyLMPredictor`
+    without `decode_slots`) cannot run a model the engine's programs can, or
+    "": that pair is the dense block's alone (as many KV heads of d_model /
+    n_heads as heads, no q/k norm, a SwiGLU, one causal token a step)."""
+    if not hasattr(model, "kinds"):
+        return ""
+    if getattr(model, "latent", None) is not None:
+        return "latent attention"
+    if getattr(model, "diffusion_block", 0):
+        return "generation by diffusion over blocks"
+    dense = ((model.n_kv_heads or model.n_heads) == model.n_heads
+             and (model.head_dim or model.d_model // model.n_heads)
+             * model.n_heads == model.d_model and not model.qk_norm
+             and not any(f == "moe" for _, f in model.kinds))
+    return "" if dense else "grouped KV heads, per-head q/k norms or experts"
 
 
 def require_servable(model) -> None:
@@ -123,17 +130,20 @@ def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     """Convert an UNROLLED TransformerLM param tree (block_0..block_{L-1})
     to the layout the decode path consumes: `{"blocks": [L, ...]}`, every
     leaf stacked on a leading layer axis, where every layer has the same
-    parameters (the dense block's programs scan over it), and a TUPLE of
-    the layers' trees as they are where they differ in kind (a dense
-    feed-forward, then expert layers): the latent programs run such layers
-    unrolled, each layer's weights read where they lie. Nothing is copied
-    for the tuple, so a tree that fills half the device still fits.
-    Trees already in either layout pass through unchanged."""
+    parameters and no experts (the dense block's programs scan over it),
+    and a TUPLE of the layers' trees as they are where they differ in kind
+    (a dense feed-forward, then expert layers) or hold experts: such layers
+    run unrolled, each layer's weights read where they lie (a scan slices
+    every layer's weights out of the stack, and an expert layer's are a
+    gigabyte). Nothing is copied for the tuple, so a tree that fills half
+    the device still fits. Trees already in either layout pass through
+    unchanged."""
     if "blocks" in params:
         return params
     blocks = [params[f"block_{i}"] for i in range(n_layers)]
     out = {k: v for k, v in params.items() if not k.startswith("block_")}
-    if len({jax.tree.structure(b) for b in blocks}) > 1:
+    if len({jax.tree.structure(b) for b in blocks}) > 1 or any(
+            "moe" in b for b in blocks):
         out["blocks"] = tuple(blocks)
         return out
     from ..ops.tree import tree_stack
@@ -216,8 +226,8 @@ def _block_math(dtype, eps: float, alpha: float):
     def merged(bl, ad_l, name, rank_scale):
         return merged_kernel(bl, ad_l, name, rank_scale, dtype)
 
-    def qkv(bl, ad_l, rank_scale, h, n_hd):
-        return project_qkv(bl, ad_l, rank_scale, h, n_hd, dtype)
+    def qkv(bl, ad_l, rank_scale, h, n_hd, head_dim=None):
+        return project_qkv(bl, ad_l, rank_scale, h, n_hd, dtype, head_dim)
 
     def mlp(bl, ad_l, rank_scale, x):
         return swiglu_mlp(bl, ad_l, rank_scale, x, dtype, eps)
@@ -389,7 +399,9 @@ def _kv_quant_write(pool, scales, wpage, woff, vals):
 def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                          dtype=jnp.float32, eps: float = 1e-6,
                          kernel: bool = False, mesh=None,
-                         quant: bool = False, rope_base: float = 10000.0):
+                         quant: bool = False, rope_base: float = 10000.0,
+                         head_dim: Optional[int] = None,
+                         qk_norm: bool = False, moe=None, block: int = 0):
     """The decode engine's programs (serving/engine.py): K/V live in a
     persistent POOL of fixed-size pages `[L, n_pages, page_size, H, Dh]`,
     and each slot's logical sequence is described by an int32 page-table
@@ -493,10 +505,85 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     partition.paged_kv_cache_spec pins on the pool, reaching the kernel
     with zero resharding. Token identity vs the gather path is pinned in
     tests/test_decode_kernel_spec.py. `eps` and `rope_base` are the
-    model's own (`norm_eps`, `rope_base`)."""
+    model's own (`norm_eps`, `rope_base`).
+
+    Where the model departs from the dense block (each default lowers to
+    the dense block's programs, text for text): heads are `head_dim` wide
+    and the pool holds as many KV heads as wk's width has of them (the
+    pool's heads axis says how many; grouped heads share a KV head's pages,
+    in the gather and in the kernel); `qk_norm` is an RMSNorm over each head
+    of q and k before the rotary positions; `moe` (a llm.moe.MoE) puts the
+    expert layer in the SwiGLU's place in every layer whose parameters hold
+    one, over the LIVE rows alone (an idle slot's and a chunk's padded rows
+    are routed nowhere), and every program then returns a third value, the
+    layers' folded counters (`moe_pairs`, `moe_experts_live`, ...). Layers
+    that `stack_blocks` left as a tuple run unrolled.
+
+    `block` = B > 0 is a block-diffusion model: position i attends j iff
+    j // B <= i // B. `chunk` and `chunk_batch` take that mask (t0 and every
+    length a whole number of blocks), and `verify` is the WINDOW program:
+    C == B tokens at a block-aligned `pos`, the window's own keys visible to
+    all its queries, the logits over each position's OWN token, the block's
+    K/V written at every call (a later call or the commit overwrites them
+    before any later block reads them). `step` has no meaning then."""
     ps = int(page_size)
     norm, dq, merged, qkv, mlp, head, split_ads = _block_math(
         dtype, eps, alpha)
+    if moe is not None:
+        from .moe import COUNTERS, ExpertLayer, fold_counters
+
+    def sees(kpos, qpos):
+        """Whether a query at `qpos` attends the key at `kpos`."""
+        return kpos // block <= qpos // block if block else kpos <= qpos
+
+    def project(bl, ad_l, rank_scale, h, posr):
+        """Roped q [B, C, H, Dh], roped k and raw v [B, C, KV, Dh] of `h`
+        at positions `posr` [B, C] (or [C]: a chunk's one row)."""
+        q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads, head_dim)
+        if qk_norm:
+            q = norm(q, dq(bl["q_norm"]["scale"]))
+            k = norm(k, dq(bl["k_norm"]["scale"]))
+        # a chunk's row is lifted where it is used, once for q and once
+        # for k, as the dense block's program always had it
+        rows = lambda: posr if posr.ndim == 2 else posr[None, :]
+        return (_rope_rows(q, rows(), rope_base),
+                _rope_rows(k, rows(), rope_base), v)
+
+    def attend_grouped(q, kk, vv, posr):
+        """q [B, C, H, Dh] at positions `posr` [B, C] over gathered kk/vv
+        [B, T, KV, Dh], query head i reading KV head i // (H / KV)."""
+        b_, c, _, d = q.shape
+        qg = q.reshape(b_, c, kk.shape[2], -1, d)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kk) * d ** -0.5
+        live = sees(jnp.arange(kk.shape[1])[None, None, :],
+                    posr[:, :, None])                        # [B, C, T]
+        s = jnp.where(live[:, None, None, :, :], s, _NEG)
+        return jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1),
+                          vv).reshape(q.shape)
+
+    def attend(q, kk, vv, posr):
+        """Attention of q [B, C, H, Dh] at positions `posr` [B, C] over a
+        slot's gathered pages [B, T, KV, Dh]."""
+        if kk.shape[2] != q.shape[2]:
+            return attend_grouped(q, kk, vv, posr)
+        scale = q.shape[-1] ** -0.5
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+        live = (sees(jnp.arange(kk.shape[1])[None, None, :],
+                     posr[:, :, None]))                      # [B, C, T]
+        s = jnp.where(live[:, None, :, :], s, _NEG)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+
+    def feed_forward(bl, ad_l, rank_scale, x, live):
+        """-> (x, counters): the SwiGLU, or the expert layer over the
+        `live` rows [B, C] with what it sows."""
+        if moe is None or "moe" not in bl:
+            with layer_scope("mlp"):
+                return mlp(bl, ad_l, rank_scale, x), {}
+        with layer_scope("moe"):
+            h = norm(x, dq(bl["RMSNorm_1"]["scale"]))
+            y, sown = ExpertLayer(moe).apply(
+                {"params": bl["moe"]}, h, live, mutable=[COUNTERS])
+            return x + y, fold_counters(sown[COUNTERS])
 
     def scan_layers(layer, x, params, blk_ads, cache):
         """THE layer scan of all four programs. The pool leaves ride the
@@ -515,13 +602,27 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         def body(carry, xs):
             x, pool = carry
             bl, ad_l, l = xs
-            return layer(x, pool, l * n_pages, bl, ad_l), None
+            x, pool, counted = layer(x, pool, l * n_pages, bl, ad_l)
+            return (x, pool), counted
 
-        (x, pool), _ = jax.lax.scan(
-            body, (x, pool),
-            (params["blocks"], blk_ads, jnp.arange(n_layers, dtype=jnp.int32)))
+        if isinstance(params["blocks"], (tuple, list)):
+            # layers `stack_blocks` left as they lie (experts): unrolled
+            counted: dict = {}
+            for i, bl in enumerate(params["blocks"]):
+                x, pool, c = layer(x, pool, i * n_pages, bl,
+                                   jax.tree.map(lambda a: a[i], blk_ads))
+                counted = {k: counted.get(k, 0) + v for k, v in c.items()}
+        else:
+            (x, pool), counted = jax.lax.scan(
+                body, (x, pool), (params["blocks"], blk_ads,
+                                  jnp.arange(n_layers, dtype=jnp.int32)))
+            counted = {k: jnp.sum(v) for k, v in counted.items()}
         return x, {name: leaf.reshape(cache[name].shape)
-                   for name, leaf in pool.items()}
+                   for name, leaf in pool.items()}, counted
+
+    def returns(cache, logits, counted):
+        return (cache, logits, counted) if moe is not None else (
+            cache, logits)
 
     def kv_write(pool, wpage, woff, k, v):
         """New K/V rows into the flat pool at (wpage, woff); `wpage`
@@ -564,39 +665,42 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr[None, :], rope_base)
-                k = _rope_rows(k, posr[None, :], rope_base)
+                q, k, v = project(bl, ad_l, rank_scale, h, posr)
             pool = kv_write(pool, base + wpage, woff, k[0], v[0])
             with layer_scope("attn"):
                 # gather AFTER the write so the chunk attends to itself;
                 # page-table order makes the gathered view contiguous
                 # virtual positions 0..n_virt-1
                 kk, vv = kv_pages(pool, base + pages_row)
-                scale = q.shape[-1] ** -0.5
-                s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
-                live = jnp.arange(n_virt)[None, :] <= posr[:, None]  # [C, T]
-                s = jnp.where(live[None, None, :, :], s, _NEG)
-                o = jnp.einsum("bhqk,khd->bqhd", jax.nn.softmax(s, -1), vv)
+                if kk.shape[1] != q.shape[2]:
+                    o = attend_grouped(q, kk[None], vv[None], posr[None])
+                else:
+                    scale = q.shape[-1] ** -0.5
+                    s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
+                    live = sees(jnp.arange(n_virt)[None, :],
+                                posr[:, None])               # [C, T]
+                    s = jnp.where(live[None, None, :, :], s, _NEG)
+                    o = jnp.einsum("bhqk,khd->bqhd", jax.nn.softmax(s, -1),
+                                   vv)
                 x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
                     bl, ad_l, "wo", rank_scale)
-            with layer_scope("mlp"):
-                x = mlp(bl, ad_l, rank_scale, x)
-            return x, pool
+            x, counted = feed_forward(bl, ad_l, rank_scale, x,
+                                      (j < length)[None])
+            return x, pool, counted
 
-        x, cache = scan_layers(layer, x, params, blk_ads, cache)
+        x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)
         with layer_scope("head"):
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                                 keepdims=False)
             logits = head(params, top_ads, rank_scale, last[None, None])
-        return cache, logits[:, 0]
+        return returns(cache, logits[:, 0], counted)
 
     if kernel:
         from ..ops.paged_attention import paged_attention
 
         def attn_fused(q, k_pool, v_pool, pages, pos, active, *scales):
             return paged_attention(q, k_pool, v_pool, pages, pos, *scales,
-                                   active=active)
+                                   active=active, causal=not block)
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -624,6 +728,10 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         emb = dq(params["embed"]["embedding"])
         x = emb[tokens]                                   # [S, C, D]
         s_, c = tokens.shape
+        if block and c != block:
+            raise ValueError(
+                f"a diffusion model's window is one block of {block} "
+                f"tokens; got {c}")
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (s_,))
         posr = pos[:, None] + jnp.arange(c)               # [S, C]
         max_pages = pages.shape[1]
@@ -638,14 +746,11 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             pages[jnp.arange(s_)[:, None], jnp.minimum(rowidx,
                                                        max_pages - 1)], 0)
         woff = posr % ps
-        n_virt = max_pages * ps
 
         def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr, rope_base)
-                k = _rope_rows(k, posr, rope_base)
+                q, k, v = project(bl, ad_l, rank_scale, h, posr)
             pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
                 if kernel:
@@ -659,28 +764,23 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                                    pos, active, *scales)
                 else:
                     kk, vv = kv_pages(pool, base + pages)
-                    scale = q.shape[-1] ** -0.5
-                    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-                    live = (jnp.arange(n_virt)[None, None, :]
-                            <= posr[:, :, None])             # [S, C, T]
-                    s = jnp.where(live[:, None, :, :], s, _NEG)
-                    o = jnp.einsum("bhqk,bkhd->bqhd",
-                                   jax.nn.softmax(s, -1), vv)
+                    o = attend(q, kk, vv, posr)
                 x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
                     bl, ad_l, "wo", rank_scale)
-            with layer_scope("mlp"):
-                x = mlp(bl, ad_l, rank_scale, x)
-            return x, pool
+            x, counted = feed_forward(
+                bl, ad_l, rank_scale, x,
+                jnp.broadcast_to(active[:, None], tokens.shape))
+            return x, pool, counted
 
-        x, cache = scan_layers(layer, x, params, blk_ads, cache)
+        x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)
         with layer_scope("head"):
             logits = head(params, top_ads, rank_scale, x)
-        return cache, logits
+        return returns(cache, logits, counted)
 
     def step(params, adapters, cache, pages, pos, token, active):
-        cache, logits = verify(params, adapters, cache, pages, pos,
-                               token[:, None], active)
-        return cache, logits[:, 0]
+        cache, logits, *counted = verify(params, adapters, cache, pages, pos,
+                                         token[:, None], active)
+        return (cache, logits[:, 0], *counted)
 
     def chunk_batch(params, adapters, cache, pages, tokens, t0, lengths):
         """Batched admission prefill (docstring above): verify-shaped
@@ -702,30 +802,22 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             pages[jnp.arange(b_)[:, None],
                   jnp.minimum(rowidx, max_pages - 1)], 0)
         woff = posr % ps
-        n_virt = max_pages * ps
 
         def layer(x, pool, base, bl, ad_l):
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads)
-                q = _rope_rows(q, posr, rope_base)
-                k = _rope_rows(k, posr, rope_base)
+                q, k, v = project(bl, ad_l, rank_scale, h, posr)
             pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
                 kk, vv = kv_pages(pool, base + pages)
-                scale = q.shape[-1] ** -0.5
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-                live = (jnp.arange(n_virt)[None, None, :]
-                        <= posr[:, :, None])                 # [B, C, T]
-                s = jnp.where(live[:, None, :, :], s, _NEG)
-                o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+                o = attend(q, kk, vv, posr)
                 x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
                     bl, ad_l, "wo", rank_scale)
-            with layer_scope("mlp"):
-                x = mlp(bl, ad_l, rank_scale, x)
-            return x, pool
+            x, counted = feed_forward(bl, ad_l, rank_scale, x,
+                                      j[None, :] < lengths[:, None])
+            return x, pool, counted
 
-        x, cache = scan_layers(layer, x, params, blk_ads, cache)
+        x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)
         # per-row last live position (PAD rows clamp to 0 — garbage the
         # engine discards alongside their dropped scatters)
         with layer_scope("head"):
@@ -733,7 +825,7 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 xr, jnp.maximum(n, 1) - 1, axis=0, keepdims=False))(
                     x, lengths)
             logits = head(params, top_ads, rank_scale, last[:, None])
-        return cache, logits[:, 0]
+        return returns(cache, logits[:, 0], counted)
 
     return chunk, step, verify, chunk_batch
 

@@ -1,11 +1,12 @@
 """Sparse expert feed-forward layer, held a share at a time.
 
 The layer a mixture-of-experts decoder has in the dense feed-forward's
-place: a router scores every token against ALL `n_experts` experts (float32,
-sigmoid), the `top_k` largest of score + selection bias are chosen, their
-scores are normalised over the chosen and scaled, and the token's output is
-the weighted sum of the chosen experts' SwiGLUs plus a shared expert every
-token passes through.
+place: a router scores every token against ALL `n_experts` experts (float32;
+`scoring` "sigmoid": the `top_k` largest of score + selection bias are
+chosen; "softmax": a softmax over all experts, the `top_k` largest chosen,
+no bias), their scores are normalised over the chosen and scaled, and the
+token's output is the weighted sum of the chosen experts' SwiGLUs plus,
+where `n_shared` > 0, a shared expert every token passes through.
 
 Expert parallelism divides the experts over chips. This module is told which
 experts it HOLDS (`MoE.held = (first, count)`): it routes over all of them,
@@ -29,9 +30,12 @@ Scopes (PERF.md section 3): `moe.route`, `moe.dispatch`, `moe.experts`,
 `moe.combine`, `moe.shared`. Counters, sown into the `counters` collection
 (`llm.federated_lora` reads them into the round's metrics): `moe_pairs`, the
 pairs computed here in this call, `moe_max_rows`, the rows of the fullest
-held expert, and `moe_rows_walked`, the buffer rows the dispatch walked
+held expert, `moe_rows_walked`, the buffer rows the dispatch walked
 (`moe_pairs` rounded up to the kernel's row block: over tokens x top_k it
-says how far the bound engages, 1.0 when every token chooses held experts).
+says how far the bound engages, 1.0 when every token chooses held experts),
+and, where the caller says which rows are live (the decode programs),
+`moe_experts_live`, the held experts with at least one row (whose weights
+the grouped product has to read).
 """
 from __future__ import annotations
 
@@ -56,7 +60,10 @@ GMM_TILES = (512, 1024, 1024)
 class MoE:
     """One expert layer's shape. `n_experts` is the router's width (every
     expert of the layer, wherever it lives); `held` = (first, count) names
-    the experts this module holds, None for all of them."""
+    the experts this module holds, None for all of them. `scoring` is the
+    router's: "sigmoid" scores chosen by score + a selection bias, or
+    "softmax" over all experts with no bias. `n_shared` = 0 builds no shared
+    expert."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -64,6 +71,12 @@ class MoE:
     n_shared: int = 1
     scale: float = 1.0           # routed_scaling_factor
     norm_topk: bool = True
+    scoring: str = "sigmoid"
+
+    def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"scoring must be 'sigmoid' or 'softmax', got {self.scoring!r}")
 
     @property
     def first(self) -> int:
@@ -216,11 +229,18 @@ def route(h, kernel, bias, spec: MoE):
     """(chosen experts [N, k] int32, their weights [N, k] float32): scores
     sigmoid(h Wr) in float32 over all experts, the k largest of score +
     bias chosen (the bias chooses only), weights the chosen scores
-    normalised over all k and scaled."""
+    normalised over all k and scaled. With `scoring` "softmax" the scores
+    are softmax(h Wr) over all experts and choose themselves (`bias` is
+    None)."""
     hi = jax.lax.Precision.HIGHEST
-    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
-                               kernel.astype(jnp.float32), precision=hi))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+    logits = jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=hi)
+    if spec.scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(s, spec.top_k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if spec.norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -243,11 +263,14 @@ class Kernel(nn.Module):
 
 class ExpertLayer(nn.Module):
     """[B, T, d] -> [B, T, d]: the held experts' part of the routed sum
-    plus the shared expert."""
+    plus the shared expert. `live` [B, T] bool (the decode programs': an
+    idle slot's rows, a chunk's padding) routes the other rows nowhere:
+    they are neither moved nor multiplied, touch no expert's weights and
+    come back as the shared expert's part alone."""
     spec: MoE
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, live=None):
         sp = self.spec
         d = h.shape[-1]
         rows = h.reshape(-1, d)
@@ -260,13 +283,16 @@ class ExpertLayer(nn.Module):
             idx, w = route(
                 rows, Kernel((d, sp.n_experts), name="router")(),
                 self.param("e_score_correction_bias", nn.initializers.zeros,
-                           (sp.n_experts,)), sp)
+                           (sp.n_experts,))
+                if sp.scoring == "sigmoid" else None, sp)
 
         with jax.named_scope("moe.dispatch"):
             # pairs (token, slot) routed to a held expert, sorted by expert;
             # the others sort to the end and are never multiplied
             local = idx - sp.first
             here = (local >= 0) & (local < sp.n_held)              # [N, k]
+            if live is not None:
+                here = here & live.reshape(-1, 1)
             key = jnp.where(here, local, sp.n_held).reshape(-1)    # [P]
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
             inv = jnp.argsort(order).astype(jnp.int32)     # pair -> its row
@@ -279,6 +305,9 @@ class ExpertLayer(nn.Module):
         self.sow(COUNTERS, "moe_max_rows", jnp.max(sizes))
         self.sow(COUNTERS, "moe_rows_walked",
                  routed_rows.rows_walked(n_here, n * k))
+        if live is not None:
+            self.sow(COUNTERS, "moe_experts_live",
+                     jnp.sum(sizes > 0, dtype=jnp.int32))
 
         with jax.named_scope("moe.experts"):
             xs_gate, xs_up = _twice(xs, n_here)
@@ -291,6 +320,8 @@ class ExpertLayer(nn.Module):
         with jax.named_scope("moe.combine"):
             y = _combine(ys, w, order, inv, here, n_here)
 
+        if not sp.n_shared:
+            return y.reshape(h.shape)
         with jax.named_scope("moe.shared"):
             wide = f * sp.n_shared
             gate = nn.Dense(wide, use_bias=False, name="shared_w_gate")(rows)

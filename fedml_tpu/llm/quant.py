@@ -175,15 +175,16 @@ def merged_kernel(block, ad_l, name, rank_scale, dtype=jnp.bfloat16):
 
 
 def project_qkv(block, ad_l, rank_scale, h, n_heads: int,
-                dtype=jnp.bfloat16):
-    """Pre-norm hidden -> per-head q/k/v [B, T, H, Dh] (RoPE is applied by
-    the caller, whose position semantics differ between train and decode)."""
-    d_model = h.shape[-1]
-    dh = d_model // n_heads
+                dtype=jnp.bfloat16, head_dim=None):
+    """Pre-norm hidden -> per-head q [B, T, H, Dh] and k/v [B, T, KV, Dh]
+    (RoPE is applied by the caller, whose position semantics differ between
+    train and decode). Heads are `head_dim` wide (d_model / n_heads when
+    None); wk and wv split into as many heads of it as their width holds."""
+    dh = head_dim or h.shape[-1] // n_heads
     q = h @ merged_kernel(block, ad_l, "wq", rank_scale, dtype)
     k = h @ merged_kernel(block, ad_l, "wk", rank_scale, dtype)
     v = h @ merged_kernel(block, ad_l, "wv", rank_scale, dtype)
-    split = lambda a: a.reshape(a.shape[:2] + (n_heads, dh))
+    split = lambda a: a.reshape(a.shape[:2] + (a.shape[-1] // dh, dh))
     return split(q), split(k), split(v)
 
 

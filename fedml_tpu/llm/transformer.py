@@ -112,6 +112,7 @@ class Block(nn.Module):
     qk_norm: bool = False               # RMSNorm over each head of q and k
     moe: Optional[MoE] = None           # the expert layer in the SwiGLU's place
     latent: Optional[Latent] = None     # latent attention in q/k/v's place
+    diffusion_block: int = 0            # > 0: block-causal mask of that length
 
     @nn.nowrap
     def latent_params(self, d_model: int) -> dict:
@@ -147,8 +148,10 @@ class Block(nn.Module):
             q, k = (rope(q, pos, self.rope_base),
                     rope(k, pos, self.rope_base))
         attn = self.attn_fn or dense_causal_attention
-        o = (attn(q, k, v) if self.window is None
-             else attn(q, k, v, window=self.window))
+        more = {} if self.window is None else {"window": self.window}
+        if self.diffusion_block:
+            more["block"] = self.diffusion_block
+        o = attn(q, k, v, **more)
         return o.reshape(o.shape[:2] + (self.n_heads * dh,))
 
     @nn.compact
@@ -220,6 +223,13 @@ class TransformerLM(nn.Module):
     moe: Optional[MoE] = None
     latent: Optional[Latent] = None
     layer_kinds: Optional[tuple] = None
+    # generation by diffusion over blocks: with `diffusion_block` = B > 0
+    # position i attends j iff j // B <= i // B (causal over blocks, both
+    # ways inside one) and a position's logits are over ITS OWN token; the
+    # decode engine generates a block at a time from `mask_id` tokens
+    # (serving/engine.py `_block_all`). 0: causal, a token a step.
+    diffusion_block: int = 0
+    mask_id: Optional[int] = None
 
     @property
     def kinds(self) -> tuple:
@@ -256,6 +266,16 @@ class TransformerLM(nn.Module):
             raise ValueError("a moe layer needs `moe`")
         if attention == "latent" and self.latent is None:
             raise ValueError("a latent layer needs `latent`")
+        if self.diffusion_block:
+            if attention != "full":
+                raise ValueError(
+                    "a diffusion block's mask is written for full attention "
+                    f"over per-head keys, not {attention!r} layers")
+            if self.mask_id is None or not 0 <= self.mask_id < self.vocab_size:
+                raise ValueError(
+                    "a diffusion model needs `mask_id`, a token of its "
+                    f"vocabulary; got {self.mask_id!r}")
+            kw["diffusion_block"] = self.diffusion_block
         windowed = attention == "window"
         cls = Block
         if self.remat:

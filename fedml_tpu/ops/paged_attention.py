@@ -23,14 +23,28 @@ layer's and the page table picks this layer's slabs out of it in place):
 
     q      [S, C, H, Dh]   C queries per slot at global positions
                            pos[s] .. pos[s] + C - 1 (C == 1 is the plain
-                           decode step; C > 1 is speculative verify)
-    k/v    [P, page_size, H, Dh]   the persistent page pool
+                           decode step; C > 1 is speculative verify, or a
+                           block-diffusion model's window)
+    k/v    [P, page_size, KV, Dh]  the persistent page pool; KV divides H
+                           (query head i reads KV head i // (H / KV))
     pages  [S, max_pages] int32    page table rows (engine convention:
                            entries beyond a slot's reservation are 0,
                            the reserved null/trash page)
     pos    [S] int32       first query position per slot
     active [S] bool        slots whose rows are wanted (default: all)
+    causal (static)        True: query i attends positions <= pos + i;
+                           False: every query of the window attends every
+                           position < pos + C, the window's own keys both
+                           ways (a diffusion block over the blocks before
+                           it: llm/decode.py `window`)
     ->     [S, C, H, Dh]
+
+Grouped heads: the H / KV query heads that share a KV head go through the
+MXU TOGETHER against that head's slab: outside the kernel q is laid out
+`[S, (H / KV) * C, KV, Dh]` (rows ordered group-major), so one DMA of K and
+V a page serves the whole group and a score tile has `(H / KV) * C` rows
+where the dense block's has C. With KV == H nothing moves and the program
+is the dense block's.
 
 With an int8 pool (`kv_quant: int8`), the per-(page, head) f32 scales
 [P, H] are gathered through the page table OUTSIDE the kernel into
@@ -99,7 +113,8 @@ def _dot(a, b, contract, batch):
 
 
 def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
-            block: int, scale: float, quant: bool):
+            block: int, scale: float, quant: bool, queries: int,
+            causal: bool):
     if quant:
         # int8 pool: this slot's per-(head, page) scales [1, H, max_pages],
         # page-table-gathered by the caller
@@ -175,11 +190,13 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         vb = vb.reshape(t_blk, h, dh)
         # scores per head: batch H, contract Dh -> [H, C, block * ps]
         s = _dot(q, kb, ((2,), (2,)), ((1,), (1,))) * scale
-        qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
+        # `c` rows hold `queries` positions, once a grouped head
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
+        qpos = pos + (row if c == queries else row % queries)
         vpos = b * t_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, t_blk), 2)
-        s = jnp.where((vpos <= qpos) & (vpos < max_pages * page_size),
-                      s, _NEG)
+        seen = vpos <= qpos if causal else vpos < pos + queries
+        s = jnp.where(seen & (vpos < max_pages * page_size), s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))   # [H, C, 1]
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -201,17 +218,24 @@ def _auto_interpret() -> bool:
     return jax.default_backend() not in ("tpu",)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool):
-    s_, c, h, dh = q.shape
-    page_size = k_pool.shape[1]
+@functools.partial(jax.jit, static_argnames=("interpret", "causal"))
+def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
+          causal: bool = True):
+    s_, queries, heads, dh = q.shape
+    page_size, h = k_pool.shape[1:3]
+    group = heads // h
+    if group > 1:
+        # the query heads of one KV head ride the rows axis, group-major
+        q = q.reshape(s_, queries, h, group, dh).transpose(
+            0, 3, 1, 2, 4).reshape(s_, group * queries, h, dh)
+    c = group * queries
     max_pages = pages.shape[1]
     block = min(_BLOCK_PAGES, max_pages)
     quant = scales is not None
     # pages that hold a position some query of the slot attends; a retired
     # slot's stale `pos` counts for nothing
     live = jnp.where(
-        active, jnp.minimum(pl.cdiv(pos + c, page_size), max_pages), 0)
+        active, jnp.minimum(pl.cdiv(pos + queries, page_size), max_pages), 0)
     slot = pl.BlockSpec((1, c, h, dh), lambda s, *_: (s, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; DMA'd by hand
     in_specs = [slot, pool, pool]
@@ -231,27 +255,32 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool):
         scratch_shapes=[buf, buf,                      # K, V double buffers
                         pltpu.SemaphoreType.DMA((2, 2))],  # [K/V, buffer]
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, block=block, scale=dh ** -0.5,
-                          quant=quant),
+                          quant=quant, queries=queries, causal=causal),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, c, h, dh), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(*operands)
+    if group > 1:
+        out = out.reshape(s_, group, queries, h, dh).transpose(
+            0, 2, 3, 1, 4).reshape(s_, queries, heads, dh)
+    return out
 
 
 def paged_attention(q, k_pool, v_pool, pages, pos,
                     k_scales=None, v_scales=None, active=None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None, causal: bool = True):
     """Fused paged decode attention (module docstring has the contract).
 
-    q [S, C, H, Dh], k/v pool [P, page_size, H, Dh], pages [S, max_pages]
+    q [S, C, H, Dh], k/v pool [P, page_size, KV, Dh], pages [S, max_pages]
     int32, pos [S] int32 -> [S, C, H, Dh]. With an int8 pool, k_scales /
-    v_scales [P, H] f32 per-(page, head) scales must both ride along —
+    v_scales [P, KV] f32 per-(page, head) scales must both ride along —
     each slab is dequantized in VMEM right after its DMA. `active` [S]
     bool (default: every slot) marks the slots whose rows are wanted: a
-    slot that is not active costs no page read and returns zeros."""
+    slot that is not active costs no page read and returns zeros.
+    `causal=False`: the window's queries see each other both ways."""
     if interpret is None:
         interpret = _auto_interpret()
     if (k_scales is None) != (v_scales is None):
@@ -264,7 +293,7 @@ def paged_attention(q, k_pool, v_pool, pages, pos,
         (n_slots,))
     scales = None if k_scales is None else (k_scales, v_scales)
     return _call(q, k_pool, v_pool, pages, pos, active, scales,
-                 bool(interpret))
+                 bool(interpret), bool(causal))
 
 
 # --------------------------------------------------------------------------

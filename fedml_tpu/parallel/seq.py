@@ -36,16 +36,22 @@ _NEG = -1e9  # finite "-inf": keeps exp() NaN-free for fully-masked rows
 
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            q_offset=0, k_offset=0,
-                           window: int | None = None) -> jax.Array:
+                           window: int | None = None,
+                           block: int = 0) -> jax.Array:
     """Reference causal attention. q: [B, T, H, D], k/v: [B, T, KV, D] with
     KV dividing H (query head i reads KV head i // (H / KV)) ->
     [B, T, H, D]. Offsets give the global position of element 0 (used when
     chunks of a sharded sequence are compared). `window`: position i sees
-    j only where 0 <= i - j < window."""
+    j only where 0 <= i - j < window. `block` > 0 is the block-causal mask
+    of a block-diffusion model: i sees j iff j // block <= i // block
+    (causal over blocks, both ways inside one, blocks counted from 0)."""
     scale = q.shape[-1] ** -0.5
     qpos = q_offset + jnp.arange(q.shape[1])
     kpos = k_offset + jnp.arange(k.shape[1])
-    mask = qpos[:, None] >= kpos[None, :]
+    if block:
+        mask = qpos[:, None] // block >= kpos[None, :] // block
+    else:
+        mask = qpos[:, None] >= kpos[None, :]
     if window is not None:
         mask = mask & (qpos[:, None] - kpos[None, :] < window)
     if k.shape[2] != q.shape[2]:

@@ -25,7 +25,9 @@ Shape of the thing:
   `make_paged_latent_decode`'s programs in `make_paged_kv_decode`'s place
   (expert layers in the step; int8 pages, `mp > 1` and LoRA adapters
   refused for it by name). Everything below (pages, the table, chunked
-  prefill, the prefix cache, retirement) is the same for both.
+  prefill, the prefix cache, retirement) is the same for both. The pool of
+  per-head rows holds as many KV heads as the model has (grouped heads
+  share them), heads of the model's own `head_dim`.
 - Admission allocates a request's pages (ceil((prompt+max_new)/page_size),
   reserved up front so a mid-decode slot can never hit page exhaustion)
   from a host free list; retirement returns them. The free list + prefix
@@ -90,6 +92,32 @@ DECODE RAW SPEED (ISSUE 11) — two legs, both token-identity pinned
   window re-writes rejected positions' pages before anything reads
   them. Accept telemetry: `serving.spec.proposed` / `.accepted`
   counters, accept-rate on the `top` engine line.
+
+GENERATION BY DIFFUSION OVER BLOCKS (a model with `diffusion_block` = B > 0,
+llm/transformer.py): the iteration is neither the step nor the verify window
+but `_block_all`: every live slot advances its current BLOCK by one forward
+over the block's B positions (`<|MASK|>` where still masked), and yields 0
+to B tokens. The carry holds, per slot, the block's token ids, which are
+still masked, the forward's index within the block and the request's
+`denoising_steps` and `confidence_threshold` as traced values. On device:
+every masked position's pick and its probability (the confidence); the
+unmask rule (the `ceil(B / steps)` most confident, and every one over the
+threshold; an unmasked token is final); when a block enters a forward with
+nothing masked that forward is the COMMIT (its K/V writes are the block's
+final ones) and the next block starts; retirement when the run of final
+tokens from the block's start reaches the budget or an `eos`. Admission
+prefills the prompt's whole blocks, yields NO token, and seeds the first
+block with the prompt's tail: the first token comes from a denoising
+forward. The host streams, after each forward, the longest run of final
+tokens from the block's start, each with the forward index at which it was
+unmasked and its confidence (`Ticket.note`). Pages are a whole number of
+blocks, so a prompt's full pages are shared as any other model's. Counters:
+`serving.engine.block_forwards` / `.commit_forwards` (live slots a drained
+block frame, and those of them that were commits), `.block_positions` (live
+slots x B), `.unmasked_tokens`, `.block_context` (the positions a live
+slot's window attends, once a forward: what the paged kernel has to read),
+and from what the expert layers sow `.moe_pairs` and `.moe_experts_live`; `steps`, `slot_steps`, `page_steps`
+and `context_keys` count block forwards as they count steps.
 
 Capacity contract per slot: `prompt_len + max_new_tokens <= max_len`
 (no step bucketing — the engine emits exactly the tokens asked for, so
@@ -171,6 +199,9 @@ from .predictor import InvalidRequest, _bucket
 
 log = logging.getLogger(__name__)
 Pytree = Any
+# a block-diffusion request's confidence threshold where it names none (the
+# SDAR family's published default; a request's null means the static rule)
+CONFIDENCE_THRESHOLD = 0.9
 
 
 def _page_key(parent: bytes, tokens) -> bytes:
@@ -227,14 +258,17 @@ class Ticket:
     `stream()` can relay them while the request still decodes (the SSE
     serving surface); `result()` keeps the block-until-done contract."""
 
-    __slots__ = ("_cv", "_done", "_tokens", "_error", "trace", "t_submit",
-                 "t_slot", "t_prefilled", "t_first", "t_done", "prefill",
-                 "_spanned")
+    __slots__ = ("_cv", "_done", "_tokens", "_notes", "_error", "trace",
+                 "t_submit", "t_slot", "t_prefilled", "t_first", "t_done",
+                 "prefill", "_spanned")
 
     def __init__(self, prompt: int = 0):
         self._cv = threading.Condition()
         self._done = threading.Event()
         self._tokens: list[int] = []
+        # beside each token of a block-diffusion model: (the forward index
+        # within its block at which it was unmasked, its confidence)
+        self._notes: list[tuple] = []
         self._error: Optional[BaseException] = None
         # created by submit() on the caller's thread (the HTTP handler's,
         # inside its `serving.request` span): the spans of this request's
@@ -276,10 +310,18 @@ class Ticket:
             trace_id=s.trace_id, parent_id=parent)
 
     # engine-thread side -------------------------------------------------
-    def _push(self, tok: int) -> None:
+    def _push(self, tok: int, note: Optional[tuple] = None) -> None:
         with self._cv:
             self._tokens.append(tok)
+            if note is not None:
+                self._notes.append(note)
             self._cv.notify_all()
+
+    def note(self, i: int) -> Optional[tuple]:
+        """(forward index, confidence) of token `i` of a block-diffusion
+        model's answer; None for a model that emits a token a step."""
+        with self._cv:
+            return self._notes[i] if i < len(self._notes) else None
 
     def _finish(self, error: Optional[BaseException] = None) -> None:
         with self._cv:
@@ -344,13 +386,19 @@ def submitted_ticket(trace_id: Optional[str]) -> Optional[Ticket]:
 
 
 class _Request:
-    __slots__ = ("tokens", "max_new", "temperature", "seed", "ticket")
+    __slots__ = ("tokens", "max_new", "temperature", "seed", "ticket",
+                 "steps", "threshold")
 
-    def __init__(self, tokens, max_new, temperature, seed):
+    def __init__(self, tokens, max_new, temperature, seed, steps=0,
+                 threshold=None):
         self.tokens = tokens
         self.max_new = max_new
         self.temperature = temperature
         self.seed = seed
+        # a block-diffusion request's denoising forwards a block and its
+        # confidence threshold (None: the static rule)
+        self.steps = steps
+        self.threshold = threshold
         self.ticket = Ticket(len(tokens))
 
 
@@ -423,15 +471,22 @@ class _SlotState:
     the prompt tail, the decode budget, and any page whose registration
     lost a race to a concurrent identical prompt)."""
 
-    __slots__ = ("req", "out", "t_first", "entries", "private")
+    __slots__ = ("req", "out", "t_first", "entries", "private",
+                 "block_pos", "run", "notes")
 
-    def __init__(self, req: _Request):
+    def __init__(self, req: _Request, block: int = 0):
         self.req = req
         req.ticket.t_slot = time.perf_counter()   # the queue wait ends here
         self.out: list[int] = []
         self.t_first: Optional[float] = None
         self.entries: list[_PrefixEntry] = []
         self.private: list[int] = []
+        # a block-diffusion slot: where its current block starts, how many
+        # of the block's positions the streamed run has passed, and each
+        # unmasked position's (forward index, confidence)
+        self.block_pos = len(req.tokens) // block * block if block else 0
+        self.run = 0
+        self.notes: dict = {}
 
 
 class DecodeEngine:
@@ -477,7 +532,12 @@ class DecodeEngine:
     up to that many same-bucket pending prompts per engine iteration
     through ONE batched chunk program — burst TTFT p99 stops paying one
     dispatch per request. Both compose with each other, the kernel, spec
-    decode, and `mesh`."""
+    decode, and `mesh`.
+
+    A model with `diffusion_block` > 0 generates a block at a time (module
+    docstring); `spec_decode`, `kv_quant='int8'`, `admit_batch` > 1 and a
+    mesh are refused for it by name, and `page_size`, `prefill_chunk` and
+    `max_len` must be whole numbers of blocks."""
 
     def __init__(self, model, params: Pytree,
                  adapters: Optional[Pytree] = None, *,
@@ -562,6 +622,31 @@ class DecodeEngine:
         if self._admit_batch < 1:
             raise ValueError(
                 f"admit_batch must be >= 1; got {admit_batch}")
+        # generation by diffusion over blocks of B positions (0: a token a
+        # step). A page, a prefill chunk and a slot's table are whole
+        # numbers of blocks: a prefix page is then shared only where whole
+        # blocks are (16 = 4 x 4), and a chunk's mask never cuts a block
+        self._block = B = int(getattr(model, "diffusion_block", 0) or 0)
+        if B:
+            for knob, on in (
+                    ("spec_decode", self._spec_on),
+                    ("kv_quant='int8'", self._quant),
+                    ("admit_batch > 1", self._admit_batch > 1),
+                    ("a mesh (mp > 1)", mesh is not None)):
+                if on:
+                    raise NotImplementedError(
+                        f"{knob} with a diffusion model: a block's forward "
+                        "is `_block_all` over the plain pool on one chip "
+                        "(no drafted window, no per-page scales, one "
+                        "request an admission program, no heads split)")
+            for knob, v in (("kv_page_size", self._page_size),
+                            ("prefill_chunk", self._prefill_chunk),
+                            ("engine_max_len", self.max_len)):
+                if v % B:
+                    raise ValueError(
+                        f"{knob} {v} is not a whole number of the model's "
+                        f"diffusion blocks of {B}: pages, prefill chunks "
+                        "and a slot's table hold whole blocks")
         self._admissions: deque[_Admission] = deque()
         # -1 never matches a token id, so eos retirement is inert
         self._eos = -1 if eos_id is None else int(eos_id)
@@ -603,10 +688,15 @@ class DecodeEngine:
             mp = mesh.shape["mp"]
             if latent:
                 partition.paged_latent_cache_spec("mp", mp)   # refuses mp > 1
-            if model.n_heads % mp:
+            if (model.n_kv_heads or model.n_heads) % mp:
                 raise ValueError(
-                    f"n_heads {model.n_heads} is not divisible by mp={mp}"
-                    " — the KV pool shards the heads axis")
+                    f"the {model.n_kv_heads or model.n_heads} KV heads are "
+                    f"not divisible by mp={mp} — the KV pool shards the "
+                    "heads axis")
+            if mp > 1 and any(f == "moe" for _, f in model.kinds):
+                raise NotImplementedError(
+                    "expert layers under an mp mesh: the partition rules "
+                    "(parallel/partition.py) split no expert's weights")
             rules = (partition_rules
                      if partition_rules is not None
                      else partition.transformer_lm_rules("mp"))
@@ -631,7 +721,9 @@ class DecodeEngine:
              chunk_batch_fn) = make_paged_kv_decode(
                 model.n_heads, self._page_size, dtype=kv_dtype,
                 eps=model.norm_eps, kernel=self._kernel_on, mesh=mesh,
-                quant=self._quant, rope_base=model.rope_base)
+                quant=self._quant, rope_base=model.rope_base,
+                head_dim=model.head_dim, qk_norm=model.qk_norm,
+                moe=model.moe if model.has_counters else None, block=B)
         S, eos, max_len_ = self.n_slots, self._eos, self.max_len
 
         def pick(logits, temp, key):
@@ -653,19 +745,45 @@ class DecodeEngine:
                                  greedy)
 
         def _admit(params, adapters, carry, tokens, t0, clen, slot,
-                   row, temp, seed, limit, final, plen):
+                   row, temp, seed, limit, final, plen, *blocked):
             """ONE chunk of one request's prefill into the paged
             carry: the slot's page-table row is (re)written, the
             chunk's K/V land in its pages, and — on the FINAL chunk —
             the last-position logits yield the first token and the
             slot's rows arm. Non-final chunks set the same rows
             (harmless while active stays False) so one program covers
-            every chunk; everything but the token buffer is traced."""
+            every chunk; everything but the token buffer is traced.
+            A diffusion model's admission (`blocked`: the prompt's
+            tail, the request's steps and threshold) yields no token:
+            the chunk is the prompt's whole blocks, the slot's first
+            block starts where they end with the tail at its head,
+            and `limit` is the position after the last one owed."""
             pages = carry["pages"].at[slot].set(row)
-            cache, logits = chunk_fn(params, adapters, carry["cache"],
-                                     row, tokens, t0, clen)
+            cache, logits, *_ = chunk_fn(params, adapters, carry["cache"],
+                                         row, tokens, t0, clen)
             key = jax.random.fold_in(jax.random.key(seed), plen)
             first = pick(logits[0], temp, key)
+            if B:
+                tail, steps, thr = blocked
+                start = plen // B * B
+                masked = jnp.arange(B) >= plen - start
+                return {
+                    "cache": cache,
+                    "pages": pages,
+                    "pos": carry["pos"].at[slot].set(start),
+                    "tok": carry["tok"],
+                    "active": carry["active"].at[slot].set(final),
+                    "temp": carry["temp"].at[slot].set(temp),
+                    "seed": carry["seed"].at[slot].set(seed),
+                    "limit": carry["limit"].at[slot].set(limit),
+                    "plen": carry["plen"].at[slot].set(plen),
+                    "blk": carry["blk"].at[slot].set(
+                        jnp.where(masked, model.mask_id, tail)),
+                    "msk": carry["msk"].at[slot].set(masked),
+                    "fwd": carry["fwd"].at[slot].set(0),
+                    "steps": carry["steps"].at[slot].set(steps),
+                    "thr": carry["thr"].at[slot].set(thr),
+                }, first
             # active iff this was the last chunk, the first token did
             # not end it, and there is budget left (limit = plen +
             # max_new - 1: the position after which no step token is owed)
@@ -702,7 +820,7 @@ class DecodeEngine:
             indices are discarded under jit), an all-zero page row
             (writes land on the null page) and clen 0."""
             pages = carry["pages"].at[slots].set(rows)
-            cache, logits = chunk_batch_fn(
+            cache, logits, *_ = chunk_batch_fn(
                 params, adapters, carry["cache"], rows, tokens,
                 t0s, clens)
             keys = jax.vmap(
@@ -736,7 +854,7 @@ class DecodeEngine:
             may point at a page re-allocated to another request, so its
             garbage write is redirected to the null page."""
             active, temp = carry["active"], carry["temp"]
-            cache, logits = paged_step(
+            cache, logits, *_ = paged_step(
                 params, adapters, carry["cache"], carry["pages"],
                 carry["pos"], carry["tok"], active)
             keys = jax.vmap(
@@ -800,7 +918,7 @@ class DecodeEngine:
                 s_idx[:, None],
                 jnp.where(active[:, None] & (widx < max_len_),
                           widx, max_len_)].set(inputs)
-            cache, logits = paged_verify(
+            cache, logits, *_ = paged_verify(
                 params, adapters, carry["cache"], carry["pages"],
                 pos, inputs, active)
             # the SAME rng schedule as the plain step (fold_in at
@@ -839,6 +957,86 @@ class DecodeEngine:
                    "temp": temp, "seed": carry["seed"],
                    "limit": carry["limit"], "hist": hist}
             return out, (g, jnp.where(active, n_acc, 0))
+
+        def _block_all(params, adapters, carry):
+            """A diffusion model's iteration, ALL slots: one forward over
+            every live slot's current block (B positions at the block's
+            start `pos`, `<|MASK|>` where still masked, over the cached
+            earlier blocks; the block's K/V written at every forward),
+            then ON DEVICE: each masked position's pick and its
+            probability (the confidence), the unmask rule, the commit,
+            the advance and retirement.
+
+            A block that ENTERS with nothing masked is committed by this
+            forward (its K/V are now those of its final tokens) and
+            gives way to the next: `pos` moves on, every position is
+            masked again, the forward index returns to 0. Otherwise the
+            `ceil(B / steps)` most confident masked positions (ties to
+            the earlier) and every one over `thr` are unmasked for good.
+            The run of final tokens from the block's start is what the
+            host streams; the slot retires when that run reaches the
+            budget (`limit`) or holds an `eos` among the positions it
+            owes, so a request's last block is never committed: nothing
+            would read it."""
+            active, pos = carry["active"], carry["pos"]
+            blk, msk, temp = carry["blk"], carry["msk"], carry["temp"]
+            cache, logits, *counted = paged_verify(
+                params, adapters, carry["cache"], carry["pages"], pos,
+                blk, active)
+            with layer_scope("unmask"):
+                j = jnp.arange(B)
+                posj = pos[:, None] + j[None, :]                   # [S, B]
+                commit = active & ~jnp.any(msk, axis=1)
+                keys = jax.vmap(lambda s, ps, f: jax.vmap(
+                    lambda q: jax.random.fold_in(jax.random.fold_in(
+                        jax.random.key(s), q + 1), f))(ps))(
+                            carry["seed"], posj, carry["fwd"])
+                g = jax.vmap(pick, in_axes=(1, None, 1), out_axes=1)(
+                    logits, temp, keys)
+                lf = logits.astype(jnp.float32)
+                conf = jnp.exp(
+                    jnp.take_along_axis(lf, g[..., None], -1)[..., 0]
+                    - jax.nn.logsumexp(lf, axis=-1))
+                cm = jnp.where(msk, conf, -1.0)
+                # a masked position's rank by confidence among the masked
+                ahead = (cm[:, None, :] > cm[:, :, None]) | (
+                    (cm[:, None, :] == cm[:, :, None])
+                    & (j[None, None, :] < j[None, :, None]))
+                rank = jnp.sum(ahead, axis=-1)
+                least = -(-B // jnp.maximum(carry["steps"], 1))
+                sel = (msk & active[:, None]
+                       & ((rank < least[:, None])
+                          | (cm > carry["thr"][:, None])))
+                blk2 = jnp.where(sel, g, blk)
+                msk2 = msk & ~sel
+                run = jnp.sum(jnp.cumprod(~msk2, axis=1), axis=1)
+                owed = ((posj >= carry["plen"][:, None])
+                        & (posj < carry["limit"][:, None])
+                        & (j[None, :] < run[:, None]))
+                retire = ~commit & (
+                    jnp.any(owed & (blk2 == eos), axis=1)
+                    | (pos + run >= carry["limit"]))
+                out = {
+                    "cache": cache,
+                    "pages": carry["pages"],
+                    "pos": jnp.where(commit, pos + B, pos),
+                    "tok": carry["tok"],
+                    "active": active & ~retire,
+                    "temp": temp,
+                    "seed": carry["seed"],
+                    "limit": carry["limit"],
+                    "plen": carry["plen"],
+                    "blk": jnp.where(commit[:, None], model.mask_id, blk2),
+                    "msk": msk2 | commit[:, None],
+                    "fwd": jnp.where(commit, 0, carry["fwd"] + 1),
+                    "steps": carry["steps"],
+                    "thr": carry["thr"],
+                }
+            sown = counted[0] if counted else {}
+            return out, (blk2, msk2, conf, sel, carry["fwd"], active,
+                         commit, {k: sown[k] for k in (
+                             "moe_pairs", "moe_experts_live") if k in sown})
+
         # the carry is DONATED: the cache never round-trips host<->device
         # and XLA may update the slot rows in place. On an mp mesh the
         # carry's output shardings are PINNED (cache on the heads split,
@@ -847,6 +1045,7 @@ class DecodeEngine:
         # would silently turn the in-place update into a full copy.
         self._spec_jit = None
         self._admit_many_jit = None
+        self._block_jit = None
         # track_jit: retrace telemetry + the XLA cost/memory ledger — each
         # program's cost_analysis/memory_analysis lands in xla.program.*
         # gauges on first compile (utils/xla_ledger.py)
@@ -855,6 +1054,9 @@ class DecodeEngine:
                 jax.jit(_admit, donate_argnums=(2,)), "engine_admit")
             self._step_jit = _mx.track_jit(
                 jax.jit(_step_all, donate_argnums=(2,)), "engine_step")
+            if B:
+                self._block_jit = _mx.track_jit(
+                    jax.jit(_block_all, donate_argnums=(2,)), "engine_block")
             if self._spec_on:
                 self._spec_jit = _mx.track_jit(
                     jax.jit(_spec_all, donate_argnums=(2,)), "engine_spec")
@@ -900,9 +1102,9 @@ class DecodeEngine:
                     out_shardings=(carry_sh, rep_sharding)),
                     "engine_admit_many")
 
-        head = model.d_model // model.n_heads
+        head = model.head_dim or model.d_model // model.n_heads
         z = (model.n_layers, self._n_pages, self._page_size,
-             model.n_heads, head)
+             model.n_kv_heads or model.n_heads, head)
         pool_dtype = jnp.int8 if self._quant else kv_dtype
         if latent:
             # one row a token and no heads: the compressed key/value and
@@ -913,7 +1115,7 @@ class DecodeEngine:
             cache = {"k": jnp.zeros(z, pool_dtype),
                      "v": jnp.zeros(z, pool_dtype)}
         if self._quant:
-            zs = (model.n_layers, self._n_pages, model.n_heads)
+            zs = z[:2] + z[3:4]
             cache["ks"] = jnp.zeros(zs, jnp.float32)
             cache["vs"] = jnp.zeros(zs, jnp.float32)
         # persistent KV bytes amortized per decode slot — THE density
@@ -932,6 +1134,14 @@ class DecodeEngine:
             "seed": jnp.zeros((S,), jnp.uint32),
             "limit": jnp.zeros((S,), jnp.int32),
         }
+        if B:
+            self._carry.update(
+                plen=jnp.zeros((S,), jnp.int32),
+                blk=jnp.zeros((S, B), jnp.int32),
+                msk=jnp.zeros((S, B), bool),
+                fwd=jnp.zeros((S,), jnp.int32),
+                steps=jnp.ones((S,), jnp.int32),
+                thr=jnp.full((S,), 2.0, jnp.float32))
         if self._spec_on:
             # per-slot token history (prompt + generated): the draft
             # source, written by admission chunks and the verify
@@ -1077,10 +1287,18 @@ class DecodeEngine:
     # ------------------------------------------------------------ admission
     def submit(self, tokens, max_new_tokens: int,
                temperature: float = 0.0,
-               seed: Optional[int] = None) -> Ticket:
+               seed: Optional[int] = None,
+               denoising_steps: Optional[int] = None,
+               confidence_threshold: Optional[float] = None) -> Ticket:
         """Queue one prompt; returns the Ticket its tokens stream to.
         Capacity contract: prompt + max_new_tokens <= max_len (exact — the
-        engine never buckets the token budget)."""
+        engine never buckets the token budget). A block-diffusion model
+        takes `denoising_steps` (1 .. its block length, which is the
+        default: at least one token a forward) and `confidence_threshold`
+        (in (0, 1]: every masked position over it is unmasked too; None:
+        the static rule); any other model refuses both."""
+        steps, threshold = self.denoising(denoising_steps,
+                                          confidence_threshold)
         tokens = [int(t) for t in tokens]
         if not tokens:
             raise InvalidRequest(
@@ -1099,7 +1317,8 @@ class DecodeEngine:
         # values into range instead of letting jnp.uint32 overflow on the
         # engine thread (still deterministic per seed)
         seed = int(seed) & 0xFFFFFFFF
-        req = _Request(tokens, max_new, float(temperature), seed)
+        req = _Request(tokens, max_new, float(temperature), seed, steps,
+                       threshold)
         with self._cond:
             if self._stopping or (self._thread is not None
                                   and not self._thread.is_alive()):
@@ -1117,6 +1336,34 @@ class DecodeEngine:
         _mx.inc("serving.engine.requests")
         _submitted.ticket = req.ticket
         return req.ticket
+
+    def denoising(self, steps, threshold) -> tuple:
+        """A request's (denoising steps, confidence threshold) as the block
+        program takes them, or InvalidRequest with the sentence why not."""
+        B = self._block
+        if not B:
+            if steps is not None or threshold is not None:
+                raise InvalidRequest(
+                    "denoising_steps and confidence_threshold are a "
+                    "block-diffusion model's parameters; this model "
+                    "generates one token a step")
+            return 0, None
+        try:
+            steps = B if steps is None else int(steps)
+            threshold = None if threshold is None else float(threshold)
+        except (TypeError, ValueError):
+            raise InvalidRequest(
+                "denoising_steps must be an integer and "
+                "confidence_threshold a number or null") from None
+        if not 1 <= steps <= B:
+            raise InvalidRequest(
+                f"denoising_steps must be 1 .. {B} (the model's block "
+                f"length); got {steps}")
+        if threshold is not None and not 0.0 < threshold <= 1.0:
+            raise InvalidRequest(
+                "confidence_threshold must lie in (0, 1] or be null (the "
+                f"static rule); got {threshold}")
+        return steps, threshold
 
     # -------------------------------------------------------------- capacity
     def admissible(self, prompt_len: int, max_new: int) -> bool:
@@ -1180,6 +1427,9 @@ class DecodeEngine:
         pin. "admit" is the chunk program (chunks are prefill_chunk-sized
         except a final pow2-bucketed remainder)."""
         pairs = [("step", self._step_jit), ("admit", self._admit_jit)]
+        if self._block_jit is not None:
+            # a diffusion model's iteration; "step" then stays 0
+            pairs.append(("block", self._block_jit))
         if self._spec_jit is not None:
             # spec mode replaces the step dispatch with ONE verify-window
             # program; "step" then stays 0 and "verify" must stay 1
@@ -1193,6 +1443,7 @@ class DecodeEngine:
     # ------------------------------------------------------------ engine loop
     def _loop(self) -> None:
         # frames: ("admit", slot, first_token_dev) | ("step", toks, mask)
+        # | ("spec", toks, counts) | ("block", *what _block_all yields)
         pending: deque[tuple] = deque()
         try:
             while True:
@@ -1217,7 +1468,12 @@ class DecodeEngine:
                 admitting = {a.slot for a in self._admissions}
                 if any(s is not None and i not in admitting
                        for i, s in enumerate(self._slots)):  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
-                    if self._spec_on:
+                    if self._block:
+                        # one forward advances every slot's current block
+                        self._carry, frame = self._block_jit(
+                            self.params, self.adapters, self._carry)
+                        pending.append(("block",) + frame)
+                    elif self._spec_on:
                         # one verify window advances every slot up to
                         # spec_k + 1 tokens — the speculative analog of
                         # the plain step, same dispatch-ahead contract
@@ -1343,7 +1599,7 @@ class DecodeEngine:
                 slot = self._free.pop()
                 # claim in the SAME critical section as the pop (stop()
                 # racing an admission must find the request somewhere)
-                self._slots[slot] = _SlotState(req)
+                self._slots[slot] = _SlotState(req, self._block)
                 _mx.set_gauge("serving.engine.queue", len(self._waiting))
             ps = self._page_size
             # with the prefix cache off there is nothing to look up OR
@@ -1405,15 +1661,28 @@ class DecodeEngine:
         adm = self._admissions.popleft()
         req = adm.req
         plen = len(req.tokens)
+        B = self._block
+        # what admission writes: the prompt, or of a diffusion model's the
+        # whole blocks (its tail opens the slot's first block; a prompt
+        # shorter than a block admits through one empty chunk)
+        end = plen // B * B if B else plen
         cap = self._prefill_chunk or self.max_len
-        clen = min(cap, plen - adm.t0)
+        clen = min(cap, end - adm.t0)
         # chunk buffers bucket to powers of two below the chunk cap, so
         # the remainder chunk reuses a bounded program set
         cb = min(_bucket(clen, pow2_cap=cap), cap)
+        blocked = ()
+        if B:
+            cb = -(-max(cb, 1) // B) * B        # whole blocks (cap is)
+            tail = np.zeros((B,), np.int32)
+            tail[:plen - end] = req.tokens[end:]
+            blocked = (jnp.asarray(tail), jnp.int32(req.steps),
+                       jnp.float32(2.0 if req.threshold is None
+                                   else req.threshold))
         buf = np.zeros((1, cb), np.int32)
         buf[0, :clen] = req.tokens[adm.t0:adm.t0 + clen]
-        final = adm.t0 + clen == plen
-        limit = plen + req.max_new - 1
+        final = adm.t0 + clen == end
+        limit = plen + req.max_new - (0 if B else 1)
         with recorder.span("serving.engine.admit", slot=adm.slot,
                            prompt=plen, t0=adm.t0, chunk=clen,
                            final=final):
@@ -1422,13 +1691,15 @@ class DecodeEngine:
                 jnp.asarray(buf), jnp.int32(adm.t0), jnp.int32(clen),
                 jnp.int32(adm.slot), jnp.asarray(adm.row),
                 jnp.float32(req.temperature), jnp.uint32(req.seed),
-                jnp.int32(limit), jnp.bool_(final), jnp.int32(plen))
+                jnp.int32(limit), jnp.bool_(final), jnp.int32(plen),
+                *blocked)
         _mx.inc("serving.engine.prefill_chunks")
         self._prefilled(req.ticket, final)
         self._count_keys(adm.t0, clen)
         if final:
             self._register_prefix(adm)
-            pending.append(("admit", adm.slot, first))
+            if not B:       # a diffusion model's admission yields no token
+                pending.append(("admit", adm.slot, first))
         else:
             adm.t0 += clen
             self._admissions.append(adm)
@@ -1570,6 +1841,11 @@ class DecodeEngine:
             for slot in np.nonzero(live)[0]:
                 for t in toks[slot, :counts[slot]]:
                     self._deliver(int(slot), int(t), first=False)
+        elif frame[0] == "block":
+            with recorder.span("serving.engine.fetch", kind="block"):
+                toks, msk, conf, sel, fwd, live, commit, sown = (
+                    jax.device_get(frame[1:]))
+            self._drain_block(toks, msk, conf, sel, fwd, live, commit, sown)
         else:
             _kind, toks_dev, mask_dev = frame
             with recorder.span("serving.engine.fetch", kind="step"):
@@ -1584,6 +1860,53 @@ class DecodeEngine:
         # an entry-mask gauge would read busy forever at idle
         _mx.set_gauge("serving.slots_active",
                       sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+
+    def _drain_block(self, toks, msk, conf, sel, fwd, live, commit,
+                     sown) -> None:
+        """One drained block frame: what `_block_all` left of every slot's
+        block ([S, B] tokens, which are still masked, the picks'
+        confidences, which were unmasked by this forward), the forward's
+        index within its block, the slots that were live at its entry and
+        those of them it committed. Counted first, as `_count_step` counts
+        a step; then each live slot's run of final tokens from the block's
+        start is delivered as far as it has grown, each token with the
+        forward that unmasked it and its confidence."""
+        B, ps = self._block, self._page_size
+        slots = np.nonzero(live)[0]
+        _mx.inc("serving.engine.steps")
+        _mx.inc("serving.engine.slot_steps", len(slots))
+        _mx.inc("serving.engine.block_forwards", len(slots))
+        _mx.inc("serving.engine.commit_forwards", int(commit[slots].sum()))
+        _mx.inc("serving.engine.block_positions", len(slots) * B)
+        _mx.inc("serving.engine.unmasked_tokens", int(sel[slots].sum()))
+        for name, v in sown.items():
+            _mx.inc(f"serving.engine.{name}", int(v))
+        pages = 0
+        for slot in slots:
+            st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+            if st is None:
+                log.warning("engine: block frame for free slot %d dropped",
+                            slot)
+                continue
+            pages += min(-(-(st.block_pos + B) // ps), self._max_pages)
+            self._count_keys(st.block_pos, B)
+            # the keys the window's B queries share: read once a forward
+            _mx.inc("serving.engine.block_context", st.block_pos + B)
+            if commit[slot]:
+                st.block_pos, st.run, st.notes = st.block_pos + B, 0, {}
+                continue
+            for j in np.nonzero(sel[slot])[0]:
+                st.notes[int(j)] = (int(fwd[slot]), float(conf[slot, j]))
+            plen, req = len(st.req.tokens), st.req
+            while st.run < B and not msk[slot, st.run]:
+                j, p = st.run, st.block_pos + st.run
+                st.run += 1
+                # the prompt's tail at the first block's head is not owed
+                if plen <= p < plen + req.max_new and self._deliver(
+                        int(slot), int(toks[slot, j]), first=not st.out,
+                        note=st.notes[j]):
+                    break
+        _mx.inc("serving.engine.page_steps", pages)
 
     def _count_step(self, live: np.ndarray, window: int = 1) -> None:
         """One drained step (or verify) frame, counted BEFORE its tokens
@@ -1617,6 +1940,10 @@ class DecodeEngine:
         so their ratio is the share of the context that enters the softmax,
         not the share of it that is read."""
         seen = n * first + n * (n + 1) // 2
+        if self._block:
+            # a query sees its block to the end: j // B <= i // B
+            at = np.arange(first, first + n)
+            seen = int(((at // self._block + 1) * self._block).sum())
         _mx.inc("serving.engine.context_keys", seen)
         if self._topk is None or first + n <= self._topk:
             _mx.inc("serving.engine.selected_keys", seen)
@@ -1626,13 +1953,16 @@ class DecodeEngine:
                 full * first + full * (full + 1) // 2
                 + (n - full) * self._topk)
 
-    def _deliver(self, slot: int, tok: int, first: bool) -> None:
+    def _deliver(self, slot: int, tok: int, first: bool,
+                 note: Optional[tuple] = None) -> bool:
+        """Push one token to the slot's ticket; True when it was the
+        request's last (the slot and its pages are released then)."""
         st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
         if st is None:
             # a frame for a slot the host already retired would mean the
             # device/host retirement conditions diverged — loud beats wrong
             log.warning("engine: token for free slot %d dropped", slot)
-            return
+            return True
         st.out.append(tok)
         _mx.inc("serving.tokens_total")
         now = time.perf_counter()
@@ -1642,7 +1972,7 @@ class DecodeEngine:
             _mx.observe("serving.ttft", now - st.req.ticket.t_submit)
         # push BEFORE the done decision: a stream() consumer sees every
         # token, including the one that retires the slot
-        st.req.ticket._push(tok)
+        st.req.ticket._push(tok, note)
         done = (tok == self._eos) or (len(st.out) >= st.req.max_new)
         if done:
             # avg time-between-tokens over the request's decode phase (the
@@ -1666,6 +1996,7 @@ class DecodeEngine:
                     self._free.append(slot)
                 self._cond.notify_all()
             _mx.inc("serving.engine.completions")
+        return done
 
     def _fail_outstanding(self, err: BaseException) -> None:
         with self._cond:

@@ -10,8 +10,12 @@ work through jit.)
 Fleet surface (ISSUE 9):
 - POST /predict with `"stream": true` answers `text/event-stream`: one
   `data: {"token": t, "index": i}` event per generated token AS the
-  decode engine retires it, then a final `data: {"done": true,
-  "generated_tokens": [...]}` event. Time to the first streamed token
+  decode engine retires it (a block-diffusion model's forward may retire
+  several at once: each is its own event, with `"forward"`, the index
+  within its block of the denoising forward that unmasked it, and its
+  `"confidence"`; such a request may name `denoising_steps` and
+  `confidence_threshold`, null for the static rule), then a final
+  `data: {"done": true, "generated_tokens": [...]}` event. Time to the first streamed token
   lands in the `serving.stream_ttft` histogram. Errors BEFORE the first
   event keep their status codes (400/409/500); an error after the stream
   opened is surfaced as a terminal `data: {"error": ...}` event — a cut

@@ -255,16 +255,18 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                  spec_decode: str = "off", spec_k: int = 4,
                  kv_quant: str = "off", admit_batch: int = 1,
                  drain_timeout_s: float = 30.0):
-        from ..llm.decode import require_servable
+        from ..llm.decode import engine_only, require_servable
 
         require_servable(model)
-        # a latent-attention model has decode programs in the engine alone
-        # (llm/decode.py make_paged_latent_decode): no per-request path
-        # exists to degrade to, or to take batched rows and top_k
-        self._engine_only = getattr(model, "latent", None) is not None
+        # what only the engine's programs run (latent attention, grouped
+        # heads, q/k norms, experts, a diffusion block: llm/decode.py
+        # `engine_only`): no per-request path exists to degrade to, or to
+        # take batched rows and top_k
+        why = engine_only(model)
+        self._engine_only = bool(why)
         if self._engine_only and not decode_slots:
             raise NotImplementedError(
-                "latent attention is served by the decode engine only "
+                f"{why}: served by the decode engine only "
                 "(serve decode_slots > 0): the per-request programs "
                 "(llm/decode.py make_kv_decode) are the dense block's")
         self.model = model
@@ -531,6 +533,28 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         return (rows, temperature, knobs,
                 _req_int(input_json, "max_new_tokens", 16))
 
+    def _denoising(self, input_json: dict) -> dict:
+        """The request's two block-diffusion parameters as `engine.submit`
+        takes them: `denoising_steps` (default: the model's block length)
+        and `confidence_threshold` (default 0.9; null: the static rule).
+        A model that generates a token a step refuses both with a
+        sentence (engine.denoising), as does a predictor without an
+        engine."""
+        named = [k for k in ("denoising_steps", "confidence_threshold")
+                 if k in input_json]
+        if self.engine is None or not self.engine.model.diffusion_block:
+            if named:
+                raise InvalidRequest(
+                    f"{'/'.join(named)}: a block-diffusion model's "
+                    "parameters; this model generates one token a step")
+            return {}
+        from .engine import CONFIDENCE_THRESHOLD
+
+        steps, threshold = self.engine.denoising(
+            input_json.get("denoising_steps"),
+            input_json.get("confidence_threshold", CONFIDENCE_THRESHOLD))
+        return {"denoising_steps": steps, "confidence_threshold": threshold}
+
     def _must_surface_engine_failure(self, prompt_len: int, new: int,
                                      temperature: float,
                                      seed: Optional[int]) -> bool:
@@ -560,6 +584,7 @@ class GreedyLMPredictor(_InstrumentedPredictor):
         batched = bool(raw) and isinstance(raw[0], (list, tuple))
         rows, temperature, knobs, new = self._parse_request(
             input_json, batched)
+        denoising = self._denoising(input_json)
         if batched and not self.kv_cache:
             raise InvalidRequest(
                 "batched prompts need kv_cache=True (the recompute path "
@@ -603,7 +628,7 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                 # already passed, re-decoding would double it.
                 gen = self.engine.submit(
                     rows[0], max(new, 1), temperature=temperature,
-                    seed=seed).result(timeout=600.0)[:new]
+                    seed=seed, **denoising).result(timeout=600.0)[:new]
             except RuntimeError:
                 # Degrade ONLY when the per-request path honors the same
                 # contract the engine did; otherwise surface the failure
@@ -737,7 +762,10 @@ class GreedyLMPredictor(_InstrumentedPredictor):
     # ---------------------------------------------------------- streaming
     def predict_stream(self, input_json: dict):
         """Generator form of predict() for single-prompt requests: yields
-        one {"token": t, "index": i} per generated token, then a final
+        one {"token": t, "index": i} per generated token (a block-diffusion
+        model's also carries "forward", the index within its block of the
+        denoising forward that unmasked it, and "confidence"; several may
+        arrive from one forward), then a final
         {"done": True, "generated_tokens": [...]} (plus generated_text
         with a detokenizer) — the payload the runner's SSE surface
         relays chunk by chunk.
@@ -760,6 +788,7 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                 "return a single response; use /predict without stream)")
         rows_w, temperature, knobs, new = self._parse_request(
             input_json, batched=False)
+        denoising = self._denoising(input_json)
         rows = rows_w[0]
         top_k = int(input_json.get("top_k", 0) or 0)
         pin = input_json.get("model_version")
@@ -770,7 +799,8 @@ class GreedyLMPredictor(_InstrumentedPredictor):
             seed = int(input_json["seed"]) if "seed" in input_json else None
             try:
                 ticket = self.engine.submit(
-                    rows, max(new, 1), temperature=temperature, seed=seed)
+                    rows, max(new, 1), temperature=temperature, seed=seed,
+                    **denoising)
             except RuntimeError:
                 # same degrade contract as predict(): greedy/unseeded
                 # falls through to the one-shot path below
@@ -793,7 +823,13 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                 if len(out) >= new:
                     break       # new == 0: the engine still decoded one
                 out.append(int(tok))
-                yield {"token": int(tok), "index": len(out) - 1}
+                chunk = {"token": int(tok), "index": len(out) - 1}
+                note = ticket.note(len(out) - 1)
+                if note is not None:
+                    # a block-diffusion model's token: the forward of its
+                    # block that unmasked it, and its confidence then
+                    chunk["forward"], chunk["confidence"] = note
+                yield chunk
             final = {"done": True, "generated_tokens": out}
             if self.detokenize is not None:
                 final["generated_text"] = self.detokenize(out)

@@ -179,19 +179,23 @@ def start_replica(spec: dict):
         lm = dict(spec.get("lm", {}))
         # the recipe's fields ARE TransformerLM's: the dense block's sizes,
         # and where the model departs from it its own norm eps and rope
-        # base, `latent` (llm.latent.Latent's fields), `moe` (llm.moe.MoE's)
-        # and `layer_kinds`, a (attention, feed-forward) pair a layer
+        # base, grouped KV heads of `head_dim`, per-head q/k norms, a
+        # diffusion block and its mask token, `latent` (llm.latent.Latent's
+        # fields), `moe` (llm.moe.MoE's, `scoring` among them) and
+        # `layer_kinds`, a (attention, feed-forward) pair a layer
+        plain = ("norm_eps", "rope_base", "n_kv_heads", "head_dim",
+                 "qk_norm", "diffusion_block", "mask_id")
         known = {"vocab_size", "d_model", "n_layers", "n_heads", "d_ff",
-                 "scan_layers", "max_len", "norm_eps", "rope_base", "latent",
-                 "moe", "layer_kinds"}
+                 "scan_layers", "max_len", "latent", "moe", "layer_kinds",
+                 *plain}
         if set(lm) - known:
             # the keys of a block this replica would silently not build
             raise NotImplementedError(
                 f"start_replica does not build what the lm recipe asks for "
-                f"with {sorted(set(lm) - known)}: grouped KV heads and "
-                "window layers cannot be served yet (llm/decode.py "
-                "`unserved` says which mechanism each lacks)")
-        more = {k: lm[k] for k in ("norm_eps", "rope_base") if k in lm}
+                f"with {sorted(set(lm) - known)}: window layers and layers "
+                "without rotary positions cannot be served yet "
+                "(llm/decode.py `unserved` says which mechanism each lacks)")
+        more = {k: lm[k] for k in plain if k in lm}
         if "latent" in lm:
             from ..llm.latent import Latent
 

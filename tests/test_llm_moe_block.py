@@ -266,16 +266,16 @@ def test_a_model_that_counts_nothing_keeps_the_rounds_three_metrics():
 # ------------------------------------------------------------------ serving
 def test_the_decode_path_refuses_each_mechanism_it_lacks_by_name():
     lacking = decode.unserved(kexaone_like())
+    # grouped KV heads, per-head q/k norms and experts under full attention
+    # are served since PR 36; what remains:
     assert [s.split(":")[0] for s in lacking] == [
-        "grouped KV heads", "window layers",
-        "expert layers under full or window attention",
-        "per-head q/k norms, layers without rotary positions"]
-    with pytest.raises(NotImplementedError, match="grouped KV heads.*"
-                       "window layers.*expert layers"):
+        "window layers", "layers without rotary positions"]
+    with pytest.raises(NotImplementedError, match="window layers.*"
+                       "layers without rotary positions"):
         decode.require_servable(kexaone_like())
     gqa = TransformerLM(vocab_size=8, d_model=32, n_heads=4, n_kv_heads=2)
-    assert [s.split(":")[0] for s in decode.unserved(gqa)] == [
-        "grouped KV heads"]
+    assert decode.unserved(gqa) == []
+    assert decode.engine_only(gqa).startswith("grouped KV heads")
 
 
 def test_serving_entry_points_refuse_the_model_before_building_anything():
@@ -284,14 +284,14 @@ def test_serving_entry_points_refuse_the_model_before_building_anything():
     from fedml_tpu.serving.scheduler import start_replica
 
     lm = kexaone_like()
-    with pytest.raises(NotImplementedError, match="expert layers"):
+    with pytest.raises(NotImplementedError, match="rotary positions"):
         GreedyLMPredictor(lm, {})
     with pytest.raises(NotImplementedError, match="window layers"):
         DecodeEngine(lm, {}, n_slots=2, max_len=16)
-    with pytest.raises(NotImplementedError, match=r"\['n_kv_heads'\]"):
+    with pytest.raises(NotImplementedError, match=r"\['rope_full'\]"):
         start_replica({"model_kind": "lm", "params": {}, "lm": {
             "vocab_size": 8, "d_model": 32, "n_layers": 1, "n_heads": 4,
-            "d_ff": 64, "n_kv_heads": 2}})
+            "d_ff": 64, "n_kv_heads": 2, "rope_full": False}})
     # layers of two kinds are not stacked: a tuple of the layers as they are
     tok = jnp.zeros((1, 8), jnp.int32)
     params = lm.init(jax.random.key(0), tok)["params"]
@@ -304,4 +304,6 @@ def test_the_sequence_parallel_round_refuses_what_it_would_silently_drop():
     from fedml_tpu.llm import make_fedllm_seq_round
 
     with pytest.raises(NotImplementedError, match="grouped KV heads"):
+        make_fedllm_seq_round(kexaone_like(), {}, TrainArgs(), mesh=None)
+    with pytest.raises(NotImplementedError, match="window layers"):
         make_fedllm_seq_round(kexaone_like(), {}, TrainArgs(), mesh=None)
