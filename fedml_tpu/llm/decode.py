@@ -516,8 +516,9 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     expert layer in the SwiGLU's place in every layer whose parameters hold
     one, over the LIVE rows alone (an idle slot's and a chunk's padded rows
     are routed nowhere), and every program then returns a third value, the
-    layers' folded counters (`moe_pairs`, `moe_experts_live`, ...). Layers
-    that `stack_blocks` left as a tuple run unrolled.
+    layers' folded counters (`moe_pairs`, `moe_experts_live`,
+    `moe_tile_rows`, ...). Layers that `stack_blocks` left as a tuple run
+    unrolled.
 
     `block` = B > 0 is a block-diffusion model: position i attends j iff
     j // B <= i // B. `chunk` and `chunk_batch` take that mask (t0 and every

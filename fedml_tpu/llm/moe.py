@@ -24,7 +24,10 @@ at run time) multiplies each expert's rows by its weights, and the moves
 around it (`_dispatch` tokens to rows, `_combine` rows back to tokens, each
 with the other's form as its backward) are the Pallas kernels of
 ops/routed_rows.py, whose walk is bounded by `n_here` at run time: rows past
-it are neither read nor written.
+it are neither read nor written. The product's row tile is the rows an
+expert can expect (`row_tile`): megablox multiplies a whole tile for every
+tile a live expert's rows touch, so a 512-row tile over an expert holding
+three rows is MXU time spent on rows that do not exist.
 
 Scopes (PERF.md section 3): `moe.route`, `moe.dispatch`, `moe.experts`,
 `moe.combine`, `moe.shared`. Counters, sown into the `counters` collection
@@ -35,7 +38,9 @@ held expert, `moe_rows_walked`, the buffer rows the dispatch walked
 says how far the bound engages, 1.0 when every token chooses held experts),
 and, where the caller says which rows are live (the decode programs),
 `moe_experts_live`, the held experts with at least one row (whose weights
-the grouped product has to read).
+the grouped product has to read), and `moe_tile_rows`, the rows the grouped
+product multiplies (`tile_rows`: `moe_pairs / moe_tile_rows` is the tiles'
+fill).
 """
 from __future__ import annotations
 
@@ -54,6 +59,9 @@ COUNTERS = "counters"
 # (rows, contraction, columns) tile caps of the grouped product; each is
 # halved until it divides (rows) or capped at the dimension
 GMM_TILES = (512, 1024, 1024)
+# the least row tile: the v5e MXU's rows (a smaller tile saves no MXU time,
+# and at a few rows an expert the weights' read sets the pace)
+GMM_MIN_ROWS = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,12 +222,33 @@ def _twice_bwd(n_here, gs):
 _twice.defvjp(_twice_fwd, _twice_bwd)
 
 
-def grouped_matmul(rows, weights, sizes):
+def row_tile(p: int, n_experts: int) -> int:
+    """The grouped product's row tile for a buffer of `p` rows routed over
+    a router `n_experts` wide: the rows an expert can expect under even
+    routing, rounded up to a power of two and clamped to [GMM_MIN_ROWS,
+    GMM_TILES[0]], then halved until it divides `p`. The tile decides how
+    many rows the MXU multiplies and throws away, never a sum."""
+    even = -(-p // n_experts)
+    tm = min(max(1 << (even - 1).bit_length(), GMM_MIN_ROWS), GMM_TILES[0])
+    return _auto_block(p, tm)
+
+
+def tile_rows(sizes, tm: int):
+    """Rows megablox multiplies at row tile `tm` over groups of `sizes`
+    rows, laid end to end: `tm` x the row tiles each nonempty group's range
+    touches (empty groups are not visited)."""
+    ends = jnp.cumsum(sizes)
+    touched = (ends + tm - 1) // tm - (ends - sizes) // tm
+    return tm * jnp.sum(jnp.where(sizes > 0, touched, 0))
+
+
+def grouped_matmul(rows, weights, sizes, n_experts: int):
     """rows [P, K] sorted by group, weights [G, K, N], sizes [G] (their
-    sum at most P): rows of group g times weights[g]. Rows past the sum
-    are not computed and hold whatever the buffer held."""
+    sum at most P), routed by a router `n_experts` wide: rows of group g
+    times weights[g]. Rows past the sum are not computed and hold whatever
+    the buffer held."""
     p, k = rows.shape
-    tiles = (_auto_block(p, GMM_TILES[0]), min(k, GMM_TILES[1]),
+    tiles = (row_tile(p, n_experts), min(k, GMM_TILES[1]),
              min(weights.shape[-1], GMM_TILES[2]))
     return megablox.gmm(rows, weights, sizes, rows.dtype, tiles,
                         interpret=_auto_interpret())
@@ -308,14 +337,18 @@ class ExpertLayer(nn.Module):
         if live is not None:
             self.sow(COUNTERS, "moe_experts_live",
                      jnp.sum(sizes > 0, dtype=jnp.int32))
+            self.sow(COUNTERS, "moe_tile_rows",
+                     tile_rows(sizes, row_tile(n * k, sp.n_experts)))
 
         with jax.named_scope("moe.experts"):
             xs_gate, xs_up = _twice(xs, n_here)
             gate = grouped_matmul(xs_gate, expert_kernel("w_gate", d, f),
-                                  sizes)
-            up = grouped_matmul(xs_up, expert_kernel("w_up", d, f), sizes)
+                                  sizes, sp.n_experts)
+            up = grouped_matmul(xs_up, expert_kernel("w_up", d, f), sizes,
+                                sp.n_experts)
             ys = grouped_matmul(_swiglu(gate, up, n_here),
-                                expert_kernel("w_down", f, d), sizes)
+                                expert_kernel("w_down", f, d), sizes,
+                                sp.n_experts)
 
         with jax.named_scope("moe.combine"):
             y = _combine(ys, w, order, inv, here, n_here)

@@ -116,7 +116,7 @@ blocks, so a prompt's full pages are shared as any other model's. Counters:
 block frame, and those of them that were commits), `.block_positions` (live
 slots x B), `.unmasked_tokens`, `.block_context` (the positions a live
 slot's window attends, once a forward: what the paged kernel has to read),
-and from what the expert layers sow `.moe_pairs` and `.moe_experts_live`; `steps`, `slot_steps`, `page_steps`
+and from what the expert layers sow `.moe_pairs`, `.moe_experts_live` and `.moe_tile_rows`; `steps`, `slot_steps`, `page_steps`
 and `context_keys` count block forwards as they count steps.
 
 Capacity contract per slot: `prompt_len + max_new_tokens <= max_len`
@@ -1035,7 +1035,8 @@ class DecodeEngine:
             sown = counted[0] if counted else {}
             return out, (blk2, msk2, conf, sel, carry["fwd"], active,
                          commit, {k: sown[k] for k in (
-                             "moe_pairs", "moe_experts_live") if k in sown})
+                             "moe_pairs", "moe_experts_live",
+                             "moe_tile_rows") if k in sown})
 
         # the carry is DONATED: the cache never round-trips host<->device
         # and XLA may update the slot rows in place. On an mp mesh the

@@ -199,6 +199,25 @@ def test_the_counters_count_block_forwards_as_they_count_steps(served):
     assert eng.program_counts()["block"] == 1
 
 
+def test_moe_tile_rows_is_counted_per_drained_block_frame(served):
+    from fedml_tpu.llm.moe import row_tile
+    _lm, _params, eng = served
+    before = mx.snapshot()["counters"]
+    eng.submit(prompt(8, 67), 8, denoising_steps=2).result(timeout=300)
+    after = mx.snapshot()["counters"]
+    d = lambda k: after.get(k, 0) - before.get(k, 0)
+    # 3 slots x 4 positions x top 2 = 24 rows a layer, over a router 8 wide
+    tile = row_tile(3 * 4 * 2, 8)
+    assert tile == 24
+    assert d("serving.engine.block_forwards") == 5
+    assert 0 < d("serving.engine.moe_pairs") <= d(
+        "serving.engine.moe_tile_rows") <= tile * d(
+            "serving.engine.moe_experts_live")
+    # a tile is the whole buffer here: each live expert's product is one tile
+    assert d("serving.engine.moe_tile_rows") == tile * d(
+        "serving.engine.moe_experts_live")
+
+
 def test_a_request_names_its_denoising_or_is_refused_with_a_sentence(served):
     _lm, _params, eng = served
     with pytest.raises(InvalidRequest, match="denoising_steps must be 1 .. 4"):

@@ -94,11 +94,15 @@ def test_chunk_and_window_are_the_modules_block_causal_forward(tiny,
     assert float(jnp.max(jnp.abs(logits[0] - full[16:20]))) < 2e-4
     assert int(counted["moe_pairs"]) == 4 * 2 * 2
     assert 2 <= int(counted["moe_experts_live"]) <= 2 * 8
+    # 8 rows a layer are one tile: every live expert's product is that tile
+    assert int(counted["moe_tile_rows"]) == 8 * int(
+        counted["moe_experts_live"])
     # an idle slot routes nothing and reads no expert
     _c, _l, idle = window(
         stacked, None, cache, row[None], jnp.asarray([16]), tokens[:, 16:20],
         jnp.asarray([False]))
     assert int(idle["moe_pairs"]) == 0 and int(idle["moe_experts_live"]) == 0
+    assert int(idle["moe_tile_rows"]) == 0
 
 
 def test_chunk_batch_is_chunk_a_row_at_a_time(tiny, programs):
@@ -194,6 +198,7 @@ def test_the_shares_of_a_softmax_layer_sum_to_the_uncut_layer():
             {"params": held}, h, mutable=[COUNTERS])
         total = total + part
         assert "moe_experts_live" not in sown[COUNTERS]   # no `live` given
+        assert "moe_tile_rows" not in sown[COUNTERS]
     assert float(jnp.max(jnp.abs(total - want))) < 1e-5
 
 

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from fedml_tpu.llm import decode
-from fedml_tpu.llm.moe import ExpertLayer, MoE
+from fedml_tpu.llm.moe import (ExpertLayer, MoE, grouped_matmul, row_tile,
+                               tile_rows)
 from fedml_tpu.llm.transformer import TransformerLM
 from fedml_tpu.ops.flash_attention import flash_attention, flash_attn_fn
 from fedml_tpu.parallel.seq import dense_causal_attention
@@ -215,6 +216,87 @@ def test_a_share_that_no_token_picks_gives_the_shared_expert_alone():
     np.testing.assert_allclose(out[0], shared, atol=2e-5)
     assert int(sown["counters"]["moe_pairs"][0]) == 0
     assert int(sown["counters"]["moe_rows_walked"][0]) == 0
+
+
+# ------------------------------------------ the grouped product's row tile
+@pytest.mark.parametrize("p,n_experts,tile", [
+    (16 * 4 * 8, 128, 128),     # SDAR's block forward: 4 rows an expert
+    (256 * 8, 128, 128),        # SDAR's admission chunk: 16
+    (512 * 8, 256, 128),        # GLM-5's admission chunk: 16
+    (16 * 1 * 8, 256, 128),     # GLM-5's decode step: the whole buffer
+    (8192 * 8, 128, 512),       # K-EXAONE's silo: 512, the ceiling
+    (4096 * 8, 16, 512),        # 2,048 an expert: capped at the ceiling
+    (24, 8, 24),                # a tiny buffer is its own tile
+    (384, 2, 128),              # 192 -> 256, halved until it divides 384
+    (640, 1, 128),              # 640 -> 512, halved to 128
+], ids=["sdar_block", "sdar_chunk", "glm5_chunk", "glm5_step",
+        "kexaone_silo", "ceiling", "tiny", "divisor", "divisor_capped"])
+def test_the_row_tile_is_the_rows_an_expert_can_expect(p, n_experts, tile):
+    assert row_tile(p, n_experts) == tile
+    assert p % tile == 0
+
+
+UNEVEN = [0, 300, 3, 0, 130, 1, 0, 200]     # empty groups, one over 3 tiles
+
+
+def test_tile_rows_counts_the_tiles_megablox_visits():
+    import importlib
+    # the package's `gmm` attribute is the function: ask for the module
+    backend = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    sizes = jnp.asarray(UNEVEN, jnp.int32)
+    # by hand at 128: 3 + 1 + 2 (303..433) + 1 + 2 (434..634) tiles
+    assert int(tile_rows(sizes, 128)) == 9 * 128
+    assert int(tile_rows(sizes, 512)) == (1 + 1 + 1 + 1 + 2) * 512
+    for tm in (128, 256, 512):
+        _meta, visited = backend.make_group_metadata(
+            group_sizes=sizes, m=1024, tm=tm, start_group=0,
+            num_nonzero_groups=len(UNEVEN), visit_empty_groups=False)
+        assert int(tile_rows(sizes, tm)) == tm * int(visited)
+    assert int(tile_rows(jnp.zeros(4, jnp.int32), 128)) == 0
+
+
+def test_the_chosen_tile_gives_the_512_tile_product_bit_for_bit():
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    sizes = jnp.asarray(UNEVEN, jnp.int32)
+    n = int(sizes.sum())
+    rows = jax.random.normal(jax.random.key(0), (1024, 128), jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(1), (8, 128, 256), jnp.bfloat16)
+    assert row_tile(1024, 64) == 128
+    got = grouped_matmul(rows, w, sizes, 64)
+    want = megablox.gmm(rows, w, sizes, rows.dtype, (512, 128, 256),
+                        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got[:n], np.float32),
+                                  np.asarray(want[:n], np.float32))
+    ends = np.cumsum(UNEVEN)
+    for g, (lo, hi) in enumerate(zip(ends - UNEVEN, ends)):
+        np.testing.assert_allclose(
+            np.asarray(got[lo:hi], np.float32),
+            np.asarray(rows[lo:hi].astype(jnp.float32)
+                       @ w[g].astype(jnp.float32)), rtol=2e-2, atol=0.3)
+
+
+def test_moe_tile_rows_is_sown_over_live_rows_only_and_counts_by_hand():
+    spec = MoE(n_experts=8, top_k=2, d_expert=16, held=(0, 4), scale=2.5)
+    p = expert_params(jax.random.key(7))
+    # every token picks experts 1 and 2, both held
+    p["e_score_correction_bias"] = jnp.zeros(8).at[jnp.array([1, 2])].set(9.0)
+    h = jax.random.normal(jax.random.key(8), (2, 64, 32))
+    live = jnp.arange(128).reshape(2, 64) < 100
+    layer = ExpertLayer(spec)
+    _out, sown = layer.apply({"params": p}, h, live, mutable=["counters"])
+    c = sown["counters"]
+    # 256 rows over a router 8 wide: 32 an expert, so the floor of 128;
+    # expert 1 holds rows 0..99 (tile 0), expert 2 rows 100..199 (tiles 0, 1)
+    assert row_tile(2 * 64 * 2, 8) == 128
+    assert int(c["moe_pairs"][0]) == 200
+    assert int(c["moe_experts_live"][0]) == 2
+    assert int(c["moe_tile_rows"][0]) == 3 * 128
+    _out, idle = layer.apply({"params": p}, h, jnp.zeros_like(live),
+                             mutable=["counters"])
+    assert int(idle["counters"]["moe_tile_rows"][0]) == 0
+    _out, training = layer.apply({"params": p}, h, mutable=["counters"])
+    assert "moe_tile_rows" not in training["counters"]
 
 
 # ------------------------------------------- federated LoRA over the block
