@@ -170,8 +170,14 @@ drained steps and dispatched prefill chunks see and those their softmax
 weighs, fewer only where an indexer selects: arithmetic on positions, not
 what the kernel read), the
 `serving.prompt_tokens` / `serving.prefix_hit_tokens` counters of admission,
-and `serving.engine.admit` / `.fetch` spans on the Chrome trace — all visible
-in `/metrics` and `python -m fedml_tpu top`. Each request also leaves three
+and the engine thread's spans on the Chrome trace — all visible in
+`/metrics` and `python -m fedml_tpu top`. The loop names what it is doing
+at every turn: `serving.engine.wait` (idle: nothing live, queued or in
+flight), `.plan` (one request's pop, prefix lookup and pages), `.admit` (a
+prefill chunk), `.dispatch` (every program call; `program` says which, and
+one that leaves a frame in flight carries the frame's number, `frame`),
+`.fetch` (a frame's wait on the device) and `.deliver` (its host
+bookkeeping, under the same `frame`). Each request also leaves three
 contiguous spans in its caller's trace once its consumer has the first
 token (`Ticket._record_spans`): `serving.engine.queue`, `.prefill`,
 `.first_fetch`; the device programs carry `decode.*` named scopes
@@ -648,6 +654,9 @@ class DecodeEngine:
                         f"diffusion blocks of {B}: pages, prefill chunks "
                         "and a slot's table hold whole blocks")
         self._admissions: deque[_Admission] = deque()
+        # the number the last frame left in flight took: its dispatch and
+        # its deliver spans carry it (`_dispatch`, `_drain`)
+        self._frame = 0
         # -1 never matches a token id, so eos retirement is inert
         self._eos = -1 if eos_id is None else int(eos_id)
         self.adapters = stack_adapter_blocks(adapters, model.n_layers)
@@ -1442,10 +1451,29 @@ class DecodeEngine:
         return {name: fn._cache_size() for name, fn in pairs}
 
     # ------------------------------------------------------------ engine loop
+    def _dispatch(self, program: str, frame: bool = True):
+        """The `serving.engine.dispatch` span around one program call. A
+        call that leaves a frame in flight takes the next frame number
+        (`self._frame`), which rides in the frame to its
+        `serving.engine.deliver` span."""
+        if not frame:
+            return recorder.span("serving.engine.dispatch", program=program)
+        self._frame += 1
+        return recorder.span("serving.engine.dispatch", program=program,
+                             frame=self._frame)
+
     def _loop(self) -> None:
-        # frames: ("admit", slot, first_token_dev) | ("step", toks, mask)
-        # | ("spec", toks, counts) | ("block", *what _block_all yields)
+        # frames: (kind, number, *what its program yielded): ("admit", n,
+        # [(index, slot)], first tokens) | ("step", n, toks, mask) |
+        # ("spec", n, toks, counts) | ("block", n, *what _block_all yields)
         pending: deque[tuple] = deque()
+        # what one iteration dispatches over every slot: a block forward
+        # (it advances every slot's current block), a verify window (up to
+        # spec_k + 1 tokens a slot, the speculative analog of the plain
+        # step under the same dispatch-ahead contract) or a plain step
+        kind, advance = (("block", self._block_jit) if self._block else
+                         ("spec", self._spec_jit) if self._spec_on else
+                         ("step", self._step_jit))
         try:
             while True:
                 with self._cond:
@@ -1455,8 +1483,15 @@ class DecodeEngine:
                     idle = (swap is None and not self._waiting and not pending
                             and all(s is None for s in self._slots))
                     if idle:
+                        t0 = time.perf_counter()
                         self._cond.wait(0.2)
-                        continue
+                        t1 = time.perf_counter()
+                if idle:
+                    # nothing live, queued, in flight or due: the device's
+                    # idle time under this span is the traffic's doing,
+                    # anywhere else the loop's
+                    recorder.record_span("serving.engine.wait", t0, t1)
+                    continue
                 if swap is not None:
                     # between iterations, by construction: the previous
                     # iteration's dispatches hold their own references,
@@ -1469,22 +1504,10 @@ class DecodeEngine:
                 admitting = {a.slot for a in self._admissions}
                 if any(s is not None and i not in admitting
                        for i, s in enumerate(self._slots)):  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
-                    if self._block:
-                        # one forward advances every slot's current block
-                        self._carry, frame = self._block_jit(
+                    with self._dispatch(kind):
+                        self._carry, out = advance(
                             self.params, self.adapters, self._carry)
-                        pending.append(("block",) + frame)
-                    elif self._spec_on:
-                        # one verify window advances every slot up to
-                        # spec_k + 1 tokens — the speculative analog of
-                        # the plain step, same dispatch-ahead contract
-                        self._carry, (toks, counts) = self._spec_jit(
-                            self.params, self.adapters, self._carry)
-                        pending.append(("spec", toks, counts))
-                    else:
-                        self._carry, (toks, mask) = self._step_jit(
-                            self.params, self.adapters, self._carry)
-                        pending.append(("step", toks, mask))
+                    pending.append((kind, self._frame, *out))
                 # drain: normally keep `fetch_chunk` frames in flight so
                 # host bookkeeping overlaps device steps; drain eagerly
                 # when requests are starved for a slot (a completion frees
@@ -1591,8 +1614,11 @@ class DecodeEngine:
         pages are currently pinned goes back to the queue HEAD — later
         requests do not overtake it (starvation beats reordering), and
         liveness holds because submit() already proved the request fits
-        the total budget: whatever is pinned now retires eventually."""
+        the total budget: whatever is pinned now retires eventually. Each
+        request popped leaves a `serving.engine.plan` span over its pop,
+        prefix lookup, page allocation and page row."""
         while True:
+            t0 = time.perf_counter()
             with self._cond:
                 if not (self._free and self._waiting):
                     return
@@ -1628,6 +1654,9 @@ class DecodeEngine:
                     self._waiting.appendleft(req)
                     _mx.set_gauge("serving.engine.queue",
                                   len(self._waiting))
+                recorder.record_span(
+                    "serving.engine.plan", t0, time.perf_counter(),
+                    prompt=len(req.tokens), hit_pages=len(hits), pinned=True)
                 return
             st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
             st.entries = list(hits)
@@ -1644,6 +1673,9 @@ class DecodeEngine:
                 _mx.inc("serving.prefix_misses")
             self._admissions.append(_Admission(
                 req, slot, row, len(hits) * ps, keys, len(hits), total))
+            recorder.record_span("serving.engine.plan", t0,
+                                 time.perf_counter(), prompt=len(req.tokens),
+                                 hit_pages=len(hits))
 
     def _advance_admissions(self, pending: deque) -> None:
         """ONE prefill chunk per engine iteration, round-robin across
@@ -1686,7 +1718,8 @@ class DecodeEngine:
         limit = plen + req.max_new - (0 if B else 1)
         with recorder.span("serving.engine.admit", slot=adm.slot,
                            prompt=plen, t0=adm.t0, chunk=clen,
-                           final=final):
+                           final=final), \
+                self._dispatch("admit", final and not B):
             self._carry, first = self._admit_jit(
                 self.params, self.adapters, self._carry,
                 jnp.asarray(buf), jnp.int32(adm.t0), jnp.int32(clen),
@@ -1700,7 +1733,7 @@ class DecodeEngine:
         if final:
             self._register_prefix(adm)
             if not B:       # a diffusion model's admission yields no token
-                pending.append(("admit", adm.slot, first))
+                pending.append(("admit", self._frame, [(0, adm.slot)], first))
         else:
             adm.t0 += clen
             self._admissions.append(adm)
@@ -1757,7 +1790,8 @@ class DecodeEngine:
             limits[i] = plen + req.max_new - 1
             finals[i] = adm.t0 + clen == plen
             plens[i] = plen
-        with recorder.span("serving.engine.admit", batch=b, chunk=cb):
+        with recorder.span("serving.engine.admit", batch=b, chunk=cb), \
+                self._dispatch("admit", finals.any()):
             self._carry, firsts = self._admit_many_jit(
                 self.params, self.adapters, self._carry,
                 jnp.asarray(toks), jnp.asarray(t0s), jnp.asarray(clens),
@@ -1767,15 +1801,18 @@ class DecodeEngine:
                 jnp.asarray(plens))
         _mx.inc("serving.engine.prefill_chunks", b)
         _mx.observe("serving.engine.admit_batch", b)
+        picks = []          # the first tokens the batch yields: ONE frame
         for i, adm in enumerate(group):
             self._prefilled(adm.req.ticket, bool(finals[i]))
             self._count_keys(adm.t0, int(clens[i]))
             if finals[i]:
                 self._register_prefix(adm)
-                pending.append(("admit", adm.slot, firsts[i]))
+                picks.append((i, adm.slot))
             else:
                 adm.t0 += int(clens[i])
                 self._admissions.append(adm)
+        if picks:
+            pending.append(("admit", self._frame, picks, firsts))
 
     @staticmethod
     def _prefilled(ticket: Ticket, final: bool = True) -> None:
@@ -1815,52 +1852,51 @@ class DecodeEngine:
 
     # -------------------------------------------------------------- draining
     def _drain(self, frame: tuple) -> None:
-        """Materialize one queued frame and route its tokens. This is the
-        only host<->device sync point; the span measures the actual wait."""
-        if frame[0] == "admit":
-            _kind, slot, first = frame
-            with recorder.span("serving.engine.fetch", kind="admit"):
-                tok = int(np.asarray(first))
-            self._deliver(slot, tok, first=True)
-        elif frame[0] == "spec":
-            # one verify window's yield: toks [S, spec_k+1] target picks,
-            # counts [S] accepted lengths (0 = slot was inert)
-            _kind, toks_dev, counts_dev = frame
-            with recorder.span("serving.engine.fetch", kind="spec"):
-                toks = np.asarray(toks_dev)
-                counts = np.asarray(counts_dev)
-            live = counts > 0
-            self._count_step(live, window=toks.shape[1])
-            if live.any():
-                # every live slot consumed spec_k drafts and banked
-                # count - 1 beyond the guaranteed token — the accept
-                # rate `top` and the bench report
-                _mx.inc("serving.spec.proposed",
-                        int(live.sum()) * (toks.shape[1] - 1))
-                _mx.inc("serving.spec.accepted",
-                        int((counts[live] - 1).sum()))
-            for slot in np.nonzero(live)[0]:
-                for t in toks[slot, :counts[slot]]:
-                    self._deliver(int(slot), int(t), first=False)
-        elif frame[0] == "block":
-            with recorder.span("serving.engine.fetch", kind="block"):
-                toks, msk, conf, sel, fwd, live, commit, sown = (
-                    jax.device_get(frame[1:]))
-            self._drain_block(toks, msk, conf, sel, fwd, live, commit, sown)
-        else:
-            _kind, toks_dev, mask_dev = frame
-            with recorder.span("serving.engine.fetch", kind="step"):
-                toks = np.asarray(toks_dev)
-                mask = np.asarray(mask_dev)
-            self._count_step(mask)
-            for slot in np.nonzero(mask)[0]:
-                self._deliver(int(slot), int(toks[slot]), first=False)
-        # publish the POST-delivery host occupancy, not the frame's entry
-        # mask: with fetch_chunk=1 the final completing frame's entry mask
-        # is >= 1 and no trailing all-inactive frame is ever dispatched —
-        # an entry-mask gauge would read busy forever at idle
-        _mx.set_gauge("serving.slots_active",
-                      sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+        """Materialize one queued frame and route its tokens. The fetch is
+        the only host<->device sync point (its span measures the actual
+        wait); `serving.engine.deliver` is the host's bookkeeping of the
+        frame, under the number its dispatch took."""
+        kind, number, *out = frame
+        with recorder.span("serving.engine.fetch", kind=kind):
+            out = jax.device_get(out)
+        with recorder.span("serving.engine.deliver", frame=number):
+            if kind == "admit":
+                picks, firsts = out
+                firsts = np.reshape(firsts, -1)
+                for i, slot in picks:
+                    self._deliver(slot, int(firsts[i]), first=True)
+            elif kind == "spec":
+                self._drain_spec(*out)
+            elif kind == "block":
+                self._drain_block(*out)
+            else:
+                toks, mask = out
+                self._count_step(mask)
+                for slot in np.nonzero(mask)[0]:
+                    self._deliver(int(slot), int(toks[slot]), first=False)
+            # publish the POST-delivery host occupancy, not the frame's
+            # entry mask: with fetch_chunk=1 the final completing frame's
+            # entry mask is >= 1 and no trailing all-inactive frame is ever
+            # dispatched — an entry-mask gauge would read busy forever at
+            # idle
+            _mx.set_gauge("serving.slots_active",
+                          sum(s is not None for s in self._slots))  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+
+    def _drain_spec(self, toks, counts) -> None:
+        """One drained verify window: toks [S, spec_k+1] target picks,
+        counts [S] accepted lengths (0 = slot was inert)."""
+        live = counts > 0
+        self._count_step(live, window=toks.shape[1])
+        if live.any():
+            # every live slot consumed spec_k drafts and banked count - 1
+            # beyond the guaranteed token — the accept rate `top` and the
+            # bench report
+            _mx.inc("serving.spec.proposed",
+                    int(live.sum()) * (toks.shape[1] - 1))
+            _mx.inc("serving.spec.accepted", int((counts[live] - 1).sum()))
+        for slot in np.nonzero(live)[0]:
+            for t in toks[slot, :counts[slot]]:
+                self._deliver(int(slot), int(t), first=False)
 
     def _drain_block(self, toks, msk, conf, sel, fwd, live, commit,
                      sown) -> None:

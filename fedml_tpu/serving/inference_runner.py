@@ -229,7 +229,6 @@ class FedMLInferenceRunner:
                     # already-open SSE body (protocol garbage); just return
                     # — the socket closes and the gateway sees a cut stream
                     log.warning("connection lost mid-request: %s", e)
-                    _mx.inc("serving.conn_lost")
                 except Exception as e:  # noqa: BLE001 — surface to caller
                     log.exception("predict failed")
                     _mx.inc("serving.errors")

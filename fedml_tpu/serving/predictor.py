@@ -808,7 +808,6 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                         len(rows), new, temperature, seed):
                     raise
         if ticket is not None:
-            _mx.inc("serving.stream_requests")
             out: list[int] = []
             for tok in ticket.stream(timeout=600.0):
                 # a hot swap that lands mid-stream finishes this slot on
@@ -837,7 +836,6 @@ class GreedyLMPredictor(_InstrumentedPredictor):
             return
         res = self.predict(dict(input_json))
         gen = res["generated_tokens"]
-        _mx.inc("serving.stream_requests")
         for i, t in enumerate(gen):
             yield {"token": int(t), "index": i}
         yield {"done": True, **res}

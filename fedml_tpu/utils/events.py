@@ -33,6 +33,7 @@ import itertools
 import json
 import logging
 import os
+import random
 import threading
 import time
 from collections import deque
@@ -80,8 +81,17 @@ def _resolve_trace_annotation() -> Callable:
     return _trace_annotation
 
 
+# 64-bit span and trace ids from a generator of the recorder's own, seeded
+# from os.urandom (and again in a forked child), not os.urandom per id: its
+# getrandom call releases the GIL, and a span opened on a busy thread then
+# waits behind every other runnable one (a serving process's engine thread
+# behind the HTTP threads that stream its tokens)
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    return f"{_ids.getrandbits(64):016x}"
 
 
 # ------------------------------------------------------------ trace context
