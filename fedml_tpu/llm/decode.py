@@ -6,8 +6,10 @@ TPU-native equivalent for this repo's LLaMA-shaped model.)
 
 Why a hand-written functional decode instead of flax mutable cache
 collections: the forward must (a) run over the SCAN-LAYERS stacked param
-layout (one [L, ...] slice per lax.scan step — the same layout the 7B
-in-scan training path uses, llm/quant.py), (b) accept int8-quantized
+layout (each lax.scan step's products read their layer of the [L, ...]
+leaves in place — the layout the 7B in-scan training path uses,
+llm/quant.py; the engine holds wq/wk/wv as one kernel so that their product
+does too, `lay_out_blocks`), (b) accept int8-quantized
 {q, s} leaves with per-layer dequant, and (c) keep every shape static so
 one compiled program serves every request. The body math mirrors
 quant.make_inscan_quant_apply (RMSNorm → RoPE causal MHA → SwiGLU,
@@ -46,7 +48,7 @@ import jax.numpy as jnp
 
 from ..parallel.seq import _NEG, dense_causal_attention
 from .quant import (
-    dequant_leaf, lm_head_logits, merged_kernel, project_qkv, rms_norm,
+    QKV, dequant_leaf, lm_head_logits, merged_kernel, project_qkv, rms_norm,
     split_adapters, swiglu_mlp,
 )
 
@@ -57,11 +59,10 @@ def layer_scope(part: str):
     """`decode.<part>` as a `jax.named_scope`: the names a device trace
     shows for the decode programs' layers (kv_write, attn, mlp, head, and in
     the latent programs index and moe; the engine adds sample). What a layer
-    scan itself does (slicing the
-    stacked weights; until PR 27 also moving the KV pool in and out) is
-    left outside every scope on purpose: it reads as the program's time
-    under no layer (PERF.md section 3). One place for the names, so a
-    single block body inherits them."""
+    scan itself does (reading its layer of the stacked weights and of the
+    pool) is left outside every scope on purpose: it reads as the
+    program's time under no layer (PERF.md section 3). One place for the
+    names, so a single block body inherits them."""
     return jax.named_scope(f"decode.{part}")
 
 
@@ -133,11 +134,12 @@ def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     parameters and no experts (the dense block's programs scan over it),
     and a TUPLE of the layers' trees as they are where they differ in kind
     (a dense feed-forward, then expert layers) or hold experts: such layers
-    run unrolled, each layer's weights read where they lie (a scan slices
-    every layer's weights out of the stack, and an expert layer's are a
-    gigabyte). Nothing is copied for the tuple, so a tree that fills half
-    the device still fits. Trees already in either layout pass through
-    unchanged."""
+    run unrolled, each layer's weights read where they lie (under a scan
+    the compiler may copy a layer's slice out of the stack before a product
+    reads it, and an expert layer's weights are a gigabyte; PERF.md section
+    6). Nothing is copied for the tuple, so a tree that fills half the
+    device still fits. Trees already in either layout pass through
+    unchanged; `lay_out_blocks` gives the engine's form of the stack."""
     if "blocks" in params:
         return params
     blocks = [params[f"block_{i}"] for i in range(n_layers)]
@@ -150,6 +152,40 @@ def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
 
     out["blocks"] = tree_stack(blocks)
     return out
+
+
+def lay_out_blocks(params: Pytree, groups: int = 1) -> Pytree:
+    """The decode engine's form of a STACKED tree (`stack_blocks`' dense
+    layout): wq, wk and wv become ONE kernel `wqkv` [L, d, G, C] that the
+    programs' q/k/v product reads in place (`quant.fused_qkv`). Group g of
+    the G holds its share of each projection's columns, `[q_g | k_g |
+    v_g]`, so an `mp` mesh of G devices splits the G axis and each device
+    keeps the columns the column-split kernels gave it
+    (`partition.transformer_lm_rules`). Int8 `{q, s}` leaves are laid out
+    alike. The three separate kernels cost the layer scan a copy of each
+    layer's slice and a layout change before every product: on the TPU the
+    product splits its output into heads, the compiler then wants each
+    kernel [heads, Dh, d], and a slice of the stack is neither (PERF.md
+    section 6). A tuple of layers, a tree laid out already, or
+    kernels of differing forms or widths not divisible by G pass through
+    unchanged."""
+    blocks = params.get("blocks")
+    if not isinstance(blocks, dict) or any(
+            set(blocks.get(n, ())) != {"kernel"} for n in QKV):
+        return params
+    kernels = [blocks[n]["kernel"] for n in QKV]
+    forms = [sorted(k) if isinstance(k, dict) else None for k in kernels]
+    if forms[1:] != forms[:-1] or any(
+            jax.tree.leaves(k)[0].shape[-1] % groups for k in kernels):
+        return params
+
+    def fuse(leaves):
+        return jnp.concatenate(
+            [a.reshape(a.shape[:-1] + (groups, -1)) for a in leaves], -1)
+
+    out = {k: v for k, v in blocks.items() if k not in QKV}
+    out["wqkv"] = {"kernel": jax.tree.map(lambda *a: fuse(a), *kernels)}
+    return {**params, "blocks": out}
 
 
 def block_layers(blocks) -> list:
@@ -866,12 +902,11 @@ def make_paged_latent_decode(model, page_size: int, dtype=jnp.float32):
 
     The layers run UNROLLED (`block_layers(params["blocks"])`), the pool
     threaded through them flat, each layer's weights read where they lie:
-    a scan over stacked layers slices every layer's weights out of the
-    stack, a copy, a step (the dense programs' `pool_copy_share`), and an
-    expert layer's stack is 1.2 GB (my chip run, PR 34, call 2: 60% of the
-    step was that copy). The model's own `norm_eps` and `rope_base` apply. LoRA adapters, int8
-    pages and an `mp` mesh are the engine's to refuse: these programs take
-    `adapters` for the signature's sake and want none."""
+    a scan over a stack of expert layers, 1.2 GB, copies each layer's
+    weights out of it every step (on the chip, 60% of the step; PERF.md
+    section 6). The model's own `norm_eps` and `rope_base` apply. LoRA
+    adapters, int8 pages and an `mp` mesh are the engine's to refuse: these
+    programs take `adapters` for the signature's sake and want none."""
     from ..ops.paged_attention import (
         index_scores, latent_attention, latent_block_pages,
     )

@@ -174,17 +174,49 @@ def merged_kernel(block, ad_l, name, rank_scale, dtype=jnp.bfloat16):
     return w
 
 
+QKV = ("wq", "wk", "wv")
+
+
+def fused_qkv(block, ad_l, rank_scale, h, width_q: int,
+              dtype=jnp.bfloat16):
+    """q [B, T, width_q] and k, v [B, T, n] of `h` through ONE product with
+    the laid-out kernel `wqkv` [d, G, C] (llm/decode.py `lay_out_blocks`):
+    G column groups, group g holding its share of each projection's
+    columns, `[q_g | k_g | v_g]`. LoRA deltas of wq/wk/wv merge into their
+    own columns, value for value as `merged_kernel` merges them."""
+    w = dequant_leaf(block["wqkv"]["kernel"], dtype)
+    d, g, c = w.shape
+    nq = width_q // g
+    widths = (nq, (c - nq) // 2, (c - nq) // 2)
+    ads = [ad_l.get(f"{n}/kernel") if ad_l else None for n in QKV]
+    if any(a is not None for a in ads):
+        w = w + rank_scale * jnp.concatenate([
+            jnp.zeros((d, g, n), w.dtype) if a is None else
+            (a["a"] @ a["b"]).astype(w.dtype).reshape(d, g, n)
+            for a, n in zip(ads, widths)], axis=-1)
+    y = (h @ w.reshape(d, g * c)).reshape(h.shape[:-1] + (g, c))
+    out, at = [], 0
+    for n in widths:
+        out.append(y[..., at:at + n].reshape(h.shape[:-1] + (g * n,)))
+        at += n
+    return out
+
+
 def project_qkv(block, ad_l, rank_scale, h, n_heads: int,
                 dtype=jnp.bfloat16, head_dim=None):
     """Pre-norm hidden -> per-head q [B, T, H, Dh] and k/v [B, T, KV, Dh]
     (RoPE is applied by the caller, whose position semantics differ between
     train and decode). Heads are `head_dim` wide (d_model / n_heads when
-    None); wk and wv split into as many heads of it as their width holds."""
+    None); wk and wv split into as many heads of it as their width holds.
+    A block laid out for serving holds the three as one kernel, `wqkv`."""
     dh = head_dim or h.shape[-1] // n_heads
+    split = lambda a: a.reshape(a.shape[:2] + (a.shape[-1] // dh, dh))
+    if "wqkv" in block:
+        q, k, v = fused_qkv(block, ad_l, rank_scale, h, n_heads * dh, dtype)
+        return split(q), split(k), split(v)
     q = h @ merged_kernel(block, ad_l, "wq", rank_scale, dtype)
     k = h @ merged_kernel(block, ad_l, "wk", rank_scale, dtype)
     v = h @ merged_kernel(block, ad_l, "wv", rank_scale, dtype)
-    split = lambda a: a.reshape(a.shape[:2] + (a.shape[-1] // dh, dh))
     return split(q), split(k), split(v)
 
 

@@ -184,10 +184,11 @@ def transformer_lm_rules(axis: str = "mp") -> Rules:
     stacked 3-D kernels (`blocks/...`, leading [L] axis replicated), and
     int8-quantized `{q, s}` leaves (`q` shards like the kernel it stores;
     per-dout scales `s` shard alongside column kernels and replicate for
-    row kernels, whose split dim is din). Embed [V, D] shards D, lm_head
-    [D, V] shards V; norms replicated. LoRA adapters are NOT in this
-    table — they are the federated round payload and resolve through
-    `lora_rules` (replicated)."""
+    row kernels, whose split dim is din), and the decode engine's one
+    kernel for the three column-split projections (`wqkv`). Embed [V, D]
+    shards D, lm_head [D, V] shards V; norms replicated. LoRA adapters are
+    NOT in this table — they are the federated round payload and resolve
+    through `lora_rules` (replicated)."""
     from jax.sharding import PartitionSpec as P
 
     a = axis
@@ -200,6 +201,10 @@ def transformer_lm_rules(axis: str = "mp") -> Rules:
         (rf"(^|/)blocks/({_COL})/kernel(/(q|s))?$", P(None, None, a)),
         (rf"(^|/)blocks/({_ROW})/kernel(/q)?$", P(None, a, None)),
         (rf"(^|/)blocks/({_ROW})/kernel/s$", P()),
+        # the decode engine's wq|wk|wv (llm/decode.py lay_out_blocks):
+        # [L, din, groups, cols], scales [L, 1, groups, cols], a column
+        # group a device
+        (r"(^|/)blocks/wqkv/kernel(/(q|s))?$", P(None, None, a, None)),
         # embed [V, D] shards D; lm_head [D, V] shards V. Their int8
         # scales are HBM-negligible and stay replicated (the llm/tp.py
         # legacy layout, kept so existing sharded checkpoints reload).

@@ -557,8 +557,8 @@ class DecodeEngine:
                  spec_k: int = 4, kv_quant: str = "off",
                  admit_batch: int = 1):
         from ..llm.decode import (
-            LATENT_ADAPTERS, layer_scope, make_paged_kv_decode,
-            make_paged_latent_decode,
+            LATENT_ADAPTERS, lay_out_blocks, layer_scope,
+            make_paged_kv_decode, make_paged_latent_decode,
             ngram_propose, require_servable, stack_adapter_blocks,
             stack_blocks,
         )
@@ -660,7 +660,16 @@ class DecodeEngine:
         # -1 never matches a token id, so eos retirement is inert
         self._eos = -1 if eos_id is None else int(eos_id)
         self.adapters = stack_adapter_blocks(adapters, model.n_layers)
-        self.params = stack_blocks(params, model.n_layers)
+        # the dense block's stacked weights take, once, the form the
+        # programs' products read in place (llm/decode.py lay_out_blocks):
+        # a column group per device of the `mp` axis
+        self.params = lay_out_blocks(
+            stack_blocks(params, model.n_layers),
+            mesh.shape.get("mp", 1) if mesh is not None else 1)
+        fused = self.params["blocks"]
+        fused = fused.get("wqkv") if isinstance(fused, dict) else None
+        _mx.set_gauge("serving.engine.weights_laid_out_bytes", sum(
+            a.nbytes for a in jax.tree.leaves(fused)))
         if dtype is not None:
             kv_dtype = jnp.dtype(dtype)
         else:

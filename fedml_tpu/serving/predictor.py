@@ -400,6 +400,9 @@ class GreedyLMPredictor(_InstrumentedPredictor):
                     paged_kernel=paged_kernel, spec_decode=spec_decode,
                     spec_k=spec_k, kv_quant=kv_quant,
                     admit_batch=int(admit_batch)).start()
+                # the per-request path serves the engine's laid-out tree:
+                # one resident copy of the weights
+                self.params = self.engine.params
             return
 
         # n_steps is a Python int at trace time (scan length must be

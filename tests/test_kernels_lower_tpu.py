@@ -179,21 +179,20 @@ def test_kernel_compiles_with_mosaic(name, v5e):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_paged_step_carries_the_pool_in_place(v5e):
-    """The decode step at chip_smoke.py's engine shape (8 slots x 2,048,
-    page 16, heads 16 x 128), cache donated, compiled for the v5e: the
-    program's temporaries stay under a quarter of the pool's bytes. With
-    the pool as the layer scan's xs/ys they were 1.1 pools — the restacked
-    copy — and each step moved the pool three times (ISSUE 27)."""
-    from fedml_tpu.llm.decode import make_paged_kv_decode
+def _engine_step(v5e, layers, d_ff):
+    """The engine's decode step at chip_smoke.py's engine shape (8 slots x
+    2,048, page 16, heads 16 x 128), over the tree the engine holds (wq, wk
+    and wv laid out as one kernel, llm/decode.py `lay_out_blocks`), cache
+    donated, compiled for the v5e. -> (compiled, pool)."""
+    from fedml_tpu.llm.decode import lay_out_blocks, make_paged_kv_decode
     from fedml_tpu.llm.transformer import TransformerLM
     from fedml_tpu.ops import paged_attention as pa
 
-    layers, d_model = 4, HEADS * DH
+    d_model = HEADS * DH
     model = TransformerLM(vocab_size=512, d_model=d_model, n_layers=layers,
-                          n_heads=HEADS, d_ff=512, scan_layers=True)
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+                          n_heads=HEADS, d_ff=d_ff, scan_layers=True)
+    shapes = jax.eval_shape(lambda: lay_out_blocks(model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
     params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), shapes)
     pool = S((layers, N_PAGES, PAGE, HEADS, DH), jnp.bfloat16)
     cache = {"k": pool, "v": pool}
@@ -211,5 +210,30 @@ def test_paged_step_carries_the_pool_in_place(v5e):
     finally:
         pa._auto_interpret = auto
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled, pool
+
+
+def test_paged_step_carries_the_pool_in_place(v5e):
+    """The program's temporaries stay under a quarter of the pool's bytes.
+    With the pool as the layer scan's xs/ys they were 1.1 pools — the
+    restacked copy — and each step moved the pool three times (ISSUE 27)."""
+    compiled, pool = _engine_step(v5e, layers=4, d_ff=512)
     pool_bytes = 2 * math.prod(pool.shape) * pool.dtype.itemsize   # K and V
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+def test_paged_step_reads_the_layers_weights_in_place(v5e):
+    """No layer's [d, d]-sized weight is cut out of the stack into a buffer
+    of its own, nor copied into another layout, before a product reads it.
+    Over three separate stacked wq/wk/wv kernels the compiled step held, a
+    layer, one `constant_dynamic-slice_fusion` and one `copy` of each
+    (PERF.md section 6: 1.04 ms of the dense serving cell's 4.27 ms step
+    on a TPU v5e)."""
+    compiled, _pool = _engine_step(v5e, layers=2, d_ff=HEADS * DH)
+    d = HEADS * DH
+    per_layer = re.compile(
+        rf"= bf16\[1,{d},(?:\d+,)*\d+\]\{{[^}}]*\}} "
+        r"(?:fusion|copy)\(")
+    moved = [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+             if per_layer.search(ln)]
+    assert not moved, moved

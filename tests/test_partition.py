@@ -142,18 +142,24 @@ def test_train_and_serve_spec_tables_identical():
     """The round-program/trainer entry point and the DecodeEngine resolve
     to the SAME spec table for the flagship model — the anti-drift
     contract for train/serve checkpoints."""
+    from fedml_tpu.llm.decode import lay_out_blocks
     from fedml_tpu.parallel.round import resolve_param_specs
 
     model, params = _flagship(scan=True)
-    train_specs = resolve_param_specs(params, "transformer_lm", axis="mp")
+    # the engine holds wq|wk|wv as one kernel, a column group a device
+    # (llm/decode.py lay_out_blocks): the same table resolves that tree
+    train_specs = resolve_param_specs(lay_out_blocks(params, 2),
+                                      "transformer_lm", axis="mp")
     eng = DecodeEngine(model, params, n_slots=2, max_len=MAXLEN,
                        mesh=make_mesh({"mp": 2})).start()
     try:
         assert jax.tree.map(lambda a, b: tuple(a) == tuple(b),
                             train_specs, eng.param_specs) == \
             jax.tree.map(lambda _: True, train_specs)
+        assert tuple(eng.param_specs["blocks"]["wqkv"]["kernel"]) == (
+            None, None, "mp", None)
         # and the engine's weights/cache are genuinely laid out that way
-        wq = eng.params["blocks"]["wq"]["kernel"]
+        wq = eng.params["blocks"]["wqkv"]["kernel"]
         assert len(wq.sharding.device_set) == 2
         assert "mp" in str(eng._carry["cache"]["k"].sharding.spec)
     finally:
