@@ -70,31 +70,23 @@ def unserved(model) -> list:
     """What a TransformerLM has that no decode program here can run, one
     sentence a mechanism. Two sets of programs exist: the block of per-head
     keys and values (`make_paged_kv_decode`: grouped or as many KV heads as
-    heads, per-head q/k norms, every layer full attention with rotary
-    positions, a SwiGLU or the expert layer, causal or a diffusion block's
-    mask; the model's own norm eps and rope base) and the LATENT block's
-    (`make_paged_latent_decode`: latent attention under its indexer in
-    EVERY layer, a SwiGLU or the expert layer a layer); llm/transformer.py's
-    block trains more than they serve."""
+    heads, per-head q/k norms, full layers with or without rotary positions
+    beside window layers that attend the last `window` positions through a
+    ring of pages a slot, a SwiGLU or the expert layer (its router reading
+    the feed-forward's input or the attention's), causal or a diffusion
+    block's mask; the model's own norm eps and rope base) and the LATENT
+    block's (`make_paged_latent_decode`: latent attention under its indexer
+    in EVERY layer, a SwiGLU or the expert layer a layer);
+    llm/transformer.py's block trains more than they serve."""
     out = []
     if not hasattr(model, "kinds"):     # no TransformerLM: nothing to depart in
         return out
-    kinds = model.kinds
-    latent = [a == "latent" for a, _ in kinds]
-    if any(a == "window" for a, _ in kinds):
-        out.append(
-            "window layers: the KV cache and the page allocator of "
-            "serving/engine.py keep every position of every layer and give "
-            "no page back once it has left a layer's window")
+    latent = [a == "latent" for a, _ in model.kinds]
     if any(latent) and not all(latent):
         out.append(
             "latent layers beside layers of per-head keys and values: a "
             "page pool holds ONE kind of row (serving/engine.py allocates "
             "`kv` and `ik` leaves or `k` and `v` leaves, never both)")
-    if not model.rope_full:
-        out.append(
-            "layers without rotary positions: the decode bodies rotate "
-            "every layer's queries and keys")
     return out
 
 
@@ -102,18 +94,26 @@ def engine_only(model) -> str:
     """Why the PER-REQUEST pair (`make_kv_decode`, and `GreedyLMPredictor`
     without `decode_slots`) cannot run a model the engine's programs can, or
     "": that pair is the dense block's alone (as many KV heads of d_model /
-    n_heads as heads, no q/k norm, a SwiGLU, one causal token a step)."""
+    n_heads as heads, no q/k norm, a SwiGLU, every layer full attention
+    with rotary positions, one causal token a step). Window layers and full
+    layers without rotary positions are the engine's alone: its cache is a
+    page pool in which a window layer keeps a ring of pages a slot."""
     if not hasattr(model, "kinds"):
         return ""
     if getattr(model, "latent", None) is not None:
         return "latent attention"
     if getattr(model, "diffusion_block", 0):
         return "generation by diffusion over blocks"
+    out = []
+    if not model.rope_full or any(a == "window" for a, _ in model.kinds):
+        out.append("window layers or full layers without rotary positions")
     dense = ((model.n_kv_heads or model.n_heads) == model.n_heads
              and (model.head_dim or model.d_model // model.n_heads)
              * model.n_heads == model.d_model and not model.qk_norm
              and not any(f == "moe" for _, f in model.kinds))
-    return "" if dense else "grouped KV heads, per-head q/k norms or experts"
+    if not dense:
+        out.append("grouped KV heads, per-head q/k norms or experts")
+    return "; ".join(out)
 
 
 def require_servable(model) -> None:
@@ -127,7 +127,8 @@ def require_servable(model) -> None:
             "lacks: " + "; ".join(lacking))
 
 
-def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
+def stack_blocks(params: Pytree, n_layers: int,
+                 unrolled: bool = False) -> Pytree:
     """Convert an UNROLLED TransformerLM param tree (block_0..block_{L-1})
     to the layout the decode path consumes: `{"blocks": [L, ...]}`, every
     leaf stacked on a leading layer axis, where every layer has the same
@@ -138,13 +139,18 @@ def stack_blocks(params: Pytree, n_layers: int) -> Pytree:
     the compiler may copy a layer's slice out of the stack before a product
     reads it, and an expert layer's weights are a gigabyte; PERF.md section
     6). Nothing is copied for the tuple, so a tree that fills half the
-    device still fits. Trees already in either layout pass through
-    unchanged; `lay_out_blocks` gives the engine's form of the stack."""
+    device still fits. `unrolled` asks for the tuple whatever the layers
+    hold (layers of different attention kinds: window and full). Trees
+    already in either layout pass through unchanged, but for a stack asked
+    for `unrolled`; `lay_out_blocks` gives the engine's form of the
+    stack."""
     if "blocks" in params:
+        if unrolled and not isinstance(params["blocks"], (tuple, list)):
+            return {**params, "blocks": tuple(block_layers(params["blocks"]))}
         return params
     blocks = [params[f"block_{i}"] for i in range(n_layers)]
     out = {k: v for k, v in params.items() if not k.startswith("block_")}
-    if len({jax.tree.structure(b) for b in blocks}) > 1 or any(
+    if unrolled or len({jax.tree.structure(b) for b in blocks}) > 1 or any(
             "moe" in b for b in blocks):
         out["blocks"] = tuple(blocks)
         return out
@@ -437,7 +443,9 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                          kernel: bool = False, mesh=None,
                          quant: bool = False, rope_base: float = 10000.0,
                          head_dim: Optional[int] = None,
-                         qk_norm: bool = False, moe=None, block: int = 0):
+                         qk_norm: bool = False, moe=None, block: int = 0,
+                         attn_kinds: Optional[tuple] = None, window: int = 0,
+                         rope_full: bool = True, ring: int = 0):
     """The decode engine's programs (serving/engine.py): K/V live in a
     persistent POOL of fixed-size pages `[L, n_pages, page_size, H, Dh]`,
     and each slot's logical sequence is described by an int32 page-table
@@ -562,8 +570,36 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
     C == B tokens at a block-aligned `pos`, the window's own keys visible to
     all its queries, the logits over each position's OWN token, the block's
     K/V written at every call (a later call or the commit overwrites them
-    before any later block reads them). `step` has no meaning then."""
+    before any later block reads them). `step` has no meaning then.
+
+    `attn_kinds` (one "full" or "window" a layer; None: every layer full)
+    with `window` = W and `ring` = R: a "window" layer's query at position
+    i attends the keys i - W + 1 .. i, and the layer keeps no page row of
+    the slot's table. Its keys and values live in a pool of their own, the
+    cache leaves `wk` / `wv` `[L_w, 1 + S * R, page_size, KV, Dh]` (L_w
+    window layers, page 0 the null page again), and slot s owns the RING
+    of pages `1 + s * R .. (s + 1) * R`: virtual page p of the slot lies at
+    ring page `p % R`, reused as the slot advances. R is at least
+    `ceil((W + C) / page_size) + 1` for the widest C queries a program
+    runs (a prefill chunk), so the pages a program reads and writes (the
+    window of its first query to its last query) never meet modulo R; a
+    slot holds R pages whatever its context. The `k` / `v` leaves hold the
+    other layers only (`[L - L_w, P, ...]`), and such layers run unrolled.
+    `chunk` and `chunk_batch` then take the slot (slots) the chunk is
+    for, as a further argument. `rope_full=False`: full layers take no
+    rotary positions (window layers always do). `moe.route_from == "attn"`
+    routes each token by the attention norm's output, read before
+    attention."""
     ps = int(page_size)
+    # per layer: whether it is a window layer (None: every layer full)
+    windowed = (tuple(a == "window" for a in attn_kinds)
+                if attn_kinds is not None else None)
+    R = int(ring) if windowed and any(windowed) else 0
+    if R and (not window or quant or mesh is not None):
+        raise NotImplementedError(
+            "window layers take `window` > 0 and keep bfloat16 ring pages "
+            "on one chip: int8 ring pages and rings under a mesh are not "
+            "built")
     norm, dq, merged, qkv, mlp, head, split_ads = _block_math(
         dtype, eps, alpha)
     if moe is not None:
@@ -573,27 +609,52 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         """Whether a query at `qpos` attends the key at `kpos`."""
         return kpos // block <= qpos // block if block else kpos <= qpos
 
-    def project(bl, ad_l, rank_scale, h, posr):
+    def is_window(i) -> bool:
+        """Whether layer i (None: a layer of the stack) is a window layer."""
+        return i is not None and bool(windowed) and windowed[i]
+
+    def rotated(i) -> bool:
+        """Whether layer i takes rotary positions: every window layer, and
+        full layers if `rope_full`."""
+        return rope_full or is_window(i)
+
+    def router_input(h):
+        """What a router that reads the attention norm's output is handed."""
+        return h if moe is not None and moe.route_from == "attn" else None
+
+    def project(bl, ad_l, rank_scale, h, posr, rotate=True):
         """Roped q [B, C, H, Dh], roped k and raw v [B, C, KV, Dh] of `h`
-        at positions `posr` [B, C] (or [C]: a chunk's one row)."""
+        at positions `posr` [B, C] (or [C]: a chunk's one row); q and k
+        unrotated where `rotate` is False."""
         q, k, v = qkv(bl, ad_l, rank_scale, h, n_heads, head_dim)
         if qk_norm:
             q = norm(q, dq(bl["q_norm"]["scale"]))
             k = norm(k, dq(bl["k_norm"]["scale"]))
+        if not rotate:
+            return q, k, v
         # a chunk's row is lifted where it is used, once for q and once
         # for k, as the dense block's program always had it
         rows = lambda: posr if posr.ndim == 2 else posr[None, :]
         return (_rope_rows(q, rows(), rope_base),
                 _rope_rows(k, rows(), rope_base), v)
 
-    def attend_grouped(q, kk, vv, posr):
+    def attend_grouped(q, kk, vv, posr, kpos=None, span=None):
         """q [B, C, H, Dh] at positions `posr` [B, C] over gathered kk/vv
-        [B, T, KV, Dh], query head i reading KV head i // (H / KV)."""
+        [B, T, KV, Dh], query head i reading KV head i // (H / KV). `kpos`
+        [B, T]: the virtual position of each gathered row (None: its row;
+        negative: never written), and then a query at i attends only the
+        keys j with i - j < `span` (None: every key it sees)."""
         b_, c, _, d = q.shape
         qg = q.reshape(b_, c, kk.shape[2], -1, d)
         s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kk) * d ** -0.5
-        live = sees(jnp.arange(kk.shape[1])[None, None, :],
-                    posr[:, :, None])                        # [B, C, T]
+        if kpos is None:
+            live = sees(jnp.arange(kk.shape[1])[None, None, :],
+                        posr[:, :, None])                    # [B, C, T]
+        else:
+            rel = posr[:, :, None] - kpos[:, None, :]        # [B, C, T]
+            live = (kpos[:, None, :] >= 0) & (rel >= 0)
+            if span is not None:
+                live = live & (rel < span)
         s = jnp.where(live[:, None, None, :, :], s, _NEG)
         return jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1),
                           vv).reshape(q.shape)
@@ -610,16 +671,42 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         s = jnp.where(live[:, None, :, :], s, _NEG)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
 
-    def feed_forward(bl, ad_l, rank_scale, x, live):
+    def ring_pages(slots, vpage):
+        """The ring page (of a window layer's pool, the layer's base left
+        out) of slot `slots`'s virtual page `vpage`."""
+        return 1 + slots * R + vpage % R
+
+    def ring_view(pool, base, slots, last):
+        """A window layer's gathered rings: K and V [B, R * page_size, KV,
+        Dh] of slots `slots` [B] in ring order, and the virtual position of
+        each row [B, R * page_size]: ring page r holds the latest virtual
+        page <= `last` [B] that is r modulo R (negative: none yet)."""
+        r = jnp.arange(R)
+        kk, vv = kv_pages(pool, base + ring_pages(slots[:, None], r),
+                          ("wk", "wv"))
+        vpage = last[:, None] - (last[:, None] - r) % R          # [B, R]
+        kpos = (vpage[:, :, None] * ps + jnp.arange(ps)).reshape(
+            vpage.shape[0], -1)
+        return kk, vv, kpos
+
+    def ring_attend(pool, base, q, posr, slots, last_page):
+        """A window layer's gathered attention of rows [B] of slots `slots`
+        over their rings (`last_page` [B]: the latest virtual page
+        written)."""
+        kk, vv, kpos = ring_view(pool, base, slots, last_page)
+        return attend_grouped(q, kk, vv, posr, kpos, window)
+
+    def feed_forward(bl, ad_l, rank_scale, x, live, route_x=None):
         """-> (x, counters): the SwiGLU, or the expert layer over the
-        `live` rows [B, C] with what it sows."""
+        `live` rows [B, C] with what it sows (its router reading `route_x`
+        where it reads the attention norm's output)."""
         if moe is None or "moe" not in bl:
             with layer_scope("mlp"):
                 return mlp(bl, ad_l, rank_scale, x), {}
         with layer_scope("moe"):
             h = norm(x, dq(bl["RMSNorm_1"]["scale"]))
             y, sown = ExpertLayer(moe).apply(
-                {"params": bl["moe"]}, h, live, mutable=[COUNTERS])
+                {"params": bl["moe"]}, h, live, route_x, mutable=[COUNTERS])
             return x + y, fold_counters(sown[COUNTERS])
 
     def scan_layers(layer, x, params, blk_ads, cache):
@@ -633,7 +720,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         input copied once more to close the loop: three pool-sized moves
         a program for rows nobody read (PERF.md section 6, PR 27)."""
         n_layers, n_pages = cache["k"].shape[:2]
-        pool = {name: leaf.reshape((n_layers * n_pages,) + leaf.shape[2:])
+        pool = {name: leaf.reshape((leaf.shape[0] * leaf.shape[1],)
+                                   + leaf.shape[2:])
                 for name, leaf in cache.items()}
 
         def body(carry, xs):
@@ -643,12 +731,22 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             return (x, pool), counted
 
         if isinstance(params["blocks"], (tuple, list)):
-            # layers `stack_blocks` left as they lie (experts): unrolled
+            # layers `stack_blocks` left as they lie (experts): unrolled.
+            # A window layer's base counts in the ring pool, any other's in
+            # the page pool
             counted: dict = {}
+            seen = [0, 0]
             for i, bl in enumerate(params["blocks"]):
-                x, pool, c = layer(x, pool, i * n_pages, bl,
-                                   jax.tree.map(lambda a: a[i], blk_ads))
+                win = int(is_window(i))
+                at, seen[win] = seen[win], seen[win] + 1
+                x, pool, c = layer(
+                    x, pool, at * (cache["wk"].shape[1] if win else n_pages),
+                    bl, jax.tree.map(lambda a: a[i], blk_ads), i)
                 counted = {k: counted.get(k, 0) + v for k, v in c.items()}
+        elif R:
+            raise ValueError("a model of window and full layers runs its "
+                             "layers unrolled (stack_blocks(..., unrolled="
+                             "True))")
         else:
             (x, pool), counted = jax.lax.scan(
                 body, (x, pool), (params["blocks"], blk_ads,
@@ -661,19 +759,21 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         return (cache, logits, counted) if moe is not None else (
             cache, logits)
 
-    def kv_write(pool, wpage, woff, k, v):
+    def kv_write(pool, wpage, woff, k, v, names=("k", "v")):
         """New K/V rows into the flat pool at (wpage, woff); `wpage`
         already carries the layer's base, so the null page of layer l is
-        base + 0 and absorbs that layer's redirected writes."""
+        base + 0 and absorbs that layer's redirected writes. `names`: the
+        leaves written (`wk` / `wv`: a window layer's ring pool)."""
         with layer_scope("kv_write"):
             if quant:
                 pk, ks = _kv_quant_write(pool["k"], pool["ks"], wpage, woff, k)
                 pv, vs = _kv_quant_write(pool["v"], pool["vs"], wpage, woff, v)
                 return {"k": pk, "v": pv, "ks": ks, "vs": vs}
-            return {"k": pool["k"].at[wpage, woff].set(k),
-                    "v": pool["v"].at[wpage, woff].set(v)}
+            kn, vn = names
+            return {**pool, kn: pool[kn].at[wpage, woff].set(k),
+                    vn: pool[vn].at[wpage, woff].set(v)}
 
-    def kv_pages(pool, idx):
+    def kv_pages(pool, idx, names=("k", "v")):
         """Gather the pages `idx` (base included) of the flat pool as a
         virtually-contiguous [..., len(idx) * page_size, H, Dh] K and V
         (int8 pools dequantize in place: scales[idx] [..., H] broadcast
@@ -684,9 +784,10 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                 g = (g.astype(jnp.float32)
                      * pool[scales][idx][..., None, :, None]).astype(dtype)
             return g.reshape(idx.shape[:-1] + (-1,) + g.shape[-2:])
-        return one("k", "ks"), one("v", "vs")
+        return one(names[0], "ks"), one(names[1], "vs")
 
-    def chunk(params, adapters, cache, pages_row, tokens, t0, length):
+    def chunk(params, adapters, cache, pages_row, tokens, t0, length,
+              slot=None):
         blk_ads, top_ads, rank_scale = split_ads(adapters)
         emb = dq(params["embed"]["embedding"])
         x = emb[tokens]                                   # [1, C, D]
@@ -698,31 +799,45 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
         wpage = jnp.where(j < length, pages_row[posr // ps], 0)
         woff = posr % ps
         n_virt = pages_row.shape[0] * ps
+        if R:
+            slot = jnp.asarray(slot, jnp.int32)
+            wring = jnp.where(j < length, ring_pages(slot, posr // ps), 0)
+            last_page = (t0 + jnp.maximum(length, 1) - 1) // ps
 
-        def layer(x, pool, base, bl, ad_l):
+        def layer(x, pool, base, bl, ad_l, i=None):
+            win = is_window(i)
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = project(bl, ad_l, rank_scale, h, posr)
-            pool = kv_write(pool, base + wpage, woff, k[0], v[0])
+                q, k, v = project(bl, ad_l, rank_scale, h, posr, rotated(i))
+            if win:
+                pool = kv_write(pool, base + wring, woff, k[0], v[0],
+                                ("wk", "wv"))
+            else:
+                pool = kv_write(pool, base + wpage, woff, k[0], v[0])
             with layer_scope("attn"):
                 # gather AFTER the write so the chunk attends to itself;
                 # page-table order makes the gathered view contiguous
                 # virtual positions 0..n_virt-1
-                kk, vv = kv_pages(pool, base + pages_row)
-                if kk.shape[1] != q.shape[2]:
-                    o = attend_grouped(q, kk[None], vv[None], posr[None])
+                if win:
+                    o = ring_attend(pool, base, q, posr[None], slot[None],
+                                    last_page[None])
                 else:
-                    scale = q.shape[-1] ** -0.5
-                    s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
-                    live = sees(jnp.arange(n_virt)[None, :],
-                                posr[:, None])               # [C, T]
-                    s = jnp.where(live[None, None, :, :], s, _NEG)
-                    o = jnp.einsum("bhqk,khd->bqhd", jax.nn.softmax(s, -1),
-                                   vv)
+                    kk, vv = kv_pages(pool, base + pages_row)
+                    if kk.shape[1] != q.shape[2]:
+                        o = attend_grouped(q, kk[None], vv[None],
+                                           posr[None])
+                    else:
+                        scale = q.shape[-1] ** -0.5
+                        s = jnp.einsum("bqhd,khd->bhqk", q, kk) * scale
+                        live = sees(jnp.arange(n_virt)[None, :],
+                                    posr[:, None])               # [C, T]
+                        s = jnp.where(live[None, None, :, :], s, _NEG)
+                        o = jnp.einsum("bhqk,khd->bqhd",
+                                       jax.nn.softmax(s, -1), vv)
                 x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
                     bl, ad_l, "wo", rank_scale)
             x, counted = feed_forward(bl, ad_l, rank_scale, x,
-                                      (j < length)[None])
+                                      (j < length)[None], router_input(h))
             return x, pool, counted
 
         x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)
@@ -783,14 +898,30 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             pages[jnp.arange(s_)[:, None], jnp.minimum(rowidx,
                                                        max_pages - 1)], 0)
         woff = posr % ps
+        if R:
+            slots = jnp.arange(s_)
+            wring = jnp.where(active[:, None] & (rowidx < max_pages),
+                              ring_pages(slots[:, None], rowidx), 0)
+            last_page = (pos + c - 1) // ps
+            ring_table = ring_pages(slots[:, None], jnp.arange(R))  # [S, R]
 
-        def layer(x, pool, base, bl, ad_l):
+        def layer(x, pool, base, bl, ad_l, i=None):
+            win = is_window(i)
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = project(bl, ad_l, rank_scale, h, posr)
-            pool = kv_write(pool, base + wpage, woff, k, v)
+                q, k, v = project(bl, ad_l, rank_scale, h, posr, rotated(i))
+            if win:
+                pool = kv_write(pool, base + wring, woff, k, v, ("wk", "wv"))
+            else:
+                pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
-                if kernel:
+                if kernel and win:
+                    # the window kernel walks the slot's ring from the
+                    # first query's window to its last query
+                    o = paged_attention(q, pool["wk"], pool["wv"],
+                                        base + ring_table, pos,
+                                        active=active, window=window)
+                elif kernel:
                     # fused path: this layer's pages read in place by the
                     # Pallas kernel, straight out of the carried pool — no
                     # virtually-contiguous copy materializes (int8 pools
@@ -799,6 +930,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     scales = (pool["ks"], pool["vs"]) if quant else ()
                     o = attn_fused(q, pool["k"], pool["v"], base + pages,
                                    pos, active, *scales)
+                elif win:
+                    o = ring_attend(pool, base, q, posr, slots, last_page)
                 else:
                     kk, vv = kv_pages(pool, base + pages)
                     o = attend(q, kk, vv, posr)
@@ -806,7 +939,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                     bl, ad_l, "wo", rank_scale)
             x, counted = feed_forward(
                 bl, ad_l, rank_scale, x,
-                jnp.broadcast_to(active[:, None], tokens.shape))
+                jnp.broadcast_to(active[:, None], tokens.shape),
+                router_input(h))
             return x, pool, counted
 
         x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)
@@ -819,7 +953,8 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
                                          token[:, None], active)
         return (cache, logits[:, 0], *counted)
 
-    def chunk_batch(params, adapters, cache, pages, tokens, t0, lengths):
+    def chunk_batch(params, adapters, cache, pages, tokens, t0, lengths,
+                    slots=None):
         """Batched admission prefill (docstring above): verify-shaped
         positions (per-row t0), chunk-shaped write masking (tokens past
         a row's length — and PAD rows entirely — redirect to the null
@@ -839,19 +974,35 @@ def make_paged_kv_decode(n_heads: int, page_size: int, alpha: float = 16.0,
             pages[jnp.arange(b_)[:, None],
                   jnp.minimum(rowidx, max_pages - 1)], 0)
         woff = posr % ps
+        if R:
+            # PAD rows' slot lies past the rings: their writes are dropped
+            # above, their reads are garbage the engine discards
+            slots = jnp.asarray(slots, jnp.int32)
+            wring = jnp.where(
+                (j[None, :] < lengths[:, None]) & (rowidx < max_pages),
+                ring_pages(slots[:, None], rowidx), 0)
+            last_page = (t0 + jnp.maximum(lengths, 1) - 1) // ps
 
-        def layer(x, pool, base, bl, ad_l):
+        def layer(x, pool, base, bl, ad_l, i=None):
+            win = is_window(i)
             with layer_scope("attn"):
                 h = norm(x, dq(bl["RMSNorm_0"]["scale"]))
-                q, k, v = project(bl, ad_l, rank_scale, h, posr)
-            pool = kv_write(pool, base + wpage, woff, k, v)
+                q, k, v = project(bl, ad_l, rank_scale, h, posr, rotated(i))
+            if win:
+                pool = kv_write(pool, base + wring, woff, k, v, ("wk", "wv"))
+            else:
+                pool = kv_write(pool, base + wpage, woff, k, v)
             with layer_scope("attn"):
-                kk, vv = kv_pages(pool, base + pages)
-                o = attend(q, kk, vv, posr)
+                if win:
+                    o = ring_attend(pool, base, q, posr, slots, last_page)
+                else:
+                    kk, vv = kv_pages(pool, base + pages)
+                    o = attend(q, kk, vv, posr)
                 x = x + o.reshape(x.shape[:2] + (-1,)) @ merged(
                     bl, ad_l, "wo", rank_scale)
             x, counted = feed_forward(bl, ad_l, rank_scale, x,
-                                      j[None, :] < lengths[:, None])
+                                      j[None, :] < lengths[:, None],
+                                      router_input(h))
             return x, pool, counted
 
         x, cache, counted = scan_layers(layer, x, params, blk_ads, cache)

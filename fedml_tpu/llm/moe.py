@@ -5,8 +5,11 @@ place: a router scores every token against ALL `n_experts` experts (float32;
 `scoring` "sigmoid": the `top_k` largest of score + selection bias are
 chosen; "softmax": a softmax over all experts, the `top_k` largest chosen,
 no bias), their scores are normalised over the chosen and scaled, and the
-token's output is the weighted sum of the chosen experts' SwiGLUs plus,
-where `n_shared` > 0, a shared expert every token passes through.
+token's output is the weighted sum of the chosen experts' gated units (`act`:
+SwiGLU, or ReGLU, relu(gate) * up) plus, where `n_shared` > 0, a shared
+expert every token passes through. The router reads the rows the layer is
+handed, or (`route_from` "attn") the rows its block hands it beside them:
+the attention norm's output, read before attention.
 
 Expert parallelism divides the experts over chips. This module is told which
 experts it HOLDS (`MoE.held = (first, count)`): it routes over all of them,
@@ -30,8 +33,10 @@ tile a live expert's rows touch, so a 512-row tile over an expert holding
 three rows is MXU time spent on rows that do not exist.
 
 Scopes (PERF.md section 3): `moe.route`, `moe.dispatch`, `moe.experts`,
-`moe.combine`, `moe.shared`. Counters, sown into the `counters` collection
-(`llm.federated_lora` reads them into the round's metrics): `moe_pairs`, the
+`moe.combine`, `moe.shared`; the live-row kernels of the gated unit are
+`moe_swiglu` / `moe_swiglu_bwd`, or `moe_reglu` / `moe_reglu_bwd`.
+Counters, sown into the `counters` collection (`llm.federated_lora` reads
+them into the round's metrics): `moe_pairs`, the
 pairs computed here in this call, `moe_max_rows`, the rows of the fullest
 held expert, `moe_rows_walked`, the buffer rows the dispatch walked
 (`moe_pairs` rounded up to the kernel's row block: over tokens x top_k it
@@ -71,7 +76,10 @@ class MoE:
     the experts this module holds, None for all of them. `scoring` is the
     router's: "sigmoid" scores chosen by score + a selection bias, or
     "softmax" over all experts with no bias. `n_shared` = 0 builds no shared
-    expert."""
+    expert. `act` is the experts' gated unit, "swiglu" (silu(gate) * up) or
+    "reglu" (relu(gate) * up); `route_from` says what the router reads,
+    "ffn" the feed-forward norm's output (the rows the layer multiplies) or
+    "attn" the block's attention norm's output, before attention."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -80,11 +88,17 @@ class MoE:
     scale: float = 1.0           # routed_scaling_factor
     norm_topk: bool = True
     scoring: str = "sigmoid"
+    act: str = "swiglu"
+    route_from: str = "ffn"
 
     def __post_init__(self):
-        if self.scoring not in ("sigmoid", "softmax"):
-            raise ValueError(
-                f"scoring must be 'sigmoid' or 'softmax', got {self.scoring!r}")
+        for field, allowed in (("scoring", ("sigmoid", "softmax")),
+                               ("act", ("swiglu", "reglu")),
+                               ("route_from", ("ffn", "attn"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(
+                    f"{field} must be " + " or ".join(map(repr, allowed))
+                    + f", got {getattr(self, field)!r}")
 
     @property
     def first(self) -> int:
@@ -199,6 +213,43 @@ def _swiglu_bwd(res, dact):
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
+def _relu_mul(gate, up):
+    g, u = gate.astype(jnp.float32), up.astype(jnp.float32)
+    return (jnp.maximum(g, 0.0) * u,)
+
+
+def _relu_mul_grads(gate, up, dact):
+    g, u, da = (a.astype(jnp.float32) for a in (gate, up, dact))
+    return jnp.where(g > 0.0, da * u, 0.0), da * jnp.maximum(g, 0.0)
+
+
+@jax.custom_vjp
+@jax.jit
+def _reglu(gate, up, n_here):
+    """relu(gate) * up on the buffer's live rows, as `_swiglu`."""
+    return routed_rows.live_map(
+        _relu_mul, n_here, [gate, up], [(gate.shape[1:], gate.dtype)],
+        name="moe_reglu")[0]
+
+
+def _reglu_fwd(gate, up, n_here):
+    return _reglu(gate, up, n_here), (gate, up, n_here)
+
+
+@jax.jit
+def _reglu_bwd(res, dact):
+    gate, up, n_here = res
+    dgate, dup = routed_rows.live_map(
+        _relu_mul_grads, n_here, [gate, up, dact],
+        [(gate.shape[1:], gate.dtype), (up.shape[1:], up.dtype)],
+        name="moe_reglu_bwd")
+    return dgate, dup, None
+
+
+_reglu.defvjp(_reglu_fwd, _reglu_bwd)
+_GATED = {"swiglu": _swiglu, "reglu": _reglu}
+
+
 @jax.custom_vjp
 def _twice(xs, n_here):
     """(xs, xs), for the two products that read the buffer: the sum of their
@@ -295,22 +346,29 @@ class ExpertLayer(nn.Module):
     plus the shared expert. `live` [B, T] bool (the decode programs': an
     idle slot's rows, a chunk's padding) routes the other rows nowhere:
     they are neither moved nor multiplied, touch no expert's weights and
-    come back as the shared expert's part alone."""
+    come back as the shared expert's part alone. `route_x` [B, T, d] is
+    what the router reads where the spec's `route_from` is "attn" (the
+    block's attention norm's output); None: the rows `h` themselves."""
     spec: MoE
 
     @nn.compact
-    def __call__(self, h, live=None):
+    def __call__(self, h, live=None, route_x=None):
         sp = self.spec
         d = h.shape[-1]
         rows = h.reshape(-1, d)
         n, k, f = rows.shape[0], sp.top_k, sp.d_expert
+        if (route_x is None) != (sp.route_from == "ffn"):
+            raise ValueError(
+                f"an expert layer routed from {sp.route_from!r} takes "
+                "`route_x` exactly when it reads the attention norm's output")
 
         def expert_kernel(name, din, dout):
             return Kernel((sp.n_held, din, dout), name=f"experts_{name}")()
 
         with jax.named_scope("moe.route"):
             idx, w = route(
-                rows, Kernel((d, sp.n_experts), name="router")(),
+                rows if route_x is None else route_x.reshape(-1, d),
+                Kernel((d, sp.n_experts), name="router")(),
                 self.param("e_score_correction_bias", nn.initializers.zeros,
                            (sp.n_experts,))
                 if sp.scoring == "sigmoid" else None, sp)
@@ -346,7 +404,7 @@ class ExpertLayer(nn.Module):
                                   sizes, sp.n_experts)
             up = grouped_matmul(xs_up, expert_kernel("w_up", d, f), sizes,
                                 sp.n_experts)
-            ys = grouped_matmul(_swiglu(gate, up, n_here),
+            ys = grouped_matmul(_GATED[sp.act](gate, up, n_here),
                                 expert_kernel("w_down", f, d), sizes,
                                 sp.n_experts)
 

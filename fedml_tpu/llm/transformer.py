@@ -166,6 +166,9 @@ class Block(nn.Module):
         norm = functools.partial(RMSNorm, eps=self.norm_eps)
         with jax.named_scope("lm.attn"):
             h = norm()(x)
+            # what a router that reads the input norm's output is handed
+            route_x = (h if self.moe is not None
+                       and self.moe.route_from == "attn" else None)
             if self.latent is not None:
                 o = latent_attention.full_attention(
                     self.latent_params(d_model), h, pos[None], self.latent,
@@ -177,7 +180,8 @@ class Block(nn.Module):
         with jax.named_scope("lm.mlp"):
             h = norm()(x)
             if self.moe is not None:
-                return x + ExpertLayer(self.moe, name="moe")(h)
+                return x + ExpertLayer(self.moe, name="moe")(
+                    h, route_x=route_x)
             gate = _dense(self, self.d_ff, "w_gate")(h)
             up = _dense(self, self.d_ff, "w_up")(h)
             x = x + _dense(self, d_model, "w_down")(nn.silu(gate) * up)
@@ -212,7 +216,9 @@ class TransformerLM(nn.Module):
     # gives each layer's (attention, feed-forward) kind, DENSE_LAYER for
     # every layer when None: "window" layers see the last `window`
     # positions, "full" layers the whole causal half, with rotary
-    # positions only if `rope_full`; "moe" layers hold `moe`'s experts.
+    # positions only if `rope_full`; "moe" layers hold `moe`'s experts
+    # (whose router reads the attention norm's output where the MoE's
+    # `route_from` is "attn", its gated unit `act`).
     n_kv_heads: Optional[int] = None
     head_dim: Optional[int] = None
     norm_eps: float = 1e-6

@@ -37,6 +37,14 @@ layer's and the page table picks this layer's slabs out of it in place):
                            position < pos + C, the window's own keys both
                            ways (a diffusion block over the blocks before
                            it: llm/decode.py `window`)
+    window (static)        W > 0: a window layer's RING (llm/decode.py
+                           `make_paged_kv_decode`): `pages` [S, R] is each
+                           slot's ring, virtual page p of the slot at ring
+                           entry p % R, and query i attends the positions
+                           pos + i - W + 1 .. pos + i alone; R must hold
+                           the pages from the first query's window to the
+                           last query (ceil((W + C) / page_size) + 1 do).
+                           The kernel then runs as `paged_attention_window`
     ->     [S, C, H, Dh]
 
 Grouped heads: the H / KV query heads that share a KV head go through the
@@ -65,7 +73,9 @@ masked here, so null-page garbage is never read into a live result).
 
 Grid: (S,), one step a slot, and inside it a loop over page BLOCKS whose
 trip count is the slot's own: ceil((pos + C) / page_size) pages hold a
-position some query attends, rounded up to `_BLOCK_PAGES`, and 0 for a
+position some query attends (with a window, the pages from the one that
+holds pos - W + 1 to the one that holds pos + C - 1, the walk starting at
+the first), rounded up to `_BLOCK_PAGES`, and 0 for a
 slot that is not `active` (its stale `pos` and table row are never
 looked at; its output row is zeros, which the engine discards). Each
 iteration waits for its block's slabs in one of two VMEM buffers (one
@@ -114,7 +124,7 @@ def _dot(a, b, contract, batch):
 
 def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             block: int, scale: float, quant: bool, queries: int,
-            causal: bool):
+            causal: bool, window: int = 0):
     if quant:
         # int8 pool: this slot's per-(head, page) scales [1, H, max_pages],
         # page-table-gathered by the caller
@@ -131,15 +141,23 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     # THE bound of the walk: blocks that hold a live position of THIS slot
     # (0 for a retired slot — no DMA, no MXU work, a zero output row)
     n_blocks = pl.cdiv(live_ref[s_idx], block)
+    if window:
+        # a ring's walk starts at the page of the first query's first key
+        lo = jnp.maximum(pos - (window - 1), 0) // page_size
 
     def copies(b, buf):
         """The block's 2 * `block` slab DMAs, pool -> VMEM buffer `buf`:
         the page table entry picks each slab straight out of the pool. A
         block's tail past the table re-reads the last entry (any real page
-        is finite; its positions are masked below)."""
+        is finite; its positions are masked below); a ring's tail wraps to
+        pages whose positions lie past the last query."""
         out = []
         for i in range(block):
-            page = pages_ref[s_idx, jnp.minimum(b * block + i, max_pages - 1)]
+            if window:
+                page = pages_ref[s_idx, (lo + b * block + i) % max_pages]
+            else:
+                page = pages_ref[s_idx,
+                                 jnp.minimum(b * block + i, max_pages - 1)]
             out.append(pltpu.make_async_copy(
                 k_hbm.at[page], k_buf.at[buf, i], sems.at[0, buf]))
             out.append(pltpu.make_async_copy(
@@ -195,8 +213,13 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         qpos = pos + (row if c == queries else row % queries)
         vpos = b * t_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, t_blk), 2)
-        seen = vpos <= qpos if causal else vpos < pos + queries
-        s = jnp.where(seen & (vpos < max_pages * page_size), s, _NEG)
+        if window:
+            vpos = vpos + lo * page_size
+            seen = (vpos <= qpos) & (vpos > qpos - window)
+        else:
+            seen = vpos <= qpos if causal else vpos < pos + queries
+            seen = seen & (vpos < max_pages * page_size)
+        s = jnp.where(seen, s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))   # [H, C, 1]
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -218,9 +241,9 @@ def _auto_interpret() -> bool:
     return jax.default_backend() not in ("tpu",)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "causal"))
+@functools.partial(jax.jit, static_argnames=("interpret", "causal", "window"))
 def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
-          causal: bool = True):
+          causal: bool = True, window: int = 0):
     s_, queries, heads, dh = q.shape
     page_size, h = k_pool.shape[1:3]
     group = heads // h
@@ -234,8 +257,13 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
     quant = scales is not None
     # pages that hold a position some query of the slot attends; a retired
     # slot's stale `pos` counts for nothing
-    live = jnp.where(
-        active, jnp.minimum(pl.cdiv(pos + queries, page_size), max_pages), 0)
+    if window:
+        first = jnp.maximum(pos - (window - 1), 0) // page_size
+        live = jnp.where(
+            active, (pos + queries - 1) // page_size - first + 1, 0)
+    else:
+        live = jnp.where(active, jnp.minimum(
+            pl.cdiv(pos + queries, page_size), max_pages), 0)
     slot = pl.BlockSpec((1, c, h, dh), lambda s, *_: (s, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; DMA'd by hand
     in_specs = [slot, pool, pool]
@@ -257,11 +285,12 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, scale=dh ** -0.5,
-                          quant=quant, queries=queries, causal=causal),
+                          quant=quant, queries=queries, causal=causal,
+                          **({"window": window} if window else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, c, h, dh), q.dtype),
         interpret=interpret,
-        name="paged_attention",
+        name="paged_attention_window" if window else "paged_attention",
     )(*operands)
     if group > 1:
         out = out.reshape(s_, group, queries, h, dh).transpose(
@@ -271,7 +300,8 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
 
 def paged_attention(q, k_pool, v_pool, pages, pos,
                     k_scales=None, v_scales=None, active=None,
-                    interpret: bool | None = None, causal: bool = True):
+                    interpret: bool | None = None, causal: bool = True,
+                    window: int = 0):
     """Fused paged decode attention (module docstring has the contract).
 
     q [S, C, H, Dh], k/v pool [P, page_size, KV, Dh], pages [S, max_pages]
@@ -280,11 +310,17 @@ def paged_attention(q, k_pool, v_pool, pages, pos,
     each slab is dequantized in VMEM right after its DMA. `active` [S]
     bool (default: every slot) marks the slots whose rows are wanted: a
     slot that is not active costs no page read and returns zeros.
-    `causal=False`: the window's queries see each other both ways."""
+    `causal=False`: the window's queries see each other both ways.
+    `window` = W > 0: `pages` is each slot's ring and query i attends the
+    last W positions up to pos + i (module docstring)."""
     if interpret is None:
         interpret = _auto_interpret()
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
+    if window and (k_scales is not None or not causal):
+        raise NotImplementedError(
+            "a window layer's ring is causal over bfloat16 pages: int8 "
+            "ring pages and a both-ways window over a ring are not built")
     n_slots = q.shape[0]
     pages = jnp.asarray(pages, jnp.int32)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (n_slots,))
@@ -293,7 +329,8 @@ def paged_attention(q, k_pool, v_pool, pages, pos,
         (n_slots,))
     scales = None if k_scales is None else (k_scales, v_scales)
     return _call(q, k_pool, v_pool, pages, pos, active, scales,
-                 bool(interpret), bool(causal))
+                 bool(interpret), bool(causal),
+                 **({"window": int(window)} if window else {}))
 
 
 # --------------------------------------------------------------------------

@@ -40,6 +40,12 @@ a block past the live count does nothing and re-targets the last live block
 (no copy in, none out); `rows_back` runs a grid over token blocks whose
 loops run over each token's `ok` pairs, packed to the front of its k places
 outside the kernel. On the CPU the same bodies run interpreted.
+
+A row whose slab does not fill whole tiles of the chip's sublanes (d / 128
+pieces not a multiple of 8 rows of 32 bits, 16 of bfloat16: hidden 2,560 is
+20 pieces) cannot be one DMA into a slot of the buffer (Mosaic refuses the
+slice); such rows move by XLA gathers over the whole buffer, the same
+values (`_gathered`).
 """
 from __future__ import annotations
 
@@ -64,6 +70,24 @@ def _slab(d: int) -> tuple:
     """(pieces, lanes) of one row of width d seen as a slab."""
     lanes = 128 if d % 128 == 0 else d
     return d // lanes, lanes
+
+
+def _tiled(d: int, dtype) -> bool:
+    """Whether a row of width d is a slab of whole sublane tiles."""
+    pieces, _ = _slab(d)
+    return pieces % (8 * max(1, 4 // jnp.dtype(dtype).itemsize)) == 0 \
+        or pieces == 1
+
+
+def _gathered(src, idx, ok, w, dy):
+    """`rows_back` / `rows_dots` as XLA gathers over every pair (a row that
+    is not `ok` weighs nothing, whatever it holds)."""
+    g = jnp.where(ok[..., None], src[idx].astype(jnp.float32), 0.0)
+    if dy is not None:
+        return jnp.einsum("nd,nkd->nk", dy.astype(jnp.float32), g)
+    w = jnp.ones(ok.shape, jnp.float32) if w is None else w.astype(
+        jnp.float32)
+    return jnp.sum(w[..., None] * g, axis=1).astype(src.dtype)
 
 
 def rows_block(p: int) -> int:
@@ -201,8 +225,14 @@ def rows_out(x, idx, n_live, scale=None, interpret: bool | None = None):
     `rows_block(P)` are zeros; later blocks are NOT written and hold
     whatever the buffer held (`megablox.gmm` reads no row past the group
     sizes' sum, `rows_back` none that is not `ok`)."""
-    return _rows_out(x, jnp.asarray(idx, jnp.int32), n_live, scale,
-                     _interpret(interpret))
+    idx = jnp.asarray(idx, jnp.int32)
+    if not _tiled(x.shape[1], x.dtype):
+        val = x[idx].astype(jnp.float32)
+        if scale is not None:
+            val = val * scale.astype(jnp.float32)[:, None]
+        live = jnp.arange(idx.shape[0]) < n_live
+        return jnp.where(live[:, None], val, 0.0).astype(x.dtype)
+    return _rows_out(x, idx, n_live, scale, _interpret(interpret))
 
 
 # ------------------------------------------- a map over the live rows' blocks
@@ -211,6 +241,14 @@ def _live_map_kernel(n_ref, *refs, fn, n_in: int, rows: int, chunk: int):
 
     @pl.when((i < pl.cdiv(n_ref[0], rows)) | (i == 0))
     def _block():
+        if chunk % 8:
+            # a piece that starts at no whole sublane tile cannot be read
+            # at a row known only at run time: the block in one piece
+            outs = fn(*(ref[...] for ref in refs[:n_in]))
+            for ref, out in zip(refs[n_in:], outs):
+                ref[...] = out.astype(ref.dtype)
+            return
+
         def body(c, carry):
             at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
             outs = fn(*(ref[at] for ref in refs[:n_in]))
@@ -224,11 +262,16 @@ def live_map(fn, n_live, arrays, outs, *, chunk: int = 16, name: str,
              interpret: bool | None = None):
     """`fn` over the row blocks (`rows_block(P)` rows) that hold a live row,
     block 0 always: arrays [P, ...] in, `outs` ((trailing shape, dtype),
-    ...) out, `fn` seeing `chunk` rows of each at a time. A block past the
-    live count is neither read nor written (it re-targets the last live
-    block), and holds whatever the buffer held."""
+    ...) out, `fn` seeing `chunk` rows of each at a time (the whole block
+    where no whole multiple of 8 rows divides it: a buffer of 6 or 12 rows,
+    one or two tokens at top 6). A block past the live count is neither
+    read nor written (it re-targets the last live block), and holds
+    whatever the buffer held."""
     p = arrays[0].shape[0]
     rows = rows_block(p)
+    chunk = _auto_block(rows, chunk)
+    if chunk % 8:
+        chunk = rows
 
     def spec(trailing):
         zeros = (0,) * len(trailing)
@@ -242,7 +285,7 @@ def live_map(fn, n_live, arrays, outs, *, chunk: int = 16, name: str,
         out_specs=[spec(shape) for shape, _ in outs])
     return pl.pallas_call(
         functools.partial(_live_map_kernel, fn=fn, n_in=len(arrays),
-                          rows=rows, chunk=_auto_block(rows, chunk)),
+                          rows=rows, chunk=chunk),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((p,) + tuple(shape), dtype)
                    for shape, dtype in outs],
@@ -379,10 +422,14 @@ def rows_back(src, idx, ok, w, n_live, interpret: bool | None = None):
     src [P, d] whose first `n_live` rows are live (every `ok` index is below
     it), idx [N, k] int32, ok [N, k] bool, w [N, k] float32 or None for
     ones -> [N, d] in src's dtype. A row of src is read only where `ok`."""
+    if not _tiled(src.shape[1], src.dtype):
+        return _gathered(src, idx, ok, w, None)
     return _rows_back(src, idx, ok, w, None, n_live, _interpret(interpret))
 
 
 def rows_dots(src, idx, ok, dy, n_live, interpret: bool | None = None):
     """`out[n, j] = <dy[n], src[idx[n, j]]>` where `ok`, else 0: [N, k]
     float32 (src, idx, ok, n_live as `rows_back`; dy [N, d])."""
+    if not _tiled(src.shape[1], src.dtype):
+        return _gathered(src, idx, ok, None, dy)
     return _rows_back(src, idx, ok, None, dy, n_live, _interpret(interpret))

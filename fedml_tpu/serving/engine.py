@@ -28,6 +28,25 @@ Shape of the thing:
   prefill, the prefix cache, retirement) is the same for both. The pool of
   per-head rows holds as many KV heads as the model has (grouped heads
   share them), heads of the model's own `head_dim`.
+- WINDOW LAYERS (a model whose `layer_kinds` mix "window" and "full"
+  layers): the page pool and the table above hold the full layers alone,
+  and each window layer keeps its keys and values in a RING of
+  `ring = ceil((window + C) / page_size) + 1` pages a slot (C the widest
+  program's queries: the prefill chunk), the cache leaves `wk` / `wv`
+  `[L_w, 1 + n_slots * ring, page_size, KV, Dh]`: virtual page p of slot s
+  lies at ring page `1 + s * ring + p % ring`, and a page that has left the
+  window is written over as the slot advances (llm/decode.py
+  `make_paged_kv_decode`). A slot holds its ring whatever its context, so
+  the budget check counts the full layers' pages alone, and retirement has
+  no ring page to return. A window layer's pages are private and reused,
+  so prefix sharing on such a model is refused by name, as are int8 pages
+  and a mesh. Counters `serving.engine.window_keys` /
+  `.window_context_keys`: the keys a window layer's queries attend, and
+  those they would with no window (steps and chunks, a query counted once
+  whatever the number of window layers); gauge
+  `serving.engine.window_pages`: the ring pages the window layers hold (the
+  pages a live slot has written, at most `ring` a slot, times the window
+  layers).
 - Admission allocates a request's pages (ceil((prompt+max_new)/page_size),
   reserved up front so a mid-decode slot can never hit page exhaustion)
   from a host free list; retirement returns them. The free list + prefix
@@ -592,6 +611,27 @@ class DecodeEngine:
                 f"chunks); got {prefill_chunk}")
         self._prefill_chunk = int(prefill_chunk)
         self._prefix_on = bool(prefix_cache)
+        # window layers: a ring of pages a slot each (module docstring)
+        self._ring_layers = sum(a == "window" for a, _ in model.kinds)
+        self._window = int(model.window or 0) if self._ring_layers else 0
+        if self._window:
+            for knob, on in (
+                    ("prefix sharing (prefix_cache)", self._prefix_on),
+                    ("kv_quant='int8'", kv_quant == "int8"),
+                    ("a mesh", mesh is not None)):
+                if on:
+                    raise NotImplementedError(
+                        f"{knob} on a model with window layers: a window "
+                        "layer's pages are its slot's private ring, "
+                        "reused as the slot advances, in bfloat16 on one "
+                        "chip (no prompt page outlives its window, no "
+                        "per-page scales, no heads split)")
+            widest = max(self._prefill_chunk or self.max_len,
+                         int(spec_k) + 1 if spec_decode == "ngram" else 1)
+            self._ring = min(-(-(self._window + widest) // self._page_size)
+                             + 1, self._max_pages)
+        else:
+            self._ring = 0
         self._free_pages: list[int] = list(range(1, self._n_pages))
         self._prefix: dict[bytes, _PrefixEntry] = {}
         self._ticks = 0
@@ -664,7 +704,7 @@ class DecodeEngine:
         # programs' products read in place (llm/decode.py lay_out_blocks):
         # a column group per device of the `mp` axis
         self.params = lay_out_blocks(
-            stack_blocks(params, model.n_layers),
+            stack_blocks(params, model.n_layers, bool(self._window)),
             mesh.shape.get("mp", 1) if mesh is not None else 1)
         fused = self.params["blocks"]
         fused = fused.get("wqkv") if isinstance(fused, dict) else None
@@ -741,7 +781,11 @@ class DecodeEngine:
                 eps=model.norm_eps, kernel=self._kernel_on, mesh=mesh,
                 quant=self._quant, rope_base=model.rope_base,
                 head_dim=model.head_dim, qk_norm=model.qk_norm,
-                moe=model.moe if model.has_counters else None, block=B)
+                moe=model.moe if model.has_counters else None, block=B,
+                **({"attn_kinds": tuple(a for a, _ in model.kinds),
+                    "window": self._window, "rope_full": model.rope_full,
+                    "ring": self._ring}
+                   if self._window or not model.rope_full else {}))
         S, eos, max_len_ = self.n_slots, self._eos, self.max_len
 
         def pick(logits, temp, key):
@@ -778,7 +822,8 @@ class DecodeEngine:
             and `limit` is the position after the last one owed."""
             pages = carry["pages"].at[slot].set(row)
             cache, logits, *_ = chunk_fn(params, adapters, carry["cache"],
-                                         row, tokens, t0, clen)
+                                         row, tokens, t0, clen,
+                                         *((slot,) if self._ring else ()))
             key = jax.random.fold_in(jax.random.key(seed), plen)
             first = pick(logits[0], temp, key)
             if B:
@@ -840,7 +885,7 @@ class DecodeEngine:
             pages = carry["pages"].at[slots].set(rows)
             cache, logits, *_ = chunk_batch_fn(
                 params, adapters, carry["cache"], rows, tokens,
-                t0s, clens)
+                t0s, clens, *((slots,) if self._ring else ()))
             keys = jax.vmap(
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
                     seeds, plens)
@@ -1122,7 +1167,8 @@ class DecodeEngine:
                     "engine_admit_many")
 
         head = model.head_dim or model.d_model // model.n_heads
-        z = (model.n_layers, self._n_pages, self._page_size,
+        z = (model.n_layers - self._ring_layers if self._window
+             else model.n_layers, self._n_pages, self._page_size,
              model.n_kv_heads or model.n_heads, head)
         pool_dtype = jnp.int8 if self._quant else kv_dtype
         if latent:
@@ -1133,6 +1179,11 @@ class DecodeEngine:
         else:
             cache = {"k": jnp.zeros(z, pool_dtype),
                      "v": jnp.zeros(z, pool_dtype)}
+        if self._window:
+            # the window layers' rings (page 0 of each layer the null page)
+            zw = (self._ring_layers, 1 + S * self._ring) + z[2:]
+            cache["wk"] = jnp.zeros(zw, kv_dtype)
+            cache["wv"] = jnp.zeros(zw, kv_dtype)
         if self._quant:
             zs = z[:2] + z[3:4]
             cache["ks"] = jnp.zeros(zs, jnp.float32)
@@ -1401,7 +1452,8 @@ class DecodeEngine:
 
     def capacity_error(self, prompt_len: int, max_new: int) -> str:
         """The message submit() raises for an inadmissible request —
-        states the page math so a 400 is actionable."""
+        states the page math so a 400 is actionable (the page pool's: a
+        window layer's ring is its slot's whatever the request)."""
         tot = prompt_len + max_new
         need = -(-tot // self._page_size)
         return (f"prompt {prompt_len} + max_new_tokens {max_new} = {tot} "
@@ -1746,6 +1798,8 @@ class DecodeEngine:
         else:
             adm.t0 += clen
             self._admissions.append(adm)
+        if self._window:
+            self._count_window_pages()
 
     def _advance_admissions_batched(self, pending: deque) -> None:
         """Batched admission (admit_batch > 1): pop up to admit_batch
@@ -1822,6 +1876,8 @@ class DecodeEngine:
                 self._admissions.append(adm)
         if picks:
             pending.append(("admit", self._frame, picks, firsts))
+        if self._window:
+            self._count_window_pages()
 
     @staticmethod
     def _prefilled(ticket: Ticket, final: bool = True) -> None:
@@ -1975,12 +2031,30 @@ class DecodeEngine:
                              self._max_pages)
                 self._count_keys(first, window)
         _mx.inc("serving.engine.page_steps", pages)
+        if self._window:
+            self._count_window_pages()
+
+    def _count_window_pages(self) -> None:
+        """The `serving.engine.window_pages` gauge: the ring pages the
+        window layers hold, the pages each live slot has written (at most
+        its ring) times the window layers."""
+        reach = {a.slot: a.t0 for a in self._admissions}
+        held = 0
+        for i, st in enumerate(self._slots):  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
+            if st is not None:
+                n = reach.get(i, len(st.req.tokens) + len(st.out))
+                held += min(self._ring, -(-n // self._page_size))
+        _mx.set_gauge("serving.engine.window_pages",
+                      held * self._ring_layers)
 
     def _count_keys(self, first: int, n: int) -> None:
         """`n` queries at positions first .. first + n - 1 of one sequence:
         `context_keys` adds the keys each of them sees (its position + 1),
         `selected_keys` those its attention weighs: all of them, or the
-        `index_topk` a latent model's indexer selects. Arithmetic on
+        `index_topk` a latent model's indexer selects; a model with window
+        layers adds to `window_keys` the keys a window layer's query
+        attends (at most the window) and to `window_context_keys` those it
+        sees. Arithmetic on
         positions, the model's own: the latent kernel still WALKS every
         live row under the selection's mask (`page_steps` counts the walk),
         so their ratio is the share of the context that enters the softmax,
@@ -1991,6 +2065,12 @@ class DecodeEngine:
             at = np.arange(first, first + n)
             seen = int(((at // self._block + 1) * self._block).sum())
         _mx.inc("serving.engine.context_keys", seen)
+        if self._window:
+            # a window layer's query at p attends min(p + 1, window) keys
+            at = np.arange(first + 1, first + n + 1)
+            _mx.inc("serving.engine.window_keys",
+                    int(np.minimum(at, self._window).sum()))
+            _mx.inc("serving.engine.window_context_keys", seen)
         if self._topk is None or first + n <= self._topk:
             _mx.inc("serving.engine.selected_keys", seen)
             return

@@ -180,11 +180,13 @@ def start_replica(spec: dict):
         # the recipe's fields ARE TransformerLM's: the dense block's sizes,
         # and where the model departs from it its own norm eps and rope
         # base, grouped KV heads of `head_dim`, per-head q/k norms, a
-        # diffusion block and its mask token, `latent` (llm.latent.Latent's
-        # fields), `moe` (llm.moe.MoE's, `scoring` among them) and
-        # `layer_kinds`, a (attention, feed-forward) pair a layer
+        # diffusion block and its mask token, `window` and `rope_full`,
+        # `latent` (llm.latent.Latent's fields), `moe` (llm.moe.MoE's,
+        # `scoring`, `act` and `route_from` among them) and `layer_kinds`,
+        # a (attention, feed-forward) pair a layer
         plain = ("norm_eps", "rope_base", "n_kv_heads", "head_dim",
-                 "qk_norm", "diffusion_block", "mask_id")
+                 "qk_norm", "diffusion_block", "mask_id", "window",
+                 "rope_full")
         known = {"vocab_size", "d_model", "n_layers", "n_heads", "d_ff",
                  "scan_layers", "max_len", "latent", "moe", "layer_kinds",
                  *plain}
@@ -192,9 +194,8 @@ def start_replica(spec: dict):
             # the keys of a block this replica would silently not build
             raise NotImplementedError(
                 f"start_replica does not build what the lm recipe asks for "
-                f"with {sorted(set(lm) - known)}: window layers and layers "
-                "without rotary positions cannot be served yet "
-                "(llm/decode.py `unserved` says which mechanism each lacks)")
+                f"with {sorted(set(lm) - known)} (llm/decode.py `unserved` "
+                "says which mechanism a model lacks)")
         more = {k: lm[k] for k in plain if k in lm}
         if "latent" in lm:
             from ..llm.latent import Latent
@@ -214,6 +215,14 @@ def start_replica(spec: dict):
             d_model=int(lm["d_model"]), n_layers=int(lm["n_layers"]),
             n_heads=int(lm["n_heads"]), d_ff=int(lm["d_ff"]),
             scan_layers=bool(lm.get("scan_layers", False)), **more)
+        if (any(a == "window" for a, _ in model.kinds)
+                and dict(spec.get("serve", {})).get("prefix_cache", True)):
+            # a window layer's pages are its slot's private ring
+            raise NotImplementedError(
+                "prefix sharing on a model with window layers: a window "
+                "layer's pages are private to its slot and reused as the "
+                "slot advances, so no prompt page can be shared; serve it "
+                "with prefix_cache false")
         # serve knobs go through the SAME mapping as the config route
         # (predictor.lm_predictor_from_serve_knobs) — one source of
         # defaults, the two surfaces cannot drift

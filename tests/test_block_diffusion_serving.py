@@ -71,10 +71,12 @@ def test_the_recipe_builds_the_module_the_builder_made(built, replica):
     assert served == lm and served.diffusion_block == 4
     assert decode.unserved(lm) == []
     assert decode.engine_only(lm) == "generation by diffusion over blocks"
-    with pytest.raises(NotImplementedError, match="window layers and layers "
-                       "without rotary positions cannot be served yet"):
+    # `window` is a recipe field now; a key no field of the
+    # module takes is still refused by name, before anything is built
+    with pytest.raises(NotImplementedError, match="does not build what the "
+                       r"lm recipe asks for with \['sliding_window'\]"):
         start_replica({"model_kind": "lm", "lm": {
-            **spec["lm"], "window": 8}, "params": {}})
+            **spec["lm"], "sliding_window": 8}, "params": {}})
 
 
 def test_the_stream_carries_each_tokens_forward_and_confidence(built,

@@ -71,6 +71,38 @@ def _paged_case(c, dtype, quant, slots=SLOTS):
         (q, pool, pool, pages, pos, sc, sc, active), {"paged_attention"})
 
 
+def _window_case(c):
+    """The window kernel at SmallThinker's widths: 32 slots, 28 query heads
+    over 4 KV heads of 128, each slot's ring of 289 pages of 16 (a window
+    of 4,096 beside a prefill chunk of 512)."""
+    slots, ring = 32, 289
+    q = S((slots, c, 28, DH), jnp.bfloat16)
+    pool = S((1 + slots * ring, PAGE, 4, DH), jnp.bfloat16)
+    return (lambda q, k, v, pg, po, act: paged_attention(
+        q, k, v, pg, po, active=act, window=4096, interpret=False),
+        (q, pool, pool, S((slots, ring), jnp.int32), S((slots,), jnp.int32),
+         S((slots,), jnp.bool_)), {"paged_attention_window"})
+
+
+def _reglu_case(p):
+    """The expert layer's gated unit over a buffer of p rows of 768 (top 6:
+    6 and 12 rows are a prefill chunk of one and of two tokens, no whole
+    multiple of 8 rows; 192 a decode step of 32 slots)."""
+    from fedml_tpu.llm.moe import _reglu
+    from fedml_tpu.ops import routed_rows
+
+    rows = S((p, 768), jnp.bfloat16)
+
+    def fn(gate, up, n):
+        auto, routed_rows._auto_interpret = (routed_rows._auto_interpret,
+                                             lambda: False)
+        try:
+            return _reglu(gate, up, n)
+        finally:
+            routed_rows._auto_interpret = auto
+    return fn, (rows, rows, S((), jnp.int32)), {"moe_reglu"}
+
+
 def _latent_case(kind, slots, c, n_pages):
     """The latent pool's two kernels at GLM-5's widths (64 heads over ONE
     640-wide row a token, 32 index heads of 128), page 16: a decode step of
@@ -137,6 +169,11 @@ CASES = {
     "paged_c4_int8": lambda: _paged_case(4, jnp.bfloat16, True),
     # the serving cell's own step (BENCHMARK.json olmo1b_decode_chat)
     "paged_c1_bf16_s16": lambda: _paged_case(1, jnp.bfloat16, False, slots=16),
+    # a window layer's step (smallthinker_decode_mixed16k)
+    "paged_window_c1_s32": lambda: _window_case(1),
+    "reglu_rows6": lambda: _reglu_case(6),
+    "reglu_rows12": lambda: _reglu_case(12),
+    "reglu_rows192": lambda: _reglu_case(192),
 }
 
 
@@ -168,7 +205,8 @@ def v5e():
     n if n in ("flash_fwd_bwd_bf16", "paged_c1_bf16_s16", "paged_c4_int8",
                "latent_index_step", "latent_attend_step",
                "latent_attend_chunk", "routed_out_silo", "routed_back_silo",
-               "routed_dots_silo")
+               "routed_dots_silo", "paged_window_c1_s32", "reglu_rows6",
+               "reglu_rows12")
     else pytest.param(n, marks=pytest.mark.slow)    # tier-1 is at its cap
     for n in sorted(CASES)])
 def test_kernel_compiles_with_mosaic(name, v5e):

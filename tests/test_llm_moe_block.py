@@ -347,14 +347,14 @@ def test_a_model_that_counts_nothing_keeps_the_rounds_three_metrics():
 
 # ------------------------------------------------------------------ serving
 def test_the_decode_path_refuses_each_mechanism_it_lacks_by_name():
-    lacking = decode.unserved(kexaone_like())
-    # grouped KV heads, per-head q/k norms and experts under full attention
-    # are served since PR 36; what remains:
-    assert [s.split(":")[0] for s in lacking] == [
-        "window layers", "layers without rotary positions"]
-    with pytest.raises(NotImplementedError, match="window layers.*"
-                       "layers without rotary positions"):
-        decode.require_servable(kexaone_like())
+    # grouped KV heads, per-head q/k norms, experts, window layers and full
+    # layers without rotary positions are all served by the engine; the
+    # per-request pair says by name what it cannot
+    assert decode.unserved(kexaone_like()) == []
+    decode.require_servable(kexaone_like())
+    assert decode.engine_only(kexaone_like()) == (
+        "window layers or full layers without rotary positions; grouped KV "
+        "heads, per-head q/k norms or experts")
     gqa = TransformerLM(vocab_size=8, d_model=32, n_heads=4, n_kv_heads=2)
     assert decode.unserved(gqa) == []
     assert decode.engine_only(gqa).startswith("grouped KV heads")
@@ -368,9 +368,13 @@ def test_serving_entry_points_refuse_the_model_before_building_anything():
     lm = kexaone_like()
     with pytest.raises(NotImplementedError, match="rotary positions"):
         GreedyLMPredictor(lm, {})
+    # the engine serves the model, but not with shared prefix pages
     with pytest.raises(NotImplementedError, match="window layers"):
         DecodeEngine(lm, {}, n_slots=2, max_len=16)
-    with pytest.raises(NotImplementedError, match=r"\['rope_full'\]"):
+    # the recipe carries `rope_full`; the per-request path
+    # refuses the model by the mechanism it lacks
+    with pytest.raises(NotImplementedError, match="full layers without "
+                       "rotary positions.*served by the decode engine only"):
         start_replica({"model_kind": "lm", "params": {}, "lm": {
             "vocab_size": 8, "d_model": 32, "n_layers": 1, "n_heads": 4,
             "d_ff": 64, "n_kv_heads": 2, "rope_full": False}})
