@@ -43,7 +43,10 @@ layer's and the page table picks this layer's slabs out of it in place):
                            entry p % R, and query i attends the positions
                            pos + i - W + 1 .. pos + i alone; R must hold
                            the pages from the first query's window to the
-                           last query (ceil((W + C) / page_size) + 1 do).
+                           last query (ceil((W + C) / page_size) + 1 do),
+                           and each row must be CONSECUTIVE pool pages (as
+                           `ring_pages` lays them out): a block of the walk
+                           that stays inside the ring is read as one range.
                            The kernel then runs as `paged_attention_window`
     ->     [S, C, H, Dh]
 
@@ -52,7 +55,7 @@ MXU TOGETHER against that head's slab: outside the kernel q is laid out
 `[S, (H / KV) * C, KV, Dh]` (rows ordered group-major), so one DMA of K and
 V a page serves the whole group and a score tile has `(H / KV) * C` rows
 where the dense block's has C. With KV == H nothing moves and the program
-is the dense block's.
+is the dense block's. (A ring is read otherwise: `_window_kernel`.)
 
 With an int8 pool (`kv_quant: int8`), the per-(page, head) f32 scales
 [P, H] are gathered through the page table OUTSIDE the kernel into
@@ -75,7 +78,8 @@ Grid: (S,), one step a slot, and inside it a loop over page BLOCKS whose
 trip count is the slot's own: ceil((pos + C) / page_size) pages hold a
 position some query attends (with a window, the pages from the one that
 holds pos - W + 1 to the one that holds pos + C - 1, the walk starting at
-the first), rounded up to `_BLOCK_PAGES`, and 0 for a
+the first), rounded up to `_BLOCK_PAGES` (a ring's walk: to
+`_WINDOW_BLOCK_PAGES`), and 0 for a
 slot that is not `active` (its stale `pos` and table row are never
 looked at; its output row is zeros, which the engine discards). Each
 iteration waits for its block's slabs in one of two VMEM buffers (one
@@ -84,7 +88,12 @@ into the other, so the walk costs what is LIVE — pages past a slot's
 last query are neither copied nor computed, where a grid over
 (S, max_pages) paid a grid step for every table entry (PERF.md section
 6, PR 31). A block's tail past the live pages re-reads table entries
-the mask then discards. The (m, l, o) state is the loop's carry.
+the mask then discards. A page table block is `block` DMAs of K and `block`
+of V, one a page; a ring block is ONE of each over its consecutive pages,
+but for the one block of a walk that runs past the ring's last page, which
+wraps page by page into the same buffer (a wait is by byte count, so one
+descriptor of the whole buffer waits for either). The (m, l, o) state is
+the loop's carry.
 Scores/accumulation are f32; matmuls run in the input dtype with f32
 accumulation (bf16 pools keep full MXU rate).
 
@@ -111,6 +120,28 @@ _NEG = -1e30
 # key tile. Fixed from the chip's three-fill table (PERF.md section 6,
 # PR 31); tables shorter than a block take one block of the whole table.
 _BLOCK_PAGES = 8
+# Ring pages a block of the window walk (`_window_kernel`), fixed from the
+# chip's table at 8 / 16 / 32 / 64 (PERF.md section 6); a ring
+# shorter than a block takes one block of the whole ring.
+_WINDOW_BLOCK_PAGES = 32
+
+
+def window_block_pages(ring: int) -> int:
+    """Pages a block of the window kernel's walk over a ring of `ring`."""
+    return min(_WINDOW_BLOCK_PAGES, ring)
+
+
+def ring_walk(first: int, queries: int, window: int, page_size: int,
+              ring: int) -> tuple[int, int]:
+    """(blocks, split blocks) of the window kernel's walk for `queries`
+    queries from position `first` over a ring of `ring` pages: host
+    arithmetic on positions, the kernel's own. A split block runs past the
+    ring's last page and is copied page by page; every other block is one
+    K and one V DMA."""
+    block = window_block_pages(ring)
+    lo = max(first - (window - 1), 0) // page_size
+    n = -(-((first + queries - 1) // page_size - lo + 1) // block)
+    return n, sum((lo + b * block) % ring + block > ring for b in range(n))
 
 
 def _dot(a, b, contract, batch):
@@ -124,7 +155,7 @@ def _dot(a, b, contract, batch):
 
 def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             block: int, scale: float, quant: bool, queries: int,
-            causal: bool, window: int = 0):
+            causal: bool):
     if quant:
         # int8 pool: this slot's per-(head, page) scales [1, H, max_pages],
         # page-table-gathered by the caller
@@ -141,23 +172,15 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     # THE bound of the walk: blocks that hold a live position of THIS slot
     # (0 for a retired slot — no DMA, no MXU work, a zero output row)
     n_blocks = pl.cdiv(live_ref[s_idx], block)
-    if window:
-        # a ring's walk starts at the page of the first query's first key
-        lo = jnp.maximum(pos - (window - 1), 0) // page_size
 
     def copies(b, buf):
         """The block's 2 * `block` slab DMAs, pool -> VMEM buffer `buf`:
         the page table entry picks each slab straight out of the pool. A
         block's tail past the table re-reads the last entry (any real page
-        is finite; its positions are masked below); a ring's tail wraps to
-        pages whose positions lie past the last query."""
+        is finite; its positions are masked below)."""
         out = []
         for i in range(block):
-            if window:
-                page = pages_ref[s_idx, (lo + b * block + i) % max_pages]
-            else:
-                page = pages_ref[s_idx,
-                                 jnp.minimum(b * block + i, max_pages - 1)]
+            page = pages_ref[s_idx, jnp.minimum(b * block + i, max_pages - 1)]
             out.append(pltpu.make_async_copy(
                 k_hbm.at[page], k_buf.at[buf, i], sems.at[0, buf]))
             out.append(pltpu.make_async_copy(
@@ -213,12 +236,8 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         qpos = pos + (row if c == queries else row % queries)
         vpos = b * t_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, t_blk), 2)
-        if window:
-            vpos = vpos + lo * page_size
-            seen = (vpos <= qpos) & (vpos > qpos - window)
-        else:
-            seen = vpos <= qpos if causal else vpos < pos + queries
-            seen = seen & (vpos < max_pages * page_size)
+        seen = vpos <= qpos if causal else vpos < pos + queries
+        seen = seen & (vpos < max_pages * page_size)
         s = jnp.where(seen, s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))   # [H, C, 1]
         p = jnp.exp(s - m_new)
@@ -237,13 +256,136 @@ def _kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         o_ref.dtype)
 
 
+def _window_kernel(pages_ref, live_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, *, block: int, page_size: int,
+                   heads: int, scale: float, window: int):
+    """`paged_attention_window`: one slot's walk over its ring, from the
+    page of the first query's first key. The pool rides in as
+    `[P, page_size * KV, Dh]` (a page's rows position-major, KV head minor:
+    a bitcast of the pool) and q as `[C * H, Dh]` (query c's head h at row
+    c * H + h), so ONE product of every query row with every key row of a
+    block scores all heads at once, each row keeping the keys of its own KV
+    head (key row r is KV head r % KV) and no operand changing its layout in
+    VMEM (PERF.md section 6)."""
+    s_idx = pl.program_id(0)
+    pos = pos_ref[s_idx]
+    ring = pages_ref.shape[1]
+    rows, (kv_rows, dh) = q_ref.shape[1], k_buf.shape[2:]
+    kv = kv_rows // page_size
+    t_rows = block * kv_rows
+    n_blocks = pl.cdiv(live_ref[s_idx], block)
+    lo = jnp.maximum(pos - (window - 1), 0) // page_size
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def whole(buf, first=0):
+        """K and V copies of `block` consecutive pool pages from `first`
+        into buffer `buf`: ONE DMA each, and the byte count every wait for
+        the buffer is made by, however its pages were started."""
+        return [pltpu.make_async_copy(pool.at[pl.ds(first, block)],
+                                      dst.at[buf], sems.at[i, buf])
+                for i, (pool, dst) in enumerate(pools)]
+
+    def start(b, buf):
+        # a ring's pages are consecutive pool pages (llm/decode.py
+        # `ring_pages`), so a block that stays inside the ring is one range
+        # of the pool; the one block of a walk that runs past the ring's
+        # last page wraps to its first page by page (its tail's positions
+        # lie past the last query and are masked below)
+        j = (lo + b * block) % ring
+
+        @pl.when(j + block <= ring)
+        def _whole():
+            for cp in whole(buf, pages_ref[s_idx, j]):
+                cp.start()
+
+        @pl.when(j + block > ring)
+        def _split():
+            for i in range(block):
+                page = pages_ref[s_idx, (j + i) % ring]
+                for n, (pool, dst) in enumerate(pools):
+                    pltpu.make_async_copy(pool.at[page], dst.at[buf, i],
+                                          sems.at[n, buf]).start()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[0]                                       # [C * H, Dh]
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    qpos = pos + row // heads
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, t_rows), 1)
+    own = (row % heads) // (heads // kv) == col % kv   # [C * H, T]
+
+    def body(b, carry):
+        m, l, o = carry
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():                                   # double buffering
+            start(b + 1, 1 - buf)
+
+        for cp in whole(buf):
+            cp.wait()
+        kb = k_buf[buf].reshape(t_rows, dh)
+        vb = v_buf[buf].reshape(t_rows, dh)
+        s = _dot(q, kb, ((1,), (1,)), ((), ())) * scale          # [C * H, T]
+        kpos = (lo + b * block) * page_size + col // kv
+        s = jnp.where(own & (kpos <= qpos) & (kpos > qpos - window), s, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        o_new = o * corr + _dot(p.astype(vb.dtype), vb, ((1,), (0,)), ((), ()))
+        return m_new, l_new, o_new
+
+    _, l, o = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.full((rows, 1), _NEG, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+        jnp.zeros((rows, dh), jnp.float32)))
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
 def _auto_interpret() -> bool:
     return jax.default_backend() not in ("tpu",)
+
+
+def _window_call(q, k_pool, v_pool, pages, pos, active, window: int,
+                 interpret: bool):
+    """`paged_attention_window` over each slot's ring (`_window_kernel`)."""
+    s_, queries, heads, dh = q.shape
+    n_pages, page_size, kv = k_pool.shape[:3]
+    ring = pages.shape[1]
+    block = window_block_pages(ring)
+    # the ring pages from the first query's first key to the last query
+    first = jnp.maximum(pos - (window - 1), 0) // page_size
+    live = jnp.where(active, (pos + queries - 1) // page_size - first + 1, 0)
+    rows = pl.BlockSpec((1, queries * heads, dh), lambda s, *_: (s, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; DMA'd by hand
+    buf = pltpu.VMEM((2, block, page_size * kv, dh), k_pool.dtype)
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, block=block, page_size=page_size,
+                          heads=heads, scale=dh ** -0.5, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,     # ring pages, live page counts, pos
+            grid=(s_,),
+            in_specs=[rows, pool, pool],
+            out_specs=rows,
+            scratch_shapes=[buf, buf,                  # K, V double buffers
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((s_, queries * heads, dh), q.dtype),
+        interpret=interpret,
+        name="paged_attention_window",
+    )(pages, live, pos, q.reshape(s_, queries * heads, dh),
+      *(p.reshape(n_pages, page_size * kv, dh) for p in (k_pool, v_pool)))
+    return out.reshape(s_, queries, heads, dh)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "causal", "window"))
 def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
           causal: bool = True, window: int = 0):
+    if window:
+        return _window_call(q, k_pool, v_pool, pages, pos, active, window,
+                            interpret)
     s_, queries, heads, dh = q.shape
     page_size, h = k_pool.shape[1:3]
     group = heads // h
@@ -257,13 +399,8 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
     quant = scales is not None
     # pages that hold a position some query of the slot attends; a retired
     # slot's stale `pos` counts for nothing
-    if window:
-        first = jnp.maximum(pos - (window - 1), 0) // page_size
-        live = jnp.where(
-            active, (pos + queries - 1) // page_size - first + 1, 0)
-    else:
-        live = jnp.where(active, jnp.minimum(
-            pl.cdiv(pos + queries, page_size), max_pages), 0)
+    live = jnp.where(active, jnp.minimum(
+        pl.cdiv(pos + queries, page_size), max_pages), 0)
     slot = pl.BlockSpec((1, c, h, dh), lambda s, *_: (s, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; DMA'd by hand
     in_specs = [slot, pool, pool]
@@ -285,12 +422,11 @@ def _call(q, k_pool, v_pool, pages, pos, active, scales, interpret: bool,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, scale=dh ** -0.5,
-                          quant=quant, queries=queries, causal=causal,
-                          **({"window": window} if window else {})),
+                          quant=quant, queries=queries, causal=causal),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, c, h, dh), q.dtype),
         interpret=interpret,
-        name="paged_attention_window" if window else "paged_attention",
+        name="paged_attention",
     )(*operands)
     if group > 1:
         out = out.reshape(s_, group, queries, h, dh).transpose(

@@ -46,7 +46,11 @@ Shape of the thing:
   whatever the number of window layers); gauge
   `serving.engine.window_pages`: the ring pages the window layers hold (the
   pages a live slot has written, at most `ring` a slot, times the window
-  layers).
+  layers). With `paged_kernel`, counters `serving.engine.window_blocks` /
+  `.window_split_blocks`: the blocks of the window kernel's ring walks in
+  drained step frames, and those of them that run past the ring's last
+  page and are copied page by page (`ops.paged_attention.ring_walk`;
+  every other block is one K and one V DMA).
 - Admission allocates a request's pages (ceil((prompt+max_new)/page_size),
   reserved up front so a mid-decode slot can never hit page exhaustion)
   from a host free list; retirement returns them. The free list + prefix
@@ -216,6 +220,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.paged_attention import ring_walk
 from ..utils import enable_compilation_cache
 from ..utils import metrics as _mx
 from ..utils import xla_ledger as _ledger
@@ -2021,7 +2026,8 @@ class DecodeEngine:
         slots = np.nonzero(live)[0]
         _mx.inc("serving.engine.steps")
         _mx.inc("serving.engine.slot_steps", len(slots))
-        pages = 0
+        pages = blocks = split = 0
+        walked = self._window and self._kernel_on
         for slot in slots:
             st = self._slots[slot]  # graftlint: disable=lock-discipline (engine-thread owned; see ownership note above _next_tick)
             if st is not None:
@@ -2030,7 +2036,16 @@ class DecodeEngine:
                 pages += min(-(-(first + window) // self._page_size),
                              self._max_pages)
                 self._count_keys(first, window)
+                if walked:
+                    n, cut = ring_walk(first, window, self._window,
+                                       self._page_size, self._ring)
+                    blocks, split = blocks + n, split + cut
         _mx.inc("serving.engine.page_steps", pages)
+        if walked:
+            # the window kernel's ring walk, once a frame whatever the
+            # number of window layers
+            _mx.inc("serving.engine.window_blocks", blocks)
+            _mx.inc("serving.engine.window_split_blocks", split)
         if self._window:
             self._count_window_pages()
 

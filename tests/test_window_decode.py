@@ -25,6 +25,7 @@ from chipbench.reference import smallthinker_21b_pp7 as ref
 from fedml_tpu.llm import decode
 from fedml_tpu.llm.moe import MoE, _reglu
 from fedml_tpu.llm.transformer import TransformerLM
+from fedml_tpu.ops import paged_attention as pa
 from fedml_tpu.ops import routed_rows as rr
 from fedml_tpu.ops.paged_attention import paged_attention
 
@@ -49,20 +50,29 @@ def tiny():
     return lm, params, tokens, want
 
 
-def run_programs(lm, params, tokens, kernel: bool) -> np.ndarray:
-    """Logits [T - PLEN + 1, V] the engine's programs give: the prompt's
-    PLEN tokens by `chunk` in chunks of C into slot 1, then a `step` a token
-    (teacher-forced) with slot 0 idle."""
-    chunk, step, _verify, _batch = decode.make_paged_kv_decode(
+def programs(lm, kernel: bool):
+    return decode.make_paged_kv_decode(
         lm.n_heads, PS, eps=lm.norm_eps, kernel=kernel,
         rope_base=lm.rope_base, head_dim=lm.head_dim, moe=lm.moe,
         attn_kinds=tuple(a for a, _ in lm.kinds), window=W,
         rope_full=False, ring=RING)
+
+
+def empty_cache() -> dict:
+    """The two global layers' page pool and the six window layers' rings."""
     pool = S * MAX_PAGES + 1
-    cache = {"k": jnp.zeros((2, pool, PS, 2, 16)),
-             "v": jnp.zeros((2, pool, PS, 2, 16)),
-             "wk": jnp.zeros((6, 1 + S * RING, PS, 2, 16)),
-             "wv": jnp.zeros((6, 1 + S * RING, PS, 2, 16))}
+    return {"k": jnp.zeros((2, pool, PS, 2, 16)),
+            "v": jnp.zeros((2, pool, PS, 2, 16)),
+            "wk": jnp.zeros((6, 1 + S * RING, PS, 2, 16)),
+            "wv": jnp.zeros((6, 1 + S * RING, PS, 2, 16))}
+
+
+def run_programs(lm, params, tokens, kernel: bool) -> np.ndarray:
+    """Logits [T - PLEN + 1, V] the engine's programs give: the prompt's
+    PLEN tokens by `chunk` in chunks of C into slot 1, then a `step` a token
+    (teacher-forced) with slot 0 idle."""
+    chunk, step, _verify, _batch = programs(lm, kernel)
+    cache = empty_cache()
     stacked = decode.stack_blocks(params, lm.n_layers, True)
     assert isinstance(stacked["blocks"], tuple)
     slot = 1
@@ -146,24 +156,62 @@ def test_a_stacked_tree_is_refused_for_a_model_of_two_kinds(tiny):
 
 
 # --------------------------------------------------------- the window kernel
-@pytest.mark.parametrize("queries", [1, 3])
-def test_the_window_kernel_is_a_gather_over_the_same_ring(queries):
+# Positions of four slots (and which are live) over a window of 8 (the
+# kernel's own block, one of the whole ring) and of 64 at page 4 in blocks
+# of 4 pages: a ring of 18 pages, four blocks and a half, so that a walk
+# has whole blocks (one DMA of K and one of V) and, past the first window,
+# one block that runs past the ring's last page (copied page by page).
+WALKS = {
+    "phases": (8, None, [2, 9, 23, 40], [True, True, False, True]),
+    "mid_block": (64, 4, [86, 101, 150, 230], None),
+    "ring_end": (64, 4, [75, 139, 143, 300], None),
+    "short": (64, 4, [0, 5, 9, 12], None),
+    "exact_window": (64, 4, [63, 63, 127, 255], None),
+    "phases_64": (64, 4, [2, 63, 85, 200], [True, True, False, True]),
+}
+
+
+@pytest.mark.parametrize("queries, case", [(1, "phases"), (3, "phases")] + [
+    (queries, case) for case in list(WALKS)[1:] for queries in (1, 3)],
+    ids=["1", "3"] + [f"{case}-{queries}" for case in list(WALKS)[1:]
+                      for queries in (1, 3)])
+def test_the_window_kernel_is_a_gather_over_the_same_ring(queries, case,
+                                                          monkeypatch):
     """Random pages in a pool of rings, four slots at positions before, at
-    and well past the ring's first turn, one of them retired: the kernel's
-    rows equal attention over the keys pos + i - W + 1 .. pos + i gathered
-    in virtual order from the ring."""
-    heads, kv, dh, ps, w = 4, 2, 8, 4, 8
+    and well past the ring's first turn (one retired where the case says):
+    the kernel's rows equal attention over the keys pos + i - W + 1 ..
+    pos + i gathered in virtual order from the ring."""
+    w, block, pos, active = WALKS[case]
+    heads, kv, dh, ps = 4, 2, 8, 4
     ring = -(-(w + queries) // ps) + 1
     slots = 4
+    if block:
+        monkeypatch.setattr(pa, "_WINDOW_BLOCK_PAGES", block)
+    block = pa.window_block_pages(ring)
     ks = jax.random.split(jax.random.key(3), 3)
     k_pool = jax.random.normal(ks[0], (1 + slots * ring, ps, kv, dh))
     v_pool = jax.random.normal(ks[1], (1 + slots * ring, ps, kv, dh))
     q = jax.random.normal(ks[2], (slots, queries, heads, dh))
     table = 1 + np.arange(slots)[:, None] * ring + np.arange(ring)[None]
-    pos = np.array([2, 9, 23, 40], np.int32)
-    active = np.array([True, True, False, True])
-    got = np.asarray(paged_attention(q, k_pool, v_pool, table, pos,
-                                     active=active, window=w))
+    pos = np.array(pos, np.int32)
+    active = np.array(active or [True] * slots)
+    walks = [pa.ring_walk(int(p), queries, w, ps, ring) for p in pos[active]]
+    if case == "mid_block":
+        assert all((p - w + 1) // ps % block for p in pos)
+    if case in ("mid_block", "ring_end", "phases_64"):
+        # both DMA plans: whole blocks, and a block split at the ring's end
+        assert any(n > cut for n, cut in walks)
+        assert any(cut for _n, cut in walks)
+    if case == "short":
+        assert pos.max() + queries <= block * ps and walks == [(1, 0)] * 4
+    if case == "exact_window":
+        assert pos[0] + 1 == w
+    pa._call.clear_cache()         # the block is read when `_call` traces
+    try:
+        got = np.asarray(paged_attention(q, k_pool, v_pool, table, pos,
+                                         active=active, window=w))
+    finally:
+        pa._call.clear_cache()
     for s in np.nonzero(active)[0]:
         for i in range(queries):
             at = pos[s] + i
@@ -177,7 +225,40 @@ def test_the_window_kernel_is_a_gather_over_the_same_ring(queries):
                 p = np.exp(sc - sc.max())
                 want = (p / p.sum()) @ vv[:, h // (heads // kv)]
                 assert np.abs(got[s, i, h] - want).max() < 1e-4
-    assert not got[2].any()                      # a retired slot: zeros
+    for s in np.nonzero(~active)[0]:
+        assert not got[s].any()                  # a retired slot: zeros
+
+
+def test_the_step_hands_the_window_kernel_consecutive_ring_pages(
+        tiny, monkeypatch):
+    """The window kernel reads a block of ring pages as ONE range of the
+    pool, so every row of the ring table the step hands it (`ring_pages`
+    over arange(R), the layer's base included) must be consecutive page
+    ids: row s of window layer l runs from l * (1 + S * R) + 1 + s * R."""
+    lm, params, tokens, _want = tiny
+    seen, real = [], pa.paged_attention
+
+    def spy(q, k, v, pages, pos, *rest, window=0, **kw):
+        if window:
+            jax.debug.callback(lambda p: seen.append(np.asarray(p)), pages)
+        return real(q, k, v, pages, pos, *rest, window=window, **kw)
+
+    monkeypatch.setattr(pa, "paged_attention", spy)
+    _chunk, step, _verify, _batch = programs(lm, kernel=True)
+    pages = np.zeros((S, MAX_PAGES), np.int32)
+    pages[1] = 1 + MAX_PAGES + np.arange(MAX_PAGES)
+    jax.block_until_ready(jax.jit(step)(
+        decode.stack_blocks(params, lm.n_layers, True), None, empty_cache(),
+        pages, np.array([0, 30], np.int32), np.array([0, tokens[30]],
+                                                     np.int32),
+        np.array([False, True])))
+    assert len(seen) == 6                        # one a window layer
+    bases = sorted(int(t[0, 0]) - 1 for t in seen)
+    assert bases == [l * (1 + S * RING) for l in range(6)]
+    for t in seen:
+        assert t.shape == (S, RING)
+        assert (t == t[0, 0] + np.arange(S)[:, None] * RING
+                + np.arange(RING)).all()
 
 
 def test_the_window_kernel_refuses_what_it_is_not():

@@ -12,6 +12,7 @@ import pytest
 
 from chipbench import inputs, manifest
 from chipbench.reference import smallthinker_21b_pp7 as ref
+from fedml_tpu.ops.paged_attention import ring_walk, window_block_pages
 from fedml_tpu.serving.engine import DecodeEngine
 from fedml_tpu.utils import metrics as mx
 
@@ -66,15 +67,31 @@ def serve_all(lm, params, prompts, **kw):
         eng.stop()
 
 
+def counted(tiny, **kw):
+    """serve_all, and what it added to the engine's counters."""
+    lm, params, prompts, _greedy = tiny
+    before = mx.snapshot()["counters"]
+    got, eng, held = serve_all(lm, params, prompts, **kw)
+    after = mx.snapshot()["counters"]
+    delta = {k[len("serving.engine."):]: after[k] - before.get(k, 0)
+             for k in after if k.startswith("serving.engine.")}
+    return got, eng, held, delta
+
+
+@pytest.fixture(scope="module")
+def with_kernel(tiny):
+    return counted(tiny, paged_kernel=True)
+
+
 @pytest.mark.parametrize("kw", [
     {"paged_kernel": True},
     {"admit_batch": 2, "spec_decode": "ngram", "spec_k": 2}],
     ids=["kernel", "gather-batched-spec"])
-def test_greedy_tokens_over_several_windows_are_the_references(tiny, kw):
-    lm, params, prompts, greedy = tiny
-    before = mx.snapshot()["counters"]
-    got, eng, held = serve_all(lm, params, prompts, **kw)
-    after = mx.snapshot()["counters"]
+def test_greedy_tokens_over_several_windows_are_the_references(
+        tiny, with_kernel, kw):
+    greedy = tiny[3]
+    got, eng, held, delta = (with_kernel if kw.get("paged_kernel")
+                             else counted(tiny, **kw))
     assert got == greedy
     # each slot owns a ring of ceil((W + C) / page) + 1 pages, whatever
     # its context; the window layers never hold more
@@ -87,15 +104,31 @@ def test_greedy_tokens_over_several_windows_are_the_references(tiny, kw):
     # gauge reached a whole ring for it
     assert max(held) >= 3 * RING
     if "spec_k" in kw:
+        # the gather walks no ring through the kernel
+        assert delta.get("window_blocks", 0) == 0
         return
     # the window counters: a query at every position but each request's
     # last token's, attending at most the window in a window layer
-    delta = {k: after.get(f"serving.engine.{k}", 0)
-             - before.get(f"serving.engine.{k}", 0)
-             for k in ("window_keys", "window_context_keys", "context_keys")}
     at = np.concatenate([np.arange(1, n + new) for n, new in PROMPTS])
     assert delta["window_context_keys"] == delta["context_keys"] == at.sum()
     assert delta["window_keys"] == np.minimum(at, W).sum()
+
+
+def test_the_window_walk_counters_follow_the_positions(with_kernel):
+    """`window_blocks` / `window_split_blocks`: the blocks of the window
+    kernel's ring walk over every step a request takes (its first token is
+    the prefill's; a step's query sits on each later token's predecessor,
+    positions n .. n + new - 2), and those that run past the ring's last
+    page, by the kernel's own arithmetic on positions. The ring of 5 pages
+    is one block here, so a walk past the first window splits."""
+    delta = with_kernel[3]
+    walks = [ring_walk(p, 1, W, PS, RING) for n, new in PROMPTS
+             for p in range(n, n + new - 1)]
+    assert window_block_pages(RING) == RING
+    assert delta["window_blocks"] == sum(b for b, _ in walks) > 0
+    assert delta["window_split_blocks"] == sum(c for _, c in walks) > 0
+    # a walk that starts at the ring's first page is one whole block
+    assert any(b and not c for b, c in walks)
 
 
 def test_what_a_model_with_window_layers_refuses_by_name(tiny):
